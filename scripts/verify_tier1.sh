@@ -301,8 +301,8 @@ if ! grep -q 'obs_device\.compiled(\s*$\|obs_device\.compiled(' \
     rc15=1
 fi
 # PR 17's widened fused tier: the chained agg→top-N stage-2 builder is
-# the newest program family — it must compile (and donate the stage-1
-# buffers) through the ledger, never via a bare jit
+# the newest program family — it must compile through the ledger,
+# never via a bare jit
 if ! grep -q '"fused_chain"' serenedb_tpu/exec/device_pipeline.py || \
         ! grep -q 'obs_device\.compiled(' \
             serenedb_tpu/exec/device_pipeline.py; then

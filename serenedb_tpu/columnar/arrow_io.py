@@ -21,10 +21,11 @@ def batch_to_arrow(batch: Batch) -> pa.RecordBatch:
     fields = []
     for name, col in zip(batch.names, batch.columns):
         mask = ~col.validity if col.validity is not None else None
-        if col.type.is_string:
-            strs = col.dictionary.astype(str)[col.data] if \
-                col.dictionary is not None else col.data.astype(str)
-            arr = pa.array(strs, type=pa.string(), mask=mask)
+        if col.type.is_string and col.dictionary is not None:
+            arr = _decode_dictionary(col, mask)
+        elif col.type.is_string:
+            arr = pa.array(col.data.astype(str), type=pa.string(),
+                           mask=mask)
         elif col.type.id is dt.TypeId.TIMESTAMP:
             arr = pa.array(col.data, type=pa.timestamp("us"), mask=mask)
         elif col.type.id is dt.TypeId.DATE:
@@ -34,6 +35,22 @@ def batch_to_arrow(batch: Batch) -> pa.RecordBatch:
         arrays.append(arr)
         fields.append(pa.field(name, arr.type))
     return pa.RecordBatch.from_arrays(arrays, schema=pa.schema(fields))
+
+
+def _decode_dictionary(col: Column, mask) -> pa.Array:
+    """A dictionary-coded VARCHAR column as ONE arrow string array,
+    decoded by arrow from (codes, dictionary) — work proportional to
+    the distinct values plus the output, never a fixed-width unicode
+    gather over every row (which pyarrow also hands back CHUNKED past a
+    size threshold, and a RecordBatch takes no chunked column). 32-bit
+    offsets cap a `string` array at 2 GiB of data; past that the column
+    travels as `large_string`, which every reader here accepts."""
+    values = pa.array(col.dictionary, type=pa.large_string())
+    arr = values.take(pa.array(col.data, mask=mask))  # null code → null
+    data = arr.buffers()[2]
+    if data is None or data.size < (1 << 31) - 1:
+        arr = arr.cast(pa.string())
+    return arr
 
 
 def batch_to_bytes(batch: Batch) -> bytes:

@@ -173,9 +173,8 @@ def to_device_column(col: Column, pad_multiple: int = BLOCK_ROWS) -> DeviceColum
             int(data2d.size * data2d.dtype.itemsize) + int(mask2d.size),
             _obsdev.array_device_ids(data2d),
             _time.perf_counter_ns() - t0)
-    # note that the backend is up so serene_shard_combine=auto's PASSIVE
-    # device-count probe (parallel/mesh.py) works even across
-    # jax-internal drift
+    # note that the backend is up: serene_shard_combine=auto's PASSIVE
+    # device-count probe (parallel/mesh.py) reads this flag
     from ..parallel import mesh as _mesh
     _mesh.note_backend_initialized()
     return DeviceColumn(col.type, data2d, mask2d, n, scheme, offset)

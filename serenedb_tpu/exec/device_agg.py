@@ -562,7 +562,7 @@ def _mesh_wrap(program, mesh_n: int, combines: list, n_inputs: int):
     (reference analog: morsel-parallel pipelines re-expressed as XLA
     collectives — SURVEY.md §2.11/§5.7). Returns the un-jitted wrapped
     callable — the obs/device compile ledger owns the jit."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from ..parallel.mesh import AXIS, apply_axis_combines, data_mesh

@@ -1924,7 +1924,7 @@ def _run_fused_collective(node, probe: _Side, build: _Side, pscan,
                           build_outs_for, bstart: dict, bmm_sis: list,
                           tspan) -> Batch:
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from ..columnar.device import host_tile_arrays
@@ -2078,12 +2078,12 @@ def _run_fused_collective(node, probe: _Side, build: _Side, pscan,
                          + [P(mesh_mod.AXIS, None, None)] * 2
                          + [P()] * (1 + len(bmm_sis)))
         out_specs = tuple(P() for _ in out_kinds)
-        # check_rep off: replication of the post-psum outputs holds by
+        # check_vma off: replication of the post-psum outputs holds by
         # construction but the checker can't infer it through the
         # scatter/gather bodies
         return shard_map(
             collective, mesh=mesh, in_specs=in_specs,
-            out_specs=out_specs, check_rep=False)
+            out_specs=out_specs, check_vma=False)
 
     jitted = obs_device.compiled("fused_collective", cache_key,
                                  build_collective, profile=prof,
@@ -2716,9 +2716,8 @@ def _stage1_out_slots(agg_plans, star_filter, distinct_plans
 def try_device_chained_topn(limit_node, ctx) -> Optional[Batch]:
     """Whole-query device residency: Limit(Sort(Project?(Aggregate)))
     over a fused-admissible join runs as TWO chained dispatches — the
-    stage-1 group accumulators NEVER leave HBM. Stage 2 (jitted with
-    donate_argnums over the stage-1 outputs, so XLA reuses their
-    buffers) masks absent groups to the sort sentinel, top_k-selects
+    stage-1 group accumulators NEVER leave HBM. Stage 2 takes them as
+    device arrays, masks absent groups to the sort sentinel, top_k-selects
     the k requested group slots, and gathers every accumulator down to
     those k rows; the host fetches only the k-row tail. Sort keys are
     group-key columns (composite-code order == value order: sorted
@@ -2833,14 +2832,13 @@ def try_device_chained_topn(limit_node, ctx) -> Optional[Batch]:
         return stage2
 
     prof = getattr(ctx, "profile", None)
-    # donate the stage-1 accumulators: XLA reuses their HBM for the
-    # gathered outputs (donation is a no-op warning on the CPU backend)
-    donate = tuple(range(len(outs))) \
-        if jax.default_backend() != "cpu" else None
+    # no buffer donation: stage 2's outputs are k_pad-row gathers, which
+    # can never alias the group_space-row accumulators (the TPU compiler
+    # reports such donations "not usable"); the accumulators' HBM is
+    # released when `outs` goes out of scope below
     jitted2 = obs_device.compiled("fused_chain", ckey, build_stage2,
                                   profile=prof,
-                                  node_key=id(limit_node),
-                                  donate_argnums=donate)
+                                  node_key=id(limit_node))
     check_cancel()
     t0 = time.perf_counter_ns()
     metrics.DEVICE_OFFLOADS.add()

@@ -181,7 +181,7 @@ def _topn_indices(provider: TableProvider, scan, col_name: str,
             return jnp.where(mask.ravel(), kv.ravel(), sent)
 
         if mesh_n > 1:
-            from jax.experimental.shard_map import shard_map
+            from jax import shard_map
             from jax.sharding import PartitionSpec as P
 
             from ..parallel.mesh import AXIS, data_mesh

@@ -3,13 +3,16 @@
 The compute path is JAX/XLA/Pallas; the CPU-bound runtime pieces mirror the
 reference's native implementation — currently the inverted-index builder
 (tokenize + postings in one pass). Compiled on first use with g++ into
-_build/; everything degrades gracefully to the Python implementations when
-no toolchain is available.
+_build/, keyed by the CONTENT of the source and the compiler flags (a
+copied or freshly checked-out tree has arbitrary mtimes); the Python
+implementations take over when no toolchain is available, counted in
+the NativeIndexFallbacks gauge so a serving process can tell.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -17,11 +20,13 @@ from typing import Optional
 
 import numpy as np
 
-from ..utils import log
+from ..utils import log, metrics
 
 _lock = threading.Lock()
 _lib = None
 _tried = False
+
+_CXX_FLAGS = ("-O3", "-std=c++17", "-shared", "-fPIC", "-pthread")
 
 
 def _build_dir() -> str:
@@ -31,22 +36,25 @@ def _build_dir() -> str:
 
 
 def load() -> Optional[ctypes.CDLL]:
-    """Compile (once) and load the native library; None if unavailable."""
+    """Compile (once per source content) and load the native library;
+    None if unavailable."""
     global _lib, _tried
     with _lock:
         if _lib is not None or _tried:
             return _lib
         _tried = True
         src = os.path.join(os.path.dirname(__file__), "indexer.cpp")
-        so = os.path.join(_build_dir(), "libsdbnative.so")
         try:
-            if not os.path.exists(so) or \
-                    os.path.getmtime(so) < os.path.getmtime(src):
-                cmd = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC",
-                       "-pthread", "-o", so + ".tmp", src]
-                subprocess.run(cmd, check=True, capture_output=True,
-                               timeout=120)
-                os.replace(so + ".tmp", so)
+            with open(src, "rb") as f:
+                digest = hashlib.sha256(
+                    " ".join(_CXX_FLAGS).encode() + b"\0" + f.read())
+            so = os.path.join(_build_dir(),
+                              f"libsdbnative-{digest.hexdigest()[:16]}.so")
+            if not os.path.exists(so):
+                tmp = f"{so}.{os.getpid()}.tmp"
+                subprocess.run(["g++", *_CXX_FLAGS, "-o", tmp, src],
+                               check=True, capture_output=True, timeout=120)
+                os.replace(tmp, so)
             lib = ctypes.CDLL(so)
         except (OSError, subprocess.SubprocessError) as e:
             log.warn("native", f"native indexer unavailable: {e}")
@@ -101,7 +109,9 @@ def build_field_index_native(texts,
     to Python)."""
     lib = load()
     if lib is None:
+        metrics.NATIVE_INDEX_FALLBACKS.add()
         return None
+    metrics.NATIVE_INDEX_BUILDS.add()
     from ..search.segment import FieldIndex
 
     parts = []

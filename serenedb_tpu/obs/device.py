@@ -345,8 +345,7 @@ class ProgramLedger:
         return f
 
     def get(self, family: str, key: tuple, builder: Callable,
-            profile=None, node_key=None,
-            donate_argnums=None) -> CompiledProgram:
+            profile=None, node_key=None) -> CompiledProgram:
         on = enabled()
         full = (family, key)
         with self._lock:
@@ -363,16 +362,7 @@ class ProgramLedger:
         # builder may construct meshes/shard_maps; a racing duplicate
         # build is wasted work, never wrong (the loser is discarded)
         import jax
-        if donate_argnums:
-            # chained-stage handoff: the caller proves the donated
-            # buffers are dead after this dispatch (stage-1 outputs
-            # consumed exactly once), so XLA may alias them into the
-            # stage-2 outputs — zero-copy HBM reuse between stages
-            fn = jax.jit(builder(),
-                         donate_argnums=tuple(donate_argnums))
-        else:
-            fn = jax.jit(builder())
-        prog = CompiledProgram(fn, family)
+        prog = CompiledProgram(jax.jit(builder()), family)
         with self._lock:
             cur = self._progs.get(full)
             if cur is not None:
@@ -488,18 +478,15 @@ PROGRAMS = ProgramLedger()
 
 
 def compiled(family: str, key: tuple, builder: Callable, *,
-             profile=None, node_key=None,
-             donate_argnums=None) -> CompiledProgram:
+             profile=None, node_key=None) -> CompiledProgram:
     """THE jit entry point (acceptance grep: no bare `jax.jit(` outside
     this module). `builder` is a zero-arg callable returning the python
     callable to jit (a traced program body, or a shard_map-wrapped
     one); it runs only on a ledger miss. `profile`/`node_key` stamp the
     hit/miss onto the plan operator so EXPLAIN ANALYZE's `Device:` line
-    can say `compile=hit|miss`. `donate_argnums` forwards to jax.jit
-    for chained-stage buffer handoff (and keys the cached executable
-    implicitly: callers pass it consistently per cache key)."""
+    can say `compile=hit|miss`."""
     return PROGRAMS.get(family, key, builder, profile=profile,
-                        node_key=node_key, donate_argnums=donate_argnums)
+                        node_key=node_key)
 
 
 # -- fused-tier decline accounting -------------------------------------------
@@ -537,9 +524,11 @@ def fused_declines() -> dict[str, int]:
 
 def device_rows() -> list[dict]:
     """One row per physical device: dispatches, transfer bytes/time
-    up/down, and the HBM live-bytes estimate (DEVICE_CACHE occupancy —
+    up/down, the HBM live-bytes estimate (DEVICE_CACHE occupancy —
     column tiles, code tiles, row masks, cached build outputs — split
-    per holding device). Lists every jax device when a backend is
+    per holding device) and, beside it, the bytes in use and the limit
+    as the backend's own `memory_stats()` reports them (None where it
+    reports none). Lists every jax device when a backend is
     already initialized (PASSIVE probe — a pure-host process must not
     pay backend init for a stats read), else only devices the ledger
     has seen."""
@@ -565,6 +554,10 @@ def device_rows() -> list[dict]:
     for i in ids:
         s = snap.get(i, zeros)
         d = devs.get(i)
+        # what the backend itself reports for the device's memory (the
+        # CPU backend reports nothing → NULL): the measured side of
+        # hbm_bytes_est, which only sums what this engine put there
+        mem = (d.memory_stats() or {}) if d is not None else {}
         rows.append({
             "device": i,
             "platform": getattr(d, "platform", ""),
@@ -576,7 +569,9 @@ def device_rows() -> list[dict]:
             "bytes_down": s["bytes_down"],
             "transfers_down": s["transfers_down"],
             "down_ms": round(s["down_ns"] / 1e6, 3),
-            "hbm_bytes_est": cache_bytes.get(i, 0)})
+            "hbm_bytes_est": cache_bytes.get(i, 0),
+            "hbm_bytes_in_use": mem.get("bytes_in_use"),
+            "hbm_bytes_limit": mem.get("bytes_limit")})
     return rows
 
 

@@ -143,8 +143,10 @@ def _merge_topk(best_d, best_r, d, r, kk: int):
     return sd[:, :kk], sr[:, :kk]
 
 
-# -- k-means (ledger-routed; matmul identity is fine here — no parity
-#    contract binds training to the scoring expression) ----------------------
+# -- k-means (ledger-routed; matmul identity at the backend's default
+#    precision is fine here — training and assignment only choose clusters
+#    (recall at nprobe < lists); no parity contract binds them to the
+#    scoring expression, and no returned distance comes from them) -----------
 
 
 def _sq_dists(x: jax.Array, c: jax.Array) -> jax.Array:
@@ -296,9 +298,14 @@ def maxsim_program(dp: int, tile: int, tmax: int, kk: int, dc: int):
             x = jnp.take(rg, jnp.take(slotmap, pos), axis=0)  # (dc,tmax,dp)
             sim = jnp.zeros((b, dc, tmax, s), jnp.float32)
             for i in range(0, dp, tile):
+                # HIGHEST: the documented scorer is f32; at the MXU's
+                # default precision this dot would multiply bf16-rounded
+                # operands (k-means above only picks clusters and keeps
+                # the default)
                 sim = sim + jnp.einsum(
                     "dtx,bsx->bdts",
-                    x[..., i:i + tile], queries[..., i:i + tile])
+                    x[..., i:i + tile], queries[..., i:i + tile],
+                    precision=jax.lax.Precision.HIGHEST)
             sim = jnp.where(live[None, :, :, None], sim, -jnp.inf)
             score = jnp.sum(jnp.max(sim, axis=2), axis=2)     # (B, dc)
             key = -score + 0.0

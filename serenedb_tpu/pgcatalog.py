@@ -787,7 +787,7 @@ def _pg_settings(db) -> MemTable:
 
 # information_schema ------------------------------------------------------
 
-#: ISO SQL feature taxonomy rows with THIS ENGINE's honest support flags
+#: ISO SQL feature classification rows with THIS ENGINE's honest support flags
 #: (reference: server/pg/information_schema/sql_features.txt). A curated
 #: representative subset of the standard's feature list.
 _SQL_FEATURES = [
@@ -1322,9 +1322,11 @@ def system_table(db, parts: list[str]) -> Optional[TableProvider]:
 
 def device_table() -> TableProvider:
     """sdb_device: one row per physical jax device — dispatches
-    executed, transfer bytes/time host→device and device→host, and the
+    executed, transfer bytes/time host→device and device→host, the
     HBM live-bytes estimate (device column cache occupancy split per
-    holding device). The device telemetry ledger (obs/device.py,
+    holding device), and the backend's own memory_stats() bytes in use
+    and limit (NULL where the backend reports none). The device
+    telemetry ledger (obs/device.py,
     serene_device_telemetry); empty counters when telemetry is off."""
     from .obs.device import device_rows
     rows = device_rows()
@@ -1334,7 +1336,8 @@ def device_table() -> TableProvider:
         ("bytes_up", dt.BIGINT), ("transfers_up", dt.BIGINT),
         ("up_ms", dt.DOUBLE), ("bytes_down", dt.BIGINT),
         ("transfers_down", dt.BIGINT), ("down_ms", dt.DOUBLE),
-        ("hbm_bytes_est", dt.BIGINT)], {
+        ("hbm_bytes_est", dt.BIGINT), ("hbm_bytes_in_use", dt.BIGINT),
+        ("hbm_bytes_limit", dt.BIGINT)], {
         "device": [r["device"] for r in rows],
         "platform": [r["platform"] for r in rows],
         "kind": [r["kind"] for r in rows],
@@ -1345,7 +1348,9 @@ def device_table() -> TableProvider:
         "bytes_down": [r["bytes_down"] for r in rows],
         "transfers_down": [r["transfers_down"] for r in rows],
         "down_ms": [r["down_ms"] for r in rows],
-        "hbm_bytes_est": [r["hbm_bytes_est"] for r in rows]})
+        "hbm_bytes_est": [r["hbm_bytes_est"] for r in rows],
+        "hbm_bytes_in_use": [r["hbm_bytes_in_use"] for r in rows],
+        "hbm_bytes_limit": [r["hbm_bytes_limit"] for r in rows]})
 
 
 def programs_table() -> TableProvider:

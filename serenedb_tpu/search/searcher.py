@@ -101,19 +101,19 @@ class SegmentSearcher:
 
     def _dense_store(self, scorer: str,
                      avgdl: float) -> bm25_ops.DenseStore:
-        """Dense saturation matrix for the small-corpus matmul path,
+        """Dense saturation matrix for the small-corpus dense path,
         cached per (scorer shape, avgdl) — segments are immutable, and
         avgdl only drifts when collection stats change."""
         cache = getattr(self, "_dense_cache", None)
         if cache is None:
             cache = self._dense_cache = {}
-        # tfidf's S (sqrt tf) is avgdl-independent — don't rebuild it when
+        # tfidf's St (sqrt tf) is avgdl-independent — don't rebuild it when
         # collection stats drift
         key = ("tfidf",) if scorer == "tfidf" \
             else ("bm25", round(avgdl, 6))
         hit = cache.get(key)
         if hit is None:
-            if len(cache) >= 2:   # S is the dominant HBM tenant — keep ≤2
+            if len(cache) >= 2:   # St is the dominant HBM tenant — keep ≤2
                 cache.clear()
             hit = cache[key] = bm25_ops.build_dense_store(
                 self._device_store(), self.index.doc_freq, avgdl, K1, B,
@@ -410,7 +410,7 @@ class SegmentSearcher:
         candidate sets — bit-identical to the score-plane kernel by the
         contrib_flat contract (ops/bm25.py), an order of magnitude cheaper
         at top-10-of-millions scale. Never taken when this store would use
-        the dense matmul path, so ragged on/off can't change a single
+        the dense gather path, so ragged on/off can't change a single
         result bit there either."""
         if self.num_docs == 0:
             return [(np.empty(0, dtype=np.float32),
@@ -480,16 +480,18 @@ class SegmentSearcher:
                      bm25_ops.dense_fits(store.ndocs_pad,
                                          len(self.index.doc_freq)))
         if use_dense:
-            # small-corpus matmul path: one MXU dispatch, no host WAND
-            # planning needed (the dense kernel is not scatter-bound)
+            # small-corpus dense path: one dispatch of row gathers, no
+            # host WAND planning needed (the dense kernel is not
+            # scatter-bound)
             ds = self._dense_store(scorer, avgdl)
-            W, require_arr, _ = bm25_ops.assemble_dense_weights(
-                ds.v_pad, queries, self.num_docs, self.index.doc_freq,
-                scorer, idf_of)
+            tid_slots, w_slots, require_arr = \
+                bm25_ops.assemble_dense_weights(
+                    queries, self.num_docs, self.index.doc_freq, scorer,
+                    idf_of)
             kk = min(bm25_ops.pad_k(k_true), store.ndocs_pad)
             vals, docs = bm25_ops.dense_topk(
-                ds.S, jnp.asarray(W), jnp.asarray(require_arr), kk,
-                bool(require_arr.any()))
+                ds.St, jnp.asarray(tid_slots), jnp.asarray(w_slots),
+                jnp.asarray(require_arr), kk, bool(require_arr.any()))
             vals, docs = jax.device_get((vals, docs))
             return self._finish_batch(nodes, shapes, vals, docs,
                                       host_results, k, scorer, idf_of,
@@ -1021,7 +1023,7 @@ def _merge_program(mesh, lp: int, kp: int, qp: int):
 
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from ..parallel.mesh import AXIS
@@ -1035,7 +1037,7 @@ def _merge_program(mesh, lp: int, kp: int, qp: int):
     @functools.partial(
         shard_map, mesh=mesh,
         in_specs=(P(AXIS, None, None), P(AXIS, None, None)),
-        out_specs=(P(), P()), check_rep=False)
+        out_specs=(P(), P()), check_vma=False)
     def step(sc, dc):
         # per-(shard, query) exact top-k: lexicographic two-key sort on
         # (score desc, doc asc). `+ 0.0` canonicalizes -0.0 so equal

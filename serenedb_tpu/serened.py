@@ -75,6 +75,17 @@ def main(argv=None):
                 ap.error(f"{name.upper()}: {e}")
 
     log.MANAGER.stdout = True
+    # this process owns the device: initialize the backend now (boot
+    # recovery below already compiles index programs) and say on the
+    # ready line which one it got — serving from somewhere else than
+    # the operator expects must never be silent
+    from .utils.backend import init_backend
+    backend = init_backend()
+    ready_suffix = (f"platform={backend['platform']} "
+                    f"devices={backend['count']} "
+                    f"device_kind={backend['device_kind']}")
+    log.info("serened", f"jax backend: {ready_suffix} "
+             f"compile_cache={backend['cache_dir']}")
     db = Database(args.datadir)
     pg = PgServer(db, args.host, args.pg_port, args.password,
                   tls_cert=args.tls_cert, tls_key=args.tls_key,
@@ -95,8 +106,8 @@ def main(argv=None):
             for sig in (signal.SIGINT, signal.SIGTERM):
                 loop.add_signal_handler(sig, stop.set)
             await front.start_async()
-            print(f"serened ready: pg={pg.port} http={front.port}",
-                  flush=True)
+            print(f"serened ready: pg={pg.port} http={front.port} "
+                  f"{ready_suffix}", flush=True)
             await stop.wait()
             # teardown order mirrors the reference: listeners drain,
             # sessions reaped, then the store closes
@@ -120,8 +131,8 @@ def main(argv=None):
         for sig in (signal.SIGINT, signal.SIGTERM):
             loop.add_signal_handler(sig, stop.set)
         await pg.start()
-        print(f"serened ready: pg={pg.port} http={http.port}",
-              flush=True)
+        print(f"serened ready: pg={pg.port} http={http.port} "
+              f"{ready_suffix}", flush=True)
         await stop.wait()
         await pg.stop()
 

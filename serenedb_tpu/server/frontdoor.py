@@ -195,10 +195,11 @@ class FrontDoor:
         self._draining = True
         if self._server is not None:
             self._server.close()
-            await self._server.wait_closed()
-            self._server = None
         # idle keep-alive sessions are parked in a read — cancel them
-        # now; active ones get to finish their current response
+        # now; active ones get to finish their current response.
+        # wait_closed() comes AFTER the sessions are gone: it waits for
+        # every accepted connection, so a parked keep-alive client would
+        # otherwise hold shutdown forever
         for task, info in list(self._sessions.items()):
             if info is None or getattr(info, "state", "") == "idle":
                 task.cancel()
@@ -211,6 +212,9 @@ class FrontDoor:
             if pending:
                 await asyncio.wait(pending, timeout=self.drain_s)
         self._sessions.clear()
+        if self._server is not None:
+            await self._server.wait_closed()
+            self._server = None
         if self.pg is not None:
             await self.pg.stop()
 

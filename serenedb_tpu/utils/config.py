@@ -165,9 +165,23 @@ declare("sdb_rerank_factor", 4, int, "ANN rerank multiplier")
 declare("sdb_scored_terms_limit", 128, int,
         "max scored terms for multi-term expansion (wildcard/fuzzy)")
 declare("sdb_strict_ddl", False, bool, "reject unknown WITH options")
+def _validate_device(v):
+    v = str(v).lower()
+    if v not in ("auto", "device", "tpu", "cpu"):
+        raise ValueError(
+            f"invalid serene_device: {v!r} (auto|device|tpu|cpu)")
+    return v
+
+
 declare("serene_device", "auto", str,
-        "compute device policy: auto|tpu|cpu (auto: TPU when available "
-        "and batch is large enough)")
+        "compute path policy: 'cpu' runs the host (numpy) operators; "
+        "'device' (alias 'tpu') takes the jitted program path on "
+        "WHATEVER backend jax initialized in this process — the setting "
+        "selects a code path, not hardware; `sdb_device()` and the "
+        "serened ready line say which platform that backend is; 'auto' "
+        "takes the jitted path when the batch is large enough "
+        "(serene_device_min_rows)",
+        validator=_validate_device)
 declare("serene_device_min_rows", 16384, int,
         "below this row count the CPU path is used even when device=auto")
 declare("serene_device_chunk_rows", 1 << 21, int,

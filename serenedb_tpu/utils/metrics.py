@@ -262,6 +262,13 @@ SEGMENT_BUILDS = REGISTRY.gauge(
 SEGMENT_MERGES = REGISTRY.gauge(
     "SegmentMerges", "tiered segment merges (adjacent runs compacted "
     "into one segment)")
+NATIVE_INDEX_BUILDS = REGISTRY.gauge(
+    "NativeIndexBuilds", "field-index chunks tokenized by the native "
+    "C++ one-pass indexer")
+NATIVE_INDEX_FALLBACKS = REGISTRY.gauge(
+    "NativeIndexFallbacks", "native-eligible field-index chunks that "
+    "fell back to the Python tokenizer because the native library "
+    "could not be built or loaded")
 POOL_MORSELS = REGISTRY.gauge("PoolMorselsExecuted",
                               "morsel tasks executed by the worker pool")
 POOL_QUEUE_WAIT_US = REGISTRY.gauge("PoolQueueWaitUs",

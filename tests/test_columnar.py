@@ -68,3 +68,23 @@ def test_common_numeric_widening():
     assert dtypes.common_numeric(dtypes.BOOL, dtypes.BIGINT) == dtypes.BIGINT
     with pytest.raises(TypeError):
         dtypes.common_numeric(dtypes.VARCHAR, dtypes.INT)
+
+
+def test_arrow_ipc_roundtrip_of_wide_string_column():
+    """A VARCHAR column past pyarrow's ~64 MB numpy-unicode conversion
+    chunk (10k rows of 2k-char JSON-ish text — the vector column shape)
+    must still serialize as ONE record batch: the WAL and snapshot
+    writers take no chunked column. NULLs and repeats survive."""
+    from serenedb_tpu.columnar.arrow_io import (batch_to_arrow,
+                                                batch_to_bytes,
+                                                bytes_to_batch)
+    vals = [None if i % 97 == 0 else
+            f"{i % 5000:05d}" + "x" * 2000 for i in range(10_000)]
+    b = Batch(["k", "s"], [Column.from_numpy(np.arange(10_000)),
+                           Column.from_pylist(vals, dtypes.VARCHAR)])
+    rb = batch_to_arrow(b)
+    assert rb.num_rows == 10_000 and str(rb.schema.field("s").type) == \
+        "string"
+    back = bytes_to_batch(batch_to_bytes(b))
+    assert back.column("s").to_pylist() == vals
+    assert back.column("k").to_pylist() == list(range(10_000))

@@ -65,9 +65,10 @@ def _request_bytes(method, path, body=b"", headers=()):
     return ("\r\n".join(head) + "\r\n\r\n").encode() + body
 
 
-def _read_response(sock):
-    """One HTTP/1.1 response off a raw socket: (status, headers, body)."""
-    buf = b""
+def _read_response(sock, buf=b""):
+    """One HTTP/1.1 response off a raw socket: (status, headers, body,
+    leftover). Pipelined readers pass the previous call's leftover back
+    in as `buf` — two small responses can share one recv."""
     while b"\r\n\r\n" not in buf:
         d = sock.recv(65536)
         assert d, f"peer closed mid-header: {buf[:200]!r}"
@@ -246,12 +247,12 @@ def test_pipelined_requests_serialized_on_one_connection(db, front):
               _request_bytes("GET", "/_test/ping"))
     st1, _, r1, rest = _read_response(s)
     assert st1 == 200
-    status, _, r2, rest = _read_response(s)
+    status, _, r2, rest = _read_response(s, rest)
     assert status == 200
     assert json.loads(r2)["rows"] == [[1]]   # saw the pipelined INSERT
-    status, _, r3, rest = _read_response(s)
+    status, _, r3, rest = _read_response(s, rest)
     assert status == 400                      # malformed fails ALONE
-    status, _, r4, _ = _read_response(s)
+    status, _, r4, _ = _read_response(s, rest)
     assert status == 200 and r4 == b'{"ok": true}'  # session survived
     s.close()
 
@@ -282,8 +283,8 @@ def test_concurrent_across_connections_serial_within(front):
     t0 = time.perf_counter()
     s.sendall(_request_bytes("GET", "/_test/sleep?ms=400") +
               _request_bytes("GET", "/_test/sleep?ms=400"))
-    _read_response(s)
-    _read_response(s)
+    _, _, _, rest = _read_response(s)
+    _read_response(s, rest)
     pipelined_s = time.perf_counter() - t0
     s.close()
     assert pipelined_s >= 0.8, \
