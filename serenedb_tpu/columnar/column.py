@@ -23,6 +23,7 @@ from typing import Iterable, Optional, Sequence
 
 import numpy as np
 
+from ..obs.trace import stage
 from . import dtypes as dt
 
 
@@ -270,16 +271,19 @@ def concat_batches(batches: Sequence[Batch]) -> Batch:
     batches = [b for b in batches if b.num_rows > 0] or list(batches[:1])
     if len(batches) == 1:
         return batches[0]
-    names = batches[0].names
-    out_cols = []
-    for i, name in enumerate(names):
-        cols = merge_dictionaries([b.columns[i] for b in batches])
-        data = np.concatenate([c.data for c in cols])
-        if any(c.validity is not None for c in cols):
-            validity = np.concatenate([c.valid_mask() for c in cols])
-        else:
-            validity = None
-        typ = next((c.type for c in cols if c.type.id is not dt.TypeId.NULL),
-                   cols[0].type)
-        out_cols.append(Column(typ, data, validity, cols[0].dictionary))
-    return Batch(list(names), out_cols)
+    # the request's `host_concat` stage: the copy, and the dictionary
+    # merge of every string column, read or not
+    with stage("host_concat"):
+        names = batches[0].names
+        out_cols = []
+        for i, name in enumerate(names):
+            cols = merge_dictionaries([b.columns[i] for b in batches])
+            data = np.concatenate([c.data for c in cols])
+            if any(c.validity is not None for c in cols):
+                validity = np.concatenate([c.valid_mask() for c in cols])
+            else:
+                validity = None
+            typ = next((c.type for c in cols
+                        if c.type.id is not dt.TypeId.NULL), cols[0].type)
+            out_cols.append(Column(typ, data, validity, cols[0].dictionary))
+        return Batch(list(names), out_cols)

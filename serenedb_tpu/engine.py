@@ -962,8 +962,27 @@ class Connection:
                                               sql_text=sql))
         return out
 
+    def begin_request(self, label: str, t0_ns: Optional[int] = None):
+        """The front door's one helper: the trace of a request whose
+        message was received at `t0_ns` (None when this session has
+        `serene_trace` off), to be handed to `execute_statement` /
+        `execute_streaming` unless the statement turns out to be a
+        utility one (`is_untraced`), and closed with
+        `obs.trace.end_request` after the last flush."""
+        from .obs.trace import begin_request
+        return begin_request(label, self._trace_enabled(), t0_ns)
+
+    @staticmethod
+    def is_untraced(st: ast.Statement) -> bool:
+        """Utility statements (SET/SHOW/txn control/LISTEN/...) are not
+        traced: their zero-span timelines would churn the bounded flight
+        recorder out of exactly the slow statements it exists to
+        preserve — a pgwire client issuing SET per query would halve the
+        ring's reach."""
+        return isinstance(st, _UNTRACED_STATEMENTS)
+
     def execute_streaming(self, st: ast.Statement, params: Optional[list] = None,
-                          sql_text: Optional[str] = None):
+                          sql_text: Optional[str] = None, trace=None):
         """Streaming SELECT execution: (names, types, batch iterator).
 
         The iterator yields result batches as the executor produces them,
@@ -972,6 +991,10 @@ class Connection:
         whole result before the first DataRow (reference: the wire
         collector streams rows to the socket DURING execution,
         server/network/pg/wire_collector.h:20-60).
+
+        `trace` is the front door's request trace (obs/trace.py:
+        begin_request), adopted here and closed by the front door once
+        the last byte is out; without one the statement traces itself.
 
         Only Select/SetOp are streamable; anything else raises ValueError
         (callers route other statements through execute_statement)."""
@@ -986,29 +1009,49 @@ class Connection:
         import time as _time
         self.stmt_now_us = int(_time.time() * 1e6)  # now() stability
         from .cache.result import RESULT_CACHE
+        from .obs.trace import stage
         self._cache_hit = False
-        probe = RESULT_CACHE.begin(self, st, params, sql_text)
+        label = sql_text if sql_text is not None else "SELECT"
+        # the generator below resumes on arbitrary threads, so the trace
+        # is pinned around each piece of work (same-thread set/reset
+        # pairs) instead of holding one token across suspensions
+        trace = self._begin_trace(label, trace, pin=False)
         token = CURRENT_CONNECTION.set(self)
         try:
-            hit = probe.fast_lookup() if probe is not None else None
-            if hit is None:
-                plan = self._plan(st, params)  # binding enforces ACLs here
-                if probe is not None:
-                    probe.prepare(plan)
-                    hit = probe.lookup()
+            with self._trace_pinned(trace):
+                with stage("cache_probe"):
+                    probe = RESULT_CACHE.begin(self, st, params, sql_text)
+                    hit = probe.fast_lookup() if probe is not None else None
+                if hit is None:
+                    with stage("plan"):
+                        # binding enforces ACLs here
+                        plan = self._plan(st, params)
+                    if probe is not None:
+                        with stage("cache_probe"):
+                            probe.prepare(plan)
+                            hit = probe.lookup()
+        except BaseException as e:  # noqa: BLE001 — re-raised
+            self._finish_trace(trace, error=f"{type(e).__name__}: {e}")
+            raise
         finally:
             CURRENT_CONNECTION.reset(token)
         if hit is not None:
+            if trace is not None:
+                trace.cache_hit = True
+
             def run_hit(b=hit):
                 t0 = time.perf_counter_ns()
-                with self._session_scope(sql_text if sql_text is not None
-                                         else "SELECT"):
-                    yield b
-                    # re-pin the hit flag at drain time: a statement
-                    # interleaved with this suspended portal may have
-                    # overwritten the connection-level attribution
-                    self._cache_hit = True
-                    self._obs_record(sql_text, t0, b.num_rows, None, None)
+                try:
+                    with self._session_scope(label):
+                        yield b
+                        # re-pin the hit flag at drain time: a statement
+                        # interleaved with this suspended portal may have
+                        # overwritten the connection-level attribution
+                        self._cache_hit = True
+                        self._obs_record(sql_text, t0, b.num_rows, None,
+                                         None)
+                finally:
+                    self._finish_trace(trace)
             return (hit.names, [c.type for c in hit.columns], run_hit())
         # streaming memory accounting: the accountant is created here
         # (so the plan's operator wrappers see it on the context) but —
@@ -1016,8 +1059,7 @@ class Connection:
         # its ACTIVE progress row registers at first resume and retires
         # on every exit path
         from .obs.resources import MemoryAccountant
-        acct = MemoryAccountant(sql_text or "SELECT",
-                                pid=self._session_id) \
+        acct = MemoryAccountant(label, pid=self._session_id) \
             if self._mem_enabled() else None
         self._active_mem = acct
         ctx = self._exec_ctx(params)
@@ -1031,21 +1073,18 @@ class Connection:
         def run():
             from .cache.result import _batch_nbytes
             from .obs.resources import ACTIVE, CURRENT_MEM
-            from .obs.trace import CURRENT_TRACE, FLIGHT, QueryTrace
             t0 = time.perf_counter_ns()
             nrows = 0
             acc: Optional[list] = [] if store_cap >= 0 else None
             acc_bytes = 0
-            # streaming trace: the generator resumes on arbitrary
-            # threads, so the trace pins CURRENT_TRACE around every
-            # step (same-thread set/reset pairs) instead of holding one
-            # token across suspensions
-            trace = QueryTrace(sql_text or "SELECT") \
-                if self._trace_enabled() else None
+            # the `execute` envelope: one span from the first resume to
+            # the drain, recorded when it ends; its id is every step's
+            # enclosing span meanwhile
+            exec_id = trace.new_span_id() if trace is not None else 0
             if acct is not None:
                 ACTIVE.register(acct)
-            with self._session_scope(sql_text if sql_text is not None
-                                     else "SELECT"):
+            error = None
+            with self._session_scope(label):
                 from .sched.governor import GOVERNOR, admission_exempt
                 ticket = None
                 try:
@@ -1053,8 +1092,7 @@ class Connection:
                     # the slot is taken when execution actually starts
                     # and held until the portal drains or drops
                     if GOVERNOR.enabled() and not admission_exempt(st):
-                        ticket = GOVERNOR.admit(self, sql_text or "SELECT",
-                                                trace)
+                        ticket = GOVERNOR.admit(self, label, trace)
                     it = plan.batches(ctx)
                     while True:
                         # the caller may resume this generator from any
@@ -1063,43 +1101,33 @@ class Connection:
                         # read it), and the trace + accountant
                         # contextvars with it
                         tok = CURRENT_CONNECTION.set(self)
-                        tok_tr = CURRENT_TRACE.set(trace) \
-                            if trace is not None else None
                         tok_mem = CURRENT_MEM.set(acct) \
                             if acct is not None else None
                         try:
-                            b = next(it)
-                        except StopIteration:
-                            if acc is not None:
-                                out = concat_batches(acc) if acc else \
-                                    Batch(list(plan.names),
-                                          [Column.from_pylist([], t)
-                                           for t in plan.types])
-                                probe.store(out)
+                            with self._trace_pinned(trace, exec_id):
+                                b = next(it, None)
+                                if b is None and acc is not None:
+                                    out = concat_batches(acc) if acc else \
+                                        Batch(list(plan.names),
+                                              [Column.from_pylist([], t)
+                                               for t in plan.types])
+                                    with stage("cache_probe"):
+                                        probe.store(out)
+                        finally:
+                            if tok_mem is not None:
+                                CURRENT_MEM.reset(tok_mem)
+                            CURRENT_CONNECTION.reset(tok)
+                        if b is None:
                             # this generator IS the miss path — re-pin
                             # the flag in case an interleaved statement
                             # on this connection flipped it while we
                             # were suspended
                             self._cache_hit = False
-                            entry = None
-                            if trace is not None:
-                                entry = trace.finish()
-                                if acct is not None:
-                                    entry["peak_bytes"] = \
-                                        acct.totals()[1]
-                                entry = FLIGHT.record(entry)
-                            trace = None
                             ACTIVE.retire(acct)
                             self._obs_record(sql_text, t0, nrows,
-                                             ctx.profile, plan, entry,
+                                             ctx.profile, plan, trace,
                                              mem=acct)
                             return
-                        finally:
-                            if tok_mem is not None:
-                                CURRENT_MEM.reset(tok_mem)
-                            if tok_tr is not None:
-                                CURRENT_TRACE.reset(tok_tr)
-                            CURRENT_CONNECTION.reset(tok)
                         nrows += b.num_rows
                         if acc is not None:
                             acc_bytes += _batch_nbytes(b)
@@ -1113,17 +1141,17 @@ class Connection:
                     # a dropped portal) still dump the timeline and
                     # retire the progress row
                     ACTIVE.retire(acct)
-                    if trace is not None:
-                        entry = trace.finish(
-                            error=f"{type(e).__name__}: {e}")
-                        if acct is not None:
-                            entry["peak_bytes"] = acct.totals()[1]
-                        FLIGHT.record(entry)
+                    error = f"{type(e).__name__}: {e}"
                     raise
                 finally:
                     # slot returns on EVERY exit: drained, errored, or
                     # a dropped portal's GeneratorExit
                     GOVERNOR.release(ticket)
+                    if trace is not None:
+                        trace.add("execute", "exec", t0,
+                                  time.perf_counter_ns(), _parent=0,
+                                  _id=exec_id)
+                    self._finish_trace(trace, error, acct)
 
         return plan.names, plan.types, run()
 
@@ -1176,7 +1204,12 @@ class Connection:
         return out
 
     def execute_statement(self, st: ast.Statement, params: list,
-                          sql_text: Optional[str] = None) -> QueryResult:
+                          sql_text: Optional[str] = None,
+                          trace=None) -> QueryResult:
+        """One statement, materialized. `trace` is the front door's
+        request trace (obs/trace.py: begin_request), adopted here and
+        closed by the front door; without one a non-utility statement
+        traces itself and the request ends with the statement."""
         if self.txn_failed and not isinstance(st, ast.Transaction):
             raise errors.SqlError(
                 errors.IN_FAILED_TRANSACTION,
@@ -1193,15 +1226,11 @@ class Connection:
                 self._active_profile = None
                 self._active_plan = None
                 self._cache_hit = False
-                # utility statements (SET/SHOW/txn control/LISTEN/...)
-                # are not traced: their zero-span timelines would churn
-                # the bounded flight recorder out of exactly the slow
-                # statements it exists to preserve — a pgwire client
-                # issuing SET per query would halve the ring's reach
                 label = sql_text if sql_text is not None \
                     else type(st).__name__
-                utility = isinstance(st, _UNTRACED_STATEMENTS)
-                trace = None if utility else self._begin_trace(label)
+                utility = self.is_untraced(st)
+                trace = None if utility else \
+                    self._begin_trace(label, trace)
                 if trace is None:
                     self._active_trace = None
                 # memory accounting + live progress share the trace's
@@ -1232,18 +1261,18 @@ class Connection:
                     # flight recorder keeps the failed statement's spans
                     # for post-mortem (sdb_trace / GET /trace/<id>)
                     self._finish_trace(trace,
-                                       error=f"{type(e).__name__}: {e}")
+                                       f"{type(e).__name__}: {e}", acct)
                     self._finish_mem(acct)
                     raise
                 finally:
                     if ticket is not None:
                         from .sched.governor import GOVERNOR
                         GOVERNOR.release(ticket)
-                entry = self._finish_trace(trace)
+                self._finish_trace(trace, None, acct)
                 self._finish_mem(acct)
                 self._obs_record(sql_text, t0, _result_rows(res),
                                  self._active_profile, self._active_plan,
-                                 entry, utility=utility, mem=acct)
+                                 trace, utility=utility, mem=acct)
                 return res
         finally:
             CURRENT_CONNECTION.reset(token)
@@ -1719,39 +1748,50 @@ class Connection:
             acct._cv_token = None
         ACTIVE.retire(acct)
 
-    def _begin_trace(self, label: str):
-        """Start the statement's timeline trace (serene_trace on):
-        allocates the trace id and publishes it through CURRENT_TRACE so
-        pool tasks / batcher members / device dispatches stamp spans
-        into this query's timeline. Observation only — executors never
-        read the trace back."""
-        if not self._trace_enabled():
-            self._active_trace = None
-            return None
-        from .obs.trace import CURRENT_TRACE, QueryTrace
-        tr = QueryTrace(label)
-        tr._cv_token = CURRENT_TRACE.set(tr)
-        self._active_trace = tr
-        return tr
+    def _begin_trace(self, label: str, trace=None, pin: bool = True):
+        """The statement's timeline trace (serene_trace on), shared by
+        both entry points: adopt the request trace the front door began
+        at message receipt, or start one here. `pin` publishes it
+        through CURRENT_TRACE for the calling thread until
+        `_finish_trace` (the materializing path); the streaming path
+        pins around each step instead (`_trace_pinned`). Observation
+        only — executors never read the trace back."""
+        if trace is None and self._trace_enabled():
+            from .obs.trace import QueryTrace
+            trace = QueryTrace(label)
+        self._active_trace = trace
+        if trace is not None and pin:
+            trace._cv_token = trace.pinned()
+            trace._cv_token.__enter__()
+        return trace
 
-    def _finish_trace(self, tr, error: Optional[str] = None):
-        """Finalize a trace into the flight recorder (success AND error
-        paths — a failed statement's timeline is exactly the one worth
-        keeping). Returns the recorded entry, or None."""
+    @staticmethod
+    def _trace_pinned(trace, span_id: int = 0):
+        return trace.pinned(span_id) if trace is not None \
+            else contextlib.nullcontext()
+
+    def _finish_trace(self, tr, error: Optional[str] = None, acct=None):
+        """The statement's end on the timeline (success AND error paths
+        — a failed statement's timeline is exactly the one worth
+        keeping): unpin, stamp the accounted peak, and close the request
+        into the flight recorder when the engine began the trace itself.
+        A front door's trace stays open until its last flush."""
         if tr is None:
             return None
-        from .obs.trace import CURRENT_TRACE, FLIGHT
         if tr._cv_token is not None:
-            CURRENT_TRACE.reset(tr._cv_token)
+            tr._cv_token.__exit__(None, None, None)
             tr._cv_token = None
-        entry = tr.finish(error)
-        # accounted peak rides the flight-recorder entry so a
-        # memory-heavy query is findable after the fact (sdb_trace
-        # listing, GET /trace, /_stats.traces)
-        acct = self._active_mem
+        if error is not None:
+            tr.error = error
         if acct is not None:
-            entry["peak_bytes"] = acct.totals()[1]
-        return FLIGHT.record(entry)
+            # accounted peak rides the flight-recorder entry so a
+            # memory-heavy query is findable after the fact (sdb_trace
+            # listing, GET /trace, /_stats.traces)
+            tr.peak_bytes = acct.totals()[1]
+        if tr.owned:
+            from .obs.trace import end_request
+            return end_request(tr)
+        return None
 
     def _exec_ctx(self, params: list) -> ExecContext:
         """Execution context with a span collector attached when
@@ -1771,61 +1811,51 @@ class Connection:
     def _run_select(self, sel: ast.Select, params: list,
                     sql_text: Optional[str] = None) -> Batch:
         from .cache.result import RESULT_CACHE
-        from .obs.trace import current_trace
-        tr = current_trace()
-        t_probe = time.perf_counter_ns() if tr is not None else 0
-        probe = RESULT_CACHE.begin(self, sel, params, sql_text)
-        if probe is not None:
+        from .obs.trace import current_trace, span, stage
+        with stage("cache_probe"):
+            # cache digest + publication observation, then the
             # plan-skipping fast path: the statement's table set was
             # learned at an earlier store — resolve, re-check ACLs,
             # observe publications, serve
-            hit = probe.fast_lookup()
-            if hit is not None:
-                if tr is not None:
-                    tr.add("cache_probe", "cache", t_probe,
-                           time.perf_counter_ns(), hit=True)
-                return hit
-        t_plan = time.perf_counter_ns() if tr is not None else 0
-        if tr is not None and t_plan - t_probe > 1000:
-            # cache digest + publication observation time: part of the
-            # statement's wall clock, attributed so plan+execute+probe
-            # spans jointly cover the timeline instead of leaving a gap
-            tr.add("cache_probe", "cache", t_probe, t_plan)
-        plan = self._plan(sel, params)
-        t_exec = time.perf_counter_ns() if tr is not None else 0
-        if tr is not None:
-            tr.add("plan", "plan", t_plan, t_exec)
-        ctx = self._exec_ctx(params)
-        if ctx.profile is not None:
-            self._active_plan = plan
+            probe = RESULT_CACHE.begin(self, sel, params, sql_text)
+            hit = probe.fast_lookup() if probe is not None else None
+        if hit is None:
+            with stage("plan"):
+                plan = self._plan(sel, params)
+            ctx = self._exec_ctx(params)
+            if ctx.profile is not None:
+                self._active_plan = plan
+            if probe is not None:
+                with stage("cache_probe"):
+                    probe.prepare(plan)
+                    hit = probe.lookup()
+        if hit is not None:
+            tr = current_trace()
+            if tr is not None:
+                tr.cache_hit = True
+            return hit
+        # the timeline's execution envelope: what runs inside stamps
+        # its own stages (device_*, host_*)
+        with span("execute", "exec"):
+            batch = plan.execute(ctx)
         if probe is not None:
-            probe.prepare(plan)
-            hit = probe.lookup()
-            if hit is not None:
-                return hit
-        batch = plan.execute(ctx)
-        if tr is not None:
-            # the timeline's execution envelope: plan-digest probe,
-            # execution and result hand-off — so cache_probe + plan +
-            # execute jointly account for the statement's wall time
-            # even when no finer-grained span fired (tiny serial
-            # queries)
-            tr.add("execute", "exec", t_exec, time.perf_counter_ns())
-        if probe is not None:
-            probe.store(batch)
+            with stage("cache_probe"):
+                probe.store(batch)
         return batch
 
     def _obs_record(self, sql_text: Optional[str], t0_ns: int, rows: int,
-                    profile, plan, trace_entry=None,
+                    profile, plan, trace=None,
                     utility: bool = False, mem=None) -> None:
         """Statement-end observability hook (begin is _session_scope):
         query gauges + latency histogram, sdb_stat_statements, the
         slow-query log and the session's pg_stat_activity query id.
         Everything is behind `serene_profile`; failures here must never
         fail the statement's own result path, so this is called only
-        after success. `trace_entry` is the statement's flight-recorder
-        timeline (serene_trace on) — the slow-query log attaches its
-        top-5 widest spans next to the annotated plan tree.
+        after success. `trace` is the statement's timeline
+        (serene_trace on) — the slow-query log attaches its top-5 widest
+        spans next to the annotated plan tree (a front door's request is
+        still open then: the log shows it as far as the statement's
+        end).
 
         The latency histogram records BEFORE the serene_profile gate:
         the pool/batch/device histograms fill regardless of that
@@ -1878,9 +1908,10 @@ class Connection:
                 from .obs.trace import annotate_plan
                 msg += "\n" + "\n".join(annotate_plan(plan, profile,
                                                       mem))
-            if trace_entry is not None:
+            if trace is not None:
                 from .obs.trace import format_top_spans
-                msg += "\n" + "\n".join(format_top_spans(trace_entry))
+                msg += "\n" + "\n".join(format_top_spans(
+                    trace.entry or trace.snapshot()))
             log.info("slow_query", msg)
 
     # -- DDL/DML -----------------------------------------------------------

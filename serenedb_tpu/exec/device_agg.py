@@ -23,6 +23,7 @@ import numpy as np
 from ..columnar import dtypes as dt
 from ..columnar.column import Batch, Column
 from ..columnar.device import DeviceNarrowingError, pad_len
+from ..obs.trace import stage
 from ..ops import agg as ops_agg
 from ..sql.binder import _expr_key
 from ..sql.expr import AggSpec, BoundColumn, BoundExpr
@@ -84,27 +85,12 @@ def try_device_aggregate(node, ctx) -> Optional[Batch]:
             # coding needs a plain column (min/max ignore DISTINCT)
             return None
     try:
-        prof = getattr(ctx, "profile", None)
-        from ..obs.trace import current_trace
-        trace = current_trace()
-        # host-vs-device attribution: everything inside _run (upload,
-        # compile-cache lookup, dispatch, readback) is device-path
-        # time, stamped on the aggregate node the offload replaced.
-        # The histogram observes UNCONDITIONALLY — the device latency
-        # signal must not vanish when profiling/tracing are off (two
-        # clock reads per ms-scale offload)
-        import time as _time
-
-        from ..utils import metrics as _metrics
-        t0 = _time.perf_counter_ns()
-        out = _run(node, scan, provider, preds, ctx)
-        t1 = _time.perf_counter_ns()
-        if prof is not None:
-            prof.add_device_ns(id(node), t1 - t0)
-        _metrics.DEVICE_DISPATCH_HIST.observe_ns(t1 - t0)
-        if trace is not None:
-            trace.add("device_dispatch", "device", t0, t1, op="agg")
-        return out
+        # the whole offload is the request's `device_prepare` stage,
+        # except what stamps itself inside it: the program call
+        # (`device_enqueue`), the readback (`device_wait`) and the host
+        # decode of the outputs (`device_finalize`)
+        with stage("device_prepare", op="agg"):
+            return _run(node, scan, provider, preds, ctx)
     except (NotCompilable, DeviceNarrowingError) as e:
         log.debug("device", f"aggregate fell back to CPU: {e}")
         return None
@@ -365,15 +351,21 @@ def _run(node, scan, provider: TableProvider, preds: list[BoundExpr], ctx) -> Ba
             chunk_tiles += (-chunk_tiles) % mesh_n
         combines = _out_combines(node, agg_plans, group_mode)
         results = _chunked_dispatch(jitted, flat_args, rowmask_arr,
-                                    chunk_tiles, combines, mesh_n)
+                                    chunk_tiles, combines, mesh_n,
+                                    profile=getattr(ctx, "profile", None),
+                                    node_key=id(node))
     else:
-        results = obs_device.fetch_all(jitted(*flat_args, rowmask_arr))
+        results = obs_device.dispatch(
+            jitted, (*flat_args, rowmask_arr),
+            profile=getattr(ctx, "profile", None), node_key=id(node))
 
-    if group_mode:
-        return _build_group_batch(node, key_plans, agg_plans, results,
-                                  provider, col_names, dictionaries,
-                                  group_space, fact, distinct_plans)
-    return _build_scalar_batch(node, agg_plans, results, distinct_plans)
+    with stage("device_finalize"):
+        if group_mode:
+            return _build_group_batch(node, key_plans, agg_plans, results,
+                                      provider, col_names, dictionaries,
+                                      group_space, fact, distinct_plans)
+        return _build_scalar_batch(node, agg_plans, results,
+                                   distinct_plans)
 
 
 def _zonemap_range(scan, provider, preds, pin, nrows,
@@ -434,6 +426,8 @@ def _range_device_columns(provider, names, pin, zrange) -> dict:
         hits = {n: e[1] for n in names
                 if (e := cache.get(n)) is not None and e[0] == (ver, lo, hi)}
     out = dict(hits)
+    metrics.DEVICE_CACHE_HITS.add(len(hits))
+    metrics.DEVICE_CACHE_MISSES.add(len(set(names)) - len(hits))
     # uploads run OUTSIDE the lock: a multi-hundred-MB host→device copy
     # must not serialize every other query's zone-stats access on this
     # provider (a racing duplicate upload is wasted work, never wrong —
@@ -505,7 +499,8 @@ def _pad_shard_axis(arr, mesh_n: int):
 
 
 def _chunked_dispatch(jitted, flat_args, rowmask_arr, chunk_tiles: int,
-                      combines: list, mesh_n: int):
+                      combines: list, mesh_n: int, profile=None,
+                      node_key=None):
     """Run the aggregate program chunk by chunk over the row-block axis,
     combining per-output partials on host ('sum' adds exactly in
     int64/float64, 'min'/'max' fold elementwise, 'rows' concatenates).
@@ -530,8 +525,9 @@ def _chunked_dispatch(jitted, flat_args, rowmask_arr, chunk_tiles: int,
                 part = jnp.pad(part, widths)
             return part
 
-        outs = obs_device.fetch_all(
-            jitted(*[cut(a) for a in flat_args], cut(rowmask_arr)))
+        outs = obs_device.dispatch(
+            jitted, (*[cut(a) for a in flat_args], cut(rowmask_arr)),
+            profile=profile, node_key=node_key)
         def widen(o, c):
             if c != "sum":
                 return o

@@ -41,7 +41,6 @@ conjuncts shrink the transfer to the surviving block envelope
 
 from __future__ import annotations
 
-import functools
 import threading
 import time
 from collections import OrderedDict
@@ -58,6 +57,7 @@ from ..ops import agg as ops_agg
 from ..obs import device as obs_device
 from ..sql.binder import _expr_key
 from ..sql.expr import AggSpec, BoundColumn, BoundExpr, BoundFunc
+from ..obs.trace import span, stage
 from ..utils import log, metrics
 from ..utils.config import REGISTRY as _settings_registry
 from .device import DeviceExpr, NotCompilable, compile_expr
@@ -77,16 +77,6 @@ _AGG_FUNCS = {"count_star", "count", "sum", "min", "max", "avg"}
 _HOST_EVAL_UNSAFE = {
     "scalar_subquery", "array_subquery", "in_subquery", "exists",
     "currval", "lastval"}
-
-
-def _trace_span(trace, name: str, t0_ns: int, **args) -> None:
-    """Timeline phase attribution (serene_trace): the same boundaries
-    the profiler's device_ns counters use, but with BEGIN/END stamps so
-    the factorize -> upload -> dispatch sequencing is visible. No-op
-    when tracing is off (trace is None); call sites bind the trace with
-    functools.partial so one helper serves every program shape."""
-    if trace is not None:
-        trace.add(name, "device", t0_ns, time.perf_counter_ns(), **args)
 
 
 def fused_enabled(settings) -> bool:
@@ -674,14 +664,20 @@ def try_device_pipeline(node, ctx) -> Optional[Batch]:
 
     admitted = _admit_pipeline(node, ctx, decline)
     if admitted is None:
-        return None
+        return None       # shape analysis only: no stage for a non-join
     join, probe_side, build_side, post_preds = admitted
-    try:
-        return _run_fused(node, join, probe_side, build_side, post_preds,
-                          ctx)
-    except (NotCompilable, DeviceNarrowingError) as e:
-        log.debug("device", f"fused pipeline fell back to CPU: {e}")
-        return decline(getattr(e, "reason", "not_compilable"))
+    # the request's `device_prepare` stage: sides, key planning,
+    # factorization, residency lookups / uploads and the program lookup
+    # — everything but what stamps itself inside it (`device_enqueue`
+    # at the program call, `device_wait` at the readback,
+    # `device_finalize` at the host decode)
+    with stage("device_prepare", op="fused"):
+        try:
+            return _run_fused(node, join, probe_side, build_side,
+                              post_preds, ctx)
+        except (NotCompilable, DeviceNarrowingError) as e:
+            log.debug("device", f"fused pipeline fell back to CPU: {e}")
+            return decline(getattr(e, "reason", "not_compilable"))
 
 
 def _run_fused(node, join, probe_side, build_side,
@@ -695,23 +691,12 @@ def _run_fused(node, join, probe_side, build_side,
     import jax.numpy as jnp
 
     prof = getattr(ctx, "profile", None)
-    from ..obs.trace import current_trace
-    trace = current_trace()
-
-    def clock() -> int:
-        # always real: the phase stamps feed the unconditional device
-        # histogram, not just the prof/trace consumers (a few ns reads
-        # per ms-scale offload)
-        return time.perf_counter_ns()
-
-    tspan = functools.partial(_trace_span, trace)
 
     pscan, ppreds = probe_side
     bscan, bpreds = build_side
     nl = len(join.left.names)
     _check_host_eval_safe(list(join.left_keys) + list(join.right_keys))
 
-    t0 = clock()
     probe = _Side(pscan, ppreds, ctx)
     build = _Side(bscan, bpreds, ctx)
 
@@ -887,14 +872,10 @@ def _run_fused(node, join, probe_side, build_side,
             raise NotCompilable("DISTINCT presence grid too large",
                                 "distinct_space")
         distinct_plans[si] = (dkind, ji, int(lo_v), vspace)
-    if prof is not None:
-        prof.add_device_ns(id(node), clock() - t0)
-    tspan("device_compile", t0)
 
     # join-key factorization (host, cached per publication pair along
     # with the worst-case pair count: every int32 count/limb scatter in
     # the program is exact below the bound)
-    t0 = clock()
     cl, cr, g, total_pairs = _join_codes(join, probe, build)
     if g + 2 > MAX_CODE_SPACE:
         raise NotCompilable("join code space too large")
@@ -941,9 +922,6 @@ def _run_fused(node, join, probe_side, build_side,
                     max(total_pairs, 1) < (1 << 31):
                 mode = "direct"
         sum_modes[si] = mode
-    if prof is not None:
-        prof.add_device_ns(id(join), clock() - t0)
-    tspan("device_factorize", t0)
 
     # empty short-circuit: zero output rows only when NEITHER side can
     # null-extend past the empty one; an outer kind whose non-empty
@@ -995,7 +973,7 @@ def _run_fused(node, join, probe_side, build_side,
             node, join, probe, build, pscan, bscan, nl, preds_probe,
             preds_build, key_plans, group_space, group_mode, agg_plans,
             sum_modes, cl, cr, g, dictionaries, shape_sig, ctx, prof,
-            clock, block_rows, n_shards)
+            block_rows, n_shards)
 
     # device environment: columns via the publication-keyed cache
     needed: set[int] = set()
@@ -1019,7 +997,6 @@ def _run_fused(node, join, probe_side, build_side,
     # keep that product off the recompile-storm detector
     p_pad = _pow2_rows(probe.n_live)
     b_pad = _pow2_rows(build.n_live)
-    t0 = clock()
     env_cols = {}
     for ji in needed:
         if ji < nl:
@@ -1056,9 +1033,6 @@ def _run_fused(node, join, probe_side, build_side,
     brow = DEVICE_CACHE.array(build.pub, "__rowmask__",
                               (build.zrange, "pad", b_pad),
                               lambda: _rowmask_tiles(build.n_live, b_pad))
-    if prof is not None:
-        prof.add_device_ns(id(pscan), clock() - t0)
-    tspan("device_upload", t0)
 
     # -- the single program -------------------------------------------------
     decode_specs = [(env_cols[i].scheme, env_cols[i].offset) for i in needed]
@@ -1181,34 +1155,29 @@ def _run_fused(node, join, probe_side, build_side,
 
     from .plan import check_cancel
     check_cancel()
-    t0 = clock()
     metrics.DEVICE_OFFLOADS.add()
-    outs = jitted(*flat_args)
     if not fetch:
         # chained handoff: accumulators STAY in HBM — the downstream
         # fused stage consumes them directly; zero device→host bytes
-        # move here (the transfer ledger is the proof)
+        # move here (the transfer ledger is the proof). The chain's
+        # device time runs from this enqueue to the readback of the
+        # stage that consumes them, and is observed there
+        t_enqueue = time.perf_counter_ns()
+        outs = jitted(*flat_args)
         fin = {"node": node, "key_plans": key_plans,
                "agg_plans": agg_plans, "probe": probe, "pscan": pscan,
                "dictionaries": dictionaries, "group_space": group_space,
                "group_mode": group_mode, "sum_modes": sum_modes,
                "star_filter": star_filter,
                "distinct_plans": distinct_plans,
-               "stage1_key": cache_key}
-        if prof is not None:
-            prof.add_device_ns(id(node), clock() - t0)
-        tspan("device_dispatch", t0)
+               "stage1_key": cache_key, "t_enqueue": t_enqueue}
         return outs, fin
-    results = obs_device.fetch_all(outs)
-    out = _finalize(node, key_plans, agg_plans, results, probe, pscan,
-                    dictionaries, group_space, group_mode, sum_modes,
-                    star_filter=star_filter,
-                    distinct_plans=distinct_plans)
-    if prof is not None:
-        prof.add_device_ns(id(node), clock() - t0)
-    metrics.DEVICE_DISPATCH_HIST.observe_ns(time.perf_counter_ns() - t0)
-    tspan("device_dispatch", t0)
-    return out
+    results = obs_device.dispatch(jitted, flat_args, profile=prof,
+                                  node_key=id(node))
+    return _finalize(node, key_plans, agg_plans, results, probe, pscan,
+                     dictionaries, group_space, group_mode, sum_modes,
+                     star_filter=star_filter,
+                     distinct_plans=distinct_plans)
 
 
 def _build_layout(agg_plans, sum_modes: dict,
@@ -1576,7 +1545,7 @@ def _run_fused_sharded(node, join, probe: _Side, build: _Side, pscan,
                        key_plans, group_space: int, group_mode: bool,
                        agg_plans, sum_modes: dict, cl: np.ndarray,
                        cr: np.ndarray, g: int, dictionaries,
-                       shape_sig: tuple, ctx, prof, clock, block_rows: int,
+                       shape_sig: tuple, ctx, prof, block_rows: int,
                        n_shards: int) -> Batch:
     import jax.numpy as jnp
 
@@ -1586,9 +1555,6 @@ def _run_fused_sharded(node, join, probe: _Side, build: _Side, pscan,
     from .plan import check_cancel
 
     settings = ctx.settings
-    from ..obs.trace import current_trace
-    trace = current_trace()
-    tspan = functools.partial(_trace_span, trace)
 
     keyset = (tuple(_expr_key(k) for k in join.left_keys),
               tuple(_expr_key(k) for k in join.right_keys))
@@ -1597,7 +1563,6 @@ def _run_fused_sharded(node, join, probe: _Side, build: _Side, pscan,
 
     # -- shard-to-shard join filter: per-build-shard key ranges prune
     # probe blocks (and their uploads) before any transfer
-    t0 = clock()
     groups = _shard_build_ranges(join, build, n_shards, block_rows)
     v_shard = None
     if groups is not None:
@@ -1676,7 +1641,6 @@ def _run_fused_sharded(node, join, probe: _Side, build: _Side, pscan,
         with build_mu:
             if "v" in build_state:
                 return build_state["v"]
-            tb = clock()
             env_b = {}
             for ji in needed_b:
                 name = bscan.columns[ji - nl]
@@ -1701,12 +1665,9 @@ def _run_fused_sharded(node, join, probe: _Side, build: _Side, pscan,
             flat_b.extend([bc_dev, brow])
             check_cancel()
             metrics.DEVICE_OFFLOADS.add()
+            # enqueue only: the build outputs stay in HBM for the probe
+            # dispatches, whose readback observes the device time
             outs = jitted_b(*flat_b)
-            if prof is not None:
-                prof.add_device_ns(id(join), clock() - tb)
-            metrics.DEVICE_DISPATCH_HIST.observe_ns(
-                time.perf_counter_ns() - tb)
-            tspan("device_dispatch", tb, phase="build")
             build_state["v"] = outs
             return outs
 
@@ -1787,13 +1748,12 @@ def _run_fused_sharded(node, join, probe: _Side, build: _Side, pscan,
         return _run_fused_collective(
             node, probe, build, pscan, preds_probe,
             key_plans, group_space, group_mode, agg_plans, sum_modes,
-            cl, g, dictionaries, shape_sig, ctx, prof, clock,
+            cl, g, dictionaries, shape_sig, ctx, prof,
             per_shard, shard_ids, pruned, keyset, needed_p,
-            _build_outs_for, bstart, bmm_sis, tspan)
+            _build_outs_for, bstart, bmm_sis)
 
     def run_shard(s: int) -> list[np.ndarray]:
         check_cancel()
-        t_up = time.perf_counter_ns() if trace is not None else 0
         device = devs[s % len(devs)] if devs else None
         spans = per_shard[s]
         spans_t = tuple(spans)
@@ -1858,22 +1818,15 @@ def _run_fused_sharded(node, join, probe: _Side, build: _Side, pscan,
         flat.extend([pc_dev, prow])
         flat.extend(bouts)
         metrics.DEVICE_OFFLOADS.add()
-        tspan("device_upload", t_up, shard=s)
-        t_d = time.perf_counter_ns()
-        outs = obs_device.fetch_all(jitted_p(*flat))
-        metrics.DEVICE_DISPATCH_HIST.observe_ns(
-            time.perf_counter_ns() - t_d)
-        tspan("device_dispatch", t_d, shard=s)
-        return outs
+        return obs_device.dispatch(jitted_p, flat, profile=prof,
+                                   node_key=id(node))
 
     shard_outs = shard_mod.run_shard_tasks(settings, run_shard, shard_ids)
-    results = _combine_shard_results(agg_plans, sum_modes, shard_outs)
+    with stage("device_finalize"):
+        results = _combine_shard_results(agg_plans, sum_modes, shard_outs)
     shard_mod.stamp_profile(ctx, id(node), len(shard_ids), pruned)
-    out = _finalize(node, key_plans, agg_plans, results, probe, pscan,
-                    dictionaries, group_space, group_mode, sum_modes)
-    if prof is not None:
-        prof.add_device_ns(id(node), clock() - t0)
-    return out
+    return _finalize(node, key_plans, agg_plans, results, probe, pscan,
+                     dictionaries, group_space, group_mode, sum_modes)
 
 
 # -- in-program collective combine (serene_shard_combine=device) ------------
@@ -1918,11 +1871,11 @@ def _run_fused_collective(node, probe: _Side, build: _Side, pscan,
                           key_plans, group_space: int, group_mode: bool,
                           agg_plans, sum_modes: dict, cl: np.ndarray,
                           g: int, dictionaries,
-                          shape_sig: tuple, ctx, prof, clock,
+                          shape_sig: tuple, ctx, prof,
                           per_shard: dict, shard_ids: list,
                           pruned: int, keyset, needed_p,
-                          build_outs_for, bstart: dict, bmm_sis: list,
-                          tspan) -> Batch:
+                          build_outs_for, bstart: dict,
+                          bmm_sis: list) -> Batch:
     import jax.numpy as jnp
     from jax import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -1952,7 +1905,6 @@ def _run_fused_collective(node, probe: _Side, build: _Side, pscan,
     sh3 = mesh_mod.data_sharding(mesh, 3)
 
     # -- shard-sharded inputs, publication-cached -------------------------
-    t0 = clock()
 
     def _for_spec(ji: int) -> tuple[str, int]:
         """Frame-of-reference scheme for one stacked column, decided
@@ -2029,9 +1981,6 @@ def _run_fused_collective(node, probe: _Side, build: _Side, pscan,
     # the collective dispatch with zero build work and zero transfer
     rep_sh = NamedSharding(mesh, P())
     bouts = build_outs_for(rep_sh, f"coll{M}")
-    if prof is not None:
-        prof.add_device_ns(id(pscan), clock() - t0)
-    tspan("device_upload", t0, shards=S)
 
     # -- the single collective program ------------------------------------
     out_kinds = _collective_out_kinds(agg_plans)
@@ -2102,18 +2051,14 @@ def _run_fused_collective(node, probe: _Side, build: _Side, pscan,
     # the shard workloads still execute — as lanes of one program
     metrics.SHARD_PIPELINES.add(S)
     from ..obs.resources import wait_scope
-    with wait_scope("Device", "CollectiveCombine"):
-        results = obs_device.fetch_all(jitted(*flat_args))
-    dt = time.perf_counter_ns() - t_d
-    metrics.COLLECTIVE_COMBINE_NS.add(dt)
-    metrics.DEVICE_DISPATCH_HIST.observe_ns(dt)
-    tspan("collective_dispatch", t_d, shards=S, mesh=M)
+    with wait_scope("Device", "CollectiveCombine"), \
+            span("collective_dispatch", "device", shards=S, mesh=M):
+        results = obs_device.dispatch(jitted, flat_args, profile=prof,
+                                      node_key=id(node))
+    metrics.COLLECTIVE_COMBINE_NS.add(time.perf_counter_ns() - t_d)
     shard_mod.stamp_profile(ctx, id(node), S, pruned, collective=True)
-    out = _finalize(node, key_plans, agg_plans, results, probe, pscan,
-                    dictionaries, group_space, group_mode, sum_modes)
-    if prof is not None:
-        prof.add_device_ns(id(node), time.perf_counter_ns() - t_d)
-    return out
+    return _finalize(node, key_plans, agg_plans, results, probe, pscan,
+                     dictionaries, group_space, group_mode, sum_modes)
 
 
 def _mm_ident(func: str) -> int:
@@ -2306,59 +2251,60 @@ def _finalize(node, key_plans, agg_plans, results, probe: _Side, pscan,
     arrive pre-gathered to the selected group rows (stage 2's top_k
     indices), `codes` holds those rows' composite group codes, and only
     rows [row_lo, row_hi) emit (host-side OFFSET/LIMIT slice)."""
-    star_filter = star_filter or {}
-    distinct_plans = distinct_plans or {}
-    ri = iter(results)
-    pair_counts = np.asarray(next(ri)).astype(np.int64)
-    if slots is not None:
-        slot_codes, row_lo, row_hi = slots
-        present = np.arange(row_lo, row_hi)
-    elif group_mode:
-        present = np.flatnonzero(pair_counts > 0)
-    else:
-        present = np.asarray([0])
-    cols: list[Column] = []
-    if group_mode:
-        sizes = [kp[3] for kp in key_plans]
-        rem = slot_codes[row_lo:row_hi].copy() if slots is not None \
-            else present.copy()
-        key_codes = []
-        for size in reversed(sizes):
-            key_codes.append(rem % size)
-            rem //= size
-        key_codes.reverse()
-        for pos, ((kind, ji, lo, size), kc) in \
-                enumerate(zip(key_plans, key_codes)):
-            null_mask = kc == (size - 1)
-            t = node.group_exprs[pos].type
-            if kind == "dict":
-                d = dictionaries[ji]
-                data = np.where(null_mask, 0, kc).astype(np.int32)
-                cols.append(Column(
-                    t, data, ~null_mask if null_mask.any() else None, d))
-            else:
-                data = (kc + lo).astype(t.np_dtype)
-                data = np.where(null_mask, 0, data).astype(t.np_dtype)
-                cols.append(Column(
-                    t, data, ~null_mask if null_mask.any() else None))
-    for si, (spec, side, ce) in enumerate(agg_plans):
-        if si in distinct_plans:
-            cols.append(_distinct_result_col(
-                spec, np.asarray(next(ri)), distinct_plans[si],
-                group_space, group_mode, present))
-            continue
-        if spec.func == "count_star" and si in star_filter:
-            c = np.asarray(next(ri)).astype(np.int64)
-            if group_mode:
-                cols.append(Column(dt.BIGINT, c[present]))
-            else:
-                cols.append(Column.from_pylist([int(c[0])], spec.type))
-            continue
-        cols.append(_agg_result_col(spec, ri, pair_counts, present,
-                                    group_mode,
-                                    sum_modes.get(si, "limb"),
-                                    dictionaries))
-    return Batch(list(node.names), cols)
+    with stage("device_finalize"):
+        star_filter = star_filter or {}
+        distinct_plans = distinct_plans or {}
+        ri = iter(results)
+        pair_counts = np.asarray(next(ri)).astype(np.int64)
+        if slots is not None:
+            slot_codes, row_lo, row_hi = slots
+            present = np.arange(row_lo, row_hi)
+        elif group_mode:
+            present = np.flatnonzero(pair_counts > 0)
+        else:
+            present = np.asarray([0])
+        cols: list[Column] = []
+        if group_mode:
+            sizes = [kp[3] for kp in key_plans]
+            rem = slot_codes[row_lo:row_hi].copy() if slots is not None \
+                else present.copy()
+            key_codes = []
+            for size in reversed(sizes):
+                key_codes.append(rem % size)
+                rem //= size
+            key_codes.reverse()
+            for pos, ((kind, ji, lo, size), kc) in \
+                    enumerate(zip(key_plans, key_codes)):
+                null_mask = kc == (size - 1)
+                t = node.group_exprs[pos].type
+                if kind == "dict":
+                    d = dictionaries[ji]
+                    data = np.where(null_mask, 0, kc).astype(np.int32)
+                    cols.append(Column(
+                        t, data, ~null_mask if null_mask.any() else None, d))
+                else:
+                    data = (kc + lo).astype(t.np_dtype)
+                    data = np.where(null_mask, 0, data).astype(t.np_dtype)
+                    cols.append(Column(
+                        t, data, ~null_mask if null_mask.any() else None))
+        for si, (spec, side, ce) in enumerate(agg_plans):
+            if si in distinct_plans:
+                cols.append(_distinct_result_col(
+                    spec, np.asarray(next(ri)), distinct_plans[si],
+                    group_space, group_mode, present))
+                continue
+            if spec.func == "count_star" and si in star_filter:
+                c = np.asarray(next(ri)).astype(np.int64)
+                if group_mode:
+                    cols.append(Column(dt.BIGINT, c[present]))
+                else:
+                    cols.append(Column.from_pylist([int(c[0])], spec.type))
+                continue
+            cols.append(_agg_result_col(spec, ri, pair_counts, present,
+                                        group_mode,
+                                        sum_modes.get(si, "limb"),
+                                        dictionaries))
+        return Batch(list(node.names), cols)
 
 
 def _distinct_result_col(spec: AggSpec, grid: np.ndarray, dplan,
@@ -2558,22 +2504,9 @@ def try_device_fused_topn(limit_node, ctx) -> Optional[Batch]:
             return None
     desc = bool(sort.descs[0])
     try:
-        prof = getattr(ctx, "profile", None)
-        from ..obs.trace import current_trace
-        trace = current_trace()
-        t0 = time.perf_counter_ns()
-        out = _run_fused_topn(limit_node, scan, preds, ki, desc, k, ctx,
-                              proj)
-        if prof is not None:
-            prof.add_device_ns(id(limit_node),
-                               time.perf_counter_ns() - t0)
-        if out is not None:
-            metrics.DEVICE_DISPATCH_HIST.observe_ns(
-                time.perf_counter_ns() - t0)
-            if trace is not None:
-                trace.add("device_dispatch", "device", t0,
-                          time.perf_counter_ns(), op="topn")
-        return out
+        with stage("device_prepare", op="fused_topn"):
+            return _run_fused_topn(limit_node, scan, preds, ki, desc, k,
+                                   ctx, proj)
     except (NotCompilable, DeviceNarrowingError) as e:
         log.debug("device", f"fused top-N fell back to CPU: {e}")
         return decline(getattr(e, "reason", "not_compilable"))
@@ -2672,22 +2605,26 @@ def _run_fused_topn(limit_node, scan, preds, ki: int, desc: bool, k: int,
     flat_args.append(rowmask)
     check_cancel()
     metrics.DEVICE_OFFLOADS.add()
-    kk, ii, nsurv = obs_device.fetch_all(jitted(*flat_args))
-    idx = ii.astype(np.int64)
-    k_eff = min(k, int(nsurv))
-    idx = idx[:k_eff]
-    if side.zrange is not None:
-        idx = idx + side.zrange[0]
-    idx = idx[limit_node.offset:]
-    if side.pin is not None and all(c in side.pin[0] for c in scan.columns):
-        base = Batch(list(scan.columns),
-                     [side.pin[0].column(c) for c in scan.columns])
-    else:
-        base = side.provider.full_batch(scan.columns)
-    base = base.take(idx)
-    if proj is None:
-        return base
-    return Batch(list(proj.names), [e.eval(base) for e in proj.exprs])
+    kk, ii, nsurv = obs_device.dispatch(
+        jitted, flat_args, profile=getattr(ctx, "profile", None),
+        node_key=id(limit_node))
+    with stage("device_finalize"):
+        idx = ii.astype(np.int64)
+        k_eff = min(k, int(nsurv))
+        idx = idx[:k_eff]
+        if side.zrange is not None:
+            idx = idx + side.zrange[0]
+        idx = idx[limit_node.offset:]
+        if side.pin is not None and \
+                all(c in side.pin[0] for c in scan.columns):
+            base = Batch(list(scan.columns),
+                         [side.pin[0].column(c) for c in scan.columns])
+        else:
+            base = side.provider.full_batch(scan.columns)
+        base = base.take(idx)
+        if proj is None:
+            return base
+        return Batch(list(proj.names), [e.eval(base) for e in proj.exprs])
 
 
 # -- chained device-resident stages: fused agg → fused top-N -----------------
@@ -2725,9 +2662,7 @@ def try_device_chained_topn(limit_node, ctx) -> Optional[Batch]:
     NULLS LAST / desc NULLS FIRST exactly) or count-family aggregates;
     min/max/sum keys decline (their device identities have no
     NULL-consistent total order to hand top_k). None → host path."""
-    import jax.numpy as jnp
-    from .device_topn import _I32_MIN
-    from .plan import AggregateNode, ProjectNode, SortNode, check_cancel
+    from .plan import AggregateNode, ProjectNode, SortNode
 
     settings = ctx.settings
     if settings.get("serene_device") == "cpu" or \
@@ -2769,6 +2704,17 @@ def try_device_chained_topn(limit_node, ctx) -> Optional[Batch]:
     admitted = _admit_pipeline(agg, ctx, decline)
     if admitted is None:
         return None
+    with stage("device_prepare", op="fused_chain"):
+        return _run_chained_topn(limit_node, agg, sort, proj, sel, ng, k,
+                                 ctx, decline, admitted)
+
+
+def _run_chained_topn(limit_node, agg, sort, proj, sel: int, ng: int,
+                      k: int, ctx, decline, admitted) -> Optional[Batch]:
+    import jax.numpy as jnp
+    from .device_topn import _I32_MIN
+    from .plan import check_cancel
+
     join, probe_side, build_side, post_preds = admitted
     try:
         res = _run_fused(agg, join, probe_side, build_side, post_preds,
@@ -2840,13 +2786,14 @@ def try_device_chained_topn(limit_node, ctx) -> Optional[Batch]:
                                   profile=prof,
                                   node_key=id(limit_node))
     check_cancel()
-    t0 = time.perf_counter_ns()
     metrics.DEVICE_OFFLOADS.add()
     metrics.REGISTRY.gauge(
         "DeviceChainedStages",
         "Fused agg→top-N chains executed with the intermediate "
         "accumulators handed off in HBM").add()
-    fetched = obs_device.fetch_all(jitted2(*outs))
+    fetched = obs_device.dispatch(jitted2, outs, profile=prof,
+                                  node_key=id(limit_node),
+                                  t0_ns=fin["t_enqueue"])
     ii_np = np.asarray(fetched[0]).astype(np.int64)
     npres = int(fetched[1])
     k_eff = min(k, npres)
@@ -2860,7 +2807,4 @@ def try_device_chained_topn(limit_node, ctx) -> Optional[Batch]:
     if proj is not None:
         out = Batch(list(proj.names),
                     [out.columns[e.index] for e in proj.exprs])
-    if prof is not None:
-        prof.add_device_ns(id(limit_node), time.perf_counter_ns() - t0)
-    metrics.DEVICE_DISPATCH_HIST.observe_ns(time.perf_counter_ns() - t0)
     return out

@@ -23,6 +23,7 @@ import numpy as np
 
 from ..columnar import dtypes as dt
 from ..columnar.column import Batch
+from ..obs.trace import stage
 from ..utils import log, metrics
 from .device import NotCompilable
 from .tables import TableProvider
@@ -74,38 +75,28 @@ def try_device_topn(limit_node, ctx) -> Optional[Batch]:
             provider.row_count() < ctx.settings.get("serene_device_min_rows"):
         return None
     from ..columnar.device import DeviceNarrowingError
-    from ..obs.trace import current_trace
-    prof = getattr(ctx, "profile", None)
-    trace = current_trace()
     try:
-        import time as _time
-        t0 = _time.perf_counter_ns()
-        idx = _topn_indices(provider, scan, scan.columns[col_idx],
-                            bool(sort.descs[0]), k, ctx,
-                            prof_key=id(limit_node))
-        t1 = _time.perf_counter_ns()
-        if prof is not None:
-            # device-path time lands on the Limit node that claimed the
-            # Sort pipeline (the offload replaced its whole subtree)
-            prof.add_device_ns(id(limit_node), t1 - t0)
-        if idx is not None:
-            # unconditional: the device latency signal survives
-            # profiling/tracing being off (None = declined, no dispatch)
-            from ..utils import metrics as _metrics
-            _metrics.DEVICE_DISPATCH_HIST.observe_ns(t1 - t0)
-            if trace is not None:
-                trace.add("device_dispatch", "device", t0, t1, op="topn")
+        # `device_prepare` but for what stamps itself inside: the
+        # program call, the readback, and the host decode below
+        with stage("device_prepare", op="topn"):
+            # device-path time (enqueue start -> readback done) lands on
+            # the Limit node that claimed the Sort pipeline (the offload
+            # replaced its whole subtree)
+            idx = _topn_indices(provider, scan, scan.columns[col_idx],
+                                bool(sort.descs[0]), k, ctx,
+                                prof_key=id(limit_node))
+            if idx is None:
+                return None
+            with stage("device_finalize"):
+                idx = idx[limit_node.offset:]
+                base = provider.full_batch(scan.columns).take(idx)
+                if proj is None:
+                    return base
+                cols = [e.eval(base) for e in proj.exprs]
+                return Batch(list(proj.names), cols)
     except (NotCompilable, DeviceNarrowingError) as e:
         log.debug("device", f"top-N fell back to CPU: {e}")
         return None
-    if idx is None:
-        return None
-    idx = idx[limit_node.offset:]
-    base = provider.full_batch(scan.columns).take(idx)
-    if proj is None:
-        return base
-    cols = [e.eval(base) for e in proj.exprs]
-    return Batch(list(proj.names), cols)
 
 
 def _topn_indices(provider: TableProvider, scan, col_name: str,
@@ -219,21 +210,24 @@ def _topn_indices(provider: TableProvider, scan, col_name: str,
     if data.shape[0] * data.shape[1] < k * max(mesh_n, 1):
         # top_k k exceeds the (per-shard) domain — tiny table, CPU wins
         raise NotCompilable("k exceeds per-shard rows")
-    kk, ii = obs_device.fetch_all(jitted(data, mask))
-    ii = ii.astype(np.int64)
-    if mesh_n > 1:
-        # merge the per-shard candidate lists: global top-k of N*k.
-        # Candidates from under-filled shards carry the padding sentinel
-        # — drop them (finite/valid keys are strictly above it by the
-        # gates), and widen to float64 so negating int32 min can't wrap.
-        kkw = kk.astype(np.float64)
-        sent = -np.inf if is_float else float(_I32_MIN)
-        valid = kkw > sent
-        kkw, ii = kkw[valid], ii[valid]
-        order = np.argsort(-kkw, kind="stable")[: k]
-        ii = ii[order]
-    if zrange is not None:
-        ii = ii + zrange[0]     # slice-relative → table row ids
-    metrics.DEVICE_OFFLOADS.add()
-    k_eff = min(k, n)
-    return ii[:k_eff]
+    kk, ii = obs_device.dispatch(jitted, (data, mask),
+                                 profile=getattr(ctx, "profile", None),
+                                 node_key=prof_key)
+    with stage("device_finalize"):
+        ii = ii.astype(np.int64)
+        if mesh_n > 1:
+            # merge the per-shard candidate lists: global top-k of N*k.
+            # Candidates from under-filled shards carry the padding sentinel
+            # — drop them (finite/valid keys are strictly above it by the
+            # gates), and widen to float64 so negating int32 min can't wrap.
+            kkw = kk.astype(np.float64)
+            sent = -np.inf if is_float else float(_I32_MIN)
+            valid = kkw > sent
+            kkw, ii = kkw[valid], ii[valid]
+            order = np.argsort(-kkw, kind="stable")[: k]
+            ii = ii[order]
+        if zrange is not None:
+            ii = ii + zrange[0]     # slice-relative → table row ids
+        metrics.DEVICE_OFFLOADS.add()
+        k_eff = min(k, n)
+        return ii[:k_eff]

@@ -34,7 +34,7 @@ import numpy as np
 from ..columnar import dtypes as dt
 from ..columnar.column import (Batch, Column, concat_batches,
                                merge_dictionaries)
-from ..obs.trace import batch_nbytes
+from ..obs.trace import batch_nbytes, stage
 from ..ops.agg import factorize_codes, factorize_keys
 from ..parallel.pool import parallel_map
 from ..sql.expr import AggSpec, BoundColumn
@@ -55,18 +55,12 @@ class _Fallback(Exception):
     """Shape turned out unsupported mid-flight — use the serial path."""
 
 
-def _stage_clocks() -> tuple[int, int]:
-    return time.perf_counter_ns(), time.thread_time_ns()
-
-
-def _stage_stamp(prof, key: int, b: Batch,
-                 clocks: tuple[int, int]) -> tuple[int, int]:
-    """One morsel × one fused stage → one add_stage() span; returns fresh
-    clocks so consecutive stages chain without double counting."""
-    t1, c1 = time.perf_counter_ns(), time.thread_time_ns()
-    prof.add_stage(key, b.num_rows, t1 - clocks[0], c1 - clocks[1],
-                   batch_nbytes(b))
-    return t1, c1
+def _stage_stamp(prof, key: int, b: Batch, t0: int) -> int:
+    """One morsel × one fused stage → one add_stage() span; returns a
+    fresh clock so consecutive stages chain without double counting."""
+    t1 = time.perf_counter_ns()
+    prof.add_stage(key, b.num_rows, t1 - t0)
+    return t1
 
 
 def try_parallel_aggregate(node, ctx) -> Optional[Batch]:
@@ -196,7 +190,7 @@ def try_parallel_aggregate(node, ctx) -> Optional[Batch]:
             # the same order of bytes — the slice size is the charge)
             mem.charge(id(scan), in_bytes)
         all_match = verdict == zonemap.ALL
-        clocks = _stage_clocks() if prof is not None else None
+        clocks = time.perf_counter_ns() if prof is not None else None
         if scan.filter is not None and not all_match:
             c = scan.filter.eval(b)
             b = b.filter(c.data.astype(bool) & c.valid_mask())
@@ -214,7 +208,8 @@ def try_parallel_aggregate(node, ctx) -> Optional[Batch]:
                 b = Batch(list(st.names), [e.eval(b) for e in st.exprs])
             if clocks is not None:
                 clocks = _stage_stamp(prof, id(st), b, clocks)
-        p = _morsel_partials(node, b)
+        with stage("host_group"):
+            p = _morsel_partials(node, b)
         if mem is not None:
             # the partial outlives the task (released by the merge
             # sink); the input slice retires with it
@@ -253,7 +248,8 @@ def try_parallel_aggregate(node, ctx) -> Optional[Batch]:
                 for pos, p in chunk:
                     ordered[pos] = p
             shard_mod.stamp_profile(ctx, id(node), len(shard_lists))
-            out = _merge_partials(node, ordered)
+            with stage("host_group"):
+                out = _merge_partials(node, ordered)
             if mem is not None:
                 mem.release(id(node),
                             sum(batch_nbytes(p) for p in ordered))
@@ -263,7 +259,8 @@ def try_parallel_aggregate(node, ctx) -> Optional[Batch]:
                           shards=len(shard_lists))
             return out
         partials = parallel_map(settings, run_morsel, keep)
-        out = _merge_partials(node, partials)
+        with stage("host_group"):
+            out = _merge_partials(node, partials)
         if mem is not None:
             mem.release(id(node),
                         sum(batch_nbytes(p) for p in partials))

@@ -18,6 +18,7 @@ import numpy as np
 from .. import errors
 from ..columnar import dtypes as dt
 from ..columnar.column import Batch, Column, concat_batches, merge_dictionaries
+from ..obs.trace import stage
 from ..sql.expr import AggSpec, BoundColumn, BoundExpr
 from ..utils.config import SessionSettings
 from .tables import TableProvider
@@ -143,9 +144,13 @@ class ScanNode(PlanNode):
         for b in self.provider.batches(self.columns):
             check_cancel()
             if self.filter is not None:
-                mask_col = self.filter.eval(b)
-                mask = mask_col.data.astype(bool) & mask_col.valid_mask()
-                b = b.filter(mask)
+                # `host_scan`: predicates and projections evaluated on
+                # the host, batch by batch (never across a yield)
+                with stage("host_scan"):
+                    mask_col = self.filter.eval(b)
+                    mask = mask_col.data.astype(bool) & \
+                        mask_col.valid_mask()
+                    b = b.filter(mask)
             yield b
 
     def _pruned_batches(self, ctx: ExecContext, join_filters=None):
@@ -231,8 +236,10 @@ class ScanNode(PlanNode):
                 # nothing about the scan filter
                 if self.filter is not None and \
                         (v_scan is None or v_scan[b] != zonemap.ALL):
-                    c = self.filter.eval(sl)
-                    sl = sl.filter(c.data.astype(bool) & c.valid_mask())
+                    with stage("host_scan"):
+                        c = self.filter.eval(sl)
+                        sl = sl.filter(c.data.astype(bool) &
+                                       c.valid_mask())
                 emitted = True
                 yield sl
             if not emitted:
@@ -318,9 +325,11 @@ class FilterNode(PlanNode):
 
     def batches(self, ctx):
         for b in self.child.batches(ctx):
-            c = self.pred.eval(b)
-            mask = c.data.astype(bool) & c.valid_mask()
-            yield b.filter(mask)
+            with stage("host_scan"):
+                c = self.pred.eval(b)
+                mask = c.data.astype(bool) & c.valid_mask()
+                out = b.filter(mask)
+            yield out
 
     def label(self):
         return "Filter"
@@ -339,7 +348,8 @@ class ProjectNode(PlanNode):
 
     def batches(self, ctx):
         for b in self.child.batches(ctx):
-            cols = [e.eval(b) for e in self.exprs]
+            with stage("host_scan"):
+                cols = [e.eval(b) for e in self.exprs]
             yield Batch(list(self.names), cols)
 
     def label(self):
@@ -460,6 +470,11 @@ class SortNode(PlanNode):
         if full.num_rows <= 1:
             yield full
             return
+        with stage("host_sort"):
+            out = self._sort(full)
+        yield out
+
+    def _sort(self, full: Batch) -> Batch:
         # np.lexsort: last key is primary. Keys are densified to int64 ranks
         # (np.unique inverse) so DESC negation and NULL placement are exact
         # for any dtype, including int64 beyond 2^53.
@@ -482,7 +497,7 @@ class SortNode(PlanNode):
             keys.append(np.where(nulls, 0, ranks))
             keys.append(nullkey)
         order = np.lexsort(tuple(keys))
-        yield full.take(order)
+        return full.take(order)
 
     def label(self):
         return f"Sort {list(zip(self.key_indices, self.descs))}"
@@ -1161,20 +1176,26 @@ class AggregateNode(PlanNode):
             return self._cpu_scalar_agg(ctx)
         full = concat_batches(list(self.child.batches(ctx)))
         from ..ops.agg import factorize_keys
-        key_cols = [g.eval(full) for g in self.group_exprs]
-        codes, uniq_vals, uniq_valid = factorize_keys(
-            [c.data for c in key_cols],
-            [c.validity for c in key_cols])
-        num_groups = len(uniq_vals[0]) if uniq_vals else 0
-        out_cols: list[Column] = []
-        for k, (kc, uv) in enumerate(zip(key_cols, uniq_vals)):
-            validity = uniq_valid[k] if uniq_valid.size else None
-            if validity is not None and validity.all():
-                validity = None
-            out_cols.append(Column(kc.type, uv, validity, kc.dictionary))
-        for spec in self.aggs:
-            out_cols.append(self._cpu_group_agg(spec, full, codes, num_groups))
-        return Batch(list(self.names), out_cols)
+        # the request's `host_group` stage: key factorization
+        # (`_unique_columns`) and every per-group aggregate, DISTINCT
+        # ones (`_cpu_group_distinct`) included
+        with stage("host_group"):
+            key_cols = [g.eval(full) for g in self.group_exprs]
+            codes, uniq_vals, uniq_valid = factorize_keys(
+                [c.data for c in key_cols],
+                [c.validity for c in key_cols])
+            num_groups = len(uniq_vals[0]) if uniq_vals else 0
+            out_cols: list[Column] = []
+            for k, (kc, uv) in enumerate(zip(key_cols, uniq_vals)):
+                validity = uniq_valid[k] if uniq_valid.size else None
+                if validity is not None and validity.all():
+                    validity = None
+                out_cols.append(Column(kc.type, uv, validity,
+                                       kc.dictionary))
+            for spec in self.aggs:
+                out_cols.append(self._cpu_group_agg(spec, full, codes,
+                                                    num_groups))
+            return Batch(list(self.names), out_cols)
 
     def _cpu_group_agg(self, spec: AggSpec, full: Batch, codes: np.ndarray,
                        g: int) -> Column:
@@ -1355,9 +1376,13 @@ class AggregateNode(PlanNode):
     def _cpu_scalar_agg(self, ctx) -> Batch:
         accs = [_ScalarAcc(spec) for spec in self.aggs]
         for b in self.child.batches(ctx):
-            for acc in accs:
-                acc.update(b)
-        cols = [acc.result() for acc in accs]
+            # `host_group`, batch by batch: the scan between two batches
+            # is not the aggregate's time
+            with stage("host_group"):
+                for acc in accs:
+                    acc.update(b)
+        with stage("host_group"):
+            cols = [acc.result() for acc in accs]
         return Batch(list(self.names), cols)
 
 

@@ -122,10 +122,8 @@ def run_shard_tasks(settings, fn: Callable, shard_items: list) -> list:
     shard's execution is stamped as a `shard_pipeline` span (with its
     shard index) into the query's timeline — the shard fan-out becomes
     visible as parallel lanes in the Chrome trace."""
-    import time
-
     from ..obs.resources import current_accountant
-    from ..obs.trace import current_trace
+    from ..obs.trace import current_trace, span
     from ..parallel.pool import parallel_map
     metrics.SHARD_PIPELINES.add(len(shard_items))
     acct = current_accountant()
@@ -144,12 +142,8 @@ def run_shard_tasks(settings, fn: Callable, shard_items: list) -> list:
         # them so the lane agrees with the device spans stamped inside;
         # other callers pass per-shard work lists, labeled by position
         label = item if isinstance(item, int) else s
-        t0 = time.perf_counter_ns()
-        try:
+        with span("shard_pipeline", "shard", shard=label):
             return fn(item)
-        finally:
-            trace.add("shard_pipeline", "shard", t0,
-                      time.perf_counter_ns(), shard=label)
 
     return parallel_map(settings, traced, list(enumerate(shard_items)))
 

@@ -89,6 +89,9 @@ class TableProvider:
             for name in names:
                 entry = self._device_cache.get(name)
                 if entry is None or entry[0] != ver:
+                    # a device-resident column is asked for: the same
+                    # gauges DEVICE_CACHE bumps, whichever cache holds it
+                    metrics.DEVICE_CACHE_MISSES.add()
                     col = (batch.column(name) if batch is not None
                            else self.full_batch([name]).column(name))
                     dc = to_device_column(col)
@@ -97,6 +100,7 @@ class TableProvider:
                     self._device_cache[name] = (ver, dc)
                     out[name] = dc
                 else:
+                    metrics.DEVICE_CACHE_HITS.add()
                     out[name] = entry[1]
             return out
 

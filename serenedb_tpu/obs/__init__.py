@@ -2,7 +2,7 @@
 statement statistics, metrics export (ISSUES 4 + 10).
 
 The instrument panel for every later perf PR: `obs.trace` collects
-per-operator spans (rows, wall+CPU time, morsel prune counters, bytes,
+per-operator spans (rows, wall time, morsel prune counters,
 device time) with per-worker-thread accumulation and a deterministic
 sink merge, AND the per-query timeline layer (trace ids, timestamped
 span events in per-thread rings, the always-on flight recorder, Chrome

@@ -61,6 +61,7 @@ from typing import Callable, Optional
 
 from ..utils import log, metrics
 from ..utils.config import REGISTRY as _settings
+from .trace import current_trace, stage
 
 #: new compiles of ONE family within a 60s window that trip the
 #: recompile-storm warning (a healthy steady state compiles each query
@@ -207,21 +208,42 @@ def note_fetch(nbytes: int, ids, ns: int = 0) -> None:
 def fetch_all(outs) -> list:
     """Device→host readback of a program's outputs (the np.asarray
     choke point): returns numpy arrays, accounting bytes/time per
-    device. Conversion is what every call site did anyway — telemetry
-    adds only the clock reads and one ledger bump."""
+    device. This is where the host blocks until the device is done, so
+    it is the request's `device_wait` stage (device execution + D2H).
+    Conversion is what every call site did anyway — telemetry adds only
+    the clock reads and one ledger bump."""
     import numpy as np
-    if not enabled():
-        return [np.asarray(o) for o in outs]
-    leaf = _first_jax_leaf(outs)
-    ids = array_device_ids(leaf) if leaf is not None else ()
-    t0 = time.perf_counter_ns()
-    arrs = [np.asarray(o) for o in outs]
-    # direct ledger calls — the enabled() gate already ran above, and
-    # re-checking inside note_fetch would take the settings-registry
-    # lock a second time on the per-dispatch hot path
-    nbytes = sum(int(a.nbytes) for a in arrs)
-    LEDGER.note_fetch(nbytes, ids, time.perf_counter_ns() - t0)
-    metrics.DEVICE_FETCH_BYTES.add(nbytes)
+    with stage("device_wait"):
+        if not enabled():
+            return [np.asarray(o) for o in outs]
+        leaf = _first_jax_leaf(outs)
+        ids = array_device_ids(leaf) if leaf is not None else ()
+        t0 = time.perf_counter_ns()
+        arrs = [np.asarray(o) for o in outs]
+        # direct ledger calls — the enabled() gate already ran above, and
+        # re-checking inside note_fetch would take the settings-registry
+        # lock a second time on the per-dispatch hot path
+        nbytes = sum(int(a.nbytes) for a in arrs)
+        LEDGER.note_fetch(nbytes, ids, time.perf_counter_ns() - t0)
+        metrics.DEVICE_FETCH_BYTES.add(nbytes)
+        return arrs
+
+
+def dispatch(prog, args, profile=None, node_key=None,
+             t0_ns: Optional[int] = None) -> list:
+    """One program call and the blocking readback of its outputs —
+    what `DeviceDispatch` and `QueryProfile.device_ns` (EXPLAIN
+    ANALYZE's `Device: time=`) mean at every site: enqueue start →
+    readback done. A chain whose first stage left its outputs in HBM
+    passes that stage's enqueue start as `t0_ns`."""
+    t0 = time.perf_counter_ns() if t0_ns is None else t0_ns
+    arrs = fetch_all(prog(*args))
+    ns = time.perf_counter_ns() - t0
+    # unconditional: the device latency signal survives profiling and
+    # tracing being off (two clock reads per ms-scale offload)
+    metrics.DEVICE_DISPATCH_HIST.observe_ns(ns)
+    if profile is not None and node_key is not None:
+        profile.add_device_ns(node_key, ns)
     return arrs
 
 
@@ -287,33 +309,42 @@ class CompiledProgram:
         self._timed = False
 
     def __call__(self, *args):
-        if self._timed:
-            if enabled():
-                out = self.fn(*args)
-                leaf = _first_jax_leaf(out)
-                LEDGER.note_dispatch(
-                    array_device_ids(leaf) if leaf is not None else ())
-                return out
-            return self.fn(*args)
         # first call: benign race — two threads may both time; the
         # ledger records both observations, results are identical
-        self._timed = True
-        if not enabled():
-            return self.fn(*args)
+        first, self._timed = not self._timed, True
         t0 = time.perf_counter_ns()
-        out = self.fn(*args)
-        ns = time.perf_counter_ns() - t0
-        self.compile_ns = ns
-        PROGRAMS.record_compile_time(self.family, ns)
-        from .trace import current_trace
-        tr = current_trace()
-        if tr is not None:
-            tr.add("device_compile", "device", t0, t0 + ns,
-                   family=self.family)
+        # the call returning IS the request's `device_enqueue` stage
+        # (the first call: trace + compile + enqueue)
+        with stage("device_enqueue"):
+            out = self.fn(*args)
+        if not enabled():
+            return out
+        if first:
+            ns = time.perf_counter_ns() - t0
+            self.compile_ns = ns
+            PROGRAMS.record_compile_time(self.family, ns)
+            tr = current_trace()
+            if tr is not None:
+                tr.add("device_compile", "device", t0, t0 + ns,
+                       family=self.family)
         leaf = _first_jax_leaf(out)
         LEDGER.note_dispatch(
             array_device_ids(leaf) if leaf is not None else ())
         return out
+
+
+def _named(family: str, body: Callable) -> Callable:
+    """The program body traced under `jax.named_scope(family)` and
+    called after its family, so a profiler trace's module and name-scope
+    lines say `jit_device_agg` / `fused_topn/...` instead of
+    `jit_program` / `%fusion`."""
+    import jax
+
+    def program(*args):
+        with jax.named_scope(family):
+            return body(*args)
+    program.__name__ = program.__qualname__ = family
+    return program
 
 
 def _new_family() -> dict:
@@ -362,7 +393,7 @@ class ProgramLedger:
         # builder may construct meshes/shard_maps; a racing duplicate
         # build is wasted work, never wrong (the loser is discarded)
         import jax
-        prog = CompiledProgram(jax.jit(builder()), family)
+        prog = CompiledProgram(jax.jit(_named(family, builder())), family)
         with self._lock:
             cur = self._progs.get(full)
             if cur is not None:

@@ -12,7 +12,7 @@ thread accumulates into its own bucket (a thread-local dict — no lock on
 the hot path after first touch); the sink merges buckets by summing
 integer counters, so the merged numbers are independent of scheduling
 order and the query result is bit-identical with profiling on or off at
-any `serene_workers`. Wall/CPU nanoseconds in morsel pipelines are
+any `serene_workers`. Wall nanoseconds in morsel pipelines are
 summed per-worker task times (they can exceed elapsed wall clock on
 purpose — that is the work the pool did).
 """
@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import contextvars
 import itertools
+import sys
 import threading
 import time
 from collections import OrderedDict
@@ -29,8 +30,7 @@ from typing import Iterator, Optional
 from ..utils import metrics
 
 #: additive per-operator counters (merge = sum; scheduling-order free)
-_COUNTERS = ("wall_ns", "cpu_ns", "rows_out", "batches", "bytes_out",
-             "loops", "morsels_scheduled", "morsels_pruned",
+_COUNTERS = ("wall_ns", "rows_out", "loops", "morsels_scheduled", "morsels_pruned",
              "morsels_jf_pruned", "device_ns", "batch_queries",
              "batch_window_ns", "batch_scoring_ns", "shard_pipelines",
              "shard_pruned", "shard_collective",
@@ -111,16 +111,12 @@ class QueryProfile:
         s.morsels_pruned += int(pruned)
         s.morsels_jf_pruned += int(jf_pruned)
 
-    def add_stage(self, key: int, rows_out: int, wall_ns: int,
-                  cpu_ns: int = 0, bytes_out: int = 0) -> None:
+    def add_stage(self, key: int, rows_out: int, wall_ns: int) -> None:
         """Fused-pipeline stamp: one morsel's pass through one operator
         (the operator's own batches() never runs in the fused path)."""
         s = self.stats(key)
         s.rows_out += int(rows_out)
         s.wall_ns += int(wall_ns)
-        s.cpu_ns += int(cpu_ns)
-        s.bytes_out += int(bytes_out)
-        s.batches += 1
 
     def add_device_ns(self, key: int, ns: int) -> None:
         self.stats(key).device_ns += int(ns)
@@ -155,30 +151,24 @@ class QueryProfile:
     def wrap_batches(self, node, fn, ctx) -> Iterator:
         """Instrumented drive of a node's raw batch generator: wall time
         accrues only while inside next() (inclusive of children, PG
-        semantics), rows/bytes per emitted batch."""
+        semantics), rows per emitted batch."""
         key = id(node)
         self.stats(key).loops += 1
         it = fn(node, ctx)
         try:
             while True:
                 t0 = time.perf_counter_ns()
-                c0 = time.thread_time_ns()
                 try:
                     b = next(it)
                 except StopIteration:
-                    s = self.stats(key)
-                    s.wall_ns += time.perf_counter_ns() - t0
-                    s.cpu_ns += time.thread_time_ns() - c0
+                    self.stats(key).wall_ns += time.perf_counter_ns() - t0
                     return
                 t1 = time.perf_counter_ns()
                 s = self.stats(key)
                 s.wall_ns += t1 - t0
-                s.cpu_ns += time.thread_time_ns() - c0
                 if s.first_ns is None:
                     s.first_ns = s.wall_ns
                 s.rows_out += b.num_rows
-                s.batches += 1
-                s.bytes_out += batch_nbytes(b)
                 yield b
         finally:
             it.close()
@@ -215,21 +205,42 @@ class QueryProfile:
 # -- timeline tracing (serene_trace) ------------------------------------------
 #
 # The QueryProfile above answers "how much" per operator; the timeline
-# layer answers "WHEN": every query gets a trace id and timestamped span
-# events — (name, category, begin ns, end ns, thread, detail) — recorded
-# into per-thread rings (a plain-list append under the GIL, no lock on
-# the hot path after first touch, the same bucket pattern QueryProfile
-# uses), so the pool's queue waits, batcher coalescing windows, shard
-# fan-outs and device dispatch phases become one Chrome-trace-loadable
-# timeline. Spans propagate across the worker pool via the CURRENT_TRACE
-# contextvar (pool tasks copy the submitter's context), and a coalesced
-# search dispatch stamps its spans under EVERY member query's trace.
+# layer answers "WHEN": every request gets a trace id and timestamped
+# span events — (id, parent, name, category, begin ns, end ns, detail)
+# — recorded into per-thread rings (a plain-list append under the GIL,
+# no lock on the hot path after first touch, the same bucket pattern
+# QueryProfile uses), so the front door's hops, the pool's queue waits,
+# batcher coalescing windows, shard fan-outs and the device phases
+# become one Chrome-trace-loadable timeline from the socket to the last
+# flushed row. The trace and the current span propagate across the
+# worker pool via the CURRENT_TRACE / CURRENT_SPAN contextvars (pool
+# tasks copy the submitter's context), and a coalesced search dispatch
+# stamps its spans under EVERY member query's trace.
+#
+# A small fixed vocabulary of spans are STAGES (`STAGES` below): they
+# cut the request's timeline into pieces that do not overlap
+# (`partition_stages`), each is also a
+# `jax.profiler.TraceAnnotation("sdb.<stage>")` while it is open (so the
+# profiler's host plane carries the same names on the device's clock),
+# and `finish()` sums them into one histogram each plus `StageOther`, so
+# that per request  sum(stages) + other == request  to the nanosecond. Envelopes (the root, `execute`) and detail spans
+# (`task`, `queue_wait`, `morsel_pipeline`, ...) are never annotated and
+# never summed.
+#
 # Like the profiler, tracing observes only — results are bit-identical
 # with it on or off at any worker/shard count.
 
 #: per-thread span ring cap: a runaway span producer degrades to
 #: counting drops instead of growing without bound
 TRACE_RING_CAP = 8192
+
+#: the stage vocabulary, fixed like the fused-tier decline reasons: one
+#: `Stage*` histogram each (utils/metrics.py), one observation per
+#: request = the stage's summed time in that request
+STAGES = ("fd_parse", "fd_queue", "fd_encode", "cache_probe", "plan",
+          "device_prepare", "device_enqueue", "device_wait",
+          "device_finalize", "host_scan", "host_concat", "host_group",
+          "host_sort")
 
 _TRACE_IDS = itertools.count(1)
 
@@ -239,11 +250,105 @@ _TRACE_IDS = itertools.count(1)
 CURRENT_TRACE: contextvars.ContextVar = contextvars.ContextVar(
     "sdb_current_trace", default=None)
 
+#: the innermost open span of this context: (trace, span id) or None.
+#: It rides the same context copy as CURRENT_TRACE, so
+#: a pool task's spans name the span that submitted them as parent.
+CURRENT_SPAN: contextvars.ContextVar = contextvars.ContextVar(
+    "sdb_current_span", default=None)
+
 
 def current_trace():
     """The executing statement's trace, or None (tracing off / outside
     a statement). One contextvar read — cheap enough for hot-ish paths."""
     return CURRENT_TRACE.get()
+
+
+class _NoSpan:
+    """What `stage()` / `span()` hand out when no trace is current."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+_NO_SPAN = _NoSpan()
+
+
+def stage(name: str, **detail):
+    """`with stage("plan"):` — one piece of the current request's
+    timeline (a no-op outside a traced request)."""
+    tr = CURRENT_TRACE.get()
+    return _NO_SPAN if tr is None else _Span(tr, name, "stage", detail)
+
+
+def stage_of(tr: Optional["QueryTrace"], name: str):
+    """`with stage_of(tr, "fd_encode"):` where the trace is held, not
+    current (the front door's event loop), and may be None."""
+    return _NO_SPAN if tr is None else _Span(tr, name, "stage", None)
+
+
+def span(name: str, cat: str, **detail):
+    """`with span("execute", "exec"):` — an envelope or detail span of
+    the current request: parented and timed, never annotated or summed."""
+    tr = CURRENT_TRACE.get()
+    return _NO_SPAN if tr is None else _Span(tr, name, cat, detail)
+
+
+_ANNOTATION = None
+
+
+def _annotate(name: str, trace_id: int):
+    """Enter `jax.profiler.TraceAnnotation("sdb.<name>")` — only in a
+    process that has imported jax already (no backend is initialised
+    for a span); one flag read in the profiler when no trace runs."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        jax = sys.modules.get("jax")
+        if jax is None:
+            return None
+        _ANNOTATION = jax.profiler.TraceAnnotation
+    ann = _ANNOTATION("sdb." + name, trace_id=trace_id)
+    ann.__enter__()
+    return ann
+
+
+class _Span:
+    """One open span. Entering makes it the context's current span (so
+    spans opened inside — on this thread or in pool tasks submitted from
+    it — name it as parent); leaving records it."""
+
+    __slots__ = ("tr", "name", "cat", "detail", "sid", "parent", "t0",
+                 "tok", "ann")
+
+    def __init__(self, tr: "QueryTrace", name: str, cat: str, detail):
+        self.tr = tr
+        self.name = name
+        self.cat = cat
+        self.detail = detail
+        self.ann = None
+
+    def __enter__(self):
+        tr = self.tr
+        self.parent = tr.current_span()
+        self.sid = next(tr._ids)
+        self.tok = CURRENT_SPAN.set((tr, self.sid))
+        if self.cat == "stage":
+            self.ann = _annotate(self.name, tr.trace_id)
+        self.t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc):
+        t1 = time.perf_counter_ns()
+        if self.ann is not None:
+            self.ann.__exit__(None, None, None)
+        CURRENT_SPAN.reset(self.tok)
+        self.tr._push(self.sid, self.parent, self.name, self.cat,
+                      self.t0, t1, self.detail or None)
+        return False
 
 
 class _Ring:
@@ -256,43 +361,92 @@ class _Ring:
         self.dropped = 0
 
 
+def partition_stages(stage_spans: list, dur: int) -> tuple[dict, list]:
+    """One request of `dur` ns cut by its stage spans [(begin, end,
+    name), ...] (offsets from the request's start): ({stage: ns} +
+    "other", the timeline as [name, begin, end] pieces in order). Stages
+    may nest (a stage opened inside `device_prepare`) and, where workers
+    of one statement run side by side, overlap: at every instant the
+    request is in the stage that BEGAN LAST among those open — the
+    innermost on a thread — so each instant is counted once and
+    sum(stages) + other == dur exactly, in integers."""
+    sums: dict = {}
+    edges = []
+    for i, (b, e, name) in enumerate(stage_spans):
+        sums.setdefault(name, 0)
+        b, e = max(b, 0), min(e, dur)
+        if e > b:
+            edges.append((b, 1, i))
+            edges.append((e, 0, i))
+    edges.sort()                      # at one instant: ends before begins
+    timeline: list = []
+    open_: list = []
+    prev = 0
+    for t, opens, i in edges:
+        if open_ and t > prev:
+            name = stage_spans[max(
+                open_, key=lambda j: (stage_spans[j][0], j))][2]
+            sums[name] += t - prev
+            if timeline and timeline[-1][0] == name and \
+                    timeline[-1][2] == prev:
+                timeline[-1][2] = t
+            else:
+                timeline.append([name, prev, t])
+        prev = t
+        if opens:
+            open_.append(i)
+        else:
+            open_.remove(i)
+    sums["other"] = dur - sum(sums.values())
+    return sums, timeline
+
+
 class QueryTrace:
-    """One query's span-event collector.
+    """One request's span-event collector.
 
     Spans are recorded at END time with explicit (begin, end)
     perf_counter_ns stamps, so within a thread they nest properly by
     construction (a span closes only after every span it started inside
-    it). `add` appends to the calling thread's ring; rings merge at
+    it). Every span has an id and names its parent (0 = the root).
+    `stage()` / `span()` are the context-manager primitive; `add` stamps
+    a span whose begin and end were read elsewhere (waits that belong to
+    no thread). All append to the calling thread's ring; rings merge at
     `finish()` into one begin-ordered span list with ns offsets relative
     to the trace start."""
 
     __slots__ = ("trace_id", "query", "t0_ns", "t0_epoch_us", "end_ns",
-                 "error", "_register_lock", "_rings", "_tl", "_cv_token")
+                 "error", "peak_bytes", "cache_hit", "entry", "owned",
+                 "_ids", "_register_lock", "_rings", "_tl", "_cv_token")
 
-    def __init__(self, query_text: str = ""):
+    def __init__(self, query_text: str = "", t0_ns: Optional[int] = None):
         self.trace_id = next(_TRACE_IDS)
         self.query = query_text
-        self.t0_ns = time.perf_counter_ns()
-        self.t0_epoch_us = int(time.time() * 1e6)
+        now = time.perf_counter_ns()
+        #: the request's start: the front door's receipt stamp when it
+        #: began the trace, else now
+        self.t0_ns = now if t0_ns is None else t0_ns
+        self.t0_epoch_us = int(time.time() * 1e6) - (now - self.t0_ns) // 1000
         self.end_ns: Optional[int] = None
         self.error: Optional[str] = None
+        #: stamped by the engine at statement end (serene_mem_account)
+        self.peak_bytes: Optional[int] = None
+        #: the engine served this statement from the result cache
+        self.cache_hit = False
+        #: the flight-recorder entry, once finished
+        self.entry: Optional[dict] = None
+        #: the engine began this trace itself (no front door) and ends
+        #: the request where the statement ends
+        self.owned = True
+        self._ids = itertools.count(1)
         self._register_lock = threading.Lock()
         self._rings: list[_Ring] = []
         self._tl = threading.local()
         self._cv_token = None
 
-    def now(self) -> int:
-        return time.perf_counter_ns()
+    # -- recording (any thread) -------------------------------------------
 
-    def add(self, name: str, cat: str, begin_ns: int, end_ns: int,
-            **detail) -> None:
-        """Record one span event from any thread. begin/end are
-        perf_counter_ns stamps (end >= begin enforced); detail keys
-        become Chrome trace `args`. Span names are free-form; the
-        "device" category carries device_compile, collective_dispatch
-        and the posting pool's posting_upload (staged page writes) /
-        posting_dispatch (batched gather-accumulate scoring) spans,
-        "search" the batcher's batch_wait / batch_dispatch pair."""
+    def _push(self, sid: int, parent: int, name: str, cat: str,
+              begin_ns: int, end_ns: int, detail) -> None:
         r = getattr(self._tl, "r", None)
         if r is None:
             t = threading.current_thread()
@@ -302,46 +456,181 @@ class QueryTrace:
         if len(r.spans) >= TRACE_RING_CAP:
             r.dropped += 1
             return
-        r.spans.append((name, cat, begin_ns, max(end_ns, begin_ns),
-                        detail or None))
+        r.spans.append((sid, parent, name, cat, begin_ns,
+                        max(end_ns, begin_ns), detail))
 
-    def finish(self, error: Optional[str] = None) -> dict:
-        """Close the trace: stamp the root `query` span, merge the
-        per-thread rings into one begin-ordered span list (offsets
-        relative to the trace start) and return the flight-recorder
-        entry dict."""
-        self.end_ns = time.perf_counter_ns()
-        self.error = error
-        dur = self.end_ns - self.t0_ns
+    def current_span(self, ctx: Optional[contextvars.Context] = None) -> int:
+        """The id of the innermost open span of this trace in the
+        calling context — or in a captured one (a pool task's, a batcher
+        member's): the parent of what is stamped there, 0 = the root."""
+        cur = CURRENT_SPAN.get() if ctx is None else ctx.get(CURRENT_SPAN)
+        return cur[1] if cur is not None and cur[0] is self else 0
+
+    def add(self, name: str, cat: str, begin_ns: int, end_ns: int,
+            _parent: Optional[int] = None, _id: Optional[int] = None,
+            **detail) -> None:
+        """Record one span event whose stamps were read elsewhere, from
+        any thread. begin/end are perf_counter_ns stamps (end >= begin
+        enforced); detail keys become Chrome trace `args`; the parent is
+        the context's current span unless given, the id a fresh one
+        unless reserved (`new_span_id`). Span names are
+        free-form; the "device" category carries device_compile,
+        collective_dispatch and the posting pool's posting_upload
+        (staged page writes) / posting_dispatch (batched
+        gather-accumulate scoring) spans, "search" the batcher's
+        batch_wait / batch_dispatch pair, "stage" a piece of the
+        request's timeline (`add_stage`)."""
+        self._push(next(self._ids) if _id is None else _id,
+                   self.current_span() if _parent is None else _parent,
+                   name, cat, begin_ns, end_ns, detail or None)
+
+    def add_stage(self, name: str, begin_ns: int, end_ns: int) -> None:
+        """A stage that belongs to no thread (`fd_queue`: submit ->
+        callable starts) or whose stamps predate the trace (`fd_parse`):
+        explicit begin/end, no profiler annotation."""
+        self._push(next(self._ids), 0, name, "stage", begin_ns, end_ns,
+                   None)
+
+    def run_span(self, name: str, cat: str, fn, *args):
+        """fn(*args) inside a span — what a pool worker runs in the
+        task's captured context, so the span's parent is the span that
+        submitted the task."""
+        with _Span(self, name, cat, None):
+            return fn(*args)
+
+    def pinned(self, span_id: int = 0):
+        """Make this trace (and `span_id` as the enclosing span) current
+        for a block: same-thread set/reset pairs, for generators that
+        resume on arbitrary threads."""
+        return _Pin(self, span_id)
+
+    def new_span_id(self) -> int:
+        """An id for an envelope that is recorded when it ends but has
+        to be named as parent while it is open (`execute`)."""
+        return next(self._ids)
+
+    # -- sink --------------------------------------------------------------
+
+    def snapshot(self, end_ns: Optional[int] = None) -> dict:
+        """The flight-recorder entry as of now (or `end_ns`): the root
+        `query` span, the per-thread rings merged into one begin-ordered
+        span list (offsets relative to the trace start) and the timeline
+        cut into its stages (`partition_stages`). No side effects — the
+        slow-query log reads a request that is still open through this."""
+        if end_ns is None:
+            end_ns = time.perf_counter_ns()
+        dur = end_ns - self.t0_ns
         with self._register_lock:
             rings = list(self._rings)
-        spans = [{"name": "query", "cat": "query", "tid": 0,
-                  "thread": "query", "begin_ns": 0, "end_ns": dur,
+        spans = [{"id": 0, "parent": None, "name": "query", "cat": "query",
+                  "tid": 0, "thread": "query", "begin_ns": 0,
+                  "end_ns": dur,
                   "args": {"query": self.query[:500],
                            "trace_id": self.trace_id}}]
         dropped = 0
+        stage_spans = []
         for r in rings:
             dropped += r.dropped
-            for name, cat, b, e, detail in r.spans:
-                spans.append({"name": name, "cat": cat, "tid": r.tid,
+            for sid, parent, name, cat, b, e, detail in list(r.spans):
+                b -= self.t0_ns
+                e -= self.t0_ns
+                spans.append({"id": sid, "parent": parent, "name": name,
+                              "cat": cat, "tid": r.tid,
                               "thread": r.thread_name,
-                              "begin_ns": b - self.t0_ns,
-                              "end_ns": e - self.t0_ns,
+                              "begin_ns": b, "end_ns": e,
                               "args": detail})
+                if cat == "stage":
+                    stage_spans.append((b, e, name))
         spans.sort(key=lambda s: (s["begin_ns"], -s["end_ns"]))
-        if dropped:
-            metrics.TRACE_SPANS_DROPPED.add(dropped)
+        stages, timeline = partition_stages(stage_spans, dur)
         # statement text truncates at entry-build time: every consumer
         # (listing, /_stats, chrome otherData) shows <= 500 chars, and
         # the always-on ring must not pin multi-MB INSERT literals
         return {"trace_id": self.trace_id, "query": self.query[:500],
                 "begin_epoch_us": self.t0_epoch_us,
-                "duration_ns": dur, "error": error,
+                "duration_ns": dur, "error": self.error,
                 "spans": spans, "spans_dropped": dropped,
-                # stamped by the statement-end hook when
-                # serene_mem_account ran (engine._finish_trace /
-                # execute_streaming): the query's accounted peak bytes
-                "peak_bytes": None}
+                # the request's timeline by stage: ns per stage (the
+                # values add up to duration_ns) and the pieces in order
+                "stages": stages, "timeline": timeline,
+                # "device" | "host" | "cache", stamped by finish()
+                "answered": None,
+                # the engine's stamp when serene_mem_account ran: the
+                # statement's accounted peak bytes
+                "peak_bytes": self.peak_bytes}
+
+    def finish(self, error: Optional[str] = None) -> dict:
+        """Close the request: build the entry (`snapshot`), observe
+        `RequestLatency`, one `Stage*` histogram per stage that occurred
+        plus `StageOther`, and the answered-by counter (device: the
+        timeline holds a `device_enqueue`; a result-cache hit counts as
+        neither; errors are not answers). Idempotent."""
+        if self.entry is not None:
+            return self.entry
+        self.end_ns = time.perf_counter_ns()
+        if error is not None:
+            self.error = error
+        entry = self.snapshot(self.end_ns)
+        if entry["spans_dropped"]:
+            metrics.TRACE_SPANS_DROPPED.add(entry["spans_dropped"])
+        metrics.REQUEST_LATENCY_HIST.observe_ns(entry["duration_ns"])
+        for name, ns in entry["stages"].items():
+            metrics.STAGE_HISTS[name].observe_ns(ns)
+        if self.error is None:
+            if "device_enqueue" in entry["stages"]:
+                entry["answered"] = "device"
+                metrics.STATEMENTS_ANSWERED_DEVICE.add()
+            elif self.cache_hit:
+                entry["answered"] = "cache"
+            else:
+                entry["answered"] = "host"
+                metrics.STATEMENTS_ANSWERED_HOST.add()
+        self.entry = entry
+        return entry
+
+
+class _Pin:
+    __slots__ = ("tr", "sid", "t1", "t2")
+
+    def __init__(self, tr: QueryTrace, sid: int):
+        self.tr = tr
+        self.sid = sid
+
+    def __enter__(self):
+        self.t1 = CURRENT_TRACE.set(self.tr)
+        self.t2 = CURRENT_SPAN.set((self.tr, self.sid))
+        return self.tr
+
+    def __exit__(self, *exc):
+        CURRENT_SPAN.reset(self.t2)
+        CURRENT_TRACE.reset(self.t1)
+        return False
+
+
+def begin_request(label: str, enabled: bool,
+                  t0_ns: Optional[int] = None) -> Optional[QueryTrace]:
+    """The front door's half of a request: a trace that began when the
+    message was received (`t0_ns`), handed to `execute_statement` /
+    `execute_streaming`, which adopt it; `end_request` closes it once
+    the response's last byte went to the transport. None when the
+    session has `serene_trace` off."""
+    if not enabled:
+        return None
+    tr = QueryTrace(label, t0_ns)
+    tr.owned = False
+    return tr
+
+
+def end_request(tr: Optional[QueryTrace],
+                error: Optional[str] = None) -> Optional[dict]:
+    """Finish a request's trace into the flight recorder (once: a trace
+    that is already closed is left alone). Success AND error paths — a
+    failed statement's timeline is exactly the one worth keeping."""
+    if tr is None:
+        return None
+    if tr.entry is not None:
+        return tr.entry
+    return FLIGHT.record(tr.finish(error))
 
 
 class FlightRecorder:
@@ -460,6 +749,8 @@ def chrome_trace(entry: dict) -> dict:
                           "duration_ms": entry["duration_ns"] / 1e6,
                           "error": entry["error"],
                           "peak_bytes": entry.get("peak_bytes"),
+                          "stages_ns": entry.get("stages"),
+                          "answered": entry.get("answered"),
                           "spans_dropped": entry["spans_dropped"]}}
 
 

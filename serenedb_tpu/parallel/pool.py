@@ -353,10 +353,22 @@ class WorkerPool:
             # traced statement submitted it
             trace = task.ctx.get(_trace_var())
             if trace is not None:
-                trace.add("queue_wait", "pool", task.t_submit_ns, t0)
+                # both spans are children of the span that SUBMITTED the
+                # task (it rides the captured context too); what the
+                # task opens inside is a child of its `task` span
+                trace.add("queue_wait", "pool", task.t_submit_ns, t0,
+                          _parent=trace.current_span(task.ctx))
             metrics.POOL_RUNNING.add()
             try:
-                result = task.ctx.run(task.fn, *task.args)
+                # the task span MUST be in the ring before the future
+                # resolves: delivering the result wakes the statement
+                # thread, which may finalize the trace immediately — a
+                # span stamped after that is lost (or outlives the
+                # timeline); it closes inside ctx.run, before set_result
+                result = task.ctx.run(task.fn, *task.args) \
+                    if trace is None else \
+                    task.ctx.run(trace.run_span, "task", "pool",
+                                 task.fn, *task.args)
                 exc = None
             except BaseException as e:  # noqa: BLE001 — delivered via future
                 exc = e
@@ -364,12 +376,6 @@ class WorkerPool:
             metrics.POOL_RUNNING.sub()
             metrics.POOL_MORSELS.add()
             metrics.POOL_BUSY_US.add((t1 - t0) // 1000)
-            # the task span MUST be in the ring before the future
-            # resolves: delivering the result wakes the statement
-            # thread, which may finalize the trace immediately — a span
-            # stamped after that is lost (or outlives the timeline)
-            if trace is not None:
-                trace.add("task", "pool", t0, t1)
             if exc is not None:
                 f.set_exception(exc)
             else:

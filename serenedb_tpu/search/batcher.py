@@ -58,7 +58,8 @@ _DISPATCH_SEQ = itertools.count(1)
 
 class _Entry:
     __slots__ = ("node", "done", "retry", "result", "n_batch",
-                 "window_ns", "scoring_ns", "t_submit_ns", "trace")
+                 "window_ns", "scoring_ns", "t_submit_ns", "trace",
+                 "span")
 
     def __init__(self, node):
         self.node = node
@@ -73,8 +74,10 @@ class _Entry:
         # coalesced dispatch stamps its window/scoring spans under
         # EVERY member query's trace, so each member's timeline shows
         # both the wait it paid and the shared dispatch it rode
+        # — as children of the span that submitted the member
         from ..obs.trace import current_trace
         self.trace = current_trace()
+        self.span = self.trace.current_span() if self.trace is not None else 0
 
 
 class _Group:
@@ -213,8 +216,10 @@ class SearchBatcher:
                         # trace until these spans are in the rings
                         if x.window_ns:
                             x.trace.add("batch_wait", "search",
-                                        x.t_submit_ns, t0)
+                                        x.t_submit_ns, t0,
+                                        _parent=x.span)
                         x.trace.add("batch_dispatch", "search", t0, t1,
+                                    _parent=x.span,
                                     queries=len(batch), dispatch=seq)
                     x.done = True
                 else:

@@ -58,11 +58,12 @@ from http.client import responses as _http_reasons
 from typing import Optional
 
 from ..engine import Database
+from ..obs.trace import stage_of
 from ..sched.governor import CONNGATE
 from ..utils import log, metrics
 from ..utils.config import REGISTRY as _settings
 from .es_api import EsApi
-from .http_server import Router
+from .http_server import RequestClock, Router
 
 #: bytes written to the transport per chunk between drain checks —
 #: bounds the per-write buffer spike on top of the high-water mark
@@ -314,11 +315,24 @@ class FrontDoor:
                 # only reader — awaits it, then fully drains the
                 # response before reading the next pipelined request
                 with metrics.HTTP_CONNECTIONS.scoped():
+                    # the request's clock starts at the receipt of its
+                    # bytes; a route that executes a statement hangs
+                    # the request's trace on it (http_server.py)
+                    clock = RequestClock()
+                    clock.submit_ns = time.perf_counter_ns()
                     status, data, ctype = await loop.run_in_executor(
                         self.executor, self.router.handle,
-                        method, target, body)
-                    await self._write_response(
-                        writer, status, data, ctype, keep_alive)
+                        method, target, body, clock)
+                    tr = clock.trace
+                    if tr is not None:
+                        tr.add_stage("fd_queue", clock.done_ns,
+                                     time.perf_counter_ns())
+                    try:
+                        with stage_of(tr, "fd_encode"):
+                            await self._write_response(
+                                writer, status, data, ctype, keep_alive)
+                    finally:
+                        clock.end()
                 if not keep_alive:
                     break
         except asyncio.TimeoutError:

@@ -28,6 +28,7 @@ from urllib.parse import parse_qs, urlparse
 
 from .. import errors
 from ..engine import Database
+from ..obs.trace import end_request, stage_of
 from ..utils import log, metrics
 from ..utils.config import REGISTRY as _settings
 from .es_api import EsApi, EsError
@@ -50,6 +51,28 @@ def encode_payload(payload) -> bytes:
     return data.encode() if isinstance(data, str) else data
 
 
+class RequestClock:
+    """One HTTP request as its transport sees it, which the router
+    cannot: when its bytes were received (`recv_ns`), when the route
+    was handed to the executor (`submit_ns`, 0 when it was not), when
+    the route began and returned (`start_ns`, `done_ns`). A route that
+    executes a statement (`/_sql`) begins the request's trace from
+    these and leaves it in `trace`; the transport calls `end()` once the
+    response's last byte is out."""
+
+    __slots__ = ("recv_ns", "submit_ns", "start_ns", "done_ns", "trace",
+                 "error")
+
+    def __init__(self):
+        self.recv_ns = time.perf_counter_ns()
+        self.submit_ns = self.start_ns = self.done_ns = 0
+        self.trace = None
+        self.error: Optional[str] = None
+
+    def end(self) -> None:
+        end_request(self.trace, self.error)
+
+
 class Router:
     """The entire HTTP surface as a pure function: (method, target,
     body) → (status, body bytes, content type). No sockets, no
@@ -60,32 +83,69 @@ class Router:
     def __init__(self, es: EsApi):
         self.es = es
 
-    def handle(self, method: str, target: str,
-               body: bytes = b"") -> tuple[int, bytes, str]:
+    def handle(self, method: str, target: str, body: bytes = b"",
+               clock: Optional["RequestClock"] = None
+               ) -> tuple[int, bytes, str]:
+        own_clock = clock is None
+        if own_clock:
+            clock = RequestClock()     # no transport: received now
+        clock.start_ns = time.perf_counter_ns()
         url = urlparse(target)
         parts = [p for p in url.path.split("/") if p]
         try:
             raw = body.decode() if isinstance(body, (bytes, bytearray)) \
                 else (body or "")
             status, payload, ctype = self._route(
-                method, parts, parse_qs(url.query), raw)
+                method, parts, parse_qs(url.query), raw, clock)
         except EsError as e:
             status, payload, ctype = e.status, e.body(), JSON_CTYPE
         except errors.SqlError as e:
+            clock.error = f"SqlError: {e}"
             status, payload, ctype = 400, {"error": {
                 "type": "sql_exception", "reason": e.message,
                 "sqlstate": e.sqlstate}, "status": 400}, JSON_CTYPE
         except Exception as e:  # pragma: no cover
+            clock.error = f"{type(e).__name__}: {e}"
             log.error("http", f"internal error: {e!r}")
             status, payload, ctype = 500, {
                 "error": {"type": "internal_error",
                           "reason": str(e)}, "status": 500}, JSON_CTYPE
-        return status, encode_payload(payload), ctype
+        with stage_of(clock.trace, "fd_encode"):
+            data = encode_payload(payload)
+        clock.done_ns = time.perf_counter_ns()
+        if own_clock:
+            clock.end()
+        return status, data, ctype
+
+    @staticmethod
+    def _sql(conn, query: str, clock: "RequestClock") -> dict:
+        """`POST /_sql`: `conn.execute(query)` with the request's trace
+        begun at the transport's receipt stamp and handed to the
+        message's first traced statement (further statements of one
+        body trace themselves, as over pgwire)."""
+        from ..columnar.column import Batch
+        from ..engine import QueryResult
+        from ..sql import parser
+        tr = conn.begin_request(query, clock.recv_ns)
+        if tr is not None and clock.submit_ns:
+            tr.add_stage("fd_queue", clock.submit_ns, clock.start_ns)
+        with stage_of(tr, "fd_parse"):
+            stmts = parser.parse(query)
+        res = QueryResult(Batch([], []), "")
+        for st in stmts:
+            hand = None
+            if clock.trace is None and not conn.is_untraced(st):
+                hand = clock.trace = tr
+            res = conn.execute_statement(st, [], sql_text=query,
+                                         trace=hand)
+        with stage_of(clock.trace, "fd_encode"):
+            return {"columns": [{"name": n} for n in res.names],
+                    "rows": [list(r) for r in res.rows()]}
 
     # -- routing -----------------------------------------------------------
 
-    def _route(self, method: str, p: list[str], q: dict,
-               body: str) -> tuple[int, object, str]:
+    def _route(self, method: str, p: list[str], q: dict, body: str,
+               clock: "RequestClock") -> tuple[int, object, str]:
         es = self.es
         if not p:
             return 200, {"name": "serenedb_tpu", "cluster_name":
@@ -196,10 +256,8 @@ class Router:
             # fresh connection per request: /_sql session state (BEGIN,
             # SET, failed-txn) must never poison the shared API connection
             conn = es.db.connect()
-            res = conn.execute(b.get("query", ""))
-            return 200, {
-                "columns": [{"name": n} for n in res.names],
-                "rows": [list(r) for r in res.rows()]}, JSON_CTYPE
+            return 200, self._sql(conn, b.get("query", ""), clock), \
+                JSON_CTYPE
         if p[0] == "_test" and len(p) > 1:
             return self._test_endpoint(method, p[1:], q, body)
         if p[0].startswith("_"):
@@ -308,14 +366,20 @@ class Handler(BaseHTTPRequestHandler):
 
     def _dispatch(self, method: str):
         with metrics.HTTP_CONNECTIONS.scoped():
+            body = self._body()
+            clock = RequestClock()
             status, data, ctype = self.router.handle(
-                method, self.path, self._body())
-            self.send_response(status)
-            self.send_header("Content-Type", ctype)
-            self.send_header("Content-Length", str(len(data)))
-            self.send_header("X-Elastic-Product", "Elasticsearch")
-            self.end_headers()
-            self.wfile.write(data)
+                method, self.path, body, clock)
+            try:
+                with stage_of(clock.trace, "fd_encode"):
+                    self.send_response(status)
+                    self.send_header("Content-Type", ctype)
+                    self.send_header("Content-Length", str(len(data)))
+                    self.send_header("X-Elastic-Product", "Elasticsearch")
+                    self.end_headers()
+                    self.wfile.write(data)
+            finally:
+                clock.end()
 
     def do_GET(self):
         self._dispatch("GET")

@@ -27,6 +27,7 @@ from ..columnar import dtypes as dt
 from ..columnar.column import Batch
 from .. import scram
 from ..engine import Connection, Database, QueryResult
+from ..obs.trace import end_request, stage_of
 from ..sql import ast, parser
 from ..utils import log, metrics
 from . import hba
@@ -291,18 +292,25 @@ class Portal:
     #: remainder after a row-budget split, "total": rows sent} — rows leave
     #: the socket as the executor produces them (wire_collector.h:20-60)
     stream: object = None
+    #: the request trace of a portal that is suspended mid-stream: it
+    #: stays open across Execute messages until the portal drains or is
+    #: closed
+    trace: object = None
 
 
 def _close_portal_stream(portal: Optional["Portal"]) -> None:
     """Close a suspended streaming portal's executor generator eagerly —
     its session scope (pg_stat_activity 'active', QUERIES_ACTIVE gauge)
-    must end now, never at GC time."""
+    must end now, never at GC time — and its request trace with it."""
     if portal is not None and portal.stream is not None:
         try:
             portal.stream["it"].close()
         except Exception:
             pass
         portal.stream = None
+    if portal is not None and portal.trace is not None:
+        end_request(portal.trace)
+        portal.trace = None
 
 
 class PgSession:
@@ -321,6 +329,11 @@ class PgSession:
         #: the connection gate's record for this socket (None when the
         #: session is driven outside the accept path, e.g. tests)
         self.gate_info = gate_info
+        #: the trace of the request being served (obs/trace.py): the
+        #: simple protocol's statement, or the extended protocol's
+        #: Parse/Bind/Execute pipeline up to its Execute — None between
+        #: requests, for utility statements and with serene_trace off
+        self._req = None
 
     # -- startup -----------------------------------------------------------
 
@@ -696,36 +709,88 @@ class PgSession:
                 continue
             await handler(payload)
 
-    async def _on_query(self, payload: bytes):
-        sql = payload[:-1].decode()
+    async def _hop(self, fn, *args, **kw):
+        """One handoff to the session pool and back. Both waits — submit
+        until the callable starts, the callable's end until this
+        coroutine runs again — are the request's `fd_queue` stage: they
+        belong to no thread, so they are stamped with explicit begin and
+        end."""
         loop = asyncio.get_running_loop()
+        tr = self._req
+        if tr is None:
+            return await loop.run_in_executor(
+                self.server.pool, functools.partial(fn, *args, **kw))
+        t_submit = time.perf_counter_ns()
+        t_done = 0
+
+        def call():
+            nonlocal t_done
+            tr.add_stage("fd_queue", t_submit, time.perf_counter_ns())
+            try:
+                return fn(*args, **kw)
+            finally:
+                t_done = time.perf_counter_ns()
         try:
-            stmts = parser.parse(sql)
-            if not stmts:
-                self.w.empty_query()
-            for st in stmts:
-                if isinstance(st, ast.CopyStmt) and \
-                        st.target in ("STDIN", "STDOUT"):
-                    await self._run_copy(st)
-                    continue
-                if isinstance(st, (ast.Select, ast.SetOp)):
-                    await self._stream_select(st, sql)
-                    continue
-                res = await loop.run_in_executor(
-                    self.server.pool,
-                    functools.partial(self.conn.execute_statement, st, [],
-                                      sql_text=sql))
-                self._send_result(res, describe=True)
-        except errors.SqlError as e:
-            self._note_error()
-            self.w.error(e)
-        except Exception as e:  # engine bug: surface as internal error
-            log.error("pg", f"internal error: {e!r}")
-            self._note_error()
-            self.w.error(errors.SqlError("XX000", f"internal error: {e}"))
-        self._drain_notifications()
-        self.w.ready(self._txn_status())
-        await self.w.flush()
+            return await loop.run_in_executor(self.server.pool, call)
+        finally:
+            if t_done:
+                tr.add_stage("fd_queue", t_done, time.perf_counter_ns())
+
+    async def _on_query(self, payload: bytes):
+        # the request begins here, at the receipt of its message, and
+        # ends below, when the last byte of the response went to the
+        # transport
+        t_recv = time.perf_counter_ns()
+        sql = payload[:-1].decode()
+        tr = self.conn.begin_request(sql, t_recv)
+        used = False
+        error = None
+        try:
+            try:
+                with stage_of(tr, "fd_parse"):
+                    stmts = parser.parse(sql)
+                if not stmts:
+                    self.w.empty_query()
+                for st in stmts:
+                    if used:
+                        # a further statement of the same message is a
+                        # request of its own, from here
+                        end_request(tr)
+                        tr = self.conn.begin_request(sql)
+                    copy = isinstance(st, ast.CopyStmt) and \
+                        st.target in ("STDIN", "STDOUT")
+                    # utility statements (and the COPY sub-protocol)
+                    # stay untraced
+                    used = not (copy or self.conn.is_untraced(st))
+                    self._req = tr if used else None
+                    if copy:
+                        await self._run_copy(st)
+                        continue
+                    if isinstance(st, (ast.Select, ast.SetOp)):
+                        await self._stream_select(st, sql)
+                        continue
+                    res = await self._hop(self.conn.execute_statement, st,
+                                          [], sql_text=sql,
+                                          trace=self._req)
+                    self._send_result(res, describe=True)
+            except errors.SqlError as e:
+                error = f"SqlError: {e}"
+                self._note_error()
+                self.w.error(e)
+            except Exception as e:  # engine bug: surface as internal error
+                error = f"{type(e).__name__}: {e}"
+                log.error("pg", f"internal error: {e!r}")
+                self._note_error()
+                self.w.error(errors.SqlError("XX000",
+                                             f"internal error: {e}"))
+            with stage_of(self._req, "fd_encode"):
+                self._drain_notifications()
+                self.w.ready(self._txn_status())
+                await self.w.flush()
+        finally:
+            self._req = None
+            if used:
+                end_request(tr, error)
 
     async def _run_copy(self, st):
         """COPY ... FROM STDIN / TO STDOUT sub-protocol (reference:
@@ -787,43 +852,56 @@ class PgSession:
         flush per executor batch (reference: wire_collector.h:20-60 —
         rows leave the socket during execution, bounding session memory
         and time-to-first-row)."""
-        loop = asyncio.get_running_loop()
-        names, types, it = await loop.run_in_executor(
-            self.server.pool,
-            functools.partial(self.conn.execute_streaming, st, [],
-                              sql_text=sql))
-        self.w.row_description(names, types)
+        tr = self._req
+        names, types, it = await self._hop(
+            self.conn.execute_streaming, st, [], sql_text=sql, trace=tr)
+        with stage_of(tr, "fd_encode"):
+            self.w.row_description(names, types)
         n = 0
         try:
             while True:
-                b = await loop.run_in_executor(self.server.pool,
-                                               lambda: next(it, None))
+                b = await self._hop(next, it, None)
                 if b is None:
                     break
                 if b.num_rows:
-                    self.w.data_rows(b)
-                    n += b.num_rows
-                    # flush per batch: backpressure via the transport drain
-                    await self.w.flush()
+                    with stage_of(tr, "fd_encode"):
+                        self.w.data_rows(b)
+                        n += b.num_rows
+                        # flush per batch: backpressure via the
+                        # transport drain
+                        await self.w.flush()
         finally:
             # deterministic engine-side cleanup (session state, metrics) on
             # error/disconnect — never wait for GC to finalize the generator
-            await loop.run_in_executor(self.server.pool, it.close)
-        self.w.command_complete(f"SELECT {n}")
+            await self._hop(it.close)
+        with stage_of(tr, "fd_encode"):
+            self.w.command_complete(f"SELECT {n}")
 
     def _send_result(self, res: QueryResult, describe: bool,
                      fmts: tuple = ()):
-        if res.batch.num_columns:
-            if describe:
-                self.w.row_description(
-                    res.batch.names, [c.type for c in res.batch.columns],
-                    fmts)
-            self.w.data_rows(res.batch, fmts)
-        self.w.command_complete(res.command_tag or "OK")
+        with stage_of(self._req, "fd_encode"):
+            if res.batch.num_columns:
+                if describe:
+                    self.w.row_description(
+                        res.batch.names,
+                        [c.type for c in res.batch.columns], fmts)
+                self.w.data_rows(res.batch, fmts)
+            self.w.command_complete(res.command_tag or "OK")
 
     # -- extended protocol -------------------------------------------------
 
+    def _ext_request(self, label: str, t_recv: int):
+        """The extended protocol's request: it begins at the receipt of
+        the first Parse or Bind after a Sync (or after the previous
+        Execute), is handed to the engine by Execute, and ends when that
+        Execute's response is flushed. A pipeline that never executes a
+        traced statement drops it at Sync."""
+        if self._req is None:
+            self._req = self.conn.begin_request(label, t_recv)
+        return self._req
+
     async def _on_parse(self, payload: bytes):
+        t_recv = time.perf_counter_ns()
         try:
             name_end = payload.index(b"\x00")
             name = payload[:name_end].decode()
@@ -831,7 +909,8 @@ class PgSession:
             sql = payload[name_end + 1:sql_end].decode()
             (n_oids,) = struct.unpack_from("!H", payload, sql_end + 1)
             oids = struct.unpack_from(f"!{n_oids}I", payload, sql_end + 3)
-            stmts = parser.parse(sql)
+            with stage_of(self._ext_request(sql, t_recv), "fd_parse"):
+                stmts = parser.parse(sql)
             if len(stmts) > 1:
                 raise errors.syntax(
                     "cannot insert multiple commands into a prepared "
@@ -843,9 +922,11 @@ class PgSession:
             self._note_error()
             self.w.error(e)
             self.ignore_till_sync = True
-        await self.w.flush()
+        with stage_of(self._req, "fd_encode"):
+            await self.w.flush()
 
     async def _on_bind(self, payload: bytes):
+        t_recv = time.perf_counter_ns()
         try:
             off = 0
             pend = payload.index(b"\x00", off)
@@ -862,6 +943,7 @@ class PgSession:
                 raise errors.SqlError(
                     "26000", f'prepared statement "{stmt_name}" does not '
                              "exist")
+            self._ext_request(prep.sql, t_recv)
             (n_params,) = struct.unpack_from("!H", payload, off)
             off += 2
             params = []
@@ -901,7 +983,8 @@ class PgSession:
             self.w.error(errors.SqlError(
                 "08P01", f"malformed Bind message: {e!r}"))
             self.ignore_till_sync = True
-        await self.w.flush()
+        with stage_of(self._req, "fd_encode"):
+            await self.w.flush()
 
     async def _on_describe(self, payload: bytes):
         kind = payload[:1]
@@ -933,7 +1016,8 @@ class PgSession:
                            ast.Explain)):
             try:
                 if isinstance(st, (ast.Select, ast.SetOp)):
-                    plan = self.conn._plan(st, [None] * prep.n_params)
+                    with stage_of(self._req, "plan"):
+                        plan = self.conn._plan(st, [None] * prep.n_params)
                     self.w.row_description(plan.names, plan.types, fmts)
                     return
             except errors.SqlError:
@@ -952,71 +1036,97 @@ class PgSession:
             self.w.no_data()
 
     async def _on_execute(self, payload: bytes):
+        t_recv = time.perf_counter_ns()
         end = payload.index(b"\x00")
         name = payload[:end].decode()
-        loop = asyncio.get_running_loop()
+        portal = None
+        error = None
         try:
-            (max_rows,) = struct.unpack_from("!I", payload, end + 1)
-            portal = self.portals.get(name)
-            if portal is None:
-                raise errors.SqlError("34000",
-                                      f'portal "{name}" does not exist')
-            if not portal.prepared.statements:
-                self.w.empty_query()
-                return
-            st0 = portal.prepared.statements[0]
-            if portal.stream is not None or (
-                    portal.pending is None and
-                    isinstance(st0, (ast.Select, ast.SetOp))):
-                try:
-                    await self._execute_streaming_portal(portal, st0,
-                                                         max_rows)
-                except Exception:
-                    # never resume a broken iterator — and close it NOW so
-                    # session-scope state (pg_stat_activity 'active',
-                    # QUERIES_ACTIVE) never waits for GC
-                    _close_portal_stream(portal)
-                    raise
-                await self.w.flush()
-                return
-            if portal.pending is None:
-                portal.pending = await loop.run_in_executor(
-                    self.server.pool,
-                    functools.partial(self.conn.execute_statement, st0,
-                                      portal.params,
-                                      sql_text=portal.prepared.sql))
-                portal.sent = 0
-            res = portal.pending
-            total = res.batch.num_rows
-            if max_rows and res.batch.num_columns and \
-                    portal.sent + max_rows < total:
-                # partial page: rows then PortalSuspended (reference:
-                # portals with row-budget paging, pg_wire_session.h:293-300)
-                page = res.batch.slice(portal.sent,
-                                       portal.sent + max_rows)
-                portal.sent += max_rows
-                self.w.data_rows(page, portal.result_fmts)
-                self.w.msg(b"s")           # PortalSuspended
+            try:
+                (max_rows,) = struct.unpack_from("!I", payload, end + 1)
+                portal = self.portals.get(name)
+                if portal is None:
+                    raise errors.SqlError("34000",
+                                          f'portal "{name}" does not exist')
+                if not portal.prepared.statements:
+                    self.w.empty_query()
+                    return
+                st0 = portal.prepared.statements[0]
+                if portal.trace is not None:
+                    # a suspended portal resumes: its request is open
+                    self._req, portal.trace = portal.trace, None
+                elif portal.pending is not None or \
+                        self.conn.is_untraced(st0):
+                    # a further page of a result that is already there,
+                    # or a utility statement: untraced
+                    self._req = None
+                else:
+                    self._ext_request(portal.prepared.sql, t_recv)
+                if portal.stream is not None or (
+                        portal.pending is None and
+                        isinstance(st0, (ast.Select, ast.SetOp))):
+                    try:
+                        await self._execute_streaming_portal(portal, st0,
+                                                             max_rows)
+                    except Exception:
+                        # never resume a broken iterator — and close it
+                        # NOW so session-scope state (pg_stat_activity
+                        # 'active', QUERIES_ACTIVE) never waits for GC
+                        _close_portal_stream(portal)
+                        raise
+                    return
+                if portal.pending is None:
+                    portal.pending = await self._hop(
+                        self.conn.execute_statement, st0, portal.params,
+                        sql_text=portal.prepared.sql, trace=self._req)
+                    portal.sent = 0
+                res = portal.pending
+                total = res.batch.num_rows
+                if max_rows and res.batch.num_columns and \
+                        portal.sent + max_rows < total:
+                    # partial page: rows then PortalSuspended (reference:
+                    # portals with row-budget paging,
+                    # pg_wire_session.h:293-300)
+                    page = res.batch.slice(portal.sent,
+                                           portal.sent + max_rows)
+                    portal.sent += max_rows
+                    with stage_of(self._req, "fd_encode"):
+                        self.w.data_rows(page, portal.result_fmts)
+                        self.w.msg(b"s")           # PortalSuspended
+                else:
+                    remainder = res
+                    if res.batch.num_columns and portal.sent:
+                        from ..engine import QueryResult as _QR
+                        remainder = _QR(res.batch.slice(portal.sent, total),
+                                        res.command_tag)
+                    self._send_result(remainder, describe=False,
+                                      fmts=portal.result_fmts)
+                    portal.pending = None
+                    portal.sent = 0
+            except errors.SqlError as e:
+                error = f"SqlError: {e}"
+                self._note_error()
+                self.w.error(e)
+                self.ignore_till_sync = True
+            except Exception as e:
+                error = f"{type(e).__name__}: {e}"
+                log.error("pg", f"internal error: {e!r}")
+                self._note_error()
+                self.w.error(errors.SqlError("XX000",
+                                             f"internal error: {e}"))
+                self.ignore_till_sync = True
+            finally:
+                with stage_of(self._req, "fd_encode"):
+                    await self.w.flush()
+        finally:
+            # the request ends with this Execute's last byte — unless the
+            # portal is suspended mid-stream, which keeps it open
+            tr, self._req = self._req, None
+            if portal is not None and portal.stream is not None and \
+                    error is None:
+                portal.trace = tr
             else:
-                remainder = res
-                if res.batch.num_columns and portal.sent:
-                    from ..engine import QueryResult as _QR
-                    remainder = _QR(res.batch.slice(portal.sent, total),
-                                    res.command_tag)
-                self._send_result(remainder, describe=False,
-                                  fmts=portal.result_fmts)
-                portal.pending = None
-                portal.sent = 0
-        except errors.SqlError as e:
-            self._note_error()
-            self.w.error(e)
-            self.ignore_till_sync = True
-        except Exception as e:
-            log.error("pg", f"internal error: {e!r}")
-            self._note_error()
-            self.w.error(errors.SqlError("XX000", f"internal error: {e}"))
-            self.ignore_till_sync = True
-        await self.w.flush()
+                end_request(tr, error)
 
     async def _execute_streaming_portal(self, portal: Portal, st,
                                         max_rows: int):
@@ -1024,13 +1134,11 @@ class PgSession:
         executor batch; a row budget suspends the portal mid-stream
         without materializing the rest (reference: wire_collector.h:20-60
         + portal row-budget paging, pg_wire_session.h:293-300)."""
-        loop = asyncio.get_running_loop()
+        tr = self._req
         if portal.stream is None:
-            names, types, it = await loop.run_in_executor(
-                self.server.pool,
-                functools.partial(self.conn.execute_streaming, st,
-                                  portal.params,
-                                  sql_text=portal.prepared.sql))
+            names, types, it = await self._hop(
+                self.conn.execute_streaming, st, portal.params,
+                sql_text=portal.prepared.sql, trace=tr)
             portal.stream = {"it": it, "leftover": None, "total": 0}
         s = portal.stream
         it = s["it"]
@@ -1039,21 +1147,23 @@ class PgSession:
             b = s["leftover"]
             s["leftover"] = None
             if b is None:
-                b = await loop.run_in_executor(self.server.pool,
-                                               lambda: next(it, None))
+                b = await self._hop(next, it, None)
             if b is None:
-                self.w.command_complete(f"SELECT {s['total']}")
+                with stage_of(tr, "fd_encode"):
+                    self.w.command_complete(f"SELECT {s['total']}")
                 portal.stream = None
                 break
             if budget is not None and b.num_rows > budget:
                 s["leftover"] = b.slice(budget, b.num_rows)
                 b = b.slice(0, budget)
             if b.num_rows:
-                self.w.data_rows(b, portal.result_fmts)
-                s["total"] += b.num_rows
-                if budget is not None:
-                    budget -= b.num_rows
-                await self.w.flush()   # backpressure via transport drain
+                with stage_of(tr, "fd_encode"):
+                    self.w.data_rows(b, portal.result_fmts)
+                    s["total"] += b.num_rows
+                    if budget is not None:
+                        budget -= b.num_rows
+                    # backpressure via transport drain
+                    await self.w.flush()
             if budget == 0:
                 self.w.msg(b"s")       # PortalSuspended
                 break
@@ -1070,6 +1180,7 @@ class PgSession:
 
     async def _on_sync(self, payload: bytes):
         self.ignore_till_sync = False
+        self._req = None      # a pipeline that executed nothing traced
         self._drain_notifications()
         self.w.ready(self._txn_status())
         await self.w.flush()

@@ -192,19 +192,26 @@ PG_CONNECTIONS = REGISTRY.gauge("PgConnections", "open PG wire connections")
 HTTP_CONNECTIONS = REGISTRY.gauge("HttpConnections", "open HTTP connections")
 QUERIES_ACTIVE = REGISTRY.gauge("QueriesActive", "queries currently executing")
 REFRESH_ACTIVE = REGISTRY.gauge("RefreshActive", "running refresh tasks")
-REFRESH_PENDING = REGISTRY.gauge("RefreshPending", "queued refresh tasks")
 COMPACTION_ACTIVE = REGISTRY.gauge("CompactionActive", "running compactions")
-COMPACTION_PENDING = REGISTRY.gauge("CompactionPending", "queued compactions")
-CLEANUP_ACTIVE = REGISTRY.gauge("CleanupActive", "running cleanup tasks")
 DEVICE_OFFLOADS = REGISTRY.gauge("DeviceOffloads", "batches dispatched to TPU")
+STATEMENTS_ANSWERED_DEVICE = REGISTRY.gauge(
+    "StatementsAnsweredDevice",
+    "traced non-utility statements whose timeline held a device_enqueue "
+    "stage (counted where the request ends; a result-cache hit counts "
+    "under neither gauge)")
+STATEMENTS_ANSWERED_HOST = REGISTRY.gauge(
+    "StatementsAnsweredHost",
+    "traced non-utility statements answered without any device program")
 DEVICE_BYTES = REGISTRY.gauge("DeviceBytesMoved", "bytes copied host->device")
 DEVICE_CACHE_HITS = REGISTRY.gauge(
     "DeviceCacheHits",
-    "device column cache probes served from HBM-resident uploads "
-    "(host->device transfer skipped)")
+    "a device-resident column was asked for and found in HBM (no "
+    "host->device transfer), whichever cache holds it: DEVICE_CACHE "
+    "(fused join tier) or a provider's own residency cache (device "
+    "aggregates, top-N, zone-map ranges)")
 DEVICE_CACHE_MISSES = REGISTRY.gauge(
     "DeviceCacheMisses",
-    "device column cache probes that had to upload from host")
+    "a device-resident column was asked for and had to be uploaded")
 DEVICE_CACHE_EVICTIONS = REGISTRY.gauge(
     "DeviceCacheEvictions",
     "device column cache entries dropped (LRU past the byte cap or a "
@@ -514,6 +521,46 @@ GC_GEN2_COLLECTIONS = REGISTRY.gauge(
 QUERY_LATENCY_HIST = REGISTRY.histogram(
     "QueryLatency",
     "end-to-end statement latency (success paths)")
+REQUEST_LATENCY_HIST = REGISTRY.histogram(
+    "RequestLatency",
+    "one traced request from receipt of its message at the front door "
+    "(or the engine call, without one) to the response's last byte "
+    "handed to the transport; QueryLatency is the statement inside it")
+#: the request's timeline by stage (obs/trace.py: STAGES): one
+#: observation per request = the stage's summed time in it, observed
+#: only when the stage occurred; per request they add up to
+#: RequestLatency exactly
+STAGE_HISTS = {name: REGISTRY.histogram(hist, desc) for name, hist, desc in (
+    ("fd_parse", "StageParse", "parser.parse at the front door"),
+    ("fd_queue", "StageFdQueue",
+     "front-door thread handoffs: run_in_executor submit -> callable "
+     "starts, callable done -> the session coroutine resumes"),
+    ("fd_encode", "StageFdEncode",
+     "wire encoding and flush of the response"),
+    ("cache_probe", "StageCacheProbe",
+     "result-cache digest, lookups and store"),
+    ("plan", "StagePlan", "bind, plan and search rewrite"),
+    ("device_prepare", "StageDevicePrepare",
+     "host work before a device program runs: admission, pin, "
+     "factorize, key planning, residency lookup / upload, program "
+     "lookup"),
+    ("device_enqueue", "StageDeviceEnqueue",
+     "the jitted call returning (first call: trace + compile)"),
+    ("device_wait", "StageDeviceWait",
+     "the blocking readback: device execution + device->host copy"),
+    ("device_finalize", "StageDeviceFinalize",
+     "host decode of the program's outputs into the result batch"),
+    ("host_scan", "StageHostScan",
+     "predicates and projections evaluated on the host, batch by batch "
+     "(scan filter, Filter, Project)"),
+    ("host_concat", "StageHostConcat",
+     "concat_batches incl. merge_dictionaries"),
+    ("host_group", "StageHostGroup",
+     "host hash-aggregate, factorize and distinct finalize"),
+    ("host_sort", "StageHostSort", "the materializing host sort"),
+    ("other", "StageOther",
+     "the request's time under no stage: RequestLatency minus the "
+     "union of its stages"))}
 POOL_QUEUE_WAIT_HIST = REGISTRY.histogram(
     "PoolQueueWait",
     "per-task worker-pool queue wait (submit -> pickup)")
@@ -528,10 +575,10 @@ SEARCH_BATCH_WINDOW_HIST = REGISTRY.histogram(
     "start)")
 DEVICE_DISPATCH_HIST = REGISTRY.histogram(
     "DeviceDispatch",
-    "per-offload device execution time: the fused pipeline observes "
-    "the dispatch section (post-upload; first call includes jit "
-    "compile), device aggregates and top-N observe the whole offload "
-    "(upload + compile-cache lookup + dispatch + readback)")
+    "per-offload device time, one meaning at every site: from the "
+    "start of the program call (enqueue) to the end of the blocking "
+    "readback of its outputs; a chained stage that leaves its outputs "
+    "in HBM is observed by the stage that reads them back")
 DEVICE_COMPILE_HIST = REGISTRY.histogram(
     "DeviceCompile",
     "first-dispatch latency of each jitted device program (XLA "

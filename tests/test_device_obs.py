@@ -359,3 +359,79 @@ def test_settings_declared_and_not_result_affecting():
                  "serene_program_cache_entries"):
         with pytest.raises(errors.SqlError):
             c.execute(f"SET {name} = 1")
+
+
+# -- one meaning of device time, programs named after their family (ISSUE 24)
+
+
+def _dispatch_hist():
+    counts, total = metrics.DEVICE_DISPATCH_HIST.snapshot()
+    return sum(counts), total
+
+
+@pytest.mark.parametrize("q,family", [
+    (PARITY_QUERIES[0], "fused"),
+    ("SELECT ik, count(*), sum(v) FROM l WHERE v > -400 GROUP BY ik "
+     "ORDER BY ik", "device_agg"),
+    ("SELECT ts, v FROM l ORDER BY ts DESC LIMIT 5", "device_topn"),
+    ("SELECT ts, v FROM l WHERE v > 0 ORDER BY ts DESC LIMIT 5",
+     "fused_topn")])
+def test_device_time_means_enqueue_to_readback_everywhere(q, family):
+    """`DeviceDispatch`, `QueryProfile.device_ns` (EXPLAIN ANALYZE's
+    `Device: time=`) and the timeline agree at every site: one
+    observation per program call + readback, from the start of the
+    enqueue to the end of the blocking readback — never the host work
+    around it."""
+    from serenedb_tpu.obs.trace import FLIGHT
+    c = _mk_conn()
+    c.execute(q)                                   # compile, upload
+    fam0 = obs_device.PROGRAMS.family(family)
+    n0, sum0 = _dispatch_hist()
+    c.execute(q)
+    n1, sum1 = _dispatch_hist()
+    entry = FLIGHT.get(c._active_trace.trace_id)
+    assert obs_device.PROGRAMS.family(family)["hits"] > fam0["hits"]
+    stages = [s for s in entry["spans"] if s["cat"] == "stage"]
+    enq = [s for s in stages if s["name"] == "device_enqueue"]
+    wait = [s for s in stages if s["name"] == "device_wait"]
+    assert len(enq) == len(wait) == n1 - n0 >= 1
+    # the host decode of the outputs is a stage of its own at every site
+    assert any(s["name"] == "device_finalize" for s in stages)
+    assert not any(s["name"] == "device_dispatch" for s in entry["spans"])
+    # the histogram's window = the union [enqueue begin, wait end] of
+    # each pair, give or take the clock reads between the stamps
+    spanned = sum(w["end_ns"] - e["begin_ns"] for e, w in zip(enq, wait))
+    observed = sum1 - sum0
+    assert spanned <= observed <= spanned + 2_000_000 * len(enq)
+    # and it lies inside `device_prepare`, which holds everything else
+    prep = max((s for s in stages if s["name"] == "device_prepare"),
+               key=lambda s: s["end_ns"] - s["begin_ns"])
+    assert prep["begin_ns"] <= enq[0]["begin_ns"] and \
+        wait[-1]["end_ns"] <= prep["end_ns"]
+    assert observed < prep["end_ns"] - prep["begin_ns"]
+    # EXPLAIN ANALYZE reads the same quantity
+    n2, sum2 = _dispatch_hist()
+    text = "\n".join(r[0] for r in c.execute("EXPLAIN ANALYZE " + q).rows())
+    n3, sum3 = _dispatch_hist()
+    times = [float(ln.split("time=")[1].split(" ms")[0])
+             for ln in text.splitlines() if "Device: time=" in ln]
+    assert times, text
+    assert sum(times) == pytest.approx((sum3 - sum2) / 1e6, abs=0.01)
+
+
+def test_programs_are_named_after_their_family():
+    """`compiled()` traces each body under `jax.named_scope(family)` and
+    names the jitted callable after it, so a profiler trace's module
+    line says `jit_device_agg`, not `jit_program`."""
+    import jax.numpy as jnp
+
+    def body(x):
+        return (x * 2).sum()
+
+    prog = obs_device.compiled("device_agg", ("named-test",), lambda: body)
+    assert prog.fn.__name__ == "device_agg"
+    x = jnp.arange(8, dtype=jnp.int32)
+    lowered = prog.fn.lower(x)
+    assert "jit_device_agg" in lowered.as_text()
+    assert "device_agg" in lowered.as_text(debug_info=True)
+    assert int(prog(x)) == 56

@@ -884,3 +884,195 @@ class TestProxyProtocol:
         port = self._server("require")
         with pytest.raises((ConnectionError, OSError)):
             self._query(port, "SELECT 1")
+
+
+# -- one request, one timeline, from the socket (ISSUE 24) -------------------
+
+
+def _entry_of(sql, after_id=0, timeout=5.0):
+    """The flight-recorder entry of the request that carried `sql`. The
+    request ends after its last byte went to the transport, so the client
+    may have read the whole answer a moment before the entry is there."""
+    import time
+
+    from serenedb_tpu.obs.trace import FLIGHT
+    deadline = time.monotonic() + timeout
+    while True:
+        hits = [e for e in FLIGHT.snapshot()
+                if e["query"] == sql[:500] and e["trace_id"] > after_id]
+        if hits:
+            return hits[-1]
+        assert time.monotonic() < deadline, f"no timeline for {sql!r}"
+        time.sleep(0.005)
+
+
+def _newest_trace_id():
+    from serenedb_tpu.obs.trace import FLIGHT
+    last = FLIGHT.last()
+    return last["trace_id"] if last else 0
+
+
+def _assert_request_timeline(entry):
+    dur = entry["duration_ns"]
+    stages = entry["stages"]
+    assert sum(stages.values()) == dur          # integer ns, exactly
+    cursor = 0
+    for _name, b, e in entry["timeline"]:
+        assert cursor <= b < e <= dur
+        cursor = e
+    by_id = {s["id"]: s for s in entry["spans"]}
+    assert len(by_id) == len(entry["spans"])
+    for s in entry["spans"]:
+        if s["id"]:
+            par = by_id[s["parent"]]
+            assert par["begin_ns"] <= s["begin_ns"] and \
+                s["end_ns"] <= par["end_ns"], (par, s)
+    staged = [s for s in entry["spans"] if s["cat"] == "stage"]
+    names = {s["name"] for s in staged}
+    # from the receipt of the message to the last flush, one trace id
+    assert {"fd_parse", "fd_queue", "plan", "fd_encode"} <= names, names
+    first = min(staged, key=lambda s: s["begin_ns"])
+    last = max(staged, key=lambda s: s["end_ns"])
+    assert first["name"] == "fd_parse" and first["begin_ns"] < 200_000
+    assert last["name"] == "fd_encode" and dur - last["end_ns"] < 5_000_000
+    assert by_id[0]["args"]["trace_id"] == entry["trace_id"]
+    return names
+
+
+WIRE_STATEMENTS = [
+    "SELECT a, s FROM t ORDER BY a",
+    "SELECT count(*), sum(a) FROM t WHERE a > 0",
+    "SELECT s, count(DISTINCT a + 1) FROM t GROUP BY s ORDER BY s",
+]
+
+
+@pytest.mark.parametrize("sql", WIRE_STATEMENTS)
+@pytest.mark.parametrize("protocol", ["simple", "extended"])
+def test_request_timeline_through_the_socket(server, sql, protocol):
+    from serenedb_tpu.utils import metrics
+    c = RawPg(server.port)
+    c.query("SET serene_result_cache = off")
+    mark = _newest_trace_id()
+    req0 = metrics.REQUEST_LATENCY_HIST.snapshot()
+    stmt0 = metrics.QUERY_LATENCY_HIST.snapshot()
+    cols, rows, tags, errs = c.query(sql) if protocol == "simple" \
+        else c.extended(sql)
+    assert not errs and rows
+    entry = _entry_of(sql, mark)
+    c.close()
+    names = _assert_request_timeline(entry)
+    assert entry["error"] is None and entry["answered"] == "host"
+    if "GROUP BY" in sql:
+        assert "host_group" in names
+    # exactly one request and one statement were observed, and the
+    # request holds the statement
+    req1 = metrics.REQUEST_LATENCY_HIST.snapshot()
+    stmt1 = metrics.QUERY_LATENCY_HIST.snapshot()
+    assert sum(req1[0]) - sum(req0[0]) == 1
+    assert sum(stmt1[0]) - sum(stmt0[0]) == 1
+    assert req1[1] - req0[1] == entry["duration_ns"]
+    assert req1[1] - req0[1] >= stmt1[1] - stmt0[1]
+
+
+def test_utility_statements_stay_untraced_over_the_wire(server):
+    import time
+    c = RawPg(server.port)
+    mark = _newest_trace_id()
+    c.query("SET serene_result_cache = off")
+    c.query("BEGIN")
+    c.query("COMMIT")
+    c.extended("SET serene_workers = 1")
+    cols, rows, tags, errs = c.query("SHOW serene_workers")
+    assert rows == [("1",)]
+    time.sleep(0.05)
+    assert _newest_trace_id() == mark
+    c.close()
+
+
+def test_trace_off_session_has_no_timeline_and_the_same_answer(server):
+    import time
+    sql = "SELECT a, s FROM t WHERE a > 0 ORDER BY a"
+    c = RawPg(server.port)
+    on = c.query(sql)
+    _entry_of(sql)
+    c.query("SET serene_trace = off")
+    mark = _newest_trace_id()
+    off = c.query(sql)
+    off_ext = c.extended(sql)
+    time.sleep(0.05)
+    assert _newest_trace_id() == mark
+    assert on[1] == off[1] == off_ext[1] and on[2] == off[2]
+    c.close()
+
+
+def test_each_statement_of_one_message_is_a_request(server):
+    c = RawPg(server.port)
+    c.query("SET serene_result_cache = off")
+    mark = _newest_trace_id()
+    sql = "SELECT 1; SET serene_workers = 1; SELECT a FROM t ORDER BY a"
+    cols, rows, tags, errs = c.query(sql)
+    assert tags == ["SELECT 1", "SET", "SELECT 2"] and not errs
+    c.close()
+    import time
+
+    from serenedb_tpu.obs.trace import FLIGHT
+    deadline = time.monotonic() + 5.0
+    while True:
+        mine = [e for e in FLIGHT.snapshot()
+                if e["query"] == sql and e["trace_id"] > mark]
+        if len(mine) >= 2 or time.monotonic() > deadline:
+            break
+        time.sleep(0.005)
+    assert len(mine) == 2                        # the SET is not traced
+    last = mine[1]
+    first = mine[0]
+    # the first holds the message's parse; the second begins where the
+    # first ended and holds the flush of ReadyForQuery
+    assert "fd_parse" in first["stages"] and \
+        "fd_parse" not in last["stages"]
+    for e in mine:
+        assert sum(e["stages"].values()) == e["duration_ns"]
+        assert "plan" in e["stages"] and "fd_encode" in e["stages"]
+
+
+def test_a_failed_statement_keeps_its_timeline(server):
+    c = RawPg(server.port)
+    mark = _newest_trace_id()
+    sql = "SELECT a / 0 FROM t"
+    _, _, _, errs = c.query(sql)
+    assert errs
+    entry = _entry_of(sql, mark)
+    c.close()
+    assert entry["error"] and entry["answered"] is None
+    assert sum(entry["stages"].values()) == entry["duration_ns"]
+    assert {"fd_parse", "plan", "fd_encode"} <= set(entry["stages"])
+
+
+def test_a_suspended_portal_keeps_its_request_open(server):
+    """Execute with a row budget suspends the portal mid-stream: the
+    request stays open across Execute messages and ends with the one
+    that drains it — one trace id, one timeline."""
+    c = RawPg(server.port)
+    mark = _newest_trace_id()
+    sql = "SELECT a FROM t ORDER BY a"
+    c.send(b"P", b"\x00" + sql.encode() + b"\x00" + b"\x00\x00")
+    c.send(b"B", b"\x00\x00" + struct.pack("!HHH", 0, 0, 0))
+    c.send(b"E", b"\x00" + struct.pack("!I", 1))      # one row, suspend
+    c.send(b"H")
+    kinds = []
+    while not kinds or kinds[-1] != b"s":
+        kinds.append(c.read_msg()[0])
+    assert kinds.count(b"D") == 1
+    import time
+    time.sleep(0.05)
+    assert _newest_trace_id() == mark                 # still open
+    c.send(b"E", b"\x00" + struct.pack("!I", 0))      # the rest
+    c.send(b"S")
+    while c.read_msg()[0] != b"Z":
+        pass
+    entry = _entry_of(sql, mark)
+    c.close()
+    assert entry["error"] is None
+    assert sum(entry["stages"].values()) == entry["duration_ns"]
+    encodes = [s for s in entry["spans"] if s["name"] == "fd_encode"]
+    assert len(encodes) >= 3

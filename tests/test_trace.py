@@ -139,11 +139,13 @@ def test_span_tree_well_formed_sharded_device():
     cats = {s["cat"] for s in entry["spans"]}
     assert "device" in cats, f"no device spans in {cats}"
     names = [s["name"] for s in entry["spans"]]
-    # the sharded fused join dispatches per shard (host combine:
-    # device_dispatch lanes) or as ONE shard_map program
-    # (serene_shard_combine=device: a collective_dispatch span)
-    assert "device_dispatch" in names or "collective_dispatch" in names
-    assert "shard_pipeline" in names or "device_upload" in names
+    # the sharded fused join dispatches per shard (host combine: one
+    # device_enqueue + device_wait pair per shard_pipeline lane) or as
+    # ONE shard_map program (serene_shard_combine=device: a
+    # collective_dispatch span around the pair)
+    assert "device_enqueue" in names and "device_wait" in names
+    assert "shard_pipeline" in names or "collective_dispatch" in names
+    assert "device_prepare" in names
 
 
 def _union_coverage(entry) -> float:
@@ -169,7 +171,7 @@ def _union_coverage(entry) -> float:
 def test_trace_coverage_at_workers_shards():
     """Acceptance shape: workers=4, shards=2 — the union of the
     attributed (non-root) spans covers >=95% of measured wall time,
-    with queue-wait and device-dispatch phases present. The agg leg
+    with queue-wait and the device stages present. The agg leg
     runs device=cpu so the morsel pipeline (pool queue waits)
     executes; the join leg runs device=auto so the fused pipeline
     dispatches."""
@@ -186,8 +188,7 @@ def test_trace_coverage_at_workers_shards():
         assert cov >= 0.95, \
             f"span coverage {cov:.3f} < 0.95 for {entry['query']}"
     assert any(s["name"] == "queue_wait" for s in entry_agg["spans"])
-    assert any(s["name"] in ("device_dispatch", "collective_dispatch")
-               for s in entry_dev["spans"])
+    assert any(s["name"] == "device_wait" for s in entry_dev["spans"])
 
 
 # -- coalesced-batch span fan-out -------------------------------------------
@@ -610,3 +611,346 @@ def test_trace_not_result_affecting():
     assert "serene_trace" not in RESULT_AFFECTING_SETTINGS
     assert "serene_flight_recorder_queries" not in \
         RESULT_AFFECTING_SETTINGS
+
+
+# -- one request, one timeline: stages (ISSUE 24) ----------------------------
+
+from serenedb_tpu.obs import trace as trace_mod  # noqa: E402
+from serenedb_tpu.sql import parser as sql_parser  # noqa: E402
+
+DEVICE_AGG_Q = AGG_Q        # with serene_device = 'auto'
+HOST_Q = "SELECT k, count(DISTINCT v + ts) AS u FROM facts GROUP BY k " \
+         "ORDER BY u DESC, k LIMIT 5"
+#: the front door's stages never occur without a front door
+_ENGINE_STAGES = set(trace_mod.STAGES) - {"fd_parse", "fd_queue",
+                                          "fd_encode"}
+
+
+def _device_conn():
+    db, c = _db_with_tables()
+    c.execute("SET serene_device = 'auto'")
+    c.execute("SET serene_device_min_rows = 1024")
+    c.execute("SET serene_result_cache = off")
+    return db, c
+
+
+def _run_entry(c, sql, entry_point):
+    """One statement through one of the engine's two entry points;
+    returns (rows, its flight-recorder entry)."""
+    if entry_point == "statement":
+        rows = c.execute(sql).rows()
+    else:
+        st = sql_parser.parse(sql)[0]
+        _names, _types, it = c.execute_streaming(st, [], sql_text=sql)
+        rows = [r for b in it for r in b.rows()]
+    return rows, _last_entry(c)
+
+
+def _assert_partition(entry):
+    """sum(stages) + other == request, in integer ns; the timeline's
+    pieces are inside the request, in order and disjoint, and add up to
+    the per-stage sums."""
+    dur = entry["duration_ns"]
+    stages = entry["stages"]
+    assert all(isinstance(v, int) and v >= 0 for v in stages.values()), \
+        stages
+    assert set(stages) - {"other"} <= set(trace_mod.STAGES)
+    assert sum(stages.values()) == dur
+    by_name, cursor = {}, 0
+    for name, b, e in entry["timeline"]:
+        assert cursor <= b < e <= dur, entry["timeline"]
+        cursor = e
+        by_name[name] = by_name.get(name, 0) + e - b
+    assert by_name == {k: v for k, v in stages.items()
+                       if k != "other" and v}
+    assert stages["other"] == dur - sum(by_name.values())
+
+
+@pytest.mark.parametrize("entry_point", ["statement", "streaming"])
+@pytest.mark.parametrize("sql,workers", [(DEVICE_AGG_Q, 0), (HOST_Q, 0),
+                                         (AGG_Q, 4)])
+def test_stage_partition_identity(sql, workers, entry_point):
+    db, c = _device_conn()
+    if workers:
+        c.execute("SET serene_device = 'cpu'")
+        c.execute("SET serene_workers = 4")    # stages on pool workers
+    _run_entry(c, sql, entry_point)
+    _rows, entry = _run_entry(c, sql, entry_point)
+    _assert_partition(entry)
+    names = {s["name"] for s in entry["spans"] if s["cat"] == "stage"}
+    assert {"cache_probe", "plan"} <= names <= _ENGINE_STAGES
+    # the engine began this trace itself: the request IS the statement
+    assert entry["stages"]["other"] < entry["duration_ns"]
+
+
+def test_partition_resolves_nesting_and_overlap():
+    part, line = trace_mod.partition_stages(
+        [(0, 100, "device_prepare"),        # the offload ...
+         (20, 30, "device_enqueue"),        # ... but for what it holds
+         (30, 70, "device_wait"),
+         (60, 90, "host_group"),            # a worker, side by side
+         (150, 260, "fd_encode")], 200)     # clipped to the request
+    assert part == {"device_prepare": 30, "device_enqueue": 10,
+                    "device_wait": 30, "host_group": 30, "fd_encode": 50,
+                    "other": 50}
+    assert sum(part.values()) == 200
+    assert line == [["device_prepare", 0, 20], ["device_enqueue", 20, 30],
+                    ["device_wait", 30, 60], ["host_group", 60, 90],
+                    ["device_prepare", 90, 100], ["fd_encode", 150, 200]]
+
+
+def _assert_parents(entry):
+    by_id = {s["id"]: s for s in entry["spans"]}
+    assert len(by_id) == len(entry["spans"]), "span ids repeat"
+    assert by_id[0]["cat"] == "query" and by_id[0]["parent"] is None
+    for s in entry["spans"]:
+        if s["id"] == 0:
+            continue
+        par = by_id.get(s["parent"])
+        assert par is not None, f"{s} names a parent that is not there"
+        assert par["begin_ns"] <= s["begin_ns"] and \
+            s["end_ns"] <= par["end_ns"], f"{par} does not enclose {s}"
+
+
+@pytest.mark.parametrize("setup,sql", [
+    (["SET serene_device = 'cpu'", "SET serene_workers = 4"], AGG_Q),
+    (["SET serene_workers = 4", "SET serene_shards = 2"], FUSED_Q),
+    (["SET serene_workers = 4", "SET serene_shards = 2",
+      "SET serene_shard_combine = 'device'"], FUSED_Q),
+    ([], DEVICE_AGG_Q),
+    ([], HOST_Q)])
+@pytest.mark.parametrize("entry_point", ["statement", "streaming"])
+def test_every_span_has_an_enclosing_parent(setup, sql, entry_point):
+    db, c = _device_conn()
+    for s in setup:
+        c.execute(s)
+    _rows, entry = _run_entry(c, sql, entry_point)
+    _assert_parents(entry)
+    _assert_partition(entry)
+    tasks = [s for s in entry["spans"] if s["name"] == "task"]
+    assert tasks or "SET serene_device = 'cpu'" not in setup
+    if tasks:
+        # across the pool: a task's parent is the span that submitted
+        # it, on another thread; what the task opened names the task
+        by_id = {s["id"]: s for s in entry["spans"]}
+        assert any(by_id[t["parent"]]["tid"] != t["tid"] for t in tasks)
+        inner = [s for s in entry["spans"]
+                 if by_id.get(s["parent"], {}).get("name") == "task"]
+        assert inner and all(s["tid"] == by_id[s["parent"]]["tid"]
+                             for s in inner)
+
+
+def _gauges():
+    return {n: g.value for n, g in (
+        ("dev", sdb_metrics.STATEMENTS_ANSWERED_DEVICE),
+        ("host", sdb_metrics.STATEMENTS_ANSWERED_HOST),
+        ("hits", sdb_metrics.DEVICE_CACHE_HITS),
+        ("misses", sdb_metrics.DEVICE_CACHE_MISSES))}
+
+
+@pytest.mark.parametrize("entry_point", ["statement", "streaming"])
+def test_device_and_host_statements_stage_sets_and_counters(entry_point):
+    db, c = _device_conn()
+    g0 = _gauges()
+    _rows, first = _run_entry(c, DEVICE_AGG_Q, entry_point)
+    g1 = _gauges()
+    _rows, again = _run_entry(c, DEVICE_AGG_Q, entry_point)
+    g2 = _gauges()
+    _rows, host = _run_entry(c, HOST_Q, entry_point)
+    g3 = _gauges()
+
+    def stage_set(entry):
+        return {s["name"] for s in entry["spans"] if s["cat"] == "stage"}
+
+    device_set = {"cache_probe", "plan", "device_prepare",
+                  "device_enqueue", "device_wait", "device_finalize",
+                  "host_scan", "host_sort"}      # Project, Sort above it
+    assert stage_set(first) == stage_set(again) == device_set
+    assert first["answered"] == again["answered"] == "device"
+    # HOST_Q: DISTINCT over an expression is refused before any device
+    # work, so no device stage at all: host scan / group / sort
+    assert stage_set(host) == {"cache_probe", "plan", "host_scan",
+                               "host_group", "host_sort"}
+    assert host["answered"] == "host"
+    assert (g1["dev"] - g0["dev"], g2["dev"] - g1["dev"],
+            g3["dev"] - g2["dev"]) == (1, 1, 0)
+    assert (g1["host"] - g0["host"], g3["host"] - g2["host"]) == (0, 1)
+    # the statement reads ts, k, v: three uploads the first time, three
+    # resident columns found on the repeat, none asked for by HOST_Q
+    assert (g1["misses"] - g0["misses"], g1["hits"] - g0["hits"]) == (3, 0)
+    assert (g2["misses"] - g1["misses"], g2["hits"] - g1["hits"]) == (0, 3)
+    assert (g3["misses"] - g2["misses"], g3["hits"] - g2["hits"]) == (0, 0)
+    # the stage histograms saw each request once
+    for e in (first, again, host):
+        _assert_partition(e)
+
+
+def test_result_cache_hit_counts_as_neither():
+    db, c = _device_conn()
+    c.execute("SET serene_result_cache = on")
+    c.execute(DEVICE_AGG_Q)
+    g0 = _gauges()
+    c.execute(DEVICE_AGG_Q)
+    entry = _last_entry(c)
+    g1 = _gauges()
+    assert entry["answered"] == "cache"
+    assert (g1["dev"], g1["host"]) == (g0["dev"], g0["host"])
+    assert {s["name"] for s in entry["spans"] if s["cat"] == "stage"} == \
+        {"cache_probe"}
+    _assert_partition(entry)
+
+
+def test_stage_histograms_sum_to_request_latency():
+    db, c = _device_conn()
+    hists = dict(sdb_metrics.STAGE_HISTS,
+                 request=sdb_metrics.REQUEST_LATENCY_HIST)
+    before = {k: h.snapshot() for k, h in hists.items()}
+    entries = [_run_entry(c, q, ep)[1]
+               for q in (DEVICE_AGG_Q, HOST_Q, DEVICE_AGG_Q)
+               for ep in ("statement", "streaming")]
+    d_sum = {k: h.snapshot()[1] - before[k][1] for k, h in hists.items()}
+    d_cnt = {k: sum(h.snapshot()[0]) - sum(before[k][0])
+             for k, h in hists.items()}
+    assert d_cnt["request"] == d_cnt["other"] == len(entries) == 6
+    assert d_sum["request"] == sum(e["duration_ns"] for e in entries)
+    assert sum(v for k, v in d_sum.items() if k != "request") == \
+        d_sum["request"]
+    # a stage is observed only in requests in which it occurred
+    assert d_cnt["device_wait"] == 4 and d_cnt["host_group"] == 2
+    assert d_cnt["fd_parse"] == d_cnt["fd_queue"] == 0
+
+
+@pytest.mark.parametrize("sql", [DEVICE_AGG_Q, HOST_Q,
+                                 "SELECT ts, v FROM facts ORDER BY v DESC "
+                                 "LIMIT 7"])
+def test_results_identical_with_trace_on_and_off(sql):
+    db, c = _device_conn()
+    got = {}
+    for tr in ("on", "off", "on"):
+        c.execute(f"SET serene_trace = {tr}")
+        for ep in ("statement", "streaming"):
+            if tr == "off":
+                if ep == "statement":
+                    rows = c.execute(sql).rows()
+                else:
+                    _n, _t, it = c.execute_streaming(
+                        sql_parser.parse(sql)[0], [], sql_text=sql)
+                    rows = [r for b in it for r in b.rows()]
+                assert c._active_trace is None
+            else:
+                rows, _e = _run_entry(c, sql, ep)
+            got.setdefault(repr(rows), []).append((tr, ep))
+    assert len(got) == 1, got
+
+
+def test_profiler_host_plane_holds_stages_not_envelopes(tmp_path):
+    """Under `jax.profiler.start_trace` a stage is a TraceAnnotation on
+    the profiler's own clock; envelopes are not annotated (they would
+    swallow every idle gap in the benchmark's reduction)."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+    db, c = _device_conn()
+    c.execute(DEVICE_AGG_Q)                  # compile outside the trace
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        c.execute(DEVICE_AGG_Q)
+        tid = c._active_trace.trace_id
+        c.execute(HOST_Q)
+    finally:
+        jax.profiler.stop_trace()
+    paths = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    assert len(paths) == 1
+    names, stats = set(), {}
+    for plane in ProfileData.from_file(paths[0]).planes:
+        if plane.name.startswith("/device:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("sdb."):
+                    names.add(ev.name)
+                    stats.setdefault(ev.name, []).append(dict(ev.stats))
+    assert {"sdb.plan", "sdb.device_wait", "sdb.device_enqueue",
+            "sdb.host_group"} <= names
+    assert names <= {"sdb." + s for s in trace_mod.STAGES}
+    assert "sdb.request" not in names and "sdb.execute" not in names \
+        and "sdb.query" not in names
+    # the annotation carries the request's trace id
+    assert any(str(d.get("trace_id")) == str(tid)
+               for d in stats["sdb.device_wait"])
+
+
+def test_stage_vocabulary_is_closed():
+    """Every stage stamped anywhere in the program is one of STAGES (an
+    unknown one would have no histogram), and the umbrella span and its
+    helper are gone."""
+    import os
+    import re
+    root = os.path.dirname(os.path.abspath(trace_mod.__file__))
+    root = os.path.dirname(root)
+    pat = re.compile(r'(?:\bstage|stage_of|add_stage)\(\s*(?:[\w.]+,\s*)?'
+                     r'"([a-z_]+)"')
+    seen = set()
+    for d, _dirs, files in os.walk(root):
+        for f in files:
+            if not f.endswith(".py"):
+                continue
+            with open(os.path.join(d, f)) as fh:
+                text = fh.read()
+            seen.update(pat.findall(text))
+            assert '"device_dispatch"' not in text, f
+            assert "_trace_span" not in text, f
+    assert seen == set(trace_mod.STAGES)
+    assert set(sdb_metrics.STAGE_HISTS) == set(trace_mod.STAGES) | {"other"}
+
+
+@pytest.mark.parametrize("frontdoor", [True, False])
+def test_http_sql_request_timeline(frontdoor):
+    """`POST /_sql` through either HTTP transport: one trace from the
+    receipt of the request to the response's last byte, readable back
+    through `GET /trace/<id>`."""
+    import time
+
+    from serenedb_tpu.server.http_server import HttpServer
+    db, c = _db_with_tables()
+    old = SETTINGS.get_global("serene_frontdoor")
+    SETTINGS.set_global("serene_frontdoor", frontdoor)
+    try:
+        srv = HttpServer(db)
+        srv.start()
+    finally:
+        SETTINGS.set_global("serene_frontdoor", old)
+    try:
+        base = f"http://127.0.0.1:{srv.port}"
+        last = FLIGHT.last()
+        mark = last["trace_id"] if last else 0
+        sql = "SELECT k, count(*) FROM facts WHERE ts < 100 GROUP BY k " \
+              f"ORDER BY k LIMIT {3 + frontdoor}"
+        req = urllib.request.Request(
+            f"{base}/_sql", data=json.dumps({"query": sql}).encode(),
+            headers={"Content-Type": "application/json"})
+        body = json.loads(urllib.request.urlopen(req).read())
+        assert len(body["rows"]) == 3 + frontdoor
+        deadline = time.monotonic() + 5.0
+        while True:
+            mine = [e for e in FLIGHT.snapshot()
+                    if e["query"] == sql and e["trace_id"] > mark]
+            if mine or time.monotonic() > deadline:
+                break
+            time.sleep(0.005)
+        assert len(mine) == 1
+        entry = mine[0]
+        _assert_partition(entry)
+        _assert_parents(entry)
+        want = {"fd_parse", "plan", "fd_encode"} | \
+            ({"fd_queue"} if frontdoor else set())
+        assert want <= set(entry["stages"])
+        doc = json.loads(urllib.request.urlopen(
+            f"{base}/trace/{entry['trace_id']}").read())
+        names = {e["name"] for e in doc["traceEvents"] if e["ph"] == "X"}
+        assert want <= names
+        assert doc["otherData"]["stages_ns"] == entry["stages"]
+        assert doc["otherData"]["answered"] == entry["answered"]
+    finally:
+        srv.stop()
