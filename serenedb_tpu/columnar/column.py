@@ -24,6 +24,7 @@ from typing import Iterable, Optional, Sequence
 import numpy as np
 
 from ..obs.trace import stage
+from ..utils import metrics
 from . import dtypes as dt
 
 
@@ -195,21 +196,35 @@ def _encode_dictionary(strs: Sequence[str]) -> tuple[np.ndarray, np.ndarray]:
 
 
 def merge_dictionaries(cols: Iterable[Column]) -> list[Column]:
-    """Re-encode VARCHAR columns from different batches onto one shared sorted
-    dictionary (needed before concatenating or comparing code spaces)."""
+    """Put VARCHAR columns on one shared sorted dictionary (needed before
+    concatenating or comparing code spaces).
+
+    The work is per DISTINCT dictionary object, not per column: slices,
+    takes and filters of one column all carry the very same array, and a
+    dictionary is sorted and unique by `Column`'s invariant, so when the
+    columns hold one object between them they already share a code space
+    and come back as they are — same `Column` objects, same dictionary
+    object (caches keyed on it stay warm). With k > 1 distinct objects
+    each is cast and remapped once, and every re-encoded output holds the
+    one merged array. Columns with no dictionary pass through."""
     cols = list(cols)
-    dicts = [c.dictionary for c in cols if c.dictionary is not None]
-    if not dicts:
+    distinct = {id(c.dictionary): c.dictionary
+                for c in cols if c.dictionary is not None}
+    if not distinct:
         return cols
-    merged = np.unique(np.concatenate([d.astype(str) for d in dicts]))
-    out = []
-    for c in cols:
-        if c.dictionary is None:
-            out.append(c)
-            continue
-        remap = np.searchsorted(merged, c.dictionary.astype(str)).astype(np.int32)
-        out.append(Column(c.type, remap[c.data], c.validity, merged.astype(object)))
-    return out
+    if len(distinct) == 1:
+        metrics.HOST_CONCAT_DICT_SHARED.add()
+        return cols
+    metrics.HOST_CONCAT_DICT_MERGED.add(len(distinct))
+    as_str = {k: d.astype(str) for k, d in distinct.items()}
+    merged = np.unique(np.concatenate(list(as_str.values())))
+    remaps = {k: np.searchsorted(merged, s).astype(np.int32)
+              for k, s in as_str.items()}
+    merged = merged.astype(object)
+    return [c if c.dictionary is None else
+            Column(c.type, remaps[id(c.dictionary)][c.data], c.validity,
+                   merged)
+            for c in cols]
 
 
 @dataclass
@@ -268,11 +283,15 @@ class Batch:
 
 
 def concat_batches(batches: Sequence[Batch]) -> Batch:
+    """One batch of all the rows, in order. A string column whose pieces
+    share one dictionary object (the slices a scan yields) keeps that
+    object and costs one copy of its codes; only pieces that bring
+    distinct dictionaries are re-encoded (`merge_dictionaries`)."""
     batches = [b for b in batches if b.num_rows > 0] or list(batches[:1])
     if len(batches) == 1:
         return batches[0]
-    # the request's `host_concat` stage: the copy, and the dictionary
-    # merge of every string column, read or not
+    # the request's `host_concat` stage: the copy of every column, read
+    # or not, and the merge of string columns' distinct dictionaries
     with stage("host_concat"):
         names = batches[0].names
         out_cols = []
@@ -285,5 +304,8 @@ def concat_batches(batches: Sequence[Batch]) -> Batch:
                 validity = None
             typ = next((c.type for c in cols
                         if c.type.id is not dt.TypeId.NULL), cols[0].type)
-            out_cols.append(Column(typ, data, validity, cols[0].dictionary))
+            # like the type: an untyped NULL piece has no dictionary
+            dictionary = next((c.dictionary for c in cols
+                               if c.dictionary is not None), None)
+            out_cols.append(Column(typ, data, validity, dictionary))
         return Batch(list(names), out_cols)

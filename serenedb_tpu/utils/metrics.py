@@ -554,13 +554,23 @@ STAGE_HISTS = {name: REGISTRY.histogram(hist, desc) for name, hist, desc in (
      "predicates and projections evaluated on the host, batch by batch "
      "(scan filter, Filter, Project)"),
     ("host_concat", "StageHostConcat",
-     "concat_batches incl. merge_dictionaries"),
+     "concat_batches: the copy of every column and the merge of string "
+     "columns' distinct dictionaries"),
     ("host_group", "StageHostGroup",
      "host hash-aggregate, factorize and distinct finalize"),
     ("host_sort", "StageHostSort", "the materializing host sort"),
     ("other", "StageOther",
      "the request's time under no stage: RequestLatency minus the "
      "union of its stages"))}
+#: how often merge_dictionaries (columnar/column.py) takes which way
+HOST_CONCAT_DICT_SHARED = REGISTRY.gauge(
+    "HostConcatDictShared",
+    "string columns put on one code space with no re-encode: every "
+    "piece held the same dictionary object (slices of one table column)")
+HOST_CONCAT_DICT_MERGED = REGISTRY.gauge(
+    "HostConcatDictMerged",
+    "distinct dictionary objects cast, merged and remapped because the "
+    "pieces of a string column brought more than one")
 POOL_QUEUE_WAIT_HIST = REGISTRY.histogram(
     "PoolQueueWait",
     "per-task worker-pool queue wait (submit -> pickup)")
