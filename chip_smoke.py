@@ -51,9 +51,12 @@ device_kind / count (`sdb_device()` over the wire), the program families
 that compiled and dispatched (`sdb_programs()` deltas), DeviceOffloads,
 fused declines, pool counters, the HBM estimate beside the backend's own
 bytes-in-use. `ran_on` is "tpu" only where the compile ledger shows the
-phase's programs dispatching with no decline; where no serving surface
-can tell (the module-level `@jax.jit` kernels of ops/bm25.py and
-ops/agg.py bypass the ledger) it says "not observable".
+phase's programs dispatching with no decline; a BM25 phase also prints
+`scored`: how many of its questions a device scoring program answered
+and how many a host tier (SearchQueriesScored{Device,Host}), and says
+"host (...)" where the host tier took them all; where no serving surface
+can tell (the module-level `@jax.jit` kernels of ops/agg.py bypass the
+ledger) it says "not observable".
 
 Any failed phase, any exception, a server that does not exit on
 SIGTERM, or a non-TPU backend: non-zero exit. The last stdout line of a
@@ -118,8 +121,8 @@ EXPECTED_HOST = {
                      "number <= 4096 is scored on the host by _cpu_score "
                      "by design (searcher.MAXSCORE_CAND_CAP); an "
                      "in-process probe on the v5e saw it take 5 of the 8 "
-                     "coalesced queries (PR 21). No serving surface shows "
-                     "it, so BM25 phases say 'not observable' (ROADMAP S8)",
+                     "coalesced queries (PR 21). The phase's `scored` "
+                     "line counts them (SearchQueriesScoredHost)",
     "sql:join_agg_10m": "the fused join tier admits a plan only while its "
                         "worst-case pair count keeps every int32 limb "
                         "scatter exact (MAX_PAIRS_EXACT = 2^23, "
@@ -358,7 +361,9 @@ _GAUGES = ("DeviceOffloads", "DeviceTransfersUp", "SearchBatchDispatches",
            "FragmentCacheHits", "ResultCacheHits",
            "PostingPoolDeviceQueries", "VectorSearchDispatches",
            "VectorSearchQueries", "NativeIndexBuilds",
-           "NativeIndexFallbacks", "SegmentBuilds")
+           "NativeIndexFallbacks", "SegmentBuilds",
+           "SearchQueriesScoredDevice", "SearchQueriesScoredHost",
+           "SearchPostingsDispatched", "SearchProgramsPrebuilt")
 
 
 def snapshot(srv: Server, pg: Pg) -> dict:
@@ -420,10 +425,18 @@ def phase_evidence(before: dict, after: dict) -> dict:
         "hbm_bytes_est": d0.get("hbm_bytes_est"),
         "hbm_bytes_in_use": d0.get("hbm_bytes_in_use"),
     }
+    # a search says itself which tier scored it (the counters partition
+    # the questions scored; a fragment-cache hit moves neither)
+    on_dev = gauges.get("SearchQueriesScoredDevice", 0)
+    on_host = gauges.get("SearchQueriesScoredHost", 0)
+    if on_dev or on_host:
+        ev["scored"] = {"device": on_dev, "host": on_host}
     if fams and dev_dispatches and not declines:
         ev["ran_on"] = d0.get("platform")
     elif declines:
         ev["ran_on"] = "host (fused tier declined)"
+    elif on_host and not on_dev:
+        ev["ran_on"] = "host (search host tier scored every question)"
     else:
         ev["ran_on"] = "not observable"
     return ev

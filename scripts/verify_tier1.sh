@@ -82,7 +82,8 @@ rc6=$?
 echo "== search-batch parity pass (serene_search_batch=off) =="
 timeout -k 10 300 env JAX_PLATFORMS=cpu SERENE_SEARCH_BATCH=off \
     python -m pytest tests/test_search_batch.py tests/test_search.py \
-    tests/test_search_regressions.py tests/test_es_api.py -q \
+    tests/test_search_regressions.py tests/test_es_api.py \
+    tests/test_search_programs.py -q \
     -m 'not slow' -p no:cacheprovider -p no:xdist -p no:randomly
 rc7=$?
 

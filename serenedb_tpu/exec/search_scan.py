@@ -65,11 +65,14 @@ class SearchScanNode(PlanNode):
     def _matching_docs(self, searcher) -> np.ndarray:
         """Doc selection with PG NULL semantics: a predicate over a NULL
         text value is NULL, never true — negation queries must not surface
-        NULL rows. The count fast path shares this exact logic."""
-        docs = searcher.eval_filter(self.qnode)
-        col = self.provider.host_column(self.search_column)
-        if col.validity is not None:
-            docs = docs[col.validity[docs]]
+        NULL rows. The count fast path shares this exact logic. The
+        doc-set algebra runs on the host: the request's `host_scan`."""
+        from ..obs.trace import stage
+        with stage("host_scan"):
+            docs = searcher.eval_filter(self.qnode)
+            col = self.provider.host_column(self.search_column)
+            if col.validity is not None:
+                docs = docs[col.validity[docs]]
         return docs
 
     def count_matching(self):

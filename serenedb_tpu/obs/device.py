@@ -308,6 +308,11 @@ class CompiledProgram:
         self.compile_ns: Optional[int] = None
         self._timed = False
 
+    @property
+    def called(self) -> bool:
+        """Has run at least once, so its first shape is built."""
+        return self._timed
+
     def __call__(self, *args):
         # first call: benign race — two threads may both time; the
         # ledger records both observations, results are identical
@@ -376,7 +381,8 @@ class ProgramLedger:
         return f
 
     def get(self, family: str, key: tuple, builder: Callable,
-            profile=None, node_key=None) -> CompiledProgram:
+            profile=None, node_key=None,
+            donate_argnums: tuple = ()) -> CompiledProgram:
         on = enabled()
         full = (family, key)
         with self._lock:
@@ -393,7 +399,9 @@ class ProgramLedger:
         # builder may construct meshes/shard_maps; a racing duplicate
         # build is wasted work, never wrong (the loser is discarded)
         import jax
-        prog = CompiledProgram(jax.jit(_named(family, builder())), family)
+        prog = CompiledProgram(
+            jax.jit(_named(family, builder()),
+                    donate_argnums=donate_argnums), family)
         with self._lock:
             cur = self._progs.get(full)
             if cur is not None:
@@ -509,15 +517,18 @@ PROGRAMS = ProgramLedger()
 
 
 def compiled(family: str, key: tuple, builder: Callable, *,
-             profile=None, node_key=None) -> CompiledProgram:
+             profile=None, node_key=None,
+             donate_argnums: tuple = ()) -> CompiledProgram:
     """THE jit entry point (acceptance grep: no bare `jax.jit(` outside
     this module). `builder` is a zero-arg callable returning the python
     callable to jit (a traced program body, or a shard_map-wrapped
     one); it runs only on a ledger miss. `profile`/`node_key` stamp the
     hit/miss onto the plan operator so EXPLAIN ANALYZE's `Device:` line
-    can say `compile=hit|miss`."""
+    can say `compile=hit|miss`. `donate_argnums` names the positional
+    arguments whose buffers the program may update in place (the BM25
+    score planes: same shape in and out, so the donation is usable)."""
     return PROGRAMS.get(family, key, builder, profile=profile,
-                        node_key=node_key)
+                        node_key=node_key, donate_argnums=donate_argnums)
 
 
 # -- fused-tier decline accounting -------------------------------------------

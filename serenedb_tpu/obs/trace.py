@@ -240,7 +240,7 @@ TRACE_RING_CAP = 8192
 STAGES = ("fd_parse", "fd_queue", "fd_encode", "cache_probe", "plan",
           "device_prepare", "device_enqueue", "device_wait",
           "device_finalize", "host_scan", "host_concat", "host_group",
-          "host_sort")
+          "host_sort", "batch_wait", "search_plan", "search_host_score")
 
 _TRACE_IDS = itertools.count(1)
 
@@ -255,6 +255,15 @@ CURRENT_TRACE: contextvars.ContextVar = contextvars.ContextVar(
 #: a pool task's spans name the span that submitted them as parent.
 CURRENT_SPAN: contextvars.ContextVar = contextvars.ContextVar(
     "sdb_current_span", default=None)
+
+
+#: where the stages of a piece of work done on behalf of OTHER requests
+#: are also noted as (name, begin ns, end ns): the search batcher's
+#: coalesced dispatch runs on one member's thread and stamps its stages
+#: under every member's trace afterwards. Rides the context copy like
+#: the two above, so pool tasks of the dispatch note theirs too.
+STAGE_SINK: contextvars.ContextVar = contextvars.ContextVar(
+    "sdb_stage_sink", default=None)
 
 
 def current_trace():
@@ -280,9 +289,49 @@ _NO_SPAN = _NoSpan()
 
 def stage(name: str, **detail):
     """`with stage("plan"):` — one piece of the current request's
-    timeline (a no-op outside a traced request)."""
+    timeline (a no-op outside a traced request, unless a `stage_sink`
+    collects for others)."""
     tr = CURRENT_TRACE.get()
-    return _NO_SPAN if tr is None else _Span(tr, name, "stage", detail)
+    if tr is None:
+        sink = STAGE_SINK.get()
+        return _NO_SPAN if sink is None else _SinkSpan(name, sink)
+    return _Span(tr, name, "stage", detail)
+
+
+class stage_sink:
+    """`with stage_sink() as sink:` — every stage closed inside, on this
+    thread or in pool tasks submitted from it, is also appended to
+    `sink` as (name, begin ns, end ns), whether or not the running
+    context has a trace of its own."""
+
+    __slots__ = ("sink", "tok")
+
+    def __enter__(self) -> list:
+        self.sink: list = []
+        self.tok = STAGE_SINK.set(self.sink)
+        return self.sink
+
+    def __exit__(self, *exc):
+        STAGE_SINK.reset(self.tok)
+        return False
+
+
+class _SinkSpan:
+    """A stage of a context with no trace, noted for a `stage_sink`."""
+
+    __slots__ = ("name", "sink", "t0")
+
+    def __init__(self, name: str, sink: list):
+        self.name = name
+        self.sink = sink
+
+    def __enter__(self):
+        self.t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc):
+        self.sink.append((self.name, self.t0, time.perf_counter_ns()))
+        return False
 
 
 def stage_of(tr: Optional["QueryTrace"], name: str):
@@ -348,6 +397,10 @@ class _Span:
         CURRENT_SPAN.reset(self.tok)
         self.tr._push(self.sid, self.parent, self.name, self.cat,
                       self.t0, t1, self.detail or None)
+        if self.cat == "stage":
+            sink = STAGE_SINK.get()
+            if sink is not None:
+                sink.append((self.name, self.t0, t1))
         return False
 
 

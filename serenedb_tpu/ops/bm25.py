@@ -28,11 +28,13 @@ from __future__ import annotations
 import functools
 import os
 from dataclasses import dataclass
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from ..obs import device as obs_device
 
 BLOCK = 128
 HEAVY_DF = 32     # terms with at least this many postings get block tiles
@@ -124,6 +126,10 @@ class BlockStore:
     block_bmax_tf: np.ndarray = None   # (NB_total+1,) int32
     block_bmin_dl: np.ndarray = None   # (NB_total+1,) int32
     norms_host: np.ndarray = None      # (num_docs,) int32
+    # valid (non-padding) postings per heavy block row, host-resident:
+    # what SearchPostingsDispatched counts for the rows handed to a
+    # scoring program
+    row_count: np.ndarray = None       # (NB_total+1,) int32
 
     @property
     def hbm_bytes(self) -> int:
@@ -137,6 +143,17 @@ class BlockStore:
         """What the same rows would cost as raw int32 doc+tf tiles."""
         n_rows = len(self.row_plane)
         return n_rows * BLOCK * 8
+
+
+def _bucket(n: int, floor: int, align: int = 1) -> int:
+    """n (at least `floor`) rounded up to a sixteenth of its octave and
+    to `align`: arrays whose length follows the data come in a few sizes
+    per octave, so the scoring programs, which XLA compiles per shape,
+    are shared by stores of like size — for at most an eighth more
+    memory."""
+    n = max(int(n), floor)
+    g = max(1 << max(n.bit_length() - 4, 0), align)
+    return -(-n // g) * g
 
 
 def build_block_store(offsets: np.ndarray, post_docs: np.ndarray,
@@ -166,6 +183,7 @@ def build_block_store(offsets: np.ndarray, post_docs: np.ndarray,
         bdocs[grow, lane] = post_docs[src]
         btfs[grow, lane] = post_tfs[src]
     bmax_tf = btfs.max(axis=1).astype(np.int32)
+    row_count = np.count_nonzero(btfs, axis=1).astype(np.int32)
     # bmin_dl without a full-size dl temporary: mask pads to int32-max
     dl_vals = norms_h[np.clip(bdocs, 0, None)] if num_docs \
         else np.zeros_like(bdocs)
@@ -193,21 +211,25 @@ def build_block_store(offsets: np.ndarray, post_docs: np.ndarray,
     n_packed = int(packable.sum())
     n_raw = int((~packable).sum())
 
-    pk_base = np.zeros(n_packed + 1, dtype=np.int32)
-    pk_gaps = np.zeros((n_packed + 1, BLOCK), dtype=np.uint16)
-    pk_tfs = np.zeros((n_packed + 1, BLOCK), dtype=np.uint8)
+    # both planes are allocated in bucketed sizes (rows past the pad slot
+    # are all-padding too), so stores of like size share their programs
+    np_rows = _bucket(n_packed + 1, 1)
+    nr_rows = _bucket(n_raw + 1, 1)
+    pk_base = np.zeros(np_rows, dtype=np.int32)
+    pk_gaps = np.zeros((np_rows, BLOCK), dtype=np.uint16)
+    pk_tfs = np.zeros((np_rows, BLOCK), dtype=np.uint8)
     pk_base[:n_packed] = base[packable]
     pk_gaps[:n_packed] = gaps[packable].astype(np.uint16)
     del gaps, docs_ff
-    r_docs = np.full((n_raw + 1, BLOCK), -1, dtype=np.int32)
-    r_tfs = np.zeros((n_raw + 1, BLOCK), dtype=np.int32)
+    r_docs = np.full((nr_rows, BLOCK), -1, dtype=np.int32)
+    r_tfs = np.zeros((nr_rows, BLOCK), dtype=np.int32)
     r_docs[:n_raw] = bdocs[~packable]
     del bdocs
     pk_tfs[:n_packed] = btfs[packable].astype(np.uint8)
     r_tfs[:n_raw] = btfs[~packable]
     del btfs
 
-    nd_pad = max(1024, ((num_docs + 1023) // 1024) * 1024)
+    nd_pad = _bucket(num_docs, 1024, 1024)
     norms_pad = np.zeros(nd_pad, dtype=np.int32)
     norms_pad[:num_docs] = norms[:num_docs]
     return BlockStore(
@@ -231,14 +253,17 @@ def build_block_store(offsets: np.ndarray, post_docs: np.ndarray,
         block_bmax_tf=bmax_tf,
         block_bmin_dl=bmin_dl,
         norms_host=norms_h,
+        row_count=row_count,
     )
 
 
 @dataclass
 class QueryBatch:
-    """Host-assembled inputs for one scoring dispatch covering B queries.
-    All arrays are tiny relative to the posting store (KBs per query).
-    Heavy-term rows split across the two tile planes (packed / raw)."""
+    """Host-assembled inputs for one scoring dispatch covering B queries,
+    at their own lengths: `query_chunks` cuts them into the fixed
+    capacities of a rung. All arrays are tiny relative to the posting
+    store (KBs per query). Heavy-term rows split across the two tile
+    planes (packed / raw)."""
 
     row_idx: np.ndarray    # (NB,) int32 PACKED-plane row gather indices
     row_w: np.ndarray      # (NB,) f32 idf weight of the row's term
@@ -251,7 +276,8 @@ class QueryBatch:
     tail_w: np.ndarray     # (TT,) f32
     tail_qid: np.ndarray   # (TT,) int32
     require: np.ndarray    # (B,) int32 — 0 = disjunction, else min hits
-    n_queries: int         # logical B before pow2 padding
+    n_queries: int         # B
+    n_postings: int = 0    # valid postings in the rows and tails above
 
 
 def _sat_exact(tfs: np.ndarray, dls: np.ndarray, k1: float, b: float,
@@ -483,6 +509,7 @@ def assemble_query_batch(store: BlockStore, n_docs: int,
     rrows, rrow_w, rrow_q = [], [], []
     tails_d, tails_f, tails_w, tails_q = [], [], [], []
     require = []
+    n_postings = 0
     for qi, (term_ids, req) in enumerate(queries):
         require.append(req)
         tid_arr = np.asarray(term_ids, dtype=np.int64)
@@ -505,6 +532,7 @@ def assemble_query_batch(store: BlockStore, n_docs: int,
                     b0 = int(store.block_offsets[tid])
                     b1 = int(store.block_offsets[tid + 1])
                     r = np.arange(b0, b1, dtype=np.int64)
+                n_postings += int(store.row_count[r].sum())
                 # split the term's global rows across the two planes
                 plane = store.row_plane[r]
                 pk = store.row_slot[r[plane == 0]]
@@ -519,6 +547,7 @@ def assemble_query_batch(store: BlockStore, n_docs: int,
                     rrow_q.append(np.full(len(rw), qi, dtype=np.int32))
             else:
                 s, e = int(store.offsets[tid]), int(store.offsets[tid + 1])
+                n_postings += e - s
                 tails_d.append(store.flat_docs[s:e])
                 tails_f.append(store.flat_tfs[s:e])
                 tails_w.append(np.full(e - s, w, dtype=np.float32))
@@ -528,44 +557,21 @@ def assemble_query_batch(store: BlockStore, n_docs: int,
         return np.concatenate(parts).astype(dtype, copy=False) if parts \
             else np.empty(0, dtype=dtype)
 
-    row_idx = cat(rows, np.int32)
-    nb_pad = _pow2(len(row_idx), 8)
-    raw_idx = cat(rrows, np.int32)
-    nr_pad = _pow2(len(raw_idx), 8)
-    tail_docs = cat(tails_d, np.int32)
-    tt_pad = _pow2(len(tail_docs), BLOCK)
     return QueryBatch(
-        row_idx=_pad_to(row_idx, nb_pad, store.n_packed),
-        row_w=_pad_to(cat(row_w, np.float32), nb_pad, 0.0),
-        row_qid=_pad_to(cat(row_q, np.int32), nb_pad, 0),
-        raw_idx=_pad_to(raw_idx, nr_pad, store.n_raw),
-        raw_w=_pad_to(cat(rrow_w, np.float32), nr_pad, 0.0),
-        raw_qid=_pad_to(cat(rrow_q, np.int32), nr_pad, 0),
-        tail_docs=_pad_to(tail_docs, tt_pad, -1),
-        tail_tfs=_pad_to(cat(tails_f, np.int32), tt_pad, 0),
-        tail_w=_pad_to(cat(tails_w, np.float32), tt_pad, 0.0),
-        tail_qid=_pad_to(cat(tails_q, np.int32), tt_pad, 0),
+        row_idx=cat(rows, np.int32),
+        row_w=cat(row_w, np.float32),
+        row_qid=cat(row_q, np.int32),
+        raw_idx=cat(rrows, np.int32),
+        raw_w=cat(rrow_w, np.float32),
+        raw_qid=cat(rrow_q, np.int32),
+        tail_docs=cat(tails_d, np.int32),
+        tail_tfs=cat(tails_f, np.int32),
+        tail_w=cat(tails_w, np.float32),
+        tail_qid=cat(tails_q, np.int32),
         require=np.asarray(require, dtype=np.int32),
         n_queries=len(queries),
+        n_postings=n_postings,
     )
-
-
-def pack_query_batch(qb: QueryBatch) -> tuple[np.ndarray, np.ndarray,
-                                              int, int, int, int]:
-    """Pack the per-query arrays into ONE int32 + ONE f32 buffer so a
-    dispatch costs two host→device transfers instead of fourteen.
-
-    ints: [row_idx | row_qid | raw_idx | raw_qid
-           | tail_docs | tail_tfs | tail_qid | require]
-    floats: [row_w | raw_w | tail_w]
-    """
-    ints = np.concatenate([qb.row_idx, qb.row_qid, qb.raw_idx, qb.raw_qid,
-                           qb.tail_docs, qb.tail_tfs,
-                           qb.tail_qid, qb.require]).astype(np.int32)
-    floats = np.concatenate([qb.row_w, qb.raw_w,
-                             qb.tail_w]).astype(np.float32)
-    return (ints, floats, len(qb.row_idx), len(qb.raw_idx),
-            len(qb.tail_docs), qb.n_queries)
 
 
 def _pow2(n: int, floor: int) -> int:
@@ -573,42 +579,238 @@ def _pow2(n: int, floor: int) -> int:
 
 
 def _pad_to(a: np.ndarray, n: int, fill) -> np.ndarray:
-    out = np.full(n, fill, dtype=a.dtype if len(a) else np.int32)
+    out = np.full(n, fill, dtype=a.dtype)
     out[:len(a)] = a
     return out
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("nb", "nr", "tt", "ndocs_pad", "k",
-                                    "n_queries", "any_require", "scorer"))
-def score_topk_packed(block_base: jax.Array, block_gaps: jax.Array,
-                      block_tfs8: jax.Array, raw_docs: jax.Array,
-                      raw_tfs: jax.Array,
-                      norms: jax.Array, ints: jax.Array, floats: jax.Array,
-                      nb: int, nr: int, tt: int, ndocs_pad: int, k: int,
-                      n_queries: int, any_require: bool, k1: float,
-                      b: float, avgdl: float,
-                      scorer: str = "bm25") -> tuple[jax.Array, jax.Array]:
-    """Packed-argument entry (2 transfers): unpack then score."""
-    row_idx = ints[:nb]
-    row_qid = ints[nb:2 * nb]
-    o = 2 * nb
-    raw_idx = ints[o:o + nr]
-    raw_qid = ints[o + nr:o + 2 * nr]
-    o += 2 * nr
-    tail_docs = ints[o:o + tt]
-    tail_tfs = ints[o + tt:o + 2 * tt]
-    tail_qid = ints[o + 2 * tt:o + 3 * tt]
-    require = ints[o + 3 * tt:o + 3 * tt + n_queries]
-    row_w = floats[:nb]
-    raw_w = floats[nb:nb + nr]
-    tail_w = floats[nb + nr:nb + nr + tt]
-    return _score_topk(block_base, block_gaps, block_tfs8, raw_docs,
-                       raw_tfs, norms, row_idx, row_w, row_qid,
-                       raw_idx, raw_w, raw_qid,
-                       tail_docs, tail_tfs, tail_w, tail_qid,
-                       require, ndocs_pad, k, n_queries, any_require,
-                       k1, b, avgdl, scorer)
+# ------------------------------------------------- the closed program set
+#
+# A scoring dispatch is a FIXED-CAPACITY accumulate step, called as often
+# as the batch needs on a donated score plane, then one top-k step. What
+# a program is compiled for is a `Rung` — the plane's query rows and the
+# step's three capacities — chosen from what the store shows (its padded
+# document count) and the batcher's cap, never from the batch: so the set
+# of programs is closed, listed by `plane_program_keys` /
+# `dense_program_keys`, and built before the index answers its first
+# search (`SegmentSearcher.prebuild`). A batch picks the smallest rung
+# that holds its queries; more queries than the largest rung split into
+# several dispatches (searcher.topk_batch).
+#
+# Parity: a (query, document) cell receives at most one contribution per
+# term, and the steps run in sequence, each adding packed rows, then raw
+# rows, then tails. `query_chunks` never puts a query's raw rows in an
+# earlier step than its packed rows, nor its tails before its raw rows, so
+# a cell's f32 additions happen in ONE order — packed, raw, tail, each in
+# query-term order — however the batch was composed or cut: the order of
+# the single-program kernel this replaced.
+
+#: the largest plane a rung may ask for, in queries
+NQ_TOP = 32
+#: slots (query terms) one dense step adds
+DENSE_SLOTS = 16
+
+
+class Rung(NamedTuple):
+    nq: int    # queries = rows of the score plane
+    nb: int    # packed rows per accumulate step
+    nr: int    # raw rows per accumulate step
+    tt: int    # light-term postings per accumulate step
+
+
+def score_rungs(ndocs_pad: int, batch_cap: int,
+                acc_entries: int) -> tuple[Rung, ...]:
+    """The ladder of at most three rungs for a store of `ndocs_pad`
+    padded documents behind a batcher that coalesces up to `batch_cap`
+    queries, a score plane holding at most `acc_entries` cells."""
+    top = max(1, min(NQ_TOP, int(batch_cap), acc_entries // ndocs_pad))
+    top = 1 << (top.bit_length() - 1)
+    return tuple(Rung(nq, min(4096, 1024 * nq), min(128, 32 * nq),
+                      min(8192, 2048 * nq))
+                 for nq in sorted({1, min(8, top), top}))
+
+
+def rung_for(rungs: tuple[Rung, ...], n_queries: int) -> Rung:
+    """The smallest rung whose plane holds `n_queries` (the caller has
+    split what exceeds the largest)."""
+    return next(r for r in rungs if r.nq >= n_queries)
+
+
+def query_chunks(qb: QueryBatch, rung: Rung, pad_packed: int,
+                 pad_raw: int, min_steps: int = 0,
+                 ) -> list[tuple[np.ndarray, np.ndarray]]:
+    """The batch cut into accumulate steps: per step ONE int32 and ONE f32
+    buffer (two host->device transfers), each of the rung's fixed size.
+
+    ints: [row_idx | row_qid | raw_idx | raw_qid
+           | tail_docs | tail_tfs | tail_qid]
+    floats: [row_w | raw_w | tail_w]
+
+    Packed rows fill steps from the first; raw rows start in the step
+    that holds the LAST packed rows, tails in the one that holds the last
+    raw rows (the parity note above). Unused capacity gathers the planes'
+    pad slots / carries doc -1 with weight 0: it scatters nothing."""
+    n_p = -(-len(qb.row_idx) // rung.nb)
+    r0 = max(n_p - 1, 0)
+    n_r = -(-len(qb.raw_idx) // rung.nr)
+    t0 = max(r0 + n_r - 1, r0)
+    n_t = -(-len(qb.tail_docs) // rung.tt)
+    n_steps = max(n_p, r0 + n_r, t0 + n_t, min_steps)
+    out = []
+    for c in range(n_steps):
+        p = slice(c * rung.nb, (c + 1) * rung.nb)
+        r = slice(max(c - r0, 0) * rung.nr, max(c - r0 + 1, 0) * rung.nr)
+        t = slice(max(c - t0, 0) * rung.tt, max(c - t0 + 1, 0) * rung.tt)
+        ints = np.concatenate([
+            _pad_to(qb.row_idx[p], rung.nb, pad_packed),
+            _pad_to(qb.row_qid[p], rung.nb, 0),
+            _pad_to(qb.raw_idx[r], rung.nr, pad_raw),
+            _pad_to(qb.raw_qid[r], rung.nr, 0),
+            _pad_to(qb.tail_docs[t], rung.tt, -1),
+            _pad_to(qb.tail_tfs[t], rung.tt, 0),
+            _pad_to(qb.tail_qid[t], rung.tt, 0)]).astype(np.int32)
+        floats = np.concatenate([
+            _pad_to(qb.row_w[p], rung.nb, 0.0),
+            _pad_to(qb.raw_w[r], rung.nr, 0.0),
+            _pad_to(qb.tail_w[t], rung.tt, 0.0)]).astype(np.float32)
+        out.append((ints, floats))
+    return out
+
+
+_NO_QUERIES = QueryBatch(
+    *(np.empty(0, dtype=t) for t in (
+        np.int32, np.float32, np.int32, np.int32, np.float32, np.int32,
+        np.int32, np.int32, np.float32, np.int32, np.int32)), 0)
+
+
+def accumulate_body(rung: Rung, ndocs_pad: int, with_hits: bool,
+                    first: bool, scorer: str):
+    """The traced body of one accumulate step: unpack the two buffers,
+    add this step's contributions to the score plane (and the hit plane
+    under a conjunction) — planes made here when `first`, else the
+    donated ones handed in."""
+    nb, nr, tt = rung.nb, rung.nr, rung.tt
+
+    def step(block_base, block_gaps, block_tfs8, raw_docs, raw_tfs, norms,
+             ints, floats, k1, b, avgdl, *planes):
+        o = 2 * nb + 2 * nr
+        scores, hits = _accumulate_scores(
+            block_base, block_gaps, block_tfs8, raw_docs, raw_tfs, norms,
+            ints[:nb], floats[:nb], ints[nb:2 * nb],
+            ints[2 * nb:2 * nb + nr], floats[nb:nb + nr],
+            ints[2 * nb + nr:o],
+            ints[o:o + tt], ints[o + tt:o + 2 * tt],
+            floats[nb + nr:nb + nr + tt], ints[o + 2 * tt:o + 3 * tt],
+            ndocs_pad, rung.nq, with_hits, k1, b, avgdl, scorer,
+            None if first else planes[0],
+            None if first or not with_hits else planes[1])
+        return (scores, hits) if with_hits else (scores,)
+
+    return step
+
+
+def topk_body(ndocs_pad: int, nq: int, with_hits: bool, k: int):
+    """The traced body of the top-k step: require-mask, per-query top-k."""
+    def step(require, scores, *hits):
+        scores = scores.reshape(nq, ndocs_pad)
+        if with_hits:
+            need = require[:, None]
+            scores = jnp.where(
+                jnp.logical_or(need <= 0,
+                               hits[0].reshape(nq, ndocs_pad) >= need),
+                scores, 0.0)
+        return jax.lax.top_k(scores, k)
+
+    return step
+
+
+def _accumulate_program(store: BlockStore, rung: Rung, with_hits: bool,
+                        first: bool, scorer: str):
+    # the planes' allocated rows are part of the key: XLA compiles per
+    # argument shape, and the ledger has to count what it really builds
+    key = (store.block_base.shape[0], store.raw_docs.shape[0],
+           store.ndocs_pad, tuple(rung), with_hits, first, scorer)
+    return obs_device.compiled(
+        "bm25_accumulate", key,
+        lambda: accumulate_body(rung, store.ndocs_pad, with_hits, first,
+                                scorer),
+        donate_argnums=() if first else
+        tuple(range(11, 13 if with_hits else 12)))
+
+
+def _topk_program(ndocs_pad: int, nq: int, with_hits: bool, k: int):
+    return obs_device.compiled(
+        "bm25_topk", (ndocs_pad, nq, with_hits, k),
+        lambda: topk_body(ndocs_pad, nq, with_hits, k))
+
+
+def plane_program_keys(rungs: tuple[Rung, ...]) -> list[tuple]:
+    """Every plane-kernel program a batch that fits one of `rungs` can
+    dispatch: (rung, with_hits, first) per accumulate step, first = None
+    for the rung's top-k step."""
+    return [(rung, with_hits, first)
+            for rung in rungs
+            for with_hits in (False, True)
+            for first in (True, False, None)]
+
+
+def score_topk_planes(store: BlockStore, qb: QueryBatch, rung: Rung,
+                      k: int, k1: float, b: float, avgdl: float,
+                      scorer: str):
+    """One dispatch of a batch that fits `rung`: its accumulate steps in
+    sequence on the donated planes, then the top-k step. Returns the
+    device (vals, docs), each (rung.nq, k): rows past qb.n_queries are
+    padding."""
+    with_hits = bool(qb.require.any())
+    planes: tuple = ()
+    for ints, floats in query_chunks(qb, rung, store.n_packed, store.n_raw):
+        prog = _accumulate_program(store, rung, with_hits, not planes,
+                                   scorer)
+        planes = prog(store.block_base, store.block_gaps, store.block_tfs8,
+                      store.raw_docs, store.raw_tfs, store.norms,
+                      ints, floats, k1, b, avgdl, *planes)
+    return _topk_program(store.ndocs_pad, rung.nq, with_hits, k)(
+        _pad_to(qb.require, rung.nq, 0), *planes)
+
+
+def prebuild_plane_programs(store: BlockStore, rungs: tuple[Rung, ...],
+                            k: int, scorer: str) -> int:
+    """Build every program of `plane_program_keys` by running it once on
+    an all-padding step — first step, next step on the planes it gave,
+    top-k — so that no search has to. The (rung, conjunction) chains are
+    independent and compile side by side. Returns how many programs this
+    call built."""
+    from concurrent.futures import ThreadPoolExecutor
+    chains: dict = {}
+    for rung, with_hits, first in plane_program_keys(rungs):
+        prog = _topk_program(store.ndocs_pad, rung.nq, with_hits, k) \
+            if first is None else \
+            _accumulate_program(store, rung, with_hits, first, scorer)
+        chains.setdefault((rung, with_hits), []).append((first, prog))
+    todo = {key: steps for key, steps in chains.items()
+            if not all(prog.called for _, prog in steps)}
+
+    def run(item):
+        (rung, _with_hits), steps = item
+        (ints, floats), = query_chunks(_NO_QUERIES, rung, store.n_packed,
+                                       store.n_raw, min_steps=1)
+        planes: tuple = ()
+        for first, prog in steps:
+            if first is None:
+                planes = prog(np.zeros(rung.nq, dtype=np.int32), *planes)
+            else:
+                planes = prog(store.block_base, store.block_gaps,
+                              store.block_tfs8, store.raw_docs,
+                              store.raw_tfs, store.norms, ints, floats,
+                              1.2, 0.75, 1.0, *planes)
+        jax.block_until_ready(planes)
+
+    built = sum(not prog.called for steps in todo.values()
+                for _, prog in steps)
+    if todo:
+        with ThreadPoolExecutor(max_workers=len(todo)) as pool:
+            list(pool.map(run, todo.items()))
+    return built
 
 
 def _decode_rows(block_base, block_gaps, block_tfs8, row_idx):
@@ -628,11 +830,12 @@ def _accumulate_scores(block_base, block_gaps, block_tfs8, raw_docs,
                        raw_w, raw_qid, tail_docs, tail_tfs, tail_w,
                        tail_qid, ndocs_pad: int, n_queries: int,
                        with_hits: bool, k1: float, b: float, avgdl,
-                       scorer: str = "bm25"):
+                       scorer: str = "bm25", scores=None, hits=None):
     """Fused gather+decode → score → batched scatter-accumulate into
-    (B, ndocs) score planes (+ hit counts when with_hits). Shared by the
-    single-device top-k and the mesh-sharded path, whose shards each
-    accumulate their posting-row slice before a psum merge."""
+    flat (B x ndocs,) score planes (+ hit counts when with_hits): onto
+    the planes handed in, or fresh ones. Shared by the accumulate step
+    and the mesh-sharded path, whose shards each accumulate their
+    posting-row slice before a psum merge."""
     avg = jnp.maximum(jnp.float32(avgdl), 1e-9)
 
     def contrib_of(docs, tfs, w):
@@ -672,9 +875,10 @@ def _accumulate_scores(block_base, block_gaps, block_tfs8, raw_docs,
             c = w * (k1 + 1.0) * tfsf / jnp.maximum(denom, 1e-9)
         return jnp.where(valid, c, 0.0), valid, safe_docs
 
-    scores = jnp.zeros((n_queries * ndocs_pad,), dtype=jnp.float32)
-    hits = jnp.zeros((n_queries * ndocs_pad,), dtype=jnp.int32) \
-        if with_hits else None
+    if scores is None:
+        scores = jnp.zeros((n_queries * ndocs_pad,), dtype=jnp.float32)
+    if with_hits and hits is None:
+        hits = jnp.zeros((n_queries * ndocs_pad,), dtype=jnp.int32)
     # packed plane: gather + in-kernel delta decode
     pdocs, ptfs = _decode_rows(block_base, block_gaps, block_tfs8, row_idx)
     wc, valid_b, safe_b = contrib_of(pdocs, ptfs, row_w[:, None])
@@ -690,38 +894,11 @@ def _accumulate_scores(block_base, block_gaps, block_tfs8, raw_docs,
     tc, valid_t, safe_t = contrib_of(tail_docs, tail_tfs, tail_w)
     tidx = tail_qid * ndocs_pad + safe_t
     scores = scores.at[tidx].add(tc)
-    scores = scores.reshape(n_queries, ndocs_pad)
     if with_hits:
         hits = hits.at[bidx].add(valid_b.reshape(-1).astype(jnp.int32))
         hits = hits.at[ridx].add(valid_r.reshape(-1).astype(jnp.int32))
         hits = hits.at[tidx].add(valid_t.astype(jnp.int32))
-        hits = hits.reshape(n_queries, ndocs_pad)
     return scores, hits
-
-
-def _score_topk(block_base, block_gaps, block_tfs8, raw_docs, raw_tfs,
-                norms, row_idx, row_w, row_qid, raw_idx, raw_w, raw_qid,
-                tail_docs, tail_tfs, tail_w, tail_qid, require,
-                ndocs_pad: int, k: int, n_queries: int, any_require: bool,
-                k1: float, b: float, avgdl: float, scorer: str = "bm25"):
-    """One dispatch scoring B queries: accumulate score planes →
-    require-mask → per-query top-k. Batching amortizes host↔device
-    dispatch latency — the QPS regime of the benchmark game.
-
-    scorer: 'bm25' (k1/b saturation + length norm) or 'tfidf'
-    (sqrt(tf)·w — the IResearch TFIDF shape, tfidf.cpp; the per-term idf
-    part of w is supplied by the caller per scorer)."""
-    scores, hits = _accumulate_scores(
-        block_base, block_gaps, block_tfs8, raw_docs, raw_tfs, norms,
-        row_idx, row_w, row_qid, raw_idx, raw_w, raw_qid,
-        tail_docs, tail_tfs, tail_w, tail_qid, ndocs_pad, n_queries,
-        any_require, k1, b, avgdl, scorer)
-    if any_require:
-        need = require[:, None]
-        scores = jnp.where(jnp.logical_or(need <= 0, hits >= need),
-                           scores, 0.0)
-    vals, docs = jax.lax.top_k(scores, k)
-    return vals, docs
 
 
 def _mesh_score_fn(mesh_n: int, ndocs_pad: int, k: int, n_queries: int,
@@ -755,11 +932,10 @@ def _mesh_score_fn(mesh_n: int, ndocs_pad: int, k: int, n_queries: int,
                 tail_docs, tail_tfs, tail_w, tail_qid, ndocs_pad,
                 n_queries, False, k1, b, avgdl, scorer)
             scores = jax.lax.psum(scores, AXIS)
-            return jax.lax.top_k(scores, k)
+            return jax.lax.top_k(scores.reshape(n_queries, ndocs_pad), k)
 
         return step
 
-    from ..obs import device as obs_device
     return obs_device.compiled(
         "bm25_mesh",
         (mesh_n, ndocs_pad, k, n_queries, scorer, k1, b),
@@ -770,12 +946,14 @@ def score_topk_mesh(store, qb: "QueryBatch", ndocs_pad: int, k: int,
                     mesh_n: int, k1: float, b: float, avgdl: float,
                     scorer: str = "bm25"):
     """Score a require-free query batch over an N-device mesh. Sections
-    pad to a mesh multiple with the no-op fills the packer already uses
-    (w=0 rows contribute nothing)."""
+    pad to a power of two, then to a mesh multiple, with the no-op fills
+    the steps use (w=0 rows contribute nothing)."""
     from ..parallel.mesh import pad_to_multiple
 
-    def pad_sec(a, fill):
-        return pad_to_multiple(np.asarray(a), mesh_n, fill)
+    def pad_sec(a, fill, floor=8):
+        a = np.asarray(a)
+        return pad_to_multiple(_pad_to(a, _pow2(len(a), floor), fill),
+                               mesh_n, fill)
 
     fn = _mesh_score_fn(mesh_n, ndocs_pad, k, qb.n_queries, scorer,
                         float(k1), float(b))
@@ -788,10 +966,10 @@ def score_topk_mesh(store, qb: "QueryBatch", ndocs_pad: int, k: int,
               jnp.asarray(pad_sec(qb.raw_idx, store.n_raw)),
               jnp.asarray(pad_sec(qb.raw_w, np.float32(0.0))),
               jnp.asarray(pad_sec(qb.raw_qid, 0)),
-              jnp.asarray(pad_sec(qb.tail_docs, -1)),
-              jnp.asarray(pad_sec(qb.tail_tfs, 0)),
-              jnp.asarray(pad_sec(qb.tail_w, np.float32(0.0))),
-              jnp.asarray(pad_sec(qb.tail_qid, 0)))
+              jnp.asarray(pad_sec(qb.tail_docs, -1, BLOCK)),
+              jnp.asarray(pad_sec(qb.tail_tfs, 0, BLOCK)),
+              jnp.asarray(pad_sec(qb.tail_w, np.float32(0.0), BLOCK)),
+              jnp.asarray(pad_sec(qb.tail_qid, 0, BLOCK)))
 
 
 
@@ -828,11 +1006,10 @@ class DenseStore:
     v_pad: int
 
 
-@functools.partial(jax.jit, static_argnames=("ndocs_pad", "v_pad", "scorer"))
 def _build_dense(block_base, block_gaps, block_tfs8, pk_tid,
                  raw_docs, raw_tfs, raw_tid, light_docs, light_tfs,
-                 light_tid, norms, ndocs_pad: int, v_pad: int, k1: float,
-                 b: float, avgdl: float, scorer: str) -> jax.Array:
+                 light_tid, norms, k1, b, avgdl, *, ndocs_pad: int,
+                 v_pad: int, scorer: str) -> jax.Array:
     """One-time scatter of every posting (decoded from the packed planes)
     into a dense term-major TF plane, then the scorer's saturation applied
     elementwise. Runs once per (segment, scorer, avgdl); per-query
@@ -869,15 +1046,14 @@ def dense_fits(ndocs_pad: int, vocab: int) -> bool:
     """True when the (V_pad, ndocs_pad) f32 saturation matrix fits the
     dense-path HBM budget. ndocs_pad is the block store's own padding so
     the estimate can't drift from the real allocation."""
-    v_pad = max(128, ((vocab + 127) // 128) * 128)
-    return ndocs_pad * v_pad * 4 <= DENSE_HBM_BUDGET
+    return ndocs_pad * _bucket(vocab, 128, 128) * 4 <= DENSE_HBM_BUDGET
 
 
 def build_dense_store(store: BlockStore, doc_freq: np.ndarray,
                       avgdl: float, k1: float, b: float,
                       scorer: str) -> DenseStore:
     T = len(doc_freq)
-    v_pad = max(128, ((T + 127) // 128) * 128)
+    v_pad = _bucket(T, 128, 128)
     nd_pad = store.ndocs_pad
     # heavy terms: already device-resident as block tiles; ship only the
     # per-row term id. Light terms: one-time flat upload (df < HEAVY_DF
@@ -888,8 +1064,8 @@ def build_dense_store(store: BlockStore, doc_freq: np.ndarray,
         np.arange(T, dtype=np.int32), rows_per_term)
     # split the global row→term map by plane (the planes' extra pad rows
     # keep tid 0 — their postings decode as invalid and never scatter)
-    pk_tid = np.zeros(store.n_packed + 1, dtype=np.int32)
-    raw_tid = np.zeros(store.n_raw + 1, dtype=np.int32)
+    pk_tid = np.zeros(store.block_base.shape[0], dtype=np.int32)
+    raw_tid = np.zeros(store.raw_docs.shape[0], dtype=np.int32)
     packed_rows = store.row_plane == 0
     pk_tid[store.row_slot[packed_rows]] = row_tid[packed_rows]
     raw_tid[store.row_slot[~packed_rows]] = row_tid[~packed_rows]
@@ -902,74 +1078,127 @@ def build_dense_store(store: BlockStore, doc_freq: np.ndarray,
     light_tfs = store.flat_tfs[light_mask].astype(np.int32)
     light_tid = post_tid[light_mask]
     n_pad = _pow2(len(light_docs), BLOCK)
-    St = _build_dense(
+    build = obs_device.compiled(
+        "dense_build",
+        (len(pk_tid), len(raw_tid), n_pad, nd_pad, v_pad, scorer),
+        lambda: functools.partial(_build_dense, ndocs_pad=nd_pad,
+                                  v_pad=v_pad, scorer=scorer))
+    St = build(
         store.block_base, store.block_gaps, store.block_tfs8,
-        jnp.asarray(pk_tid), store.raw_docs, store.raw_tfs,
-        jnp.asarray(raw_tid),
-        jnp.asarray(_pad_to(light_docs, n_pad, -1)),
-        jnp.asarray(_pad_to(light_tfs, n_pad, 0)),
-        jnp.asarray(_pad_to(light_tid, n_pad, 0)),
-        store.norms, nd_pad, v_pad, k1, b, avgdl, scorer)
+        pk_tid, store.raw_docs, store.raw_tfs, raw_tid,
+        _pad_to(light_docs, n_pad, -1), _pad_to(light_tfs, n_pad, 0),
+        _pad_to(light_tid, n_pad, 0), store.norms, k1, b, avgdl)
     return DenseStore(St=St, ndocs_pad=nd_pad, v_pad=v_pad)
 
 
-@functools.partial(jax.jit, static_argnames=("k", "any_require"))
-def dense_topk(St: jax.Array, tids: jax.Array, w: jax.Array,
-               require: jax.Array, k: int,
-               any_require: bool) -> tuple[jax.Array, jax.Array]:
-    """scores[q, d] = Σ_j w[q, j] · St[tids[q, j], d], added in slot
+def dense_body(nq: int, first: bool, last: bool, k: int):
+    """The traced body of one dense step: scores[q, d] += Σ_j w[q, j] ·
+    St[tids[q, j], d] over this step's DENSE_SLOTS slots, added in slot
     order j (the query's own term order; pad slots carry w = 0 and add
-    exactly 0.0); optional conjunction masking by counting the slots
-    that hit; exact per-query top-k."""
-    nq, n_slots = tids.shape
-    nd = St.shape[1]
+    exactly 0.0), counting the slots that hit; planes made here when
+    `first`, else the donated ones. The `last` step masks conjunctions
+    and returns the exact per-query top-k instead of the planes."""
+    def step(St, tids, w, require, *planes):
+        nd = St.shape[1]
 
-    def add_slot(j, carry):
-        scores, hits = carry
-        rows = St[jax.lax.dynamic_index_in_dim(tids, j, 1, False)]
-        wj = jax.lax.dynamic_index_in_dim(w, j, 1, False)[:, None]
-        scores = scores + rows * wj
-        if any_require:
-            hits = hits + jnp.logical_and(rows > 0, wj > 0).astype(jnp.int32)
-        return scores, hits
+        def add_slot(j, carry):
+            scores, hits = carry
+            rows = St[jax.lax.dynamic_index_in_dim(tids, j, 1, False)]
+            wj = jax.lax.dynamic_index_in_dim(w, j, 1, False)[:, None]
+            return (scores + rows * wj,
+                    hits + jnp.logical_and(rows > 0,
+                                           wj > 0).astype(jnp.int32))
 
-    scores, hits = jax.lax.fori_loop(
-        0, n_slots, add_slot,
-        (jnp.zeros((nq, nd), dtype=jnp.float32),
-         jnp.zeros((nq, nd) if any_require else (), dtype=jnp.int32)))
-    if any_require:
+        scores, hits = jax.lax.fori_loop(
+            0, DENSE_SLOTS, add_slot,
+            (jnp.zeros((nq, nd), dtype=jnp.float32),
+             jnp.zeros((nq, nd), dtype=jnp.int32)) if first else planes)
+        if not last:
+            return scores, hits
         need = require[:, None]
         scores = jnp.where(jnp.logical_or(need <= 0, hits >= need),
                            scores, 0.0)
-    vals, docs = jax.lax.top_k(scores, k)
-    return vals, docs
+        return jax.lax.top_k(scores, k)
+
+    return step
 
 
-def assemble_dense_weights(queries: list[tuple[np.ndarray, int]],
-                           n_docs: int, doc_freq: np.ndarray, scorer: str,
-                           idf_of=None) -> tuple[np.ndarray, np.ndarray,
-                                                 np.ndarray]:
-    """(tids, w, require): per-query term-id and weight slots in query
-    order (tiny — B × T). Both axes pad to powers of two so jit caches
-    stay small across varying batch sizes and query widths; pad slots
-    point at row 0 with weight 0."""
-    b_pad = _pow2(len(queries), 8)
-    t_pad = _pow2(max((len(t) for t, _ in queries), default=1), 1)
-    tids = np.zeros((b_pad, t_pad), dtype=np.int32)
-    w = np.zeros((b_pad, t_pad), dtype=np.float32)
-    require = np.zeros(b_pad, dtype=np.int32)
-    for qi, (term_ids, req) in enumerate(queries):
-        require[qi] = req
-        if not len(term_ids):
+def _dense_program(ds: DenseStore, nq: int, first: bool, last: bool,
+                   k: int):
+    return obs_device.compiled(
+        "dense_topk", (ds.v_pad, ds.ndocs_pad, nq, first, last, k),
+        lambda: dense_body(nq, first, last, k),
+        donate_argnums=() if first or last else (4, 5))
+
+
+def dense_program_keys(rungs: tuple[Rung, ...]) -> list[tuple]:
+    """Every dense step a batch that fits one of `rungs` can dispatch:
+    (queries, first, last), on the ladder the plane kernel climbs."""
+    return [(rung.nq, first, last)
+            for rung in rungs
+            for first in (True, False) for last in (False, True)]
+
+
+def dense_score_topk(ds: DenseStore, slots: list[tuple[np.ndarray,
+                                                       np.ndarray]],
+                     require: np.ndarray, nq: int, k: int):
+    """One dense dispatch: the queries' (term id, weight) slots, cut into
+    steps of DENSE_SLOTS and added in sequence. Returns the device
+    (vals, docs), each (nq, k): rows past len(slots) are padding."""
+    n_steps = max(1, -(-max((len(t) for t, _ in slots), default=0)
+                       // DENSE_SLOTS))
+    require = _pad_to(require, nq, 0)
+    out: tuple = ()
+    for c in range(n_steps):
+        tids = np.zeros((nq, DENSE_SLOTS), dtype=np.int32)
+        w = np.zeros((nq, DENSE_SLOTS), dtype=np.float32)
+        for qi, (t, wq) in enumerate(slots):
+            part = slice(c * DENSE_SLOTS, (c + 1) * DENSE_SLOTS)
+            tids[qi, :len(t[part])] = t[part]
+            w[qi, :len(t[part])] = wq[part]
+        out = _dense_program(ds, nq, c == 0, c == n_steps - 1, k)(
+            ds.St, tids, w, require, *out)
+    return out
+
+
+def prebuild_dense_programs(ds: DenseStore, rungs: tuple[Rung, ...],
+                            k: int) -> int:
+    """Build every program of `dense_program_keys` by running it once on
+    an all-padding step. Returns how many programs this call built."""
+    built = 0
+    for nq in sorted({rung.nq for rung in rungs}):
+        progs = {(first, last): _dense_program(ds, nq, first, last, k)
+                 for n, first, last in dense_program_keys(rungs)
+                 if n == nq}
+        if all(p.called for p in progs.values()):
             continue
+        built += sum(not p.called for p in progs.values())
+        tids = np.zeros((nq, DENSE_SLOTS), dtype=np.int32)
+        w = np.zeros((nq, DENSE_SLOTS), dtype=np.float32)
+        req = np.zeros(nq, dtype=np.int32)
+        planes = progs[(True, False)](ds.St, tids, w, req)
+        planes = progs[(False, False)](ds.St, tids, w, req, *planes)
+        jax.block_until_ready((
+            progs[(False, True)](ds.St, tids, w, req, *planes),
+            progs[(True, True)](ds.St, tids, w, req)))
+    return built
+
+
+def dense_slots(queries: list[tuple[np.ndarray, int]], n_docs: int,
+                doc_freq: np.ndarray, scorer: str, idf_of=None,
+                ) -> tuple[list[tuple[np.ndarray, np.ndarray]], np.ndarray]:
+    """([(term ids, weights) per query, in query order], require)."""
+    slots = []
+    for term_ids, _req in queries:
         tid_arr = np.asarray(term_ids, dtype=np.int64)
-        if idf_of is not None:
+        if not len(tid_arr):
+            idf = np.empty(0, dtype=np.float32)
+        elif idf_of is not None:
             idf = np.asarray(idf_of(tid_arr), dtype=np.float32)
         else:
             idf = idf_for(scorer, n_docs, doc_freq[tid_arr])
-        tids[qi, :len(tid_arr)] = tid_arr
-        w[qi, :len(tid_arr)] = idf
-    return tids, w, require
+        slots.append((tid_arr.astype(np.int32), idf))
+    return slots, np.asarray([req for _, req in queries], dtype=np.int32)
 
 
 # -------------------------------------------------- ragged batched serving
@@ -1006,13 +1235,16 @@ def contrib_expr(tfs: jax.Array, dls: jax.Array, w: jax.Array, k1,
     return w * (k1 + 1.0) * tfsf / jnp.maximum(denom, 1e-9)
 
 
-@functools.partial(jax.jit, static_argnames=("scorer",))
-def contrib_flat(tfs: jax.Array, dls: jax.Array, w: jax.Array, k1: float,
-                 b: float, avgdl: float,
+def contrib_flat(tfs, dls, w, k1: float, b: float, avgdl: float,
                  scorer: str = "bm25") -> jax.Array:
-    """Per-posting score contribution w·sat(tf, dl) over flat arrays.
-    Padding entries (tf=0, w=0) contribute exactly 0.0."""
-    return contrib_expr(tfs, dls, w, k1, b, avgdl, scorer)
+    """Per-posting score contribution w·sat(tf, dl) over flat arrays
+    (one program per padded length and scorer, in the ledger's
+    `bm25_contrib` family). Padding entries (tf=0, w=0) contribute
+    exactly 0.0."""
+    return obs_device.compiled(
+        "bm25_contrib", (len(tfs), scorer),
+        lambda: functools.partial(contrib_expr, scorer=scorer))(
+            tfs, dls, w, k1, b, avgdl)
 
 
 def ragged_contribs(tfs: np.ndarray, dls: np.ndarray, w: np.ndarray,
@@ -1029,9 +1261,8 @@ def ragged_contribs(tfs: np.ndarray, dls: np.ndarray, w: np.ndarray,
         out[:n] = a
         return out
 
-    c = contrib_flat(jnp.asarray(pad(tfs, 0, np.int32)),
-                     jnp.asarray(pad(dls, 0, np.int32)),
-                     jnp.asarray(pad(w, 0.0, np.float32)),
+    c = contrib_flat(pad(tfs, 0, np.int32), pad(dls, 0, np.int32),
+                     pad(w, 0.0, np.float32),
                      scorer_param(scorer, k1), b, avgdl, scorer)
     return np.asarray(c)[:n]
 
@@ -1048,22 +1279,6 @@ def topk_tie_exact(scores: np.ndarray, docs: np.ndarray, k: int,
     else:
         order = np.argsort(-scores, kind="stable")[:k]
     return scores[order], docs[order]
-
-
-@functools.partial(jax.jit, static_argnames=("ndocs_pad",))
-def match_bitmap(block_base: jax.Array, block_gaps: jax.Array,
-                 block_tfs8: jax.Array, row_idx: jax.Array,
-                 raw_docs: jax.Array, raw_idx: jax.Array,
-                 tail_docs: jax.Array, ndocs_pad: int) -> jax.Array:
-    """Disjunctive match bitmap (unscored filter pushdown)."""
-    pdocs, _ = _decode_rows(block_base, block_gaps, block_tfs8, row_idx)
-    pdocs = pdocs.reshape(-1)
-    rdocs = raw_docs[raw_idx].reshape(-1)
-    m = jnp.zeros((ndocs_pad,), dtype=jnp.bool_)
-    m = m.at[jnp.where(pdocs >= 0, pdocs, 0)].max(pdocs >= 0)
-    m = m.at[jnp.where(rdocs >= 0, rdocs, 0)].max(rdocs >= 0)
-    m = m.at[jnp.where(tail_docs >= 0, tail_docs, 0)].max(tail_docs >= 0)
-    return m
 
 
 def pad_k(k: int) -> int:

@@ -58,8 +58,8 @@ _DISPATCH_SEQ = itertools.count(1)
 
 class _Entry:
     __slots__ = ("node", "done", "retry", "result", "n_batch",
-                 "window_ns", "scoring_ns", "t_submit_ns", "trace",
-                 "span")
+                 "window_ns", "scoring_ns", "t_submit_ns", "t_scored_ns",
+                 "trace", "span")
 
     def __init__(self, node):
         self.node = node
@@ -70,6 +70,7 @@ class _Entry:
         self.window_ns = 0
         self.scoring_ns = 0
         self.t_submit_ns = time.perf_counter_ns()
+        self.t_scored_ns = 0
         # the submitter's timeline (None when tracing is off): a
         # coalesced dispatch stamps its window/scoring spans under
         # EVERY member query's trace, so each member's timeline shows
@@ -167,6 +168,11 @@ class SearchBatcher:
             # query down
             out = searcher.topk_batch([node], k, scorer, mesh_n=mesh_n)[0]
             return out, {"queries": 1, "window_ns": 0, "scoring_ns": 0}
+        if batch is None and e.trace is not None:
+            # a member's second wait: from the end of the dispatch that
+            # scored it until this thread runs again with the result
+            e.trace.add_stage("batch_wait", e.t_scored_ns,
+                              time.perf_counter_ns())
         return e.result, {"queries": e.n_batch, "window_ns": e.window_ns,
                           "scoring_ns": e.scoring_ns}
 
@@ -187,14 +193,19 @@ class SearchBatcher:
                   scorer: str, mesh_n: int) -> None:
         """Score one claimed batch and hand each member its result. On
         ANY failure every member retries serially on its own thread."""
+        from ..obs.trace import stage_sink
         t0 = time.perf_counter_ns()
         outs = None
-        try:
-            outs = g.searcher.topk_batch([x.node for x in batch], k,
-                                         scorer, mesh_n=mesh_n,
-                                         ragged=True)
-        except BaseException:
-            outs = None   # members retry serially; the bad one re-raises
+        # the dispatch's stages (search_plan, device_enqueue,
+        # device_wait, search_host_score) land in THIS thread's trace,
+        # the claimer's (batch[0]); the sink notes them for the others
+        with stage_sink() as stages:
+            try:
+                outs = g.searcher.topk_batch([x.node for x in batch], k,
+                                             scorer, mesh_n=mesh_n,
+                                             ragged=True)
+            except BaseException:
+                outs = None   # members retry serially; the bad re-raises
         t1 = time.perf_counter_ns()
         wait_ns = 0
         seq = next(_DISPATCH_SEQ) if outs is not None else 0
@@ -206,21 +217,25 @@ class SearchBatcher:
                     x.n_batch = len(batch)
                     x.window_ns = max(t0 - x.t_submit_ns, 0)
                     x.scoring_ns = t1 - t0
+                    x.t_scored_ns = t1
                     wait_ns += x.window_ns
                     if x.trace is not None:
                         # per-member timeline: how long THIS query
-                        # waited queued, then the shared scoring
-                        # dispatch it rode. Stamped from the
+                        # waited queued (the `batch_wait` stage), then
+                        # the shared scoring dispatch it rode, with the
+                        # dispatch's own stages. Stamped from the
                         # dispatching thread BEFORE x.done releases the
                         # member — its statement cannot finalize its
                         # trace until these spans are in the rings
                         if x.window_ns:
-                            x.trace.add("batch_wait", "search",
-                                        x.t_submit_ns, t0,
-                                        _parent=x.span)
+                            x.trace.add_stage("batch_wait",
+                                              x.t_submit_ns, t0)
                         x.trace.add("batch_dispatch", "search", t0, t1,
                                     _parent=x.span,
                                     queries=len(batch), dispatch=seq)
+                        if i:
+                            for name, b, e in stages:
+                                x.trace.add_stage(name, b, e)
                     x.done = True
                 else:
                     x.retry = True

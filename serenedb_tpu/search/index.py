@@ -170,6 +170,7 @@ def build_index_for_table(provider, columns, using, options) -> SearchIndex:
             fi = _build_field(texts, an)
             ms = MultiSearcher(an)
             ms.add_segment(SegmentSearcher(fi, an, len(texts)), 0)
+            ms.prebuild()
             searchers[col_name] = ms
     return SearchIndex(list(columns), using, dict(options), analyzer_name,
                        searchers, provider.data_version,
@@ -275,6 +276,7 @@ def refresh_index(provider, idx, *,
         ms = MultiSearcher(an)
         for seg, seg_base in segs:
             ms.add_segment(seg, seg_base)
+        ms.prebuild()
         new_searchers[col_name] = ms
     return SearchIndex(list(idx.columns), idx.using, dict(idx.options),
                        idx.analyzer_name, new_searchers,

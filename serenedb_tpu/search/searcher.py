@@ -16,6 +16,8 @@ included); NOT-subtrees and phrase adjacency affect *matching* only.
 
 from __future__ import annotations
 
+import threading
+import time
 from dataclasses import dataclass
 from typing import NamedTuple, Optional
 
@@ -24,7 +26,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..columnar.device import pad_len
+from ..obs import device as obs_device
+from ..obs.trace import stage
 from ..ops import bm25 as bm25_ops
+from ..utils import metrics
+from ..utils.config import REGISTRY
 from . import posting_pool
 from .analysis import Analyzer
 from .automaton import intersect_sorted, levenshtein_nfa
@@ -47,6 +53,13 @@ def _host_backend() -> bool:
     if _HOST_BACKEND is None:
         _HOST_BACKEND = jax.default_backend() == "cpu"
     return _HOST_BACKEND
+
+
+def _batch_cap() -> int:
+    """Queries the batcher may coalesce into one `topk_batch` call
+    (`serene_search_batch_max`): with the store's padded document count,
+    what the closed set of scoring programs is enumerated from."""
+    return max(int(REGISTRY.get_global("serene_search_batch_max")), 1)
 
 
 class _RaggedSlice(NamedTuple):
@@ -385,8 +398,9 @@ class SegmentSearcher:
         return self.topk_batch([node], k, scorer, mesh_n=mesh_n)[0]
 
     # cap on per-dispatch accumulator entries (B × ndocs_pad f32): bounds
-    # HBM at large corpora — the batch splits into query chunks instead of
-    # materializing (256, 8.8M) at MS-MARCO scale
+    # HBM at large corpora — the rung ladder stops below it, and a batch
+    # splits into query chunks instead of materializing (256, 8.8M) at
+    # MS-MARCO scale
     ACC_ENTRY_CAP = 128 * 1024 * 1024
 
     #: per-query cap on ragged host-path posting entries: past this the
@@ -394,14 +408,56 @@ class SegmentSearcher:
     #: query stays on the device dispatch
     RAGGED_ENTRY_CAP = 1 << 18
 
+    def _rungs(self, store) -> tuple:
+        """This store's ladder of program shapes, from what can be seen
+        of it: its padded document count, the batcher's cap, the
+        accumulator cap. The one list `prebuild` builds and
+        `topk_batch` picks from."""
+        return bm25_ops.score_rungs(store.ndocs_pad, _batch_cap(),
+                                    self.ACC_ENTRY_CAP)
+
+    def _use_dense(self, store, scorer: str, avgdl: float) -> bool:
+        """The small-corpus dense path answers this store's searches
+        (what `prebuild` builds for, and what `topk_batch` dispatches)."""
+        return (scorer not in bm25_ops.LM_SCORERS and
+                (scorer == "tfidf" or avgdl > 0.0) and
+                bm25_ops.dense_fits(store.ndocs_pad,
+                                    len(self.index.doc_freq)))
+
+    def prebuild(self, avgdl: Optional[float] = None,
+                 scorer: str = "bm25") -> int:
+        """Upload this segment's posting store and build the closed set
+        of programs its searches dispatch (ops/bm25.py), so that none is
+        built by a search: the dense steps where the saturation matrix
+        fits, else the plane kernel's accumulate and top-k steps, for
+        every rung up to the batcher's cap at the first top-k bucket.
+        Returns how many programs this call built."""
+        if self.num_docs == 0:
+            return 0
+        store = self._device_store()
+        avgdl = self.index.avgdl if avgdl is None else avgdl
+        kk = min(bm25_ops.pad_k(1), store.ndocs_pad)
+        if self._use_dense(store, scorer, avgdl):
+            built = bm25_ops.prebuild_dense_programs(
+                self._dense_store(scorer, avgdl), self._rungs(store), kk)
+        else:
+            built = bm25_ops.prebuild_plane_programs(
+                store, self._rungs(store), kk, scorer)
+        metrics.SEARCH_PROGRAMS_PREBUILT.add(built)
+        return built
+
     def topk_batch(self, nodes: list[QNode], k: int, scorer: str = "bm25",
                    idf_of=None, avgdl_override=None, mesh_n: int = 0,
-                   ragged: bool = False,
+                   ragged: bool = False, tiers: Optional[list] = None,
                    ) -> list[tuple[np.ndarray, np.ndarray]]:
         """Top-k (scores, doc ids) for a batch of queries in ONE device
-        dispatch (amortizes dispatch latency — the QPS regime). Pure term
-        disjunctions/conjunctions run fully on device; other shapes get an
-        exact-match CPU mask applied to the device scores.
+        dispatch (amortizes dispatch latency — the QPS regime): the
+        batch is fitted to a rung of the store's closed program set
+        (ops/bm25.py), and what exceeds the largest rung is split into
+        several dispatches. Pure term disjunctions/conjunctions run fully
+        on device; other shapes get an exact-match CPU mask applied to
+        the device scores. `tiers`, when given, is filled per query with
+        "device" or "host": where its top-k was scored.
 
         ragged=True (the batched-serving path, search/batcher.py) admits
         pure disjunctions on the host jax backend to `_ragged_resolve`:
@@ -412,7 +468,10 @@ class SegmentSearcher:
         at top-10-of-millions scale. Never taken when this store would use
         the dense gather path, so ragged on/off can't change a single
         result bit there either."""
+        if tiers is None:
+            tiers = [None] * len(nodes)
         if self.num_docs == 0:
+            tiers[:] = ["host"] * len(nodes)
             return [(np.empty(0, dtype=np.float32),
                      np.empty(0, dtype=np.int32))] * len(nodes)
         if scorer in bm25_ops.LM_SCORERS and idf_of is None:
@@ -423,126 +482,119 @@ class SegmentSearcher:
                 return bm25_ops.term_weight_for(
                     scorer, self.num_docs, None, _ctf[tids], _tot)
         store = self._device_store()
-        max_b = max(1, self.ACC_ENTRY_CAP // store.ndocs_pad)
+        rungs = self._rungs(store)
+        max_b = rungs[-1].nq
         if len(nodes) > max_b:
             out = []
             for i in range(0, len(nodes), max_b):
+                part = [None] * len(nodes[i:i + max_b])
                 out.extend(self.topk_batch(nodes[i:i + max_b], k, scorer,
                                            idf_of, avgdl_override, mesh_n,
-                                           ragged))
+                                           ragged, part))
+                tiers[i:i + max_b] = part
             return out
         nd_pad = store.ndocs_pad
-        shapes = [self._query_shape(n) for n in nodes]
-        queries = [(np.asarray(tids, dtype=np.int64) if not empty
-                    else np.empty(0, dtype=np.int64), req)
-                   for tids, req, _, empty in shapes]
-        # pad the query axis to a power of two with no-op empties: the
-        # packed/mesh kernels are jitted per n_queries, and coalesced
-        # batches arrive at every size — without bucketing each new size
-        # would compile a fresh program. Empty pads scatter nothing and
-        # their accumulator rows are never read back, so real queries'
-        # bits are untouched.
-        for _ in range(bm25_ops._pow2(len(queries), 1) - len(queries)):
-            queries.append((np.empty(0, dtype=np.int64), 0))
-        # block-max WAND applies to pure disjunctions whose device top-k is
-        # final (no exact-match mask re-ranking a subset afterwards); the
-        # LM scorers don't decompose as w·sat, so their bounds don't hold
-        prunable = [req == 0 and not needs_mask and not empty and
-                    scorer not in bm25_ops.LM_SCORERS
-                    for _, req, needs_mask, empty in shapes]
         avgdl = (avgdl_override if avgdl_override is not None
                  else self.index.avgdl)
         k_true = min(max(k, 1), max(self.num_docs, 1))
-        if mesh_n > 1 and len(jax.devices()) >= mesh_n and \
-                not any(req for _, req in queries):
-            # mesh-sharded scoring: posting-row sections shard across the
-            # devices, score planes psum over ICI (SURVEY §5.7 — "scale
-            # one query across all compute"). require-free shapes only;
-            # _finish_batch applies exact-match masks as usual.
-            qb = bm25_ops.assemble_query_batch(
-                store, self.num_docs, queries, self.index.doc_freq,
-                scorer, idf_of=idf_of)
-            kk = min(bm25_ops.pad_k(k_true), nd_pad)
-            if any(len(q[0]) > 0 for q in queries):
-                vals, docs = jax.device_get(bm25_ops.score_topk_mesh(
-                    store, qb, nd_pad, kk, mesh_n,
-                    bm25_ops.scorer_param(scorer, K1), B, avgdl, scorer))
-            else:
-                vals = np.zeros((qb.n_queries, kk), dtype=np.float32)
-                docs = np.zeros((qb.n_queries, kk), dtype=np.int32)
-            return self._finish_batch(nodes, shapes, vals, docs, {}, k,
-                                      scorer, idf_of, avgdl_override,
-                                      nd_pad)
-        plans: list = [None] * len(queries)
+        kk = min(bm25_ops.pad_k(k_true), nd_pad)
+        plans: list = [None] * len(nodes)
         host_results: dict[int, tuple[np.ndarray, np.ndarray]] = {}
-        use_dense = (scorer not in bm25_ops.LM_SCORERS and
-                     (scorer == "tfidf" or avgdl > 0.0) and
-                     bm25_ops.dense_fits(store.ndocs_pad,
-                                         len(self.index.doc_freq)))
-        if use_dense:
-            # small-corpus dense path: one dispatch of row gathers, no
-            # host WAND planning needed (the dense kernel is not
-            # scatter-bound)
-            ds = self._dense_store(scorer, avgdl)
-            tid_slots, w_slots, require_arr = \
-                bm25_ops.assemble_dense_weights(
+        with stage("search_plan"):
+            shapes = [self._query_shape(n) for n in nodes]
+            queries = [(np.asarray(tids, dtype=np.int64) if not empty
+                        else np.empty(0, dtype=np.int64), req)
+                       for tids, req, _, empty in shapes]
+            # block-max WAND applies to pure disjunctions whose device
+            # top-k is final (no exact-match mask re-ranking a subset
+            # afterwards); the LM scorers don't decompose as w·sat, so
+            # their bounds don't hold
+            prunable = [req == 0 and not needs_mask and not empty and
+                        scorer not in bm25_ops.LM_SCORERS
+                        for _, req, needs_mask, empty in shapes]
+            use_mesh = mesh_n > 1 and len(jax.devices()) >= mesh_n and \
+                not any(req for _, req in queries)
+            use_dense = not use_mesh and self._use_dense(store, scorer,
+                                                         avgdl)
+            if not use_mesh and not use_dense and \
+                    store.norms_host is not None and \
+                    (scorer == "tfidf" or avgdl > 0.0):
+                for qi, (tids, req, needs_mask, empty) in enumerate(shapes):
+                    if not (prunable[qi] and tids):
+                        continue
+                    plan = self._wand_plan_cached(store, tids, k_true,
+                                                  avgdl, scorer, idf_of)
+                    if plan is None:
+                        continue
+                    plans[qi] = plan
+                    cand = self._maxscore_candidates(plan, tids, k_true)
+                    if cand is not None:
+                        with stage("search_host_score"):
+                            host_results[qi] = self._cpu_score(
+                                cand, tids, k, scorer, idf_of,
+                                avgdl_override)
+                        queries[qi] = (np.empty(0, dtype=np.int64), 0)
+                if ragged and _host_backend():
+                    todo = [qi for qi in range(len(shapes))
+                            if prunable[qi] and shapes[qi][0] and
+                            qi not in host_results]
+                    if todo:
+                        for qi, res in self._ragged_resolve(
+                                store, todo, shapes, plans, k, scorer,
+                                idf_of, avgdl).items():
+                            host_results[qi] = res
+                            queries[qi] = (np.empty(0, dtype=np.int64), 0)
+            live = any(len(q[0]) > 0 for q in queries)
+            for qi, q in enumerate(queries):
+                tiers[qi] = "device" if len(q[0]) > 0 else "host"
+            if use_dense:
+                slots, require = bm25_ops.dense_slots(
                     queries, self.num_docs, self.index.doc_freq, scorer,
                     idf_of)
-            kk = min(bm25_ops.pad_k(k_true), store.ndocs_pad)
-            vals, docs = bm25_ops.dense_topk(
-                ds.St, jnp.asarray(tid_slots), jnp.asarray(w_slots),
-                jnp.asarray(require_arr), kk, bool(require_arr.any()))
-            vals, docs = jax.device_get((vals, docs))
+                n_postings = sum(int(self.index.doc_freq[t].sum())
+                                 for t, _ in slots)
+            elif live:
+                if use_mesh:
+                    # the mesh programs are jitted per n_queries: pad the
+                    # query axis to a power of two with no-op empties
+                    queries += [(np.empty(0, dtype=np.int64), 0)] * (
+                        bm25_ops._pow2(len(queries), 1) - len(queries))
+                qb = bm25_ops.assemble_query_batch(
+                    store, self.num_docs, queries, self.index.doc_freq,
+                    scorer, idf_of=idf_of, plans=plans)
+                n_postings = qb.n_postings
+        if live:
+            t_d = time.perf_counter_ns()
+            if use_mesh:
+                # mesh-sharded scoring: posting-row sections shard across
+                # the devices, score planes psum over ICI (SURVEY §5.7 —
+                # "scale one query across all compute"). require-free
+                # shapes only; _finish_batch applies exact-match masks as
+                # usual.
+                out = bm25_ops.score_topk_mesh(
+                    store, qb, nd_pad, kk, mesh_n,
+                    bm25_ops.scorer_param(scorer, K1), B, avgdl, scorer)
+            elif use_dense:
+                # small-corpus dense path: row gathers, no host WAND
+                # planning needed (the dense kernel is not scatter-bound)
+                out = bm25_ops.dense_score_topk(
+                    self._dense_store(scorer, avgdl), slots, require,
+                    bm25_ops.rung_for(rungs, len(queries)).nq, kk)
+            else:
+                out = bm25_ops.score_topk_planes(
+                    store, qb, bm25_ops.rung_for(rungs, len(queries)), kk,
+                    bm25_ops.scorer_param(scorer, K1), B, avgdl, scorer)
+            vals, docs = obs_device.fetch_all(out)
+            metrics.DEVICE_DISPATCH_HIST.observe_ns(
+                time.perf_counter_ns() - t_d)
+            metrics.SEARCH_POSTINGS_DISPATCHED.add(n_postings)
+        else:  # every query resolved host-side — skip the dispatch entirely
+            vals = np.zeros((len(queries), kk), dtype=np.float32)
+            docs = np.zeros((len(queries), kk), dtype=np.int32)
+        with stage("search_host_score"):
             return self._finish_batch(nodes, shapes, vals, docs,
                                       host_results, k, scorer, idf_of,
-                                      avgdl_override, store.ndocs_pad)
-        if store.norms_host is not None and \
-                (scorer == "tfidf" or avgdl > 0.0):
-            for qi, (tids, req, needs_mask, empty) in enumerate(shapes):
-                if not (prunable[qi] and tids):
-                    continue
-                plan = self._wand_plan_cached(store, tids, k_true, avgdl,
-                                              scorer, idf_of)
-                if plan is None:
-                    continue
-                plans[qi] = plan
-                cand = self._maxscore_candidates(plan, tids, k_true)
-                if cand is not None:
-                    host_results[qi] = self._cpu_score(
-                        cand, tids, k, scorer, idf_of, avgdl_override)
-                    queries[qi] = (np.empty(0, dtype=np.int64), 0)
-        if ragged and _host_backend() and \
-                (scorer == "tfidf" or avgdl > 0.0) and \
-                store.norms_host is not None:
-            todo = [qi for qi in range(len(shapes))
-                    if prunable[qi] and shapes[qi][0] and
-                    qi not in host_results]
-            if todo:
-                for qi, res in self._ragged_resolve(
-                        store, todo, shapes, plans, k, scorer, idf_of,
-                        avgdl).items():
-                    host_results[qi] = res
-                    queries[qi] = (np.empty(0, dtype=np.int64), 0)
-        qb = bm25_ops.assemble_query_batch(store, self.num_docs, queries,
-                                           self.index.doc_freq, scorer,
-                                           idf_of=idf_of, plans=plans)
-        kk = bm25_ops.pad_k(k_true)
-        kk = min(kk, nd_pad)
-        nq = qb.n_queries
-        if any(len(q[0]) > 0 for q in queries):
-            ints, floats, nb, nr, tt, nq = bm25_ops.pack_query_batch(qb)
-            vals, docs = bm25_ops.score_topk_packed(
-                store.block_base, store.block_gaps, store.block_tfs8,
-                store.raw_docs, store.raw_tfs, store.norms,
-                jnp.asarray(ints), jnp.asarray(floats), nb, nr, tt,
-                nd_pad, kk, nq, bool(qb.require.any()),
-                bm25_ops.scorer_param(scorer, K1), B, avgdl, scorer)
-            vals, docs = jax.device_get((vals, docs))
-        else:  # every query resolved host-side — skip the dispatch entirely
-            vals = np.zeros((nq, kk), dtype=np.float32)
-            docs = np.zeros((nq, kk), dtype=np.int32)
-        return self._finish_batch(nodes, shapes, vals, docs, host_results,
-                                  k, scorer, idf_of, avgdl_override, nd_pad)
+                                      avgdl_override, nd_pad, tiers)
 
     #: byte budget for the ragged memo caches hung off plans and stores
     #: (_ragged_slices masked copies, _ragged_accum candidate tables,
@@ -761,7 +813,7 @@ class SegmentSearcher:
         return out
 
     def _finish_batch(self, nodes, shapes, vals, docs, host_results, k,
-                      scorer, idf_of, avgdl_override, nd_pad,
+                      scorer, idf_of, avgdl_override, nd_pad, tiers,
                       ) -> list[tuple[np.ndarray, np.ndarray]]:
         """Shared device-result postprocessing: host-resolved queries,
         always-empty conjunctions, zero-score matches, exact-match mask
@@ -797,6 +849,7 @@ class SegmentSearcher:
                     # be the true top-k of the match set; exact CPU rescore
                     scores, dd = self._cpu_score(match, tids, k, scorer,
                                                  idf_of, avgdl_override)
+                    tiers[qi] = "host"
                 else:
                     scores, dd = scores[ok], dd[ok]
             keep = scores > 0.0
@@ -1221,6 +1274,29 @@ class MultiSearcher:
         result cache's settings digest."""
         from ..cache.fragments import FRAGMENTS, qnode_sig
         sigs = [qnode_sig(n) for n in nodes]
+        # where each query's top-k was scored, over its segments: "device"
+        # if a device program scored it in any, "host" if only host tiers
+        # did, None if every segment's fragment was cached
+        scored: list = [None] * len(nodes)
+        scored_lock = threading.Lock()    # segments score on pool threads
+
+        def score_segment(seg, idxs, **kw):
+            tiers = [None] * len(idxs)
+            out = seg.topk_batch([nodes[i] for i in idxs], k, scorer,
+                                 mesh_n=mesh_n, ragged=ragged, tiers=tiers,
+                                 **kw)
+            with scored_lock:
+                for i, tier in zip(idxs, tiers):
+                    if scored[i] != "device":
+                        scored[i] = tier
+            return out
+
+        def count_scored():
+            metrics.SEARCH_QUERIES_SCORED_DEVICE.add(
+                sum(t == "device" for t in scored))
+            metrics.SEARCH_QUERIES_SCORED_HOST.add(
+                sum(t == "host" for t in scored))
+
         if len(self.segments) == 1:
             seg, base = self.segments[0]
             # single segment: local stats ARE the global stats — the
@@ -1228,10 +1304,8 @@ class MultiSearcher:
             shapes = [None if s is None else ("topk1", s, k, scorer, mesh_n)
                       for s in sigs]
             out = FRAGMENTS.cached_batch(
-                seg, shapes,
-                lambda idxs: seg.topk_batch([nodes[i] for i in idxs], k,
-                                            scorer, mesh_n=mesh_n,
-                                            ragged=ragged))
+                seg, shapes, lambda idxs: score_segment(seg, idxs))
+            count_scored()
             return [(s, d.astype(np.int64) + base) for s, d in out]
         idf_factory = self._segment_idf_factory(nodes, scorer)
         avgdl = self.global_avgdl
@@ -1248,11 +1322,9 @@ class MultiSearcher:
                                               segset) for s in sigs]
             return FRAGMENTS.cached_batch(
                 seg, shapes,
-                lambda idxs: seg.topk_batch([nodes[i] for i in idxs], k,
-                                            scorer,
-                                            idf_of=idf_factory(seg),
-                                            avgdl_override=avgdl,
-                                            mesh_n=mesh_n, ragged=ragged))
+                lambda idxs: score_segment(seg, idxs,
+                                           idf_of=idf_factory(seg),
+                                           avgdl_override=avgdl))
 
         # segments are independent top-k collectors: search them on the
         # shared worker pool (reference: parallel scored collectors over
@@ -1261,9 +1333,18 @@ class MultiSearcher:
         from ..parallel.pool import get_pool, session_workers
         cap = 1 if mesh_n > 1 else session_workers(None)
         seg_outs = _run_segment_shards(run_segment, self.segments, cap)
+        count_scored()
         return _combine_topk(seg_outs,
                              [b for _, b in self.segments],
                              len(nodes), k)
+
+    def prebuild(self) -> int:
+        """Every segment's posting store uploaded and its scoring
+        programs built (`SegmentSearcher.prebuild`), under the collection
+        statistics a search will score with: what an index build or
+        refresh calls before it publishes this searcher."""
+        avgdl = None if len(self.segments) == 1 else self.global_avgdl
+        return sum(seg.prebuild(avgdl) for seg, _ in self.segments)
 
     def probe_topk(self, node: QNode, k: int, scorer: str = "bm25",
                    mesh_n: int = 0,

@@ -64,6 +64,27 @@ class EsApi:
             conn = self._tl.conn = self.db.connect()
         return conn
 
+    def begin_request(self, label: str, clock):
+        """The trace of one `_search` request (None when tracing is off),
+        begun on the transport's clock and left in `clock.trace` for the
+        transport to close after the last byte (the `/_sql` route's
+        pattern)."""
+        clock.trace = clock.begin(self._rconn(), label)
+        return clock.trace
+
+    def _read(self, sql: str, trace=None):
+        """One read statement on this thread's connection — on the
+        request's trace when there is one, so that the statements of one
+        `_search` land on one timeline."""
+        conn = self._rconn()
+        if trace is None:
+            return conn.execute(sql)
+        from ..sql import parser
+        res = None
+        for st in parser.parse(sql):
+            res = conn.execute_statement(st, [], sql_text=sql, trace=trace)
+        return res
+
     # -- index management --------------------------------------------------
 
     def _table(self, index: str, create: bool = False) -> MemTable:
@@ -368,7 +389,10 @@ class EsApi:
         return {"count": int(n),
                 "_shards": {"total": 1, "successful": 1, "failed": 0}}
 
-    def search(self, index: str, body: Optional[dict] = None) -> dict:
+    def search(self, index: str, body: Optional[dict] = None,
+               trace=None) -> dict:
+        """`trace`: the request's trace (`begin_request`); the scored
+        SELECT and the exact-total count(*) both run under it."""
         body = body or {}
         t = self._table(index)
         size = int(body.get("size", 10))
@@ -428,11 +452,11 @@ class EsApi:
             score_col = "multi"
         else:
             sql += order + f" LIMIT {size} OFFSET {from_}"
-            rows = list(self._rconn().execute(sql).rows())
+            rows = list(self._read(sql, trace).rows())
             total_sql = f'SELECT count(*) FROM "{index}"'
             if where:
                 total_sql += f" WHERE {where}"
-            total = int(self._rconn().execute(total_sql).scalar())
+            total = int(self._read(total_sql, trace).scalar())
         hits = []
         max_score = 0.0
         for row in rows:

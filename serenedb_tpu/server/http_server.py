@@ -69,6 +69,14 @@ class RequestClock:
         self.trace = None
         self.error: Optional[str] = None
 
+    def begin(self, conn, label: str):
+        """The request's trace (None when `conn` has tracing off): begun
+        at the receipt stamp, the executor handoff as its `fd_queue`."""
+        tr = conn.begin_request(label, self.recv_ns)
+        if tr is not None and self.submit_ns:
+            tr.add_stage("fd_queue", self.submit_ns, self.start_ns)
+        return tr
+
     def end(self) -> None:
         end_request(self.trace, self.error)
 
@@ -126,9 +134,7 @@ class Router:
         from ..columnar.column import Batch
         from ..engine import QueryResult
         from ..sql import parser
-        tr = conn.begin_request(query, clock.recv_ns)
-        if tr is not None and clock.submit_ns:
-            tr.add_stage("fd_queue", clock.submit_ns, clock.start_ns)
+        tr = clock.begin(conn, query)
         with stage_of(tr, "fd_parse"):
             stmts = parser.parse(query)
         res = QueryResult(Batch([], []), "")
@@ -302,7 +308,9 @@ class Router:
             if "scroll" in q:
                 return 200, es.search_scroll_start(
                     index, b, q["scroll"][0]), JSON_CTYPE
-            return 200, es.search(index, b), JSON_CTYPE
+            return 200, es.search(
+                index, b, es.begin_request(f"{method} /{index}/_search",
+                                           clock)), JSON_CTYPE
         if verb == "_mget" and method == "POST":
             return 200, es.mget(index, _json_body(body) or {}), JSON_CTYPE
         if verb == "_msearch" and method == "POST":

@@ -348,6 +348,27 @@ SEARCH_BATCH_COALESCED = REGISTRY.gauge(
     "SearchBatchCoalesced",
     "queries that shared their scoring dispatch with at least one other "
     "query (the batching win; singleton dispatches don't count)")
+SEARCH_QUERIES_SCORED_DEVICE = REGISTRY.gauge(
+    "SearchQueriesScoredDevice",
+    "top-k queries whose top-k came out of a device scoring program "
+    "(bm25_accumulate + bm25_topk, dense_topk, the mesh kernel) in at "
+    "least one segment; with SearchQueriesScoredHost a partition of the "
+    "queries scored (a fragment-cache hit is neither)")
+SEARCH_QUERIES_SCORED_HOST = REGISTRY.gauge(
+    "SearchQueriesScoredHost",
+    "top-k queries whose top-k came out of a host tier in every "
+    "segment: _cpu_score over MaxScore candidates or an exact-match "
+    "rescore, the ragged host tier, or no scoring at all (no term of "
+    "the query is indexed)")
+SEARCH_POSTINGS_DISPATCHED = REGISTRY.gauge(
+    "SearchPostingsDispatched",
+    "valid (non-padding) postings in the block rows and light-term "
+    "tails handed to device scoring programs after pruning; the dense "
+    "path counts the document frequencies of the rows it gathers")
+SEARCH_PROGRAMS_PREBUILT = REGISTRY.gauge(
+    "SearchProgramsPrebuilt",
+    "scoring programs built by an index build or refresh before the "
+    "index answered a search (SegmentSearcher.prebuild)")
 POSTING_POOL_HITS = REGISTRY.gauge(
     "PostingPoolHits",
     "posting-pool term lookups served by pages already resident in the "
@@ -559,6 +580,16 @@ STAGE_HISTS = {name: REGISTRY.histogram(hist, desc) for name, hist, desc in (
     ("host_group", "StageHostGroup",
      "host hash-aggregate, factorize and distinct finalize"),
     ("host_sort", "StageHostSort", "the materializing host sort"),
+    ("batch_wait", "StageBatchWait",
+     "a top-k query waiting in the search batcher: submission until the "
+     "dispatch that carries it starts, and, for a member another thread "
+     "dispatched, the end of that dispatch until its own thread resumes"),
+    ("search_plan", "StageSearchPlan",
+     "host planning of a scoring dispatch: query shapes, block-max WAND "
+     "plans, MaxScore candidates, batch assembly and packing"),
+    ("search_host_score", "StageSearchHostScore",
+     "host scoring: _cpu_score over candidates, exact-match masks and "
+     "the result postprocessing of a scoring dispatch"),
     ("other", "StageOther",
      "the request's time under no stage: RequestLatency minus the "
      "union of its stages"))}

@@ -443,3 +443,51 @@ def test_qps_smoke():
     t_on = drive("on")
     t_off = drive("off")
     assert t_on < t_off * 1.5, (t_on, t_off)
+
+
+# -- the closed program set behind the batcher (ISSUE 26) -------------------
+
+
+@pytest.mark.parametrize("n_queries", [1, 8, 9, 33, 80])
+def test_batch_of_any_size_keeps_serial_bits_on_the_plane_kernel(
+        monkeypatch, n_queries):
+    """A coalesced batch is fitted to a rung of the store's program ladder
+    (1 / 8 / 32 queries) and split past the largest: per query the bits
+    are those of a dispatch of its own, on the accelerator's tier choice
+    (no ragged host tier)."""
+    from serenedb_tpu.ops import bm25 as bm25_ops
+    from serenedb_tpu.search import searcher as searcher_mod
+    monkeypatch.setattr(bm25_ops, "DENSE_HBM_BUDGET", 0)
+    monkeypatch.setattr(searcher_mod, "_HOST_BACKEND", False)
+    an = get_analyzer("text")
+    rng = np.random.default_rng(5)
+    docs = [" ".join(rng.choice(WORDS, rng.integers(3, 24)))
+            for _ in range(900)]
+    docs[::13] = ["apple banana apple"] * len(docs[::13])   # ties
+    fi = build_field_index(docs, an)
+    ms = MultiSearcher(an)
+    ms.add_segment(SegmentSearcher(fi, an, len(docs)), 0)
+    pool = ["apple", "apple | dog", "apple & banana", '"quick brown"',
+            "zzznothing", "banana | fox | dog", "quick & fox & jumps",
+            "search | engine | database | index | query | term"]
+    nodes = [parse_query(pool[i % len(pool)], an) for i in range(n_queries)]
+    solo = [ms.topk_batch([n], 10)[0] for n in nodes]
+    batched = ms.topk_batch(nodes, 10, ragged=True)
+    for i in range(n_queries):
+        assert np.array_equal(batched[i][0].view(np.uint32),
+                              solo[i][0].view(np.uint32)), i
+        assert np.array_equal(batched[i][1], solo[i][1]), i
+
+
+def test_batcher_counts_each_query_once_by_its_tier(db):
+    """Through the batcher, SearchQueriesScoredDevice + ...Host moves by
+    the queries answered (fragment cache off: no query is neither)."""
+    conn = db.connect()
+    conn.execute("SET serene_result_cache = off")
+    before = (metrics.SEARCH_QUERIES_SCORED_DEVICE.value +
+              metrics.SEARCH_QUERIES_SCORED_HOST.value)
+    for q in QUERIES[:2] + QUERIES[4:6]:
+        conn.execute(q).rows()
+    after = (metrics.SEARCH_QUERIES_SCORED_DEVICE.value +
+             metrics.SEARCH_QUERIES_SCORED_HOST.value)
+    assert after - before == 4
