@@ -20,6 +20,7 @@ from ..columnar import dtypes as dt
 from ..columnar.column import Batch, Column, concat_batches, merge_dictionaries
 from ..obs.trace import stage
 from ..sql.expr import AggSpec, BoundColumn, BoundExpr
+from ..utils import metrics
 from ..utils.config import SessionSettings
 from .tables import TableProvider
 
@@ -1089,6 +1090,67 @@ class _Rev:
 #: DISTINCT qualifier on them runs the plain accumulator
 _DISTINCT_INVARIANT = {"min", "max", "bool_and", "bool_or", "every"}
 
+#: a scalar DISTINCT accumulator sorts what it holds once this many
+#: values wait unmerged (or as many as it already holds distinct, if
+#: that is more): a table below it is sorted once, in `result()`, and a
+#: larger one keeps the distinct values plus at most as many again
+_DISTINCT_MERGE_ROWS = 1 << 22
+
+
+def _dedups_as_integers(col: Column) -> bool:
+    """COUNT / SUM / AVG(DISTINCT col) can dedup the typed array itself:
+    a fixed-width integer array that IS the value (integers, bool, date,
+    timestamp, interval, oids), so equal values are equal bits and one
+    sort puts them side by side. Floats keep the object path (NaN ≠ NaN
+    and -0.0 == 0.0 there), strings too (codes mean nothing across
+    dictionaries)."""
+    return col.data.dtype.kind in "iub" and not col.type.is_string
+
+
+#: rows per group from which `_distinct_pairs` sorts each group's values
+#: on its own (one step of Python per group) rather than rank them all
+_PARTITION_ROWS_PER_GROUP = 64
+
+
+def _distinct_pairs(vc: np.ndarray, vals: np.ndarray, g: int, values: bool,
+                    ) -> tuple[np.ndarray, Optional[np.ndarray]]:
+    """Distinct (group code, value) pairs of an integer-like `vals`,
+    ordered by group and then value: their codes and, if `values`, their
+    values. No two-key lexsort; which single-key sorts do it follows the
+    input. Where the values' span times `g` fits 63 bits, ONE sort of
+    `code * span + (value - min)`. Wider values in few, large groups:
+    the rows partitioned by group (a stable sort of the narrow codes),
+    then each group's values sorted where they lie. Else each value
+    ranked among the distinct values (one argsort, inside `np.unique`)
+    and one sort of `code * ranks + rank`."""
+    lo = int(vals.min())
+    ranks = int(vals.max()) - lo + 1
+    if ranks * g < 1 << 63:
+        uniq = None
+        rank = vals.astype(np.int64) - lo
+    elif g * _PARTITION_ROWS_PER_GROUP <= len(vals):
+        order = np.argsort(vc.astype(np.uint16) if g <= 1 << 16 else vc,
+                           kind="stable")
+        sv = vals[order]
+        counts = np.bincount(vc, minlength=g)
+        start = 0
+        for end in np.cumsum(counts).tolist():
+            sv[start:end].sort()
+            start = end
+        sc = np.repeat(np.arange(g), counts)
+        keep = np.concatenate([[True], (sc[1:] != sc[:-1])
+                               | (sv[1:] != sv[:-1])])
+        return sc[keep], sv[keep] if values else None
+    else:
+        uniq, rank = np.unique(vals, return_inverse=True)
+        ranks = len(uniq)
+    keys = np.unique(vc.astype(np.int64) * ranks + rank)
+    uc = keys // ranks
+    if not values:
+        return uc, None
+    ur = keys - uc * ranks
+    return uc, (ur + lo).astype(vals.dtype) if uniq is None else uniq[ur]
+
 
 class AggregateNode(PlanNode):
     def __init__(self, child: PlanNode, group_exprs: list[BoundExpr],
@@ -1175,16 +1237,15 @@ class AggregateNode(PlanNode):
         if not self.group_exprs:
             return self._cpu_scalar_agg(ctx)
         full = concat_batches(list(self.child.batches(ctx)))
-        from ..ops.agg import factorize_keys
-        # the request's `host_group` stage: key factorization
-        # (`_unique_columns`) and every per-group aggregate, DISTINCT
-        # ones (`_cpu_group_distinct`) included
+        from .morsel import _group_codes
+        # the request's `host_group` stage: key coding (the direct slot
+        # coding the morsel sink and the device tier share, or
+        # `factorize_keys` past its cap: the same groups in the same
+        # order either way) and every per-group aggregate, DISTINCT ones
+        # (`_cpu_group_distinct`) included
         with stage("host_group"):
             key_cols = [g.eval(full) for g in self.group_exprs]
-            codes, uniq_vals, uniq_valid = factorize_keys(
-                [c.data for c in key_cols],
-                [c.validity for c in key_cols])
-            num_groups = len(uniq_vals[0]) if uniq_vals else 0
+            codes, uniq_vals, uniq_valid, num_groups = _group_codes(key_cols)
             out_cols: list[Column] = []
             for k, (kc, uv) in enumerate(zip(key_cols, uniq_vals)):
                 validity = uniq_valid[k] if uniq_valid.size else None
@@ -1347,13 +1408,17 @@ class AggregateNode(PlanNode):
         valid = arg.valid_mask()
         vc = codes[valid]
         vals = arg.data[valid]
-        if len(vc):
+        if not len(vc):
+            uc, uv = vc, vals
+        elif _dedups_as_integers(arg):
+            metrics.HOST_DISTINCT_SORTED.add()
+            uc, uv = _distinct_pairs(vc, vals, g, spec.func != "count")
+        else:
+            metrics.HOST_DISTINCT_OBJECTS.add()
             order = np.lexsort((vals, vc))
             sc, sv = vc[order], vals[order]
             keep = np.concatenate([[True], (sc[1:] != sc[:-1]) | (sv[1:] != sv[:-1])])
             uc, uv = sc[keep], sv[keep]
-        else:
-            uc, uv = vc, vals
         if spec.func == "count":
             data = np.bincount(uc, minlength=g).astype(np.int64)
             return Column(dt.BIGINT, data)
@@ -1402,6 +1467,12 @@ class _ScalarAcc:
         self.distinct: Optional[set] = set() \
             if spec.distinct and spec.func in ("count", "sum", "avg") \
             else None
+        # an integer-like DISTINCT argument never becomes Python objects:
+        # its sorted distinct values so far, and the batches not yet
+        # merged into them (`_dedups_as_integers`)
+        self.uniq: Optional[np.ndarray] = None
+        self.pending: list[np.ndarray] = []
+        self.pending_rows = 0
         self.strings: list[str] = []
         self.bool_acc = None
 
@@ -1419,6 +1490,14 @@ class _ScalarAcc:
         if n_valid == 0:
             return
         if self.distinct is not None:
+            if _dedups_as_integers(col):
+                self.pending.append(col.data[valid])
+                self.pending_rows += n_valid
+                if self.pending_rows >= max(
+                        _DISTINCT_MERGE_ROWS,
+                        0 if self.uniq is None else len(self.uniq)):
+                    self._merge_pending()
+                return
             vals = col.to_pylist()
             self.distinct.update(v for v in vals if v is not None)
             return
@@ -1479,21 +1558,45 @@ class _ScalarAcc:
         else:
             raise errors.unsupported(f"aggregate {spec.func}")
 
+    def _merge_pending(self):
+        parts = self.pending if self.uniq is None \
+            else [self.uniq] + self.pending
+        self.uniq = np.unique(np.concatenate(parts))
+        self.pending, self.pending_rows = [], 0
+
+    def _distinct_count_sum(self) -> tuple[int, int]:
+        """(number, exact sum) of the distinct values seen."""
+        if self.pending:
+            self._merge_pending()
+        u = self.uniq
+        if u is None:
+            if self.distinct:
+                metrics.HOST_DISTINCT_OBJECTS.add()
+            return len(self.distinct), \
+                sum(self.distinct) if self.spec.func != "count" else 0
+        metrics.HOST_DISTINCT_SORTED.add()
+        if self.spec.func == "count":
+            return len(u), 0
+        # `u` is sorted: its ends bound every value, so an int64 sum
+        # that cannot wrap is taken as is, and one that could is summed
+        # in Python's integers
+        if max(abs(int(u[0])), abs(int(u[-1]))) * len(u) < 1 << 63:
+            return len(u), int(u.sum(dtype=np.int64))
+        return len(u), sum(u.tolist())
+
     def result(self) -> Column:
         spec = self.spec
         t = spec.type
         if spec.func == "count_star":
             return Column.from_pylist([self.count], t)
         if self.distinct is not None:
+            n, s = self._distinct_count_sum()
             if spec.func == "count":
-                return Column.from_pylist([len(self.distinct)], t)
+                return Column.from_pylist([n], t)
             if spec.func == "sum":
-                s = sum(self.distinct) if self.distinct else None
-                return Column.from_pylist([s], t)
+                return Column.from_pylist([s if n else None], t)
             if spec.func == "avg":
-                a = (sum(self.distinct) / len(self.distinct)
-                     if self.distinct else None)
-                return Column.from_pylist([a], t)
+                return Column.from_pylist([s / n if n else None], t)
             raise errors.unsupported(f"DISTINCT {spec.func}")
         if spec.func == "count":
             return Column.from_pylist([self.count], t)

@@ -602,6 +602,19 @@ HOST_CONCAT_DICT_MERGED = REGISTRY.gauge(
     "HostConcatDictMerged",
     "distinct dictionary objects cast, merged and remapped because the "
     "pieces of a string column brought more than one")
+#: which way COUNT / SUM / AVG(DISTINCT x) deduplicated on the host
+#: (exec/plan.py: `_ScalarAcc`, `_cpu_group_distinct`), one per aggregate
+#: that saw a non-NULL value
+HOST_DISTINCT_SORTED = REGISTRY.gauge(
+    "HostDistinctSorted",
+    "host DISTINCT aggregates answered from sorted typed arrays: the "
+    "argument is a fixed-width integer array (integers, bool, date, "
+    "timestamp), deduplicated by one sort and a comparison of neighbours")
+HOST_DISTINCT_OBJECTS = REGISTRY.gauge(
+    "HostDistinctObjects",
+    "host DISTINCT aggregates that went through Python objects (scalar: "
+    "a set over to_pylist) or the generic two-key lexsort (grouped): "
+    "float and string arguments")
 POOL_QUEUE_WAIT_HIST = REGISTRY.histogram(
     "PoolQueueWait",
     "per-task worker-pool queue wait (submit -> pickup)")
