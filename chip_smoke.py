@@ -11,11 +11,10 @@ Drives the system the way a user does and proves where the work ran:
    stops — non-zero, nothing on stdout — unless that backend is a TPU.
 2. It writes the data files from `--seed`, loads them over raw pgwire
    (`COPY … (FORMAT parquet)`, `CREATE INDEX … USING inverted|ivf`):
-   a ClickBench-`hits`-shaped table (10M rows, bench_hits' column set
-   and distributions; its first 1M rows again as `hits_1m`, the fused
-   join tier's own flagship size), a 1M-document corpus (bench_bm25_1m's
-   vocabulary and length distribution), 100k × 256-d vectors (the
-   vector_search shape). `--scale` cuts ROWS only and every cut is
+   a ClickBench-`hits`-shaped table (10M rows; its first 1M rows again
+   as `hits_1m`, the fused join tier's own flagship size), a 1M-document
+   corpus (30k-word vocabulary, zipf terms), 100k × 256-d vectors on a
+   clustered grid. `--scale` cuts ROWS only and every cut is
    printed under `reduced`.
 3. It runs a few queries of each class over pgwire, HTTP `/_sql` and the
    ES API and compares every answer with a plain reference:
@@ -83,10 +82,10 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 
 # -- deployment sizes (the repo's flagship shapes; --scale cuts rows only) ----
 
-HITS_ROWS = 10_000_000      # bench_hits
-DOCS = 1_000_000            # bench_bm25_1m
+HITS_ROWS = 10_000_000
+DOCS = 1_000_000
 VOCAB = 30_000
-VECTORS = 100_000           # bench_vector_search
+VECTORS = 100_000
 DIM = 256
 LISTS = 64
 BULK_DOCS = 1_000
@@ -114,9 +113,6 @@ SERVER_ENV = {"SERENE_VECTOR_PAGES": "8192",
 #: paths this smoke EXPECTS to be answered off the device, with the
 #: reason — anything else that lands on the host fails the run
 EXPECTED_HOST = {
-    "posting_pool": "unreachable on a non-CPU backend: searcher.py gates "
-                    "the ragged tier (the pool's only caller) on "
-                    "jax.default_backend() == 'cpu' (ROADMAP S4)",
     "bm25:maxscore": "a disjunction whose MaxScore-essential postings "
                      "number <= 4096 is scored on the host by _cpu_score "
                      "by design (searcher.MAXSCORE_CAND_CAP); an "
@@ -132,7 +128,7 @@ EXPECTED_HOST = {
 }
 
 #: rows of `hits` that also load as `hits_1m`: the fused join tier's own
-#: flagship size (bench_device_pipeline) and under its 2^23-pair wall
+#: flagship size, under its 2^23-pair wall
 FUSED_ROWS = 1_000_000
 
 BM25_K1, BM25_B = 1.2, 0.75
@@ -359,7 +355,7 @@ def http_json(port: int, method: str, path: str, body=None,
 _GAUGES = ("DeviceOffloads", "DeviceTransfersUp", "SearchBatchDispatches",
            "SearchBatchQueries", "SearchBatchCoalesced",
            "FragmentCacheHits", "ResultCacheHits",
-           "PostingPoolDeviceQueries", "VectorSearchDispatches",
+           "VectorSearchDispatches",
            "VectorSearchQueries", "NativeIndexBuilds",
            "NativeIndexFallbacks", "SegmentBuilds",
            "SearchQueriesScoredDevice", "SearchQueriesScoredHost",
@@ -386,7 +382,6 @@ def snapshot(srv: Server, pg: Pg) -> dict:
                      else int(r[8])} for r in rows],
         "programs": {p["family"]: p for p in dev["programs"]},
         "fused_declines": dev["fused_declines"],
-        "posting_pool": dev["posting_pool"],
         "vector_pool": dev["vector_pool"],
         "gauges": gauges,
     }
@@ -447,7 +442,7 @@ def phase_evidence(before: dict, after: dict) -> dict:
 
 def gen_hits(seed: int, n: int, path: str, regions_path: str,
              prefix_path: str = None) -> dict:
-    """bench_hits' generator: full-range int64 UserID (zipf-skewed user
+    """A `hits`-shaped table: full-range int64 UserID (zipf-skewed user
     activity), skewed RegionID, mostly-zero AdvEngineID, mostly-empty
     SearchPhrase, SearchEngineID, ResolutionWidth — plus the 9000-row
     `regions` dimension the join queries use."""
@@ -486,7 +481,7 @@ def gen_hits(seed: int, n: int, path: str, regions_path: str,
 
 
 def gen_docs(seed: int, n_docs: int, path: str) -> dict:
-    """bench_bm25_1m's corpus: 30k-word vocabulary, zipf(1.25) terms,
+    """The document corpus: 30k-word vocabulary, zipf(1.25) terms,
     8–39 tokens per document; an ES-shaped table (_id, _source, body)."""
     import numpy as np
     import pyarrow as pa
@@ -511,7 +506,7 @@ def gen_docs(seed: int, n_docs: int, path: str) -> dict:
 
 
 def gen_vectors(seed: int, n: int, path: str) -> dict:
-    """bench_vector_search's clustered grid corpus: centers k/16
+    """A clustered grid corpus: centers k/16
     (|k|<48) + noise k/16 (|k|<16) — every coordinate a multiple of
     2^-4 with |v| < 4, so squared distances are exact in f32 whatever
     the summation order. Vectors travel as JSON-array text (the
@@ -1258,7 +1253,6 @@ def main(argv=None) -> int:
         emit({"phase": "run1:shutdown", "ok": True,
               "sigterm_to_exit_seconds": round(stop_s, 2),
               "hbm": final["devices"], "gauges_total": final["gauges"],
-              "posting_pool": final["posting_pool"],
               "vector_pool": final["vector_pool"],
               "bm25_max_rel_err": run1.worst_bm25,
               "compile_cache_entries": len(cache_after_1),

@@ -177,23 +177,7 @@ timeout -k 10 600 env JAX_PLATFORMS=cpu SERENE_DEVICE_TELEMETRY=on \
     -m 'not slow' -p no:cacheprovider -p no:xdist -p no:randomly
 rc13=$?
 
-# Pass 14 is the posting-pool stress leg: the device-resident paged
-# posting tier is forced ON with the page budget pinned at a tiny 16
-# pages (the conftest env hooks arm both globals) over the search,
-# search-batch, posting-pool and device-observability suites — the
-# starved budget forces partial residency and mid-stream LRU eviction
-# on practically every ragged search, proving the pool changes WHERE
-# postings are scored (HBM page tables vs host flatten), never a
-# result bit, while its gauges/relations record suite-wide.
-echo "== posting pool stress pass (pool on, 16-page budget) =="
-timeout -k 10 600 env JAX_PLATFORMS=cpu SERENE_POSTING_POOL=on \
-    SERENE_POSTING_PAGES=16 \
-    python -m pytest tests/test_search.py tests/test_search_batch.py \
-    tests/test_posting_pool.py tests/test_device_obs.py -q \
-    -m 'not slow' -p no:cacheprovider -p no:xdist -p no:randomly
-rc14=$?
-
-# Pass 16 is the fused-admission parity leg, two runs over the new
+# Pass 14 is the fused-admission parity leg, two runs over the new
 # admission/chaining suites: (a) the whole fused tier forced OFF
 # globally — every widened shape (string/FILTER/DISTINCT aggregates,
 # outer joins, residual predicates, chained agg→top-N) answers from
@@ -207,16 +191,16 @@ timeout -k 10 600 env JAX_PLATFORMS=cpu SERENE_DEVICE_FUSED=off \
     python -m pytest tests/test_fused_admission.py \
     tests/test_device_pipeline.py -q \
     -m 'not slow' -p no:cacheprovider -p no:xdist -p no:randomly
-rc16=$?
-if [ "$rc16" -eq 0 ]; then
+rc14=$?
+if [ "$rc14" -eq 0 ]; then
     timeout -k 10 600 env JAX_PLATFORMS=cpu SERENE_DEVICE_FUSED_EXT=off \
         python -m pytest tests/test_fused_admission.py \
         tests/test_device_pipeline.py -q \
         -m 'not slow' -p no:cacheprovider -p no:xdist -p no:randomly
-    rc16=$?
+    rc14=$?
 fi
 
-# Pass 17 is the streaming-ingest parity leg: parallel analysis is
+# Pass 15 is the streaming-ingest parity leg: parallel analysis is
 # forced ON with the segment-merge ladder pinned at a tiny cap of 3
 # (the conftest env hooks arm serene_parallel_ingest and
 # serene_max_segments) over the storage, segment, search, ES API and
@@ -232,9 +216,9 @@ timeout -k 10 600 env JAX_PLATFORMS=cpu SERENE_PARALLEL_INGEST=on \
     tests/test_search.py tests/test_es_api.py \
     tests/test_ingest_stream.py -q \
     -m 'not slow' -p no:cacheprovider -p no:xdist -p no:randomly
-rc17=$?
+rc15=$?
 
-# Pass 18 is the vector-retrieval leg, two runs over the vector/search
+# Pass 16 is the vector-retrieval leg, two runs over the vector/search
 # serving suites: (a) the paged vector pool forced ON with the page
 # budget starved at 16 pages — practically every knn/MaxSim dispatch
 # then walks partial residency, cold-path fallback and LRU eviction,
@@ -250,17 +234,17 @@ timeout -k 10 600 env JAX_PLATFORMS=cpu SERENE_VECTOR_POOL=on \
     python -m pytest tests/test_vector_store.py tests/test_vector.py \
     tests/test_search.py tests/test_es_api.py -q \
     -m 'not slow' -p no:cacheprovider -p no:xdist -p no:randomly
-rc18=$?
-if [ "$rc18" -eq 0 ]; then
+rc16=$?
+if [ "$rc16" -eq 0 ]; then
     timeout -k 10 600 env JAX_PLATFORMS=cpu SERENE_NPROBE=4096 \
         python -m pytest tests/test_vector_store.py tests/test_vector.py \
         tests/test_es_api.py -q \
         -m 'not slow' -p no:cacheprovider -p no:xdist -p no:randomly
-    rc18=$?
+    rc16=$?
 fi
 
 echo "== front-door serving pass (socket admission forced at 8 connections) =="
-# PR 20's asyncio front door: the pgwire/HTTP/ES suites plus the new
+# Pass 17, PR 20's asyncio front door: the pgwire/HTTP/ES suites plus the new
 # transport suite all run with serene_max_connections=8 FORCED, so every
 # keep-alive leak or unreleased gate slot in any suite turns into a hard
 # 429/53300 failure within eight connections instead of surviving unseen
@@ -268,15 +252,15 @@ timeout -k 10 600 env JAX_PLATFORMS=cpu SERENE_MAX_CONNECTIONS=8 \
     python -m pytest tests/test_frontdoor.py tests/test_pgwire.py \
     tests/test_es_api.py tests/test_admission.py -q \
     -m 'not slow' -p no:cacheprovider -p no:xdist -p no:randomly
-rc19=$?
-if [ "$rc19" -eq 0 ]; then
+rc17=$?
+if [ "$rc17" -eq 0 ]; then
     # parity leg: the same serving suites with the front door OFF (the
     # legacy thread-per-connection oracle kept for one release) — the
     # route tables are shared, so divergence here is a transport bug
     timeout -k 10 600 env JAX_PLATFORMS=cpu SERENE_FRONTDOOR=off \
         python -m pytest tests/test_pgwire.py tests/test_es_api.py -q \
         -m 'not slow' -p no:cacheprovider -p no:xdist -p no:randomly
-    rc19=$?
+    rc17=$?
 fi
 
 # Structural grep lint: every jit compilation in the engine must route
@@ -284,22 +268,16 @@ fi
 # cache stays bounded and observable — a bare jax.jit( call site
 # anywhere outside obs/device.py (or the ops/ kernel modules, which
 # pre-date the ledger and are wrapped at their call sites) regresses
-# the invariant. The posting pool's gather-accumulate programs are the
-# newest client; assert they compile through the ledger.
+# the invariant.
 echo "== compile-ledger grep lint =="
-rc15=0
+rc_lint=0
 if grep -rn "jax\.jit(" serenedb_tpu/ \
         --include='*.py' \
         | grep -v "^serenedb_tpu/obs/device.py:" \
         | grep -v "^serenedb_tpu/ops/" \
         | grep -v "#.*jax\.jit("; then
     echo "FAIL: bare jax.jit( outside obs/device.py and ops/ kernels"
-    rc15=1
-fi
-if ! grep -q 'obs_device\.compiled(\s*$\|obs_device\.compiled(' \
-        serenedb_tpu/search/posting_pool.py; then
-    echo "FAIL: posting_pool.py does not compile through obs.device.compiled"
-    rc15=1
+    rc_lint=1
 fi
 # PR 17's widened fused tier: the chained agg→top-N stage-2 builder is
 # the newest program family — it must compile through the ledger,
@@ -308,7 +286,7 @@ if ! grep -q '"fused_chain"' serenedb_tpu/exec/device_pipeline.py || \
         ! grep -q 'obs_device\.compiled(' \
             serenedb_tpu/exec/device_pipeline.py; then
     echo "FAIL: chained fused top-N does not compile through obs.device.compiled"
-    rc15=1
+    rc_lint=1
 fi
 # PR 19's vector subsystem: unlike the older ops/ kernels, ops/vector.py
 # post-dates the ledger — it gets NO bare-jit exemption, and both it and
@@ -317,15 +295,15 @@ fi
 if grep -n "jax\.jit(" serenedb_tpu/ops/vector.py \
         | grep -v "#.*jax\.jit("; then
     echo "FAIL: bare jax.jit( in ops/vector.py — vector kernels must use the ledger"
-    rc15=1
+    rc_lint=1
 fi
 if ! grep -q 'obs_device\.compiled(' serenedb_tpu/ops/vector.py; then
     echo "FAIL: ops/vector.py does not compile through obs.device.compiled"
-    rc15=1
+    rc_lint=1
 fi
 if ! grep -q 'obs_device\.compiled(' serenedb_tpu/search/vector_store.py; then
     echo "FAIL: vector_store.py does not compile through obs.device.compiled"
-    rc15=1
+    rc_lint=1
 fi
 
 [ "$rc" -ne 0 ] && exit "$rc"
@@ -342,8 +320,7 @@ fi
 [ "$rc12" -ne 0 ] && exit "$rc12"
 [ "$rc13" -ne 0 ] && exit "$rc13"
 [ "$rc14" -ne 0 ] && exit "$rc14"
+[ "$rc15" -ne 0 ] && exit "$rc15"
 [ "$rc16" -ne 0 ] && exit "$rc16"
 [ "$rc17" -ne 0 ] && exit "$rc17"
-[ "$rc18" -ne 0 ] && exit "$rc18"
-[ "$rc19" -ne 0 ] && exit "$rc19"
-exit "$rc15"
+exit "$rc_lint"

@@ -619,9 +619,6 @@ class Database(TableResolver):
         if name == "sdb_device_cache":
             from .pgcatalog import device_cache_table
             return device_cache_table()
-        if name == "sdb_posting_pool":
-            from .pgcatalog import posting_pool_table
-            return posting_pool_table()
         raise errors.SqlError(errors.UNDEFINED_FUNCTION,
                               f"table function {name} does not exist")
 

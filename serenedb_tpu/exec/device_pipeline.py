@@ -166,7 +166,7 @@ class DeviceColumnCache:
     def _cap_bytes(self) -> int:
         """Byte cap of THIS side of the device budget. With the
         pressure trade on, the cap is the serene_device_cache_mb
-        envelope minus the posting pool's LIVE page bytes, floored at a
+        envelope minus the vector pool's LIVE page bytes, floored at a
         quarter of the envelope — the pool's residency squeezes the
         column cache instead of a static carve-out, and vice versa via
         shed_colder. Consults the pool's lock, so call it OUTSIDE
@@ -179,10 +179,8 @@ class DeviceColumnCache:
         env = mb << 20
         if self._trade_on():
             try:
-                from ..search.posting_pool import POOL
                 from ..search.vector_store import VPOOL
-                return max(env // 4,
-                           env - POOL.live_bytes() - VPOOL.live_bytes())
+                return max(env // 4, env - VPOOL.live_bytes())
             except Exception:  # noqa: BLE001 — sizing only, never fatal
                 pass
         return env
@@ -231,23 +229,14 @@ class DeviceColumnCache:
                     break
         if over > 0 and self._trade_on() and tail_idle_s is not None:
             # pressure trade: before shedding our own tail, offer the
-            # eviction to the COLDEST pool tail (posting pages or vector
-            # pages) if it is idler than ours — freed pages raise this
-            # cache's cap directly
+            # eviction to the vector pool's tail if it is idler than
+            # ours — freed pages raise this cache's cap directly
             try:
-                from ..search.posting_pool import POOL
                 from ..search.vector_store import VPOOL
-                pools = sorted(
-                    ((idle, p) for p in (POOL, VPOOL)
-                     for idle in (p.tail_idle_ns(),) if idle is not None),
-                    reverse=True)
-                shed = False
-                for pool_idle, p in pools:
-                    if pool_idle > tail_idle_s * 1e9 and \
-                            p.shed_colder(int(tail_idle_s * 1e9), over):
-                        shed = True
-                        break
-                if shed:
+                pool_idle = VPOOL.tail_idle_ns()
+                if pool_idle is not None and \
+                        pool_idle > tail_idle_s * 1e9 and \
+                        VPOOL.shed_colder(int(tail_idle_s * 1e9), over):
                     cap = self._cap_bytes()
             except Exception:  # noqa: BLE001 — sizing only, never fatal
                 pass

@@ -103,11 +103,6 @@ class SearchScanNode(PlanNode):
         else:
             full = self.provider.full_batch(self.columns)
         mesh_n = int(ctx.settings.get("serene_mesh") or 0)
-        # stamp the scan's publication identity onto the searcher's
-        # segments so posting-pool pages written for them report which
-        # table/version/epoch they serve (sdb_posting_pool rows)
-        from ..search import posting_pool
-        posting_pool.note_publication(searcher, self.provider, pin)
         if self.topk is not None:
             # all serving paths (SQL @@@/bm25 scans, ES _search/_msearch)
             # funnel through this scan — the batcher coalesces concurrent

@@ -34,8 +34,7 @@ device tier's nervous system, three ledgers behind one switch:
 
 - **HBM attribution**: DEVICE_CACHE occupancy split per device (entry
   bytes divided across the devices holding them) — the live-bytes
-  estimate `sdb_device()` reports, and the signal the ROADMAP's paged
-  postings pool will be tuned against.
+  estimate `sdb_device()` reports.
 
 Surfaces: `sdb_device()` / `sdb_programs()` / `sdb_device_cache()`
 relations (pgcatalog), `GET /device`, the `/_stats` `device` section,
@@ -54,6 +53,7 @@ program, never a different one).
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from collections import OrderedDict, deque
@@ -352,6 +352,22 @@ def _named(family: str, body: Callable) -> Callable:
     return program
 
 
+_announced = threading.local()
+
+
+@contextlib.contextmanager
+def announced_builds():
+    """The programs this thread looks up inside are a closed set built
+    ahead of the queries (an index build's `prebuild`), not shapes a
+    churning cache key keeps producing: their compiles are counted like
+    any other but stay out of the recompile-storm window."""
+    _announced.on = True
+    try:
+        yield
+    finally:
+        _announced.on = False
+
+
 def _new_family() -> dict:
     return {"entries": 0, "compiles": 0, "hits": 0, "misses": 0,
             "evictions": 0, "compile_ns": 0, "timed": 0,
@@ -421,7 +437,8 @@ class ProgramLedger:
                 metrics.DEVICE_PROGRAMS_COMPILED.add()
                 if profile is not None and node_key is not None:
                     profile.stats(node_key).device_prog_misses += 1
-                self._note_storm(family, fam)
+                if not getattr(_announced, "on", False):
+                    self._note_storm(family, fam)
             cap = _cap()
             # the cap is STRUCTURAL (it bounds HBM/host memory) and
             # applies with telemetry off too — but dark means dark:
@@ -576,15 +593,12 @@ def device_rows() -> list[dict]:
     has seen."""
     from ..exec.device_pipeline import DEVICE_CACHE
     from ..parallel import mesh as mesh_mod
-    from ..search.posting_pool import POOL
     from ..search.vector_store import VPOOL
     cache_bytes = DEVICE_CACHE.device_bytes()
-    for pool in (POOL, VPOOL):
-        # the posting pool's and vector pool's paged regions are
-        # HBM-live alongside the column cache — one estimate covers
-        # every tenant
-        for i, n in pool.device_bytes().items():
-            cache_bytes[i] = cache_bytes.get(i, 0) + n
+    # the vector pool's paged region is HBM-live alongside the column
+    # cache — one estimate covers every tenant
+    for i, n in VPOOL.device_bytes().items():
+        cache_bytes[i] = cache_bytes.get(i, 0) + n
     snap = LEDGER.snapshot()
     devs = {}
     if mesh_mod.device_count_if_initialized():
@@ -631,13 +645,11 @@ def stats_section() -> dict:
     """The `/_stats` / `GET /device` JSON payload: per-device ledger
     rows, the compile ledger, and the program/column cache summaries."""
     from ..exec.device_pipeline import DEVICE_CACHE
-    from ..search.posting_pool import POOL
     from ..search.vector_store import VPOOL
     return {"devices": device_rows(),
             "programs": PROGRAMS.snapshot(),
             "program_cache": {"entries": PROGRAMS.entries(),
                               "cap": _cap()},
             "column_cache": DEVICE_CACHE.stats(),
-            "posting_pool": POOL.stats(),
             "vector_pool": VPOOL.stats(),
             "fused_declines": fused_declines()}

@@ -96,8 +96,7 @@ class MemoryAccountant:
     """Per-query live/peak byte accounting + progress counters.
 
     Charge/release are per-BATCH or per-morsel events (never per row),
-    one thread-local dict access plus integer adds each — the same
-    <3% budget as the profiler (mem_overhead bench shape). A release
+    one thread-local dict access plus integer adds each. A release
     may land on a different thread than its charge (a coordinating
     thread retiring worker-produced partials): that thread's live goes
     negative, the SUMMED live stays exact, and per-thread peaks remain
@@ -211,8 +210,8 @@ class MemoryAccountant:
         return live, peak
 
     def event_count(self) -> int:
-        """Charge/release events recorded — the direct-decomposition
-        input for the mem_overhead bench shape."""
+        """Charge/release events recorded (the MemAccountEvents
+        gauge)."""
         with self._register_lock:
             buckets = list(self._buckets)
         return sum(b.events for b in buckets)

@@ -72,8 +72,7 @@ class QueryProfile:
     """Per-query collector keyed by id(plan node).
 
     Hot-path cost is one thread-local dict lookup plus integer adds per
-    BATCH (never per row); batches are morsel-sized, so the budget is
-    <3% on the profile_overhead bench shape.
+    BATCH (never per row); batches are morsel-sized.
     """
 
     def __init__(self):

@@ -194,8 +194,8 @@ def build_block_store(offsets: np.ndarray, post_docs: np.ndarray,
 
     # Pack: forward-fill pads with the last real doc so gaps stay small,
     # then delta-encode along the lane axis (in place — the build holds at
-    # most two full-size temporaries at a time; tiles reach GBs at the 8M
-    # bench shape).
+    # most two full-size temporaries at a time; tiles reach GBs at 8M
+    # documents).
     docs_ff = np.maximum.accumulate(bdocs, axis=1)
     base = docs_ff[:, 0].copy()
     docs_ff[:, 1:] = docs_ff[:, 1:] - docs_ff[:, :-1]
@@ -1199,86 +1199,6 @@ def dense_slots(queries: list[tuple[np.ndarray, int]], n_docs: int,
             idf = idf_for(scorer, n_docs, doc_freq[tid_arr])
         slots.append((tid_arr.astype(np.int32), idf))
     return slots, np.asarray([req for _, req in queries], dtype=np.int32)
-
-
-# -------------------------------------------------- ragged batched serving
-#
-# QPS regime on the HOST backend: a (B, ndocs_pad) score plane per query is
-# memory-bound work proportional to the corpus, while a 2-term top-10 query
-# only ever touches its own postings. The batched ragged path flattens every
-# query's (WAND-kept) postings into ONE (entries,) array — the ragged
-# (terms, query-offsets) layout of Ragged Paged Attention — computes the
-# per-posting saturation contributions in ONE tiny elementwise dispatch, and
-# leaves accumulation + exact top-k to numpy over the candidate sets.
-#
-# Bit-parity contract: `contrib_flat` states the per-posting score with THE
-# SAME expression tree as `_accumulate_scores.contrib_of`, so XLA applies
-# the same algebraic simplification/contraction and the f32 contribution
-# bits are identical to the plane kernel's (asserted by the search-batch
-# parity suite; a numpy restatement of the formula is 1 ULP off under
-# XLA's simplifier, which is why this stays a jitted kernel). Only bm25 and
-# tfidf decompose this way — LM scorers never take the ragged path.
-
-def contrib_expr(tfs: jax.Array, dls: jax.Array, w: jax.Array, k1,
-                 b, avgdl, scorer: str = "bm25") -> jax.Array:
-    """THE shared contribution expression tree — traced identically by
-    `contrib_flat` (the host ragged path) and the posting-pool device
-    program (search/posting_pool.py), so XLA applies the same algebraic
-    simplification in both and their f32 contribution bits agree with
-    each other and with the plane kernel's."""
-    avg = jnp.maximum(jnp.float32(avgdl), 1e-9)
-    tfsf = tfs.astype(jnp.float32)
-    if scorer == "tfidf":
-        return w * jnp.sqrt(tfsf)
-    dl = dls.astype(jnp.float32)
-    denom = tfsf + k1 * (1.0 - b + b * dl / avg)
-    return w * (k1 + 1.0) * tfsf / jnp.maximum(denom, 1e-9)
-
-
-def contrib_flat(tfs, dls, w, k1: float, b: float, avgdl: float,
-                 scorer: str = "bm25") -> jax.Array:
-    """Per-posting score contribution w·sat(tf, dl) over flat arrays
-    (one program per padded length and scorer, in the ledger's
-    `bm25_contrib` family). Padding entries (tf=0, w=0) contribute
-    exactly 0.0."""
-    return obs_device.compiled(
-        "bm25_contrib", (len(tfs), scorer),
-        lambda: functools.partial(contrib_expr, scorer=scorer))(
-            tfs, dls, w, k1, b, avgdl)
-
-
-def ragged_contribs(tfs: np.ndarray, dls: np.ndarray, w: np.ndarray,
-                    k1: float, b: float, avgdl: float,
-                    scorer: str) -> np.ndarray:
-    """contrib_flat over host arrays, padded to a power of two so the jit
-    cache stays small across ragged batch sizes (pads score 0.0 and are
-    sliced back off)."""
-    n = len(tfs)
-    n_pad = _pow2(n, 1024)
-
-    def pad(a, fill, dtype):
-        out = np.full(n_pad, fill, dtype=dtype)
-        out[:n] = a
-        return out
-
-    c = contrib_flat(pad(tfs, 0, np.int32), pad(dls, 0, np.int32),
-                     pad(w, 0.0, np.float32),
-                     scorer_param(scorer, k1), b, avgdl, scorer)
-    return np.asarray(c)[:n]
-
-
-def topk_tie_exact(scores: np.ndarray, docs: np.ndarray, k: int,
-                   ) -> tuple[np.ndarray, np.ndarray]:
-    """Exact (score desc, doc asc) top-k of a candidate set — the same
-    selection jax.lax.top_k makes over a score plane (ties → lowest doc
-    index first). Partition first so only the k-plus-ties head is sorted."""
-    if len(scores) > max(k, 1):
-        kth = np.partition(-scores, k - 1)[k - 1]
-        sel = np.flatnonzero(-scores <= kth)     # score >= kth, ties incl.
-        order = sel[np.argsort(-scores[sel], kind="stable")][:k]
-    else:
-        order = np.argsort(-scores, kind="stable")[:k]
-    return scores[order], docs[order]
 
 
 def pad_k(k: int) -> int:

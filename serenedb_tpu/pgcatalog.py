@@ -1315,8 +1315,6 @@ def system_table(db, parts: list[str]) -> Optional[TableProvider]:
         return programs_table()
     if name == "sdb_device_cache":
         return device_cache_table()
-    if name == "sdb_posting_pool":
-        return posting_pool_table()
     return None
 
 
@@ -1384,9 +1382,7 @@ def device_cache_table() -> TableProvider:
     """sdb_device_cache: one row per live DEVICE_CACHE entry — which
     publication (table/version/epoch) and column occupies HBM, the
     entry kind (col = column tiles, arr = code/rowmask/build-output
-    arrays), bytes, holding devices, hit count and idle time. The
-    per-publication occupancy view the paged-postings roadmap item
-    tunes against."""
+    arrays), bytes, holding devices, hit count and idle time."""
     from .obs.device import device_cache_rows
     rows = device_cache_rows()
     return _typed("sdb_device_cache", [
@@ -1405,33 +1401,6 @@ def device_cache_table() -> TableProvider:
         "tag": [r["tag"] for r in rows],
         "bytes": [r["bytes"] for r in rows],
         "devices": [r["devices"] for r in rows],
-        "hits": [r["hits"] for r in rows],
-        "idle_ms": [r["idle_ms"] for r in rows]})
-
-
-def posting_pool_table() -> TableProvider:
-    """sdb_posting_pool: one row per (publication, segment) group of
-    resident posting-pool terms — which table/version/epoch occupies
-    the paged HBM region, how many terms/pages/bytes it holds, hit
-    counts and idle time. The occupancy view operators size
-    `serene_posting_pages` from (search/posting_pool.py)."""
-    from .obs.device import provider_name
-    from .search.posting_pool import POOL
-    rows = POOL.snapshot()
-    return _typed("sdb_posting_pool", [
-        ("table_name", dt.VARCHAR), ("token", dt.BIGINT),
-        ("data_version", dt.BIGINT), ("mutation_epoch", dt.BIGINT),
-        ("segment", dt.BIGINT), ("terms", dt.BIGINT),
-        ("pages", dt.BIGINT), ("bytes", dt.BIGINT),
-        ("hits", dt.BIGINT), ("idle_ms", dt.DOUBLE)], {
-        "table_name": [provider_name(r["token"]) for r in rows],
-        "token": [r["token"] for r in rows],
-        "data_version": [r["data_version"] for r in rows],
-        "mutation_epoch": [r["mutation_epoch"] for r in rows],
-        "segment": [r["segment"] for r in rows],
-        "terms": [r["terms"] for r in rows],
-        "pages": [r["pages"] for r in rows],
-        "bytes": [r["bytes"] for r in rows],
         "hits": [r["hits"] for r in rows],
         "idle_ms": [r["idle_ms"] for r in rows]})
 

@@ -1,19 +1,14 @@
 """Search query batcher: coalesce concurrent top-k queries into one
-ragged scoring dispatch per (searcher, k, scorer, mesh) group.
+`topk_batch` call per (searcher, k, scorer, mesh) group.
 
 Production search traffic is thousands of concurrent SMALL queries over
 the SAME index data — each paying full scoring-dispatch overhead alone.
-This module is the serving-side fix (ROADMAP "batched ragged search
-serving"; the shape of Ragged Paged Attention's ragged-batch kernel and
-GPUSparse's parallel inverted indices): queries arriving within a short
-window fold into one `MultiSearcher.topk_batch` call, which scores them
-in a single vectorized pass per segment over the shared postings/norms
-(ragged per-query term lists — search/searcher._ragged_resolve on the
-host backend, the batched plane kernel on devices). With
-`serene_posting_pool` on, the coalesced dispatch is the one that never
-leaves the device: page-resident batches score as ONE jitted
-gather-and-accumulate program over the pool's HBM page tables
-(search/posting_pool.py), and a warm repeat uploads zero posting bytes.
+Queries arriving within a short window fold into one
+`MultiSearcher.topk_batch` call, which scores them together, per
+segment, on the ladder `SegmentSearcher.topk_batch` describes: the
+batch is fitted to a rung of the store's closed program set, so a
+coalesced batch of any size dispatches programs `prebuild` already
+built.
 
 Coalescing is group-commit shaped, so an idle system never waits:
 
@@ -130,7 +125,7 @@ class SearchBatcher:
                             g.active <= len(g.queue)):
                         # claim the dispatch: this entry plus the oldest
                         # queued others (up to the cap) score in one
-                        # ragged pass on THIS thread. Own entry ALWAYS
+                        # call on THIS thread. Own entry ALWAYS
                         # rides its own claim — leaving it queued while
                         # falling back serially would orphan it (scored
                         # twice by a later claimer, or pinning the group
@@ -202,8 +197,7 @@ class SearchBatcher:
         with stage_sink() as stages:
             try:
                 outs = g.searcher.topk_batch([x.node for x in batch], k,
-                                             scorer, mesh_n=mesh_n,
-                                             ragged=True)
+                                             scorer, mesh_n=mesh_n)
             except BaseException:
                 outs = None   # members retry serially; the bad re-raises
         t1 = time.perf_counter_ns()

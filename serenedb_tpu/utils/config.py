@@ -203,19 +203,18 @@ declare("serene_device_fused_ext", True, bool,
         "residual join predicates, LEFT/RIGHT/FULL outer joins, and "
         "the chained fused-aggregate→top-N device handoff. Off "
         "restores the PR 7 admission walls (those shapes decline to "
-        "the host path) — the before/after lever of the "
-        "fused_admission bench shape; results are bit-identical on or "
+        "the host path); results are bit-identical on or "
         "off because the host path is the oracle for every shape")
 declare("serene_device_cache_trade", True, bool,
         "pressure-based budget trade between the device column cache "
-        "(§19) and the posting pool (§27) inside the one "
+        "(§19) and the vector pool (§30) inside the one "
         "serene_device_cache_mb envelope: the column cache's byte cap "
         "is the envelope minus the pool's LIVE page bytes (floored at "
         "a quarter of the envelope), so pool residency squeezes the "
         "cache instead of a static carve-out; and when the cache must "
         "evict, it first sheds the POOL's tail if that tail is colder "
         "(idle longer), which raises its own cap back. Off restores "
-        "the static carve-out (serene_posting_pages bounds the pool; "
+        "the static carve-out (serene_vector_pages bounds the pool; "
         "the column cache ignores pool occupancy)",
         scope=Scope.GLOBAL)
 declare("serene_device_cache_mb", 256, int,
@@ -226,27 +225,6 @@ declare("serene_device_cache_mb", 256, int,
         "transfer entirely; least-recently-used entries evict past the "
         "cap and superseded generations are swept eagerly on store",
         scope=Scope.GLOBAL, validator=lambda v: max(1, int(v)))
-declare("serene_posting_pool", True, bool,
-        "device-resident paged posting pool (search/posting_pool.py): "
-        "the batched ragged search path uploads each (segment, term) "
-        "posting list ONCE into a paged HBM region and scores "
-        "page-resident coalesced batches as one jitted gather-and-"
-        "accumulate program over page tables — zero host→device "
-        "posting bytes on the warm path. Misses fall back per query to "
-        "the host ragged path and partial residency merges host tails "
-        "deterministically, so results are BIT-IDENTICAL on or off at "
-        "any worker/shard/cache setting (off = the parity oracle) and "
-        "the setting stays out of the result cache's settings digest",
-        scope=Scope.GLOBAL)
-declare("serene_posting_pages", 4096, int,
-        "page budget of the posting pool's device region (pages of "
-        "1024 postings; docs+tfs = 8 KiB/page, so the default 4096 is "
-        "32 MiB of HBM). The region never exceeds the "
-        "serene_device_cache_mb byte cap — the pool is carved out of "
-        "the device-cache budget, not added to it. Least-recently-used "
-        "terms evict past the budget; size from sdb_posting_pool() "
-        "occupancy/hit rows",
-        scope=Scope.GLOBAL, validator=lambda v: max(8, int(v)))
 declare("serene_vector_pool", True, bool,
         "device-resident paged vector pool (search/vector_store.py): "
         "IVF and MaxSim indexes upload their cluster-major vector "
@@ -290,8 +268,7 @@ declare("serene_device_telemetry", True, bool,
         "/metrics, plus device_compile trace spans and the EXPLAIN "
         "ANALYZE Device: compile=hit|miss key. Observation only: "
         "telemetry never changes which program runs — results are "
-        "bit-identical on or off at any worker/shard/combine setting "
-        "(<3% overhead budget, device_observe bench shape)",
+        "bit-identical on or off at any worker/shard/combine setting",
         scope=Scope.GLOBAL)
 declare("serene_program_cache_entries", 256, int,
         "entry cap of the process-wide compiled-program LRU "
@@ -355,8 +332,7 @@ declare("serene_profile", True, bool,
         "per-operator query profiling (obs/trace.py): every statement "
         "collects rows/time/morsel-prune spans per plan operator, feeds "
         "sdb_stat_statements, the slow-query log and pg_stat_activity "
-        "query ids; results are bit-identical on or off (<3% overhead "
-        "budget, profile_overhead bench shape)")
+        "query ids; results are bit-identical on or off")
 declare("serene_trace", True, bool,
         "query timeline tracing (obs/trace.py): every statement gets a "
         "trace id and timestamped span events — worker-pool queue waits, "
@@ -377,9 +353,8 @@ declare("serene_mem_account", True, bool,
         "columns in sdb_stat_statements, the QueryPeakBytes histogram, "
         "and registers live progress rows for sdb_query_progress() / "
         "GET /progress. Observation only: results are bit-identical "
-        "on or off at any worker/shard count (<3% overhead budget, "
-        "mem_overhead bench shape) — the prerequisite the "
-        "admission-control / serene_work_mem roadmap item builds on")
+        "on or off at any worker/shard count; serene_work_mem is "
+        "enforced from its accounting")
 declare("serene_flight_recorder_queries", 64, int,
         "size of the always-on flight recorder: the last N completed "
         "query timelines are kept in a bounded ring so the slow-query "
@@ -416,11 +391,11 @@ declare("serene_fragment_cache_mb", 32, int,
         "(per-segment filter doc sets and top-k collector outputs)",
         scope=Scope.GLOBAL, validator=lambda v: max(1, int(v)))
 declare("serene_search_batch", True, bool,
-        "batched ragged search serving (search/batcher.py): concurrent "
+        "batched search serving (search/batcher.py): concurrent "
         "_search/@@@ top-k queries against the same index coalesce into "
-        "ONE vectorized scoring dispatch over the shared postings, with "
-        "ragged per-query term lists and per-query WAND thresholds "
-        "preserved; per-query results are bit-identical to serial "
+        "ONE topk_batch call over the shared postings, with per-query "
+        "term lists and per-query WAND thresholds preserved; "
+        "per-query results are bit-identical to serial "
         "dispatch (scores, doc ids, tie order), so this setting is "
         "deliberately excluded from the result cache's settings digest; "
         "off dispatches every query alone (the parity oracle). A lone "

@@ -358,8 +358,7 @@ SEARCH_QUERIES_SCORED_HOST = REGISTRY.gauge(
     "SearchQueriesScoredHost",
     "top-k queries whose top-k came out of a host tier in every "
     "segment: _cpu_score over MaxScore candidates or an exact-match "
-    "rescore, the ragged host tier, or no scoring at all (no term of "
-    "the query is indexed)")
+    "rescore, or no scoring at all (no term of the query is indexed)")
 SEARCH_POSTINGS_DISPATCHED = REGISTRY.gauge(
     "SearchPostingsDispatched",
     "valid (non-padding) postings in the block rows and light-term "
@@ -369,36 +368,6 @@ SEARCH_PROGRAMS_PREBUILT = REGISTRY.gauge(
     "SearchProgramsPrebuilt",
     "scoring programs built by an index build or refresh before the "
     "index answered a search (SegmentSearcher.prebuild)")
-POSTING_POOL_HITS = REGISTRY.gauge(
-    "PostingPoolHits",
-    "posting-pool term lookups served by pages already resident in the "
-    "device region (search/posting_pool.py) — each hit is one term's "
-    "postings the batched ragged path did NOT re-upload")
-POSTING_POOL_MISSES = REGISTRY.gauge(
-    "PostingPoolMisses",
-    "posting-pool term lookups that allocated and wrote fresh pages "
-    "(first touch of a (segment, term) key, or re-entry after eviction)")
-POSTING_POOL_EVICTIONS = REGISTRY.gauge(
-    "PostingPoolEvictions",
-    "resident terms evicted LRU from the posting pool to make room "
-    "under the serene_posting_pages budget")
-POSTING_POOL_PAGES_USED = REGISTRY.gauge(
-    "PostingPoolPagesUsed",
-    "pages of the device posting region currently holding resident "
-    "terms (live; budget is serene_posting_pages)")
-POSTING_POOL_BYTES = REGISTRY.gauge(
-    "PostingPoolBytes",
-    "bytes of the device posting region currently occupied by resident "
-    "terms (live; PagesUsed x page size x docs+tfs)")
-POSTING_POOL_DEVICE_QUERIES = REGISTRY.gauge(
-    "PostingPoolDeviceQueries",
-    "batched ragged queries scored fully on device because every slice "
-    "was page-resident (final top-k left the device sorted)")
-POSTING_POOL_PARTIAL = REGISTRY.gauge(
-    "PostingPoolPartialQueries",
-    "batched ragged queries whose resident prefix scored on device "
-    "with the host merging the non-resident tail slices (deterministic "
-    "same-order f32 adds — bit-identical to the all-host path)")
 VECTOR_SEARCH_QUERIES = REGISTRY.gauge(
     "VectorSearchQueries",
     "knn / MaxSim queries scored by the vector subsystem "
@@ -520,8 +489,7 @@ TRACE_SPANS_DROPPED = REGISTRY.gauge(
 MEM_ACCOUNT_EVENTS = REGISTRY.gauge(
     "MemAccountEvents",
     "charge/release events recorded by per-query memory accounting "
-    "(serene_mem_account) — the direct-decomposition input for the "
-    "mem_overhead bench shape")
+    "(serene_mem_account)")
 PROCESS_RSS_BYTES = REGISTRY.gauge(
     "ProcessRssBytes",
     "resident set size of this process (/proc/self/statm), sampled at "

@@ -214,25 +214,6 @@ for _env, _setting in _GOVERNOR_ENV_HOOKS.items():
         _SDB_REG_GOV.set_global(_setting, os.environ[_env])
 
 
-# scripts/verify_tier1.sh posting-pool parity leg: force
-# serene_posting_pool to the given value ("on"/"off") and/or pin the
-# page budget at a tiny SERENE_POSTING_PAGES (e.g. "16") for a whole
-# run — the tiny-budget pass forces partial residency and mid-stream
-# LRU eviction on every ragged search, proving the device-resident
-# paged tier changes WHERE postings are scored, never a result bit.
-if os.environ.get("SERENE_POSTING_POOL"):
-    from serenedb_tpu.utils.config import REGISTRY as _SDB_REG_PP
-
-    _SDB_REG_PP.set_global("serene_posting_pool",
-                           os.environ["SERENE_POSTING_POOL"])
-
-if os.environ.get("SERENE_POSTING_PAGES"):
-    from serenedb_tpu.utils.config import REGISTRY as _SDB_REG_PPG
-
-    _SDB_REG_PPG.set_global("serene_posting_pages",
-                            os.environ["SERENE_POSTING_PAGES"])
-
-
 # scripts/verify_tier1.sh streaming-ingest parity leg: force the
 # write-path knobs to the given values for a whole run —
 # SERENE_PARALLEL_INGEST=on (with a small SERENE_INGEST_CHUNK_DOCS so

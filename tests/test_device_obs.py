@@ -346,6 +346,50 @@ def test_telemetry_off_keeps_ledgers_dark():
         assert sum(d["bytes_up"] for d in led1.values()) == up0
 
 
+def test_device_section_lists_the_owners_that_remain():
+    """`GET /device` and `/_stats` carry no posting-pool section, and
+    `sdb_device()`'s hbm_bytes_est is the sum of the HBM owners that
+    remain: the device column cache and the vector pool's region."""
+    from serenedb_tpu.exec.device_pipeline import DEVICE_CACHE
+    from serenedb_tpu.search.vector_store import VPOOL
+    from serenedb_tpu.server.http_server import HttpServer
+    c = _mk_conn()
+    c.execute("SELECT count(*), sum(v), sum(w) FROM l JOIN r "
+              "ON l.ik = r.ik WHERE v > 0")
+    srv = HttpServer(c.db)
+    srv.start()
+    try:
+        base = f"http://127.0.0.1:{srv.port}"
+        dev = json.load(urllib.request.urlopen(base + "/device"))
+        stats = json.load(urllib.request.urlopen(base + "/_stats"))
+    finally:
+        srv.stop()
+    assert "posting_pool" not in dev
+    assert "posting_pool" not in stats["device"]
+    assert {"column_cache", "vector_pool"} <= set(dev)
+    want = sum(DEVICE_CACHE.device_bytes().values()) + \
+        sum(VPOOL.device_bytes().values())
+    got = c.execute("SELECT sum(hbm_bytes_est) FROM sdb_device").rows()
+    assert got[0][0] == want > 0
+
+
+@pytest.mark.parametrize("stmt,sqlstate", [
+    ("SET serene_posting_pool = off", "42704"),
+    ("SET serene_posting_pages = 16", "42704"),
+    ("SELECT * FROM sdb_posting_pool", "42P01"),
+    ("SELECT * FROM sdb_posting_pool()", "42883")])
+def test_the_posting_pool_names_are_unknown(stmt, sqlstate):
+    """The pool went with its switch, its budget and its relation: each
+    name is refused like any other unknown setting or relation."""
+    from serenedb_tpu import errors
+    c = Database().connect()
+    with pytest.raises(errors.SqlError) as e:
+        c.execute(stmt)
+    assert e.value.sqlstate == sqlstate
+    assert not any(n.startswith("PostingPool")
+                   for n in metrics.REGISTRY.snapshot())
+
+
 def test_settings_declared_and_not_result_affecting():
     from serenedb_tpu.cache.result import RESULT_AFFECTING_SETTINGS
     assert SETTINGS.get_global("serene_device_telemetry") in (True, False)
@@ -381,42 +425,65 @@ def test_device_time_means_enqueue_to_readback_everywhere(q, family):
     `Device: time=`) and the timeline agree at every site: one
     observation per program call + readback, from the start of the
     enqueue to the end of the blocking readback — never the host work
-    around it."""
+    around it. The statement's own readings (its profile, its trace) are
+    compared with each other; the histogram is the process's, so it is
+    only held to have grown by at least as much."""
     from serenedb_tpu.obs.trace import FLIGHT
     c = _mk_conn()
     c.execute(q)                                   # compile, upload
-    fam0 = obs_device.PROGRAMS.family(family)
-    n0, sum0 = _dispatch_hist()
-    c.execute(q)
-    n1, sum1 = _dispatch_hist()
-    entry = FLIGHT.get(c._active_trace.trace_id)
-    assert obs_device.PROGRAMS.family(family)["hits"] > fam0["hits"]
-    stages = [s for s in entry["spans"] if s["cat"] == "stage"]
-    enq = [s for s in stages if s["name"] == "device_enqueue"]
-    wait = [s for s in stages if s["name"] == "device_wait"]
-    assert len(enq) == len(wait) == n1 - n0 >= 1
-    # the host decode of the outputs is a stage of its own at every site
-    assert any(s["name"] == "device_finalize" for s in stages)
-    assert not any(s["name"] == "device_dispatch" for s in entry["spans"])
-    # the histogram's window = the union [enqueue begin, wait end] of
-    # each pair, give or take the clock reads between the stamps
-    spanned = sum(w["end_ns"] - e["begin_ns"] for e, w in zip(enq, wait))
-    observed = sum1 - sum0
-    assert spanned <= observed <= spanned + 2_000_000 * len(enq)
-    # and it lies inside `device_prepare`, which holds everything else
-    prep = max((s for s in stages if s["name"] == "device_prepare"),
-               key=lambda s: s["end_ns"] - s["begin_ns"])
-    assert prep["begin_ns"] <= enq[0]["begin_ns"] and \
-        wait[-1]["end_ns"] <= prep["end_ns"]
-    assert observed < prep["end_ns"] - prep["begin_ns"]
-    # EXPLAIN ANALYZE reads the same quantity
+    slack = []
+    for _ in range(5):
+        fam0 = obs_device.PROGRAMS.family(family)
+        n0, sum0 = _dispatch_hist()
+        c.execute(q)
+        n1, sum1 = _dispatch_hist()
+        own = c._active_profile.totals().device_ns
+        entry = FLIGHT.get(c._active_trace.trace_id)
+        assert obs_device.PROGRAMS.family(family)["hits"] > fam0["hits"]
+        stages = [s for s in entry["spans"] if s["cat"] == "stage"]
+        enq = [s for s in stages if s["name"] == "device_enqueue"]
+        wait = [s for s in stages if s["name"] == "device_wait"]
+        assert len(enq) == len(wait) >= 1
+        assert n1 - n0 >= len(enq) and sum1 - sum0 >= own
+        # the host decode of the outputs is a stage of its own at every
+        # site
+        assert any(s["name"] == "device_finalize" for s in stages)
+        assert not any(s["name"] == "device_dispatch"
+                       for s in entry["spans"])
+        # the observed window = the union [enqueue begin, wait end] of
+        # each pair, plus the clock reads between the stamps
+        spanned = sum(w["end_ns"] - e["begin_ns"]
+                      for e, w in zip(enq, wait))
+        assert spanned <= own
+        # and it lies inside `device_prepare`, which holds everything
+        # else
+        prep = max((s for s in stages if s["name"] == "device_prepare"),
+                   key=lambda s: s["end_ns"] - s["begin_ns"])
+        assert prep["begin_ns"] <= enq[0]["begin_ns"] and \
+            wait[-1]["end_ns"] <= prep["end_ns"]
+        assert own < prep["end_ns"] - prep["begin_ns"]
+        slack.append((own - spanned) / len(enq))
+        if slack[-1] <= 2_000_000:
+            break
+    # between the stamps lie two clock reads a dispatch: a worker that
+    # is descheduled there reads more, so the quietest of the
+    # executions is judged — host work inside the window would be in
+    # every one of them
+    assert min(slack) <= 2_000_000, slack
+    # EXPLAIN ANALYZE reads the same quantity: what its own dispatches
+    # fed the histogram, around the stages of its own trace
     n2, sum2 = _dispatch_hist()
     text = "\n".join(r[0] for r in c.execute("EXPLAIN ANALYZE " + q).rows())
     n3, sum3 = _dispatch_hist()
     times = [float(ln.split("time=")[1].split(" ms")[0])
              for ln in text.splitlines() if "Device: time=" in ln]
     assert times, text
-    assert sum(times) == pytest.approx((sum3 - sum2) / 1e6, abs=0.01)
+    stages = [s for s in FLIGHT.get(c._active_trace.trace_id)["spans"]
+              if s["cat"] == "stage"]
+    spanned = sum(w["end_ns"] - e["begin_ns"] for e, w in zip(
+        [s for s in stages if s["name"] == "device_enqueue"],
+        [s for s in stages if s["name"] == "device_wait"]))
+    assert spanned / 1e6 - 0.01 <= sum(times) <= (sum3 - sum2) / 1e6 + 0.01
 
 
 def test_programs_are_named_after_their_family():

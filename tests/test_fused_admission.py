@@ -216,13 +216,13 @@ def test_explain_analyze_declined_reason():
 
 def test_cache_cap_trades_against_pool_residency():
     from serenedb_tpu.exec.device_pipeline import DEVICE_CACHE
-    from serenedb_tpu.search.posting_pool import POOL
+    from serenedb_tpu.search.vector_store import VPOOL
 
     env = int(SETTINGS.get_global("serene_device_cache_mb")) << 20
     old_trade = SETTINGS.get_global("serene_device_cache_trade")
     try:
         SETTINGS.set_global("serene_device_cache_trade", True)
-        live = POOL.live_bytes()
+        live = VPOOL.live_bytes()
         cap = DEVICE_CACHE.stats()["cap_bytes"]
         assert cap == max(env // 4, env - live)
         SETTINGS.set_global("serene_device_cache_trade", False)
@@ -235,25 +235,26 @@ def test_pool_sheds_colder_tail():
     """shed_colder frees LRU pages idle longer than the threshold and
     stops at the first warmer entry — the column cache's cross-eviction
     primitive."""
-    from serenedb_tpu.search.posting_pool import PAGE, POOL, _Entry
+    from serenedb_tpu.search.vector_store import PAGE_F32, VPOOL, _Entry
 
-    POOL.clear()
-    with POOL._lock:
-        POOL._region()
+    page = PAGE_F32 * 4
+    VPOOL.clear()
+    with VPOOL._lock:
+        VPOOL._region()
         # hand-plant two entries: a cold tail and a hot head
-        slots_a = POOL._alloc(2, set())
-        slots_b = POOL._alloc(1, set())
-        ea = _Entry(("t", 1), slots_a, 2 * PAGE, 1, None)
-        eb = _Entry(("t", 2), slots_b, PAGE, 2, None)
+        slots_a = VPOOL._alloc(2, set())
+        slots_b = VPOOL._alloc(1, set())
+        ea = _Entry(1, slots_a, 8, 128, 1, None)
+        eb = _Entry(2, slots_b, 4, 128, 2, None)
         import time as _t
         ea.last_ns = _t.perf_counter_ns() - int(60e9)   # idle 60 s
-        POOL._entries[ea.key] = ea
-        POOL._entries[eb.key] = eb
-    assert POOL.live_bytes() == 3 * PAGE * 8
+        VPOOL._entries[ea.key] = ea
+        VPOOL._entries[eb.key] = eb
+    assert VPOOL.live_bytes() == 3 * page
     # threshold 30 s: only the 60 s-idle tail qualifies
-    freed = POOL.shed_colder(int(30e9), 10 * PAGE * 8)
-    assert freed == 2 * PAGE * 8
-    assert POOL.live_bytes() == PAGE * 8
+    freed = VPOOL.shed_colder(int(30e9), 10 * page)
+    assert freed == 2 * page
+    assert VPOOL.live_bytes() == page
     # the warm survivor blocks further shedding
-    assert POOL.shed_colder(int(30e9), PAGE * 8) == 0
-    POOL.clear()
+    assert VPOOL.shed_colder(int(30e9), page) == 0
+    VPOOL.clear()

@@ -23,7 +23,6 @@ from benchmark.references import bm25_numpy                  # noqa: E402
 from benchmark.sources.match_questions import Source         # noqa: E402
 from serenedb_tpu.engine import Database                     # noqa: E402
 from serenedb_tpu.ops import bm25 as bm25_ops                # noqa: E402
-from serenedb_tpu.search import searcher as searcher_mod     # noqa: E402
 from serenedb_tpu.server.es_api import EsApi                 # noqa: E402
 
 BENCH = os.path.join(ROOT, "benchmark")
@@ -73,7 +72,6 @@ def test_search_equals_the_plain_reference(collection, regime, monkeypatch):
     cfg, ds = collection
     if regime == "plane":
         monkeypatch.setattr(bm25_ops, "DENSE_HBM_BUDGET", 0)
-        monkeypatch.setattr(searcher_mod, "_HOST_BACKEND", False)
     db = Database()
     c = db.connect()
     for stmt in ds["load"]:

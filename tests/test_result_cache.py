@@ -435,9 +435,14 @@ def test_sweep_reclaims_superseded_generations():
     c.execute("INSERT INTO sweep_gen_t VALUES (1)")
     c.execute(q)
     # two generations of the same statement live until the lazy sweep
-    assert RESULT_CACHE.sweep() >= 1
+    # (one, where the second store was the process-wide cache's
+    # SWEEP_EVERY-th and swept by itself)
+    label = "select count ( * ) from sweep_gen_t"
+    live = [e["query"] for e in RESULT_CACHE.snapshot()].count(label)
+    assert live in (1, 2)
+    assert RESULT_CACHE.sweep() >= live - 1
     labels = [e["query"] for e in RESULT_CACHE.snapshot()]
-    assert labels.count("select count ( * ) from sweep_gen_t") == 1
+    assert labels.count(label) == 1
 
 
 def test_prometheus_and_stats_export_cache_sections():

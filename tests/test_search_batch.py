@@ -1,4 +1,4 @@
-"""Batched ragged search serving (search/batcher.py): parity matrix,
+"""Batched search serving (search/batcher.py): parity matrix,
 coalescing mechanics, error isolation, metrics, and the cache contract.
 
 The core contract under test: per-query top-k results are BIT-IDENTICAL
@@ -137,11 +137,11 @@ def test_query_batched_with_itself(db):
     assert all(rows == ref for rows in got[q])
 
 
-def test_ragged_path_parity_packed_regime(db, monkeypatch):
-    """Force the packed-plane regime (no dense matmul) so the ragged host
-    resolver actually fires on this corpus, then assert searcher-level
-    bit parity: batched+ragged vs solo dispatch, including duplicate
-    nodes, ties, and k > hits."""
+def test_plane_path_parity_packed_regime(db, monkeypatch):
+    """Force the packed-plane regime (no dense matmul) so the plane
+    kernel scores this corpus, then assert searcher-level bit parity:
+    batched vs solo dispatch, including duplicate nodes, ties, and
+    k > hits."""
     from serenedb_tpu.ops import bm25 as bm25_ops
     monkeypatch.setattr(bm25_ops, "DENSE_HBM_BUDGET", 0)
     an = get_analyzer("text")
@@ -157,15 +157,15 @@ def test_ragged_path_parity_packed_regime(db, monkeypatch):
     nodes = [parse_query(q, an) for q in qs]
     for k in (3, 10, 5000):
         solo = [ms.topk_batch([n], k)[0] for n in nodes]
-        batched = ms.topk_batch(nodes, k, ragged=True)
+        batched = ms.topk_batch(nodes, k)
         for i in range(len(nodes)):
             assert np.array_equal(batched[i][0].view(np.uint32),
                                   solo[i][0].view(np.uint32)), (k, qs[i])
             assert np.array_equal(batched[i][1], solo[i][1]), (k, qs[i])
 
 
-def test_multi_segment_ragged_parity(monkeypatch):
-    """Global idf/avgdl spanning segments: ragged batched per-segment
+def test_multi_segment_plane_parity(monkeypatch):
+    """Global idf/avgdl spanning segments: batched per-segment plane
     results merge to the solo bits."""
     from serenedb_tpu.ops import bm25 as bm25_ops
     monkeypatch.setattr(bm25_ops, "DENSE_HBM_BUDGET", 0)
@@ -182,7 +182,7 @@ def test_multi_segment_ragged_parity(monkeypatch):
     nodes = [parse_query(q, an)
              for q in ("apple", "apple | dog", "cherry | term")]
     solo = [ms.topk_batch([n], 10)[0] for n in nodes]
-    batched = ms.topk_batch(nodes, 10, ragged=True)
+    batched = ms.topk_batch(nodes, 10)
     for i in range(len(nodes)):
         assert np.array_equal(batched[i][0].view(np.uint32),
                               solo[i][0].view(np.uint32))
@@ -199,7 +199,7 @@ class _StubSearcher:
         self.calls: list[list] = []
         self._lock = threading.Lock()
 
-    def topk_batch(self, nodes, k, scorer="bm25", mesh_n=0, ragged=False):
+    def topk_batch(self, nodes, k, scorer="bm25", mesh_n=0):
         with self._lock:
             self.calls.append(list(nodes))
         if self.delay:
@@ -305,6 +305,111 @@ def test_batcher_error_isolation_serial_retry():
     assert set(errs) == {"BAD"}
     # the poisoned coalesced dispatch really happened before the retries
     assert any(len(c) > 1 and "BAD" in c for c in stub.calls)
+
+
+def _plane_segment(n=700, seed=11):
+    an = get_analyzer("text")
+    rng = np.random.default_rng(seed)
+    docs = [" ".join(rng.choice(WORDS, rng.integers(3, 24)))
+            for _ in range(n)]
+    return SegmentSearcher(build_field_index(docs, an), an, len(docs)), an
+
+
+def _bits_equal(a, b):
+    return (np.array_equal(a[0].view(np.uint32), b[0].view(np.uint32))
+            and np.array_equal(a[1], b[1]))
+
+
+class _PoisonWrap:
+    """Real scoring, except batches containing the poison node raise —
+    the batcher must serial-retry every member on its own thread."""
+
+    def __init__(self, seg, poison):
+        self.seg, self.poison = seg, poison
+
+    def topk_batch(self, nodes, k, scorer="bm25", mesh_n=0):
+        if any(n is self.poison for n in nodes):
+            raise ValueError("poisoned query")
+        return self.seg.topk_batch(nodes, k, scorer, mesh_n=mesh_n)
+
+    def topk(self, node, k, scorer="bm25", mesh_n=0):
+        return self.topk_batch([node], k, scorer, mesh_n)[0]
+
+    def probe_topk(self, node, k, scorer="bm25", mesh_n=0):
+        return None
+
+
+def test_batcher_poison_isolated_under_device_tier(monkeypatch):
+    """A poisoned query coalesced with siblings the plane kernel scores
+    fails ONLY its own caller; every sibling's serial retry returns the
+    bits of a dispatch of its own."""
+    from serenedb_tpu.ops import bm25 as bm25_ops
+    monkeypatch.setattr(bm25_ops, "DENSE_HBM_BUDGET", 0)
+    seg, an = _plane_segment()
+    good = [parse_query(q, an)
+            for q in ("apple | dog", "banana | fox | dog")]
+    poison = parse_query("cherry | term", an)
+    ref = [seg.topk_batch([n], 5000)[0] for n in good]
+    wrap = _PoisonWrap(seg, poison)
+    b = SearchBatcher()
+    results, errors = {}, {}
+    bar = threading.Barrier(3)
+
+    def run(node, slot):
+        bar.wait(timeout=30)
+        try:
+            results[slot] = b.submit(wrap, node, 5000, "bm25", 0, 0.5, 128)
+        except ValueError as e:
+            errors[slot] = e
+    ts = [threading.Thread(target=run, args=(n, i))
+          for i, n in enumerate(good + [poison])]
+    [t.start() for t in ts]
+    [t.join(timeout=60) for t in ts]
+    assert set(errors) == {2}, "poison must fail exactly its own caller"
+    for i in range(2):
+        out, _stats = results[i]
+        assert _bits_equal(out, ref[i]), i
+
+
+@pytest.mark.parametrize("query,k,tier", [
+    ("apple | dog", 5000, "device"),             # past MaxScore: the plane
+    ("banana | fox | engine", 10, "host"),       # MaxScore candidates
+    ("zzznothing", 10, "host")])                 # no indexed term
+def test_the_batcher_scores_on_the_tier_topk_batch_alone_takes(
+        monkeypatch, query, k, tier):
+    """Where a question is scored is chosen from the store and the
+    question: through `batched_topk` it moves SearchQueriesScoredDevice /
+    ...Host exactly as through `topk_batch` alone, on any backend."""
+    from serenedb_tpu.ops import bm25 as bm25_ops
+    from serenedb_tpu.utils.config import REGISTRY as SETTINGS
+    monkeypatch.setattr(bm25_ops, "DENSE_HBM_BUDGET", 0)
+    prior = (SETTINGS.get_global("serene_search_batch"),
+             SETTINGS.get_global("serene_result_cache"))
+    SETTINGS.set_global("serene_search_batch", True)
+    SETTINGS.set_global("serene_result_cache", False)
+    try:
+        seg, an = _plane_segment()
+        ms = MultiSearcher(an)
+        ms.add_segment(seg, 0)
+        node = parse_query(query, an)
+
+        def moved(call):
+            d0 = metrics.SEARCH_QUERIES_SCORED_DEVICE.value
+            h0 = metrics.SEARCH_QUERIES_SCORED_HOST.value
+            out = call()
+            return (out, metrics.SEARCH_QUERIES_SCORED_DEVICE.value - d0,
+                    metrics.SEARCH_QUERIES_SCORED_HOST.value - h0)
+
+        alone, d_a, h_a = moved(lambda: ms.topk_batch([node], k)[0])
+        (batched, stats), d_b, h_b = moved(
+            lambda: batched_topk(ms, node, k))
+    finally:
+        SETTINGS.set_global("serene_search_batch", prior[0])
+        SETTINGS.set_global("serene_result_cache", prior[1])
+    assert stats is not None              # it went through the batcher
+    assert (d_b, h_b) == (d_a, h_a) == \
+        ((1, 0) if tier == "device" else (0, 1))
+    assert _bits_equal(batched, alone)
 
 
 def test_batched_topk_cache_hit_skips_batch(db):
@@ -415,9 +520,8 @@ def test_explain_analyze_batch_line(db):
 @pytest.mark.slow
 def test_qps_smoke():
     """Aggregate throughput smoke: 16 concurrent distinct 2-term top-10
-    searches, batched vs serial — batched must not lose, and with the
-    ragged path live it should win. Kept loose (this is a smoke test;
-    bench.py `search_batch` carries the real ≥5x assertion)."""
+    searches, batched vs serial — batched must not lose. Kept loose:
+    a speed is the benchmark's to read (`msmarco.search_c32`)."""
     db = _make_db(n=4000, seed=3)
 
     def drive(batch):
@@ -453,12 +557,9 @@ def test_batch_of_any_size_keeps_serial_bits_on_the_plane_kernel(
         monkeypatch, n_queries):
     """A coalesced batch is fitted to a rung of the store's program ladder
     (1 / 8 / 32 queries) and split past the largest: per query the bits
-    are those of a dispatch of its own, on the accelerator's tier choice
-    (no ragged host tier)."""
+    are those of a dispatch of its own."""
     from serenedb_tpu.ops import bm25 as bm25_ops
-    from serenedb_tpu.search import searcher as searcher_mod
     monkeypatch.setattr(bm25_ops, "DENSE_HBM_BUDGET", 0)
-    monkeypatch.setattr(searcher_mod, "_HOST_BACKEND", False)
     an = get_analyzer("text")
     rng = np.random.default_rng(5)
     docs = [" ".join(rng.choice(WORDS, rng.integers(3, 24)))
@@ -472,7 +573,7 @@ def test_batch_of_any_size_keeps_serial_bits_on_the_plane_kernel(
             "search | engine | database | index | query | term"]
     nodes = [parse_query(pool[i % len(pool)], an) for i in range(n_queries)]
     solo = [ms.topk_batch([n], 10)[0] for n in nodes]
-    batched = ms.topk_batch(nodes, 10, ragged=True)
+    batched = ms.topk_batch(nodes, 10)
     for i in range(n_queries):
         assert np.array_equal(batched[i][0].view(np.uint32),
                               solo[i][0].view(np.uint32)), i

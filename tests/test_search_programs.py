@@ -21,7 +21,6 @@ from serenedb_tpu.obs import device as obs_device
 from serenedb_tpu.obs import trace as trace_mod
 from serenedb_tpu.obs.trace import FLIGHT
 from serenedb_tpu.ops import bm25 as bm25_ops
-from serenedb_tpu.search import searcher as searcher_mod
 from serenedb_tpu.search.analysis import get_analyzer
 from serenedb_tpu.search.batcher import batched_topk
 from serenedb_tpu.search.query import QAnd, QOr, QTerm
@@ -71,11 +70,10 @@ def _questions(seed, n):
 @pytest.fixture(scope="module")
 def plane():
     """A single-segment searcher whose store is on the plane kernel
-    (dense budget 0 BEFORE the prebuild), read as on an accelerator (no
-    ragged host tier), with the fragment cache out of the way."""
+    (dense budget 0 BEFORE the prebuild), with the fragment cache out of
+    the way."""
     mp = pytest.MonkeyPatch()
     mp.setattr(bm25_ops, "DENSE_HBM_BUDGET", 0)
-    mp.setattr(searcher_mod, "_HOST_BACKEND", False)
     prior = SETTINGS.get_global("serene_result_cache")
     SETTINGS.set_global("serene_result_cache", False)
     an = get_analyzer("simple")
@@ -83,16 +81,40 @@ def plane():
     seg = SegmentSearcher(build_field_index(docs, an), an, len(docs))
     ms = MultiSearcher(an)
     ms.add_segment(seg, 0)
+    # what THIS prebuild compiled: the ledger is the process's, and a
+    # file that ran before in this worker may have built programs of
+    # the same keys (the top-k step's key holds no more of the store
+    # than its padded document count)
+    before = _ledger("compiles", "storms")
     built = ms.prebuild()
-    yield ms, seg, built
+    yield ms, seg, (built, _ledger("compiles", "storms", since=before))
     SETTINGS.set_global("serene_result_cache", prior)
     mp.undo()
+
+
+def _ledger(*fields, since=None):
+    """The compile ledger's `fields`, summed per family — or, per family
+    that moved, their growth since the reading `since`."""
+    now = {p["family"]: sum(p[f] for f in fields)
+           for p in obs_device.PROGRAMS.snapshot()}
+    if since is None:
+        return now
+    return {f: n - since.get(f, 0) for f, n in now.items()
+            if n != since.get(f, 0)}
+
+
+#: the families a search can dispatch
+SEARCH_FAMILIES = ("bm25_accumulate", "bm25_topk", "dense_topk",
+                   "dense_build")
 
 
 class _Builds:
     """Programs jax built while the block ran, counted from outside the
     program as the benchmark's child wrapper does, beside the ledger's
-    own compile count."""
+    own compile count of the search families. Both are the process's:
+    what a thread that was alive before the block built (the maintenance
+    loop of a database an earlier file of this worker left open) is not
+    this block's, so jax's events are kept by thread."""
 
     _events: list = []
     _hooked = False
@@ -101,18 +123,23 @@ class _Builds:
         import jax.monitoring as mon
         if not _Builds._hooked:
             mon.register_event_duration_secs_listener(
-                lambda ev, dur, **kw: _Builds._events.append(ev))
+                lambda ev, dur, **kw: _Builds._events.append(
+                    (ev, threading.current_thread())))
             _Builds._hooked = True
         self.n0 = len(_Builds._events)
-        self.c0 = sum(p["compiles"] for p in obs_device.PROGRAMS.snapshot())
+        self.others = set(threading.enumerate()) - \
+            {threading.current_thread()}
+        self.c0 = _ledger("compiles")
         return self
 
     def __exit__(self, *exc):
         self.jax = sum(
             ev == "/jax/core/compile/backend_compile_duration"
-            for ev in _Builds._events[self.n0:])
-        self.ledger = sum(p["compiles"] for p in
-                          obs_device.PROGRAMS.snapshot()) - self.c0
+            and th not in self.others
+            for ev, th in _Builds._events[self.n0:])
+        self.ledger = sum(
+            n for f, n in _ledger("compiles", since=self.c0).items()
+            if f in SEARCH_FAMILIES)
         return False
 
 
@@ -121,16 +148,28 @@ def _bits(res):
 
 
 def test_prebuild_lists_and_builds_the_closed_set(plane):
-    ms, seg, built = plane
+    ms, seg, (built, compiled) = plane
     store = seg._device_store()
     rungs = seg._rungs(store)
     keys = bm25_ops.plane_program_keys(rungs)
     assert [r.nq for r in rungs] == [1, 8, 32]
     assert len(keys) == len(set(keys)) == 18 <= 24
-    assert built == 18
-    fams = {p["family"]: p for p in obs_device.PROGRAMS.snapshot()}
-    assert fams["bm25_accumulate"]["compiles"] >= 12
-    assert fams["bm25_topk"]["compiles"] >= 6
+    # the fixture's prebuild built what the process did not hold yet,
+    # of these two families only: all 18 in a fresh process — and a
+    # closed set built ahead of the queries is no recompile storm, so
+    # the sum below holds compiles alone
+    assert set(compiled) <= {"bm25_accumulate", "bm25_topk"}
+    assert compiled.get("bm25_accumulate", 0) <= 12
+    assert compiled.get("bm25_topk", 0) <= 6
+    assert built == sum(compiled.values()) <= 18
+    # and every program of the set is built now, whoever built it
+    kk = min(bm25_ops.pad_k(1), store.ndocs_pad)
+    for rung, with_hits, first in keys:
+        prog = bm25_ops._topk_program(store.ndocs_pad, rung.nq, with_hits,
+                                      kk) if first is None else \
+            bm25_ops._accumulate_program(store, rung, with_hits, first,
+                                         "bm25")
+        assert prog.called, (rung, with_hits, first)
     with _Builds() as b:
         assert ms.prebuild() == 0          # nothing left to build
     assert (b.jax, b.ledger) == (0, 0)
@@ -154,7 +193,7 @@ def test_500_mixed_queries_build_nothing_and_match_serial_and_wand(plane):
         for n in sizes:
             k = int(rng.integers(1, 11))
             part = nodes[at:at + n]
-            got = ms.topk_batch(part, k, ragged=True)
+            got = ms.topk_batch(part, k)
             serial = [ms.topk_batch([q], k)[0] for q in part]
             assert _bits(got) == _bits(serial), (n, k)
             batched += [(q, k, r) for q, r in zip(part, got)]
@@ -193,6 +232,50 @@ def test_500_mixed_queries_build_nothing_and_match_serial_and_wand(plane):
             assert a == c or np.isclose(
                 sa, ws[wd.tolist().index(a)] if a in wd.tolist() else sa,
                 rtol=2e-5)
+
+
+def test_the_batcher_dispatches_only_the_closed_set(plane):
+    """200 questions through the batcher, coalescing as it happens to:
+    every program looked up is of the families `prebuild` built, on any
+    backend, and none is built."""
+    ms, _seg, _ = plane
+    nodes = _questions(47, 200)
+    before = _ledger("hits", "misses")
+    outs = [None] * len(nodes)
+    with _Builds() as b:
+        for lo in range(0, len(nodes), 40):
+            bar = threading.Barrier(40)
+
+            def submit(i):
+                bar.wait(timeout=30)
+                outs[i] = batched_topk(ms, nodes[i], 10)[0]
+            ts = [threading.Thread(target=submit, args=(i,))
+                  for i in range(lo, lo + 40)]
+            [t.start() for t in ts]
+            [t.join(timeout=120) for t in ts]
+    assert (b.jax, b.ledger) == (0, 0)
+    # the scoring families that were looked up (other families are other
+    # threads' business, if any ran)
+    moved = {f for f in _ledger("hits", "misses", since=before)
+             if f.startswith(("bm25_", "dense_"))}
+    assert moved == {"bm25_accumulate", "bm25_topk"}
+    assert "bm25_contrib" not in _ledger("compiles")
+    assert _bits(outs[::9]) == _bits([ms.topk_batch([q], 10)[0]
+                                      for q in nodes[::9]])
+
+
+def test_no_recompile_storm_across_batch_sizes(plane):
+    """Coalesced batches arrive at every size; each is fitted to a rung
+    of the closed set, so the compile ledger stays quiet: nothing is
+    built and no DeviceRecompileStorms fires."""
+    _ms, seg, _ = plane
+    nodes = _questions(53, 40)
+    s0 = metrics.DEVICE_RECOMPILE_STORMS.value
+    with _Builds() as b:
+        for size in (1, 2, 3, 4, 5, 8, 9, 32, 33, 40):
+            seg.topk_batch(nodes[:size], 10)
+    assert (b.jax, b.ledger) == (0, 0)
+    assert metrics.DEVICE_RECOMPILE_STORMS.value == s0
 
 
 def test_a_split_batch_equals_the_same_queries_alone(plane):
