@@ -5,7 +5,7 @@ hash aggregate over the columnstore; ClickBench shapes in BASELINE.md) as a
 single jitted XLA program per (table, query) over HBM-cached columns:
 
     mask   = predicate(cols) & validity          (fused elementwise)
-    counts = one-hot matmul / scatter over codes (ops/agg.py)
+    counts = MXU histogram / scatter over codes  (ops/agg.py)
     sums   = exact int64 via limb scatter        (ops/agg.py)
 
 Falls back to the CPU oracle (plan.AggregateNode._cpu_aggregate) whenever
@@ -225,6 +225,11 @@ def _run(node, scan, provider: TableProvider, preds: list[BoundExpr], ctx) -> Ba
         return [arrays[i] for i in ce.inputs]
 
     group_mode = bool(node.group_exprs)
+    reductions = _count_reductions(group_mode, group_space, agg_plans,
+                                   distinct_plans)
+    n_hist = sum(map(ops_agg.hist_form, reductions))
+    metrics.DEVICE_AGG_HISTOGRAM.add(n_hist)
+    metrics.DEVICE_AGG_SCATTER.add(len(reductions) - n_hist)
     # capture only the flag, not the fact dict — the closure lives in the
     # program cache and must not pin the codes buffer in HBM
     has_fact = fact is not None
@@ -263,10 +268,10 @@ def _run(node, scan, provider: TableProvider, preds: list[BoundExpr], ctx) -> Ba
                     c = jnp.where(ok, c, jnp.int32(size - 1))
                     codes = codes * jnp.int32(size) + jnp.clip(c, 0, size - 1)
             outputs.append(
-                ops_agg.group_count_scatter(codes, mask, group_space))
+                ops_agg.group_count_cells(codes, mask, group_space))
             for si, (spec, ce) in enumerate(agg_plans):
                 if si in distinct_plans:
-                    outputs.append(_presence_scatter(
+                    outputs.append(_presence(
                         distinct_plans[si], arrays, codes, mask,
                         group_space))
                 else:
@@ -278,7 +283,7 @@ def _run(node, scan, provider: TableProvider, preds: list[BoundExpr], ctx) -> Ba
             for si, (spec, ce) in enumerate(agg_plans):
                 if si in distinct_plans:
                     zc = jnp.zeros_like(mask, dtype=jnp.int32)
-                    outputs.append(_presence_scatter(
+                    outputs.append(_presence(
                         distinct_plans[si], arrays, zc, mask, 1))
                 else:
                     outputs.extend(
@@ -446,10 +451,28 @@ def _range_device_columns(provider, names, pin, zrange) -> dict:
     return out
 
 
-def _presence_scatter(dplan, arrays, gcodes, mask, group_space):
+def _count_reductions(group_mode: bool, group_space: int, agg_plans,
+                      distinct_plans) -> list[int]:
+    """The cell count of every grouped count / presence reduction that
+    `program` runs for these plans, one entry per `group_count_cells`
+    call of `program`, `_group_agg_device` and `_presence`: what
+    `DeviceAggHistogram` / `DeviceAggScatter` count, by `hist_form`."""
+    cells = [group_space] if group_mode else []
+    for si, (spec, _ce) in enumerate(agg_plans):
+        if si in distinct_plans:
+            cells.append(group_space * distinct_plans[si][3])
+        elif group_mode and spec.func != "count_star":
+            # float MIN counts its non-NaN rows besides
+            cells.extend([group_space] * (
+                2 if spec.func == "min" and spec.arg.type.is_float else 1))
+    return cells
+
+
+def _presence(dplan, arrays, gcodes, mask, group_space):
     """(group, value) presence matrix for one DISTINCT aggregate: int32
-    0/1 cells, scatter-max over the coded pairs. NULL values contribute 0
-    (their row mask is False), so no cell lights up for them."""
+    0/1 cells, the count of the coded pairs where it is above 0. NULL
+    values contribute 0 (their row mask is False), so no cell lights up
+    for them."""
     import jax.numpy as jnp
     kind, vi, lo, vsize = dplan
     data, ok = arrays[vi]
@@ -458,10 +481,9 @@ def _presence_scatter(dplan, arrays, gcodes, mask, group_space):
         vc = vc - jnp.int32(lo)
     vc = jnp.clip(vc, 0, vsize - 1)
     m = jnp.logical_and(mask, ok)
-    pair = (gcodes * jnp.int32(vsize) + vc).ravel()
-    pres = jnp.zeros((group_space * vsize,), jnp.int32)
-    pres = pres.at[pair].max(m.ravel().astype(jnp.int32))
-    return pres.reshape(group_space, vsize)
+    pair = gcodes * jnp.int32(vsize) + vc
+    counts = ops_agg.group_count_cells(pair, m, group_space * vsize)
+    return (counts > 0).astype(jnp.int32).reshape(group_space, vsize)
 
 
 def _out_combines(node, agg_plans, group_mode) -> list:
@@ -722,10 +744,10 @@ def _group_agg_device(spec: AggSpec, ce, arrays, codes, mask, env_for, g):
     v, ok = ce.fn(env_for(ce, arrays))
     m = jnp.logical_and(mask, ok)
     if spec.func == "count":
-        return [ops_agg.group_count_scatter(codes, m, g)]
+        return [ops_agg.group_count_cells(codes, m, g)]
     is_float = jnp.issubdtype(v.dtype, jnp.floating)
     if spec.func in ("sum", "avg"):
-        cnt = ops_agg.group_count_scatter(codes, m, g)
+        cnt = ops_agg.group_count_cells(codes, m, g)
         if is_float:
             return [ops_agg.group_sum_float(codes, m, v, g), cnt]
         if codes.shape[0] > ops_agg.SCATTER_CHUNK_TILES:
@@ -737,15 +759,15 @@ def _group_agg_device(spec: AggSpec, ce, arrays, codes, mask, env_for, g):
             # group is ALL NaN (then it IS NaN). Counts keep the
             # original mask so NULL detection is untouched. (Under the
             # mesh, a group all-NaN on one shard only is a known edge.)
-            counts = ops_agg.group_count_scatter(codes, m, g)
+            counts = ops_agg.group_count_cells(codes, m, g)
             m_nn = jnp.logical_and(m, jnp.logical_not(jnp.isnan(v)))
-            nonnan = ops_agg.group_count_scatter(codes, m_nn, g)
+            nonnan = ops_agg.group_count_cells(codes, m_nn, g)
             red = ops_agg.group_min_max(codes, m_nn, v, g, "min")
             red = jnp.where(jnp.logical_and(counts > 0, nonnan == 0),
                             jnp.nan, red)
             return [red, counts]
         return [ops_agg.group_min_max(codes, m, v, g, spec.func),
-                ops_agg.group_count_scatter(codes, m, g)]
+                ops_agg.group_count_cells(codes, m, g)]
     raise NotCompilable(spec.func)
 
 

@@ -9,12 +9,13 @@ time, so only an entry point that means to dispatch calls
 from __future__ import annotations
 
 import os
+import re
 
 #: <checkout>/.jax_cache — a FIXED path, because the cache directory is
 #: part of XLA's cache key: a directory that moves never hits
-DEFAULT_CACHE_DIR = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__)))), ".jax_cache")
+CHECKOUT_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+DEFAULT_CACHE_DIR = os.path.join(CHECKOUT_ROOT, ".jax_cache")
 
 
 def configure_compile_cache() -> str:
@@ -34,6 +35,12 @@ def configure_compile_cache() -> str:
         jax.config.update("jax_compilation_cache_dir", cache_dir)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    # a Pallas kernel travels inside its program as serialized MLIR that
+    # names the source files of its call stack, and that text is part of
+    # the cache key: name them from the checkout's root, or a checkout
+    # that moved would compile every histogram program again
+    jax.config.update("jax_hlo_source_file_canonicalization_regex",
+                      re.escape(CHECKOUT_ROOT + os.sep))
     return cache_dir
 
 

@@ -583,6 +583,18 @@ HOST_DISTINCT_OBJECTS = REGISTRY.gauge(
     "host DISTINCT aggregates that went through Python objects (scalar: "
     "a set over to_pylist) or the generic two-key lexsort (grouped): "
     "float and string arguments")
+#: which form the grouped count / DISTINCT presence reductions of the
+#: `device_agg` programs took (ops/agg.py: `hist_form`), one per reduction
+#: of every statement the family answered
+DEVICE_AGG_HISTOGRAM = REGISTRY.gauge(
+    "DeviceAggHistogram",
+    "grouped count / presence reductions dispatched as the tiled MXU "
+    "histogram: the padded cell count was at most HIST_MAX_CELLS")
+DEVICE_AGG_SCATTER = REGISTRY.gauge(
+    "DeviceAggScatter",
+    "grouped count / presence reductions dispatched as the serial "
+    "scatter: a cell count past HIST_MAX_CELLS, or a backend that does "
+    "not lower the histogram kernel")
 POOL_QUEUE_WAIT_HIST = REGISTRY.histogram(
     "PoolQueueWait",
     "per-task worker-pool queue wait (submit -> pickup)")

@@ -109,6 +109,23 @@ def test_compile_cache_defaults_to_checkout():
     assert helper_dir == jax_dir == os.path.join(REPO, ".jax_cache")
 
 
+def test_cache_keys_name_sources_from_the_checkout_root(tmp_path):
+    """A Pallas kernel's serialized MLIR names its call stack's files and
+    is part of the cache key: the path up to the checkout must not be."""
+    r = _run_py(
+        "import jax, jax.numpy as jnp\n"
+        "from serenedb_tpu.utils import backend\n"
+        "from serenedb_tpu.ops import agg\n"
+        "backend.configure_compile_cache()\n"
+        "x = jnp.zeros((8, 128), jnp.int32)\n"
+        "print(jax.jit(lambda c: agg.group_count_scatter(c, c > 0, 4))"
+        ".lower(x).as_text(debug_info=True))\n",
+        JAX_COMPILATION_CACHE_DIR=str(tmp_path / "c"), JAX_PLATFORMS="cpu")
+    assert r.returncode == 0, r.stderr[-2000:]
+    assert '"serenedb_tpu/ops/agg.py"' in r.stdout
+    assert REPO not in r.stdout
+
+
 def test_import_sets_no_cache_dir():
     """The helper runs only when an entry point calls it."""
     r = _run_py(
