@@ -359,7 +359,9 @@ _GAUGES = ("DeviceOffloads", "DeviceTransfersUp", "SearchBatchDispatches",
            "VectorSearchQueries", "NativeIndexBuilds",
            "NativeIndexFallbacks", "SegmentBuilds",
            "SearchQueriesScoredDevice", "SearchQueriesScoredHost",
-           "SearchPostingsDispatched", "SearchProgramsPrebuilt")
+           "SearchPostingsDispatched", "SearchProgramsPrebuilt",
+           "VectorQueriesScoredFlat", "VectorQueriesScoredProbe",
+           "VectorRowsScanned")
 
 
 def snapshot(srv: Server, pg: Pg) -> dict:
@@ -426,6 +428,11 @@ def phase_evidence(before: dict, after: dict) -> dict:
     on_host = gauges.get("SearchQueriesScoredHost", 0)
     if on_dev or on_host:
         ev["scored"] = {"device": on_dev, "host": on_host}
+    # a knn question likewise: the exact flat scan or the IVF probe
+    flat = gauges.get("VectorQueriesScoredFlat", 0)
+    probe = gauges.get("VectorQueriesScoredProbe", 0)
+    if flat or probe:
+        ev.setdefault("scored", {}).update(flat=flat, probe=probe)
     if fams and dev_dispatches and not declines:
         ev["ran_on"] = d0.get("platform")
     elif declines:

@@ -438,7 +438,12 @@ class ResultCache:
         h = hashlib.blake2b(digest_size=16)
         h.update("\x1e".join(parts).encode())
         h.update(b"\x00")
-        h.update(repr(tuple(params)).encode())
+        for prm in params:
+            # an array parameter (a knn query vector): its bytes — repr
+            # elides the middle of a long array
+            h.update(prm.tobytes() if hasattr(prm, "tobytes")
+                     else repr(prm).encode())
+            h.update(b"\x1f")
         h.update(b"\x00")
         h.update(ResultCache._settings_digest(settings).encode())
         return h.digest()

@@ -12,6 +12,10 @@ SURVEY.md §3.2). Here the layout is chosen for HBM/TPU:
   (<, <=, =, >, >=, GROUP BY, ORDER BY) are exact on codes.
 - a NULL code of -1 is never used; validity carries nullness so codes stay
   non-negative and usable as gather indices.
+- VECTOR(n) is the one 2-D column: `data` is ONE contiguous float32
+  (rows, n) array (a NULL row is zeros under its validity bit), so take /
+  slice / concat are the same axis-0 operations and an index reads the
+  array as it stands, with no per-row parse.
 
 Columns are immutable by convention: operators build new ones.
 """
@@ -36,9 +40,16 @@ class Column:
     dictionary: Optional[np.ndarray] = None  # VARCHAR only: sorted unique strs
 
     def __post_init__(self):
-        assert self.data.ndim == 1
+        if self.type.is_vector:
+            if self.data.ndim == 1 and not len(self.data):
+                # the many `np.empty(0, dtype=t.np_dtype)` of empty tables
+                self.data = self.data.reshape(0, self.type.dim)
+            assert self.data.ndim == 2 and \
+                self.data.shape[1] == self.type.dim
+        else:
+            assert self.data.ndim == 1
         if self.validity is not None:
-            assert self.validity.shape == self.data.shape
+            assert self.validity.shape == self.data.shape[:1]
             if bool(self.validity.all()):
                 self.validity = None
 
@@ -64,7 +75,13 @@ class Column:
             typ = _infer_type(non_null)
         validity = np.array([v is not None for v in values], dtype=bool)
         n = len(values)
-        if typ.is_string:
+        if typ.is_vector:
+            data = np.zeros((n, typ.dim), dtype=np.float32)
+            for i, v in enumerate(values):
+                if v is not None:
+                    data[i] = vector_value(v, typ.dim)
+            col = Column(typ, data, validity)
+        elif typ.is_string:
             strs = [("" if v is None else str(v)) for v in values]
             dictionary, codes = _encode_dictionary(strs)
             col = Column(typ, codes.astype(np.int32), validity, dictionary)
@@ -108,6 +125,12 @@ class Column:
         on literal materialization."""
         if typ is None:
             typ = _infer_type([] if value is None else [value])
+        if typ.is_vector:
+            row = np.zeros(typ.dim, np.float32) if value is None \
+                else vector_value(value, typ.dim)
+            return Column(typ, np.broadcast_to(row, (n, typ.dim)),
+                          None if value is not None
+                          else np.zeros(n, dtype=bool))
         if value is None:
             if typ.is_string:
                 return Column(typ, np.zeros(n, dtype=np.int32),
@@ -136,7 +159,10 @@ class Column:
     def to_pylist(self) -> list:
         out = []
         valid = self.valid_mask()
-        if self.type.is_string:
+        if self.type.is_vector:
+            for i in range(len(self.data)):
+                out.append(vector_text(self.data[i]) if valid[i] else None)
+        elif self.type.is_string:
             d = self.dictionary
             for i in range(len(self.data)):
                 out.append(str(d[self.data[i]]) if valid[i] else None)
@@ -152,6 +178,8 @@ class Column:
             return None
         if self.type.is_string:
             return str(self.dictionary[self.data[i]])
+        if self.type.is_vector:
+            return vector_text(self.data[i])
         return self.data[i].item()
 
     def take(self, indices: np.ndarray) -> "Column":
@@ -174,6 +202,41 @@ class Column:
         remap = np.zeros(len(self.dictionary), dtype=np.int32)
         remap[used] = np.arange(len(used), dtype=np.int32)
         return Column(self.type, remap[self.data], self.validity, new_dict)
+
+
+def vector_text(row: np.ndarray) -> str:
+    """pgvector's text form of one float32 row, '[v1,v2,...]': the
+    shortest digits that read back as the same float32 bits."""
+    return "[" + ",".join(map(str, np.asarray(row, np.float32))) + "]"
+
+
+def vector_value(v, dim: Optional[int] = None) -> np.ndarray:
+    """One vector value as float32 from its text form ('[v1,v2,...]':
+    pgvector's, which is also a JSON array), a sequence or an array; of
+    `dim` dimensions when that is given. THE parser of a vector literal:
+    `search/ivf.parse_vector` is this."""
+    from .. import errors
+    if isinstance(v, str):
+        import json
+        text = v
+        try:
+            v = np.asarray(json.loads(text), dtype=np.float32)
+        except (ValueError, TypeError):
+            raise errors.SqlError(errors.INVALID_TEXT_REPRESENTATION,
+                                  f"invalid vector literal: {text[:40]!r}")
+    else:
+        try:
+            v = np.asarray(v, dtype=np.float32)
+        except (ValueError, TypeError):
+            raise errors.SqlError(errors.INVALID_TEXT_REPRESENTATION,
+                                  "vector literal must be a flat array")
+    if v.ndim != 1:
+        raise errors.SqlError(errors.INVALID_TEXT_REPRESENTATION,
+                              "vector literal must be a flat array")
+    if dim is not None and len(v) != dim:
+        raise errors.SqlError(errors.DATATYPE_MISMATCH,
+                              f"expected {dim} dimensions, got {len(v)}")
+    return v
 
 
 def _infer_type(non_null: list) -> dt.SqlType:

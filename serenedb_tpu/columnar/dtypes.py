@@ -15,6 +15,7 @@ Here the logical SQL type system is small and explicit, and every type has a
 from __future__ import annotations
 
 import enum
+import re
 from dataclasses import dataclass
 
 import numpy as np
@@ -48,6 +49,10 @@ class TypeId(enum.Enum):
                              # JSON {"o":[oid,...],"v":[...]} text in a
                              # dictionary column; wire layer renders PG
                              # (…) text / the binary record format (2249)
+    VECTOR = "VECTOR"        # pgvector's VECTOR(n): n float32 a row,
+                             # physically ONE contiguous (rows, n) float32
+                             # array (the only 2-D column); text form
+                             # '[v1,v2,...]' on the wire and in literals
 
 
 _NUMPY_OF = {
@@ -65,6 +70,7 @@ _NUMPY_OF = {
     TypeId.NULL: np.dtype(np.int32),
     TypeId.ARRAY: np.dtype(np.int32),     # dictionary codes (JSON text)
     TypeId.RECORD: np.dtype(np.int32),    # dictionary codes (JSON text)
+    TypeId.VECTOR: np.dtype(np.float32),  # (rows, dim)
     TypeId.OID: np.dtype(np.int64),
     TypeId.REGCLASS: np.dtype(np.int64),
     TypeId.REGTYPE: np.dtype(np.int64),
@@ -87,6 +93,12 @@ class SqlType:
     #: ARRAY element type (None elsewhere); frozen+defaulted so equality
     #: and hashing of existing scalar types are unchanged
     elem: "TypeId | None" = None
+    #: VECTOR dimension count (0 elsewhere)
+    dim: int = 0
+
+    @property
+    def is_vector(self) -> bool:
+        return self.id is TypeId.VECTOR
 
     @property
     def np_dtype(self) -> np.dtype:
@@ -114,6 +126,8 @@ class SqlType:
             return f"{(self.elem or TypeId.VARCHAR).value}[]"
         if self.id is TypeId.RECORD:
             return "record"
+        if self.id is TypeId.VECTOR:
+            return f"VECTOR({self.dim})"
         return self.id.value
 
 
@@ -144,6 +158,19 @@ def array_of(elem: "SqlType | TypeId | None") -> SqlType:
     if elem in (None, TypeId.NULL, TypeId.ARRAY):
         elem = TypeId.VARCHAR
     return SqlType(TypeId.ARRAY, elem)
+
+def vector_of(dim: int) -> SqlType:
+    """pgvector's VECTOR(dim): dim float32 a row, stored contiguously."""
+    dim = int(dim)
+    if not 1 <= dim <= 16000:          # pgvector's own ceiling
+        raise ValueError(f"vector dimensions must be 1..16000, got {dim}")
+    return SqlType(TypeId.VECTOR, None, dim)
+
+
+#: VECTOR(n), pgvector's spelling — and FLOAT4[n] / REAL[n] / FLOAT[n],
+#: what a Postgres user without the extension writes for the same thing
+_VECTOR_NAME = re.compile(
+    r"^(?:VECTOR\s*\(\s*(\d+)\s*\)|(?:FLOAT4|REAL|FLOAT)\s*\[\s*(\d+)\s*\])$")
 
 _BY_NAME = {
     "BOOLEAN": BOOL, "BOOL": BOOL,
@@ -176,6 +203,10 @@ _RANK = {
 
 def type_from_name(name: str) -> SqlType:
     key = name.upper().strip()
+    m = _VECTOR_NAME.match(key)
+    if m:
+        return vector_of(int(m.group(1) or m.group(2)))
+    key = re.sub(r"\[\d+\]$", "[]", key)   # PG ignores a declared array size
     if key.endswith("[]"):
         return array_of(type_from_name(key[:-2]))
     if key == "ARRAY":          # legacy/unparameterized
@@ -222,7 +253,7 @@ def common_numeric(a: SqlType, b: SqlType) -> SqlType:
 
 def type_of_numpy(dt: np.dtype) -> SqlType:
     for tid, nd in _NUMPY_OF.items():
-        if tid in (TypeId.VARCHAR, TypeId.NULL, TypeId.DATE):
+        if tid in (TypeId.VARCHAR, TypeId.NULL, TypeId.DATE, TypeId.VECTOR):
             continue
         if nd == dt:
             return SqlType(tid)

@@ -248,19 +248,21 @@ class IvfScanNode(PlanNode):
     def batches(self, ctx):
         from .plan import check_cancel
         check_cancel()
+        from ..obs.trace import stage
         from ..search import vector_store
         from ..search.ivf import find_ivf_index
-        idx = find_ivf_index(self.provider, self.vector_column)
-        if idx is None:
-            raise RuntimeError("ivf index disappeared under the plan")
-        pin = self.provider.try_pin()
-        # stamp the publication identity onto the index so vector-pool
-        # pages written for its segments report which table/version they
-        # serve (sdb_vector_pool rows)
-        vector_store.note_publication(idx, self.provider, pin)
-        nprobe = vector_store.effective_nprobe(ctx.settings)
-        rerank = int(ctx.settings.get("sdb_rerank_factor"))
-        mesh_n = int(ctx.settings.get("serene_mesh") or 0)
+        with stage("device_prepare"):
+            idx = find_ivf_index(self.provider, self.vector_column)
+            if idx is None:
+                raise RuntimeError("ivf index disappeared under the plan")
+            pin = self.provider.try_pin()
+            # stamp the publication identity onto the index so
+            # vector-pool pages written for its segments report which
+            # table/version they serve (sdb_vector_pool rows)
+            vector_store.note_publication(idx, self.provider, pin)
+            nprobe = vector_store.effective_nprobe(ctx.settings)
+            rerank = int(ctx.settings.get("sdb_rerank_factor"))
+            mesh_n = int(ctx.settings.get("serene_mesh") or 0)
         # knn dispatches coalesce through the same batcher as BM25 —
         # the probe knobs ride in the scorer string, so queries with
         # different (k, nprobe, rerank) never share a stacked dispatch
@@ -273,12 +275,14 @@ class IvfScanNode(PlanNode):
             prof.add_search_batch(id(self), queries=bstats["queries"],
                                   window_ns=bstats["window_ns"],
                                   scoring_ns=bstats["scoring_ns"])
-        keep = np.isfinite(dists)
-        d, r = dists[keep], rows[keep]
-        full = self.provider.full_batch(self.columns)
-        out = full.take(r.astype(np.int64))
-        yield Batch(list(self.names),
-                    out.columns + [Column(dt.DOUBLE, d.astype(np.float64))])
+        with stage("host_scan"):
+            keep = np.isfinite(dists)
+            d, r = dists[keep], rows[keep]
+            full = self.provider.full_batch(self.columns)
+            out = full.take(r.astype(np.int64))
+            res = Batch(list(self.names), out.columns +
+                        [Column(dt.DOUBLE, d.astype(np.float64))])
+        yield res
 
 
 class MaxSimScanNode(PlanNode):

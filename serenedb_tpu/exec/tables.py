@@ -277,6 +277,20 @@ def _arrow_to_column(arr) -> Column:
     null_mask = None
     if arr.null_count:
         null_mask = np.asarray(arr.is_valid())
+    if pa.types.is_fixed_size_list(t) and (
+            pa.types.is_floating(t.value_type)):
+        # VECTOR(n): the list's child buffer IS the (rows, n) array —
+        # float32 is wrapped as it stands, float64 narrowed once
+        vals = arr.flatten() if not arr.null_count else \
+            arr.values[arr.offset * t.list_size:
+                       (arr.offset + len(arr)) * t.list_size]
+        if vals.null_count:
+            vals = vals.fill_null(0)
+        data = np.asarray(vals).astype(np.float32, copy=False) \
+            .reshape(len(arr), t.list_size)
+        if null_mask is not None:
+            data = np.where(null_mask[:, None], data, np.float32(0))
+        return Column(dt.vector_of(t.list_size), data, null_mask)
     if pa.types.is_string(t) or pa.types.is_large_string(t):
         if arr.null_count:
             arr = arr.fill_null("")
@@ -376,6 +390,9 @@ def _arrow_field_type(t) -> dt.SqlType:
     import pyarrow as pa
     if pa.types.is_dictionary(t):
         t = t.value_type
+    if pa.types.is_fixed_size_list(t) and \
+            pa.types.is_floating(t.value_type):
+        return dt.vector_of(t.list_size)
     if pa.types.is_boolean(t):
         return dt.BOOL
     if pa.types.is_int8(t):

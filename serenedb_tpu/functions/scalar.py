@@ -1855,9 +1855,58 @@ def _current_schema(ts):
 
 # -- vector functions (CPU oracle; reference: functions/vector.cpp) --------
 
+def _vector_rows(col, dim: int) -> np.ndarray:
+    """One argument of a vec_* function as a float32 (n, dim) array: a
+    VECTOR column as it stands, a text column parsed once per distinct
+    value (a literal: once; NULL rows are masked by the caller)."""
+    from ..search.ivf import parse_vector
+    if col.type.is_vector:
+        if col.type.dim != dim:
+            raise errors.SqlError(
+                errors.DATATYPE_MISMATCH,
+                f"vector dims differ: {col.type.dim} vs {dim}")
+        return col.data
+    valid = col.valid_mask()
+    used = np.unique(col.data[valid])
+    table = np.zeros((len(col.dictionary), dim), np.float32)
+    for code in used:
+        table[code] = parse_vector(str(col.dictionary[code]), dim)
+    return table[col.data]
+
+
+#: rows of a typed vec_* evaluation held in float64 at a time
+_VEC_CHUNK = 1 << 16
+
+
 def _make_vec_fn(metric):
     def resolver(ts):
+        def typed(cols, n):
+            # a VECTOR(n) argument: whole-array arithmetic, a slab of
+            # rows at a time, the same expressions as the per-row
+            # oracle below
+            dim = next(c.type.dim for c in cols if c.type.is_vector)
+            xs = _vector_rows(cols[0], dim)
+            ys = _vector_rows(cols[1], dim)
+            out = np.zeros(n, dtype=np.float64)
+            for at in range(0, n, _VEC_CHUNK):
+                x, y = xs[at:at + _VEC_CHUNK], ys[at:at + _VEC_CHUNK]
+                if metric == "l2":
+                    d = x.astype(np.float64) - y.astype(np.float64)
+                    o = np.einsum("ij,ij->i", d, d)
+                elif metric == "ip":
+                    o = -np.einsum("ij,ij->i", x.astype(np.float64),
+                                   y.astype(np.float64))
+                else:
+                    nx = np.linalg.norm(x, axis=1).astype(np.float64)
+                    ny = np.linalg.norm(y, axis=1).astype(np.float64)
+                    dot = np.einsum("ij,ij->i", x, y).astype(np.float64)
+                    o = 1.0 - dot / np.maximum(nx * ny, 1e-9)
+                out[at:at + _VEC_CHUNK] = o
+            return _result(dt.DOUBLE, out, cols)
+
         def impl(cols, n):
+            if any(c.type.is_vector for c in cols):
+                return typed(cols, n)
             # strict NULL propagation: never parse rows where either side is
             # NULL ('' placeholders would raise)
             from ..search.ivf import parse_vector
@@ -1929,6 +1978,9 @@ def _vec_maxsim(ts):
 def _vec_dims(ts):
     def impl(cols, n):
         from ..search.ivf import parse_vector
+        if cols[0].type.is_vector:
+            return _result(dt.BIGINT, np.full(n, cols[0].type.dim,
+                                              np.int64), cols)
         vals = string_values(cols[0])
         valid = propagate_nulls(cols)
         out = np.zeros(n, dtype=np.int64)

@@ -253,6 +253,121 @@ def probe_program(metric: str, dp: int, l_real: int, nprobe: int,
     return run
 
 
+# -- flat scan: the exact index (ES `index_options.type: flat`) ---------------
+
+#: queries per dispatch the flat program is built for — the closed set a
+#: flat index prebuilds (what `ops/bm25.score_rungs` is to BM25); a
+#: coalesced batch is fitted to the smallest rung that holds it and
+#: split past the largest
+FLAT_RUNGS = (1, 8, 32)
+
+#: rows per tile of the scan: one `dot_general` and one top-k each
+FLAT_TILE = 131072
+
+
+def flat_rung(n_queries: int) -> int:
+    return next((r for r in FLAT_RUNGS if r >= n_queries), FLAT_RUNGS[-1])
+
+
+def flat_aux_program(metric: str):
+    """Per-row factor the scan needs besides the dot product, computed
+    ONCE when a segment is uploaded: cos → 1 / ||x|| (0 for a zero row),
+    l2 → ||x||^2, ip → 0; NaN marks a dead (NULL) row."""
+    def program(vecs, live):
+        sq = jnp.sum(vecs * vecs, axis=1)
+        if metric == "cos":
+            aux = jnp.where(sq > 0, jax.lax.rsqrt(jnp.maximum(sq, 1e-30)),
+                            0.0)
+        elif metric == "l2":
+            aux = sq
+        else:
+            aux = jnp.zeros_like(sq)
+        return jnp.where(live, aux, jnp.nan).astype(jnp.float32)
+    return program
+
+
+def _flat_dist(dot, aux, q, metric: str):
+    """(Q, T) distances from the tile's dot products: cos = 1 - cosine,
+    l2 = squared L2, ip = negated inner product (smaller = nearer, the
+    `vec_*` functions' own senses); +inf on dead rows."""
+    if metric == "cos":
+        qn = jnp.sqrt(jnp.sum(q * q, axis=1, keepdims=True))
+        qinv = jnp.where(qn > 0, 1.0 / jnp.maximum(qn, 1e-30), 0.0)
+        d = 1.0 - dot * aux[None, :] * qinv
+    elif metric == "l2":
+        qsq = jnp.sum(q * q, axis=1, keepdims=True)
+        d = jnp.maximum(aux[None, :] - 2.0 * dot + qsq, 0.0)
+    else:
+        d = -dot + aux[None, :]
+    # a dead row's NaN factor, and an overflow on the way, rank last
+    return jnp.where(jnp.isnan(d), jnp.inf, d)
+
+
+def _tile_topk(d, base, kk: int):
+    """The kk nearest of one tile, (distance asc, row asc): `lax.top_k`
+    puts the lower index first among equals."""
+    neg, idx = jax.lax.top_k(-d, kk)
+    return -neg, (base + idx).astype(jnp.int32)
+
+
+def flat_scan_program(metric: str, n: int, dim: int, kk: int,
+                      tile: int = FLAT_TILE):
+    """Exact top-kk over EVERY row of one resident (n, dim) float32
+    segment: per row tile one `dot_general((Q, dim), (tile, dim))` at
+    `Precision.HIGHEST` (float32 products: the default would lower them
+    to one bfloat16 pass), the metric's distance from the factors stored
+    at upload, the tile's own top-kk, and a merge into the running
+    top-kk — carry first, so equal distances keep the lower row. The
+    rows are read once per DISPATCH whatever Q is. A tail shorter than a
+    tile is read as the LAST `tile` rows with the overlap masked, so the
+    array needs no padding in either axis.
+
+    program(vecs (n, dim), aux (n,), q (Q, dim)) → (dist (Q, kk) f32
+    ascending, row (Q, kk) i32); lanes past the live rows carry
+    (+inf, _PAD_ROW)."""
+    tile = min(tile, n)
+    nt, rem = divmod(n, tile)
+    dn = (((1,), (1,)), ((), ()))
+
+    def scan_tile(vecs, aux, q, start, first_live):
+        x = jax.lax.dynamic_slice(vecs, (start, 0), (tile, dim))
+        a = jax.lax.dynamic_slice(aux, (start,), (tile,))
+        dot = jax.lax.dot_general(
+            q, x, dn, precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
+        d = _flat_dist(dot, a, q, metric)
+        if first_live is not None:
+            d = jnp.where((jnp.arange(tile) < first_live)[None, :],
+                          jnp.inf, d)
+        if tile < kk:
+            d = jnp.pad(d, ((0, 0), (0, kk - tile)),
+                        constant_values=jnp.inf)
+        return _tile_topk(d, start, kk)
+
+    def merge(best_d, best_r, d, r):
+        cd = jnp.concatenate([best_d, d], axis=1)
+        cr = jnp.concatenate([best_r, r], axis=1)
+        neg, idx = jax.lax.top_k(-cd, kk)
+        return -neg, jnp.take_along_axis(cr, idx, axis=1)
+
+    def program(vecs, aux, q):
+        nq = q.shape[0]
+        best = (jnp.full((nq, kk), jnp.inf, jnp.float32),
+                jnp.full((nq, kk), _PAD_ROW, jnp.int32))
+
+        def body(i, carry):
+            d, r = scan_tile(vecs, aux, q, i * tile, None)
+            return merge(*carry, d, r)
+
+        best = jax.lax.fori_loop(0, nt, body, best)
+        if rem:
+            d, r = scan_tile(vecs, aux, q, n - tile, tile - rem)
+            best = merge(*best, d, r)
+        bd, br = best
+        return bd, jnp.where(jnp.isfinite(bd), br, _PAD_ROW)
+    return program
+
+
 def chunk_maps(nprobe: int, m: int, mc: int) -> tuple[np.ndarray,
                                                       np.ndarray]:
     """Host-built flattening of the (nprobe, M) probe grid into mc-lane

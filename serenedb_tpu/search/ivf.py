@@ -32,6 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import errors
+from ..columnar.column import vector_value
 from ..ops import vector as vops
 from ..utils import log
 from .vector_store import VPOOL
@@ -49,20 +50,7 @@ _FRAG_CAP = 64
 
 def parse_vector(text: Optional[str], dim: Optional[int] = None,
                  ) -> Optional[np.ndarray]:
-    if text is None:
-        return None
-    try:
-        v = np.asarray(json.loads(text), dtype=np.float32)
-    except (json.JSONDecodeError, ValueError):
-        raise errors.SqlError(errors.INVALID_TEXT_REPRESENTATION,
-                              f"invalid vector literal: {text[:40]!r}")
-    if v.ndim != 1:
-        raise errors.SqlError(errors.INVALID_TEXT_REPRESENTATION,
-                              "vector literal must be a flat array")
-    if dim is not None and len(v) != dim:
-        raise errors.SqlError(errors.DATATYPE_MISMATCH,
-                              f"expected {dim} dimensions, got {len(v)}")
-    return v
+    return None if text is None else vector_value(text, dim)
 
 
 def parse_multi_vector(text: Optional[str], dim: Optional[int] = None,
@@ -111,6 +99,23 @@ class VecSegment:
         self.codes = np.ascontiguousarray(codes[order], dtype=np.int32)
         self.counts = np.bincount(self.codes, minlength=lists)[:lists] \
             .astype(np.int64)
+
+
+class FlatSegment:
+    """One immutable slab of a FLAT index: the table rows `base` …
+    `base + len(vals)` as they stand in the column — `vals` is the
+    column's own (rows, dim) float32 array (a view: no copy, no sort),
+    `valid` its validity slice (None = every row live). The device pool
+    keys residency on the segment OBJECT, as for `VecSegment`."""
+
+    __slots__ = ("vals", "base", "valid", "__weakref__", "_vpool_uid")
+
+    def __init__(self, vals: np.ndarray, base: int,
+                 valid: Optional[np.ndarray] = None):
+        self.vals = np.ascontiguousarray(vals, dtype=np.float32)
+        self.base = int(base)
+        self.valid = None if valid is None or bool(valid.all()) \
+            else np.ascontiguousarray(valid, bool)
 
 
 class _VecIndexBase:
@@ -217,8 +222,13 @@ class IvfIndex(_VecIndexBase):
                  centroids: np.ndarray, segs: list, num_rows: int,
                  data_version: int, mutation_epoch: int = 0,
                  options: dict = None, quantized: bool = False,
-                 host_vectors=None, sq8_lo=None, sq8_scale=None):
+                 host_vectors=None, sq8_lo=None, sq8_scale=None,
+                 flat: bool = False):
         super().__init__()
+        #: the index's DECLARED type (`WITH (type = 'flat')`): exact scan
+        #: of every row by `knn_flat_scan` instead of the cluster probe.
+        #: Nothing else chooses between the two programs
+        self.flat = flat
         self.column = column
         self.dim = dim
         self.lists = lists
@@ -249,6 +259,9 @@ class IvfIndex(_VecIndexBase):
         (Q, kk)); dead lanes carry (+inf, pad) — callers filter
         non-finite distances."""
         q = np.ascontiguousarray(queries, dtype=np.float32)
+        if self.flat:
+            d, r = VPOOL.flat_search(self, q, k)
+            return d, r.astype(np.int64)
         lay = self.layout()
         ntot = lay["ntot"]
         if ntot == 0:
@@ -293,13 +306,16 @@ class IvfIndex(_VecIndexBase):
 
     def topk_batch(self, nodes, k: int, scorer: str, mesh_n: int = 0,
                    ragged: bool = False):
-        nprobe, rerank = _parse_knn_scorer(scorer)
-        q = np.stack([np.ascontiguousarray(n, np.float32)
-                      for n in nodes])
+        from ..obs.trace import stage
+        with stage("device_prepare"):
+            nprobe, rerank = _parse_knn_scorer(scorer)
+            q = np.stack([np.ascontiguousarray(n, np.float32)
+                          for n in nodes])
         d, r = self.search(q, k, nprobe, rerank)
-        outs = [(d[i], r[i]) for i in range(len(nodes))]
-        for node, out in zip(nodes, outs):
-            self._frag_store(node, k, scorer, out)
+        with stage("device_finalize"):
+            outs = [(d[i], r[i]) for i in range(len(nodes))]
+            for node, out in zip(nodes, outs):
+                self._frag_store(node, k, scorer, out)
         return outs
 
 
@@ -406,31 +422,98 @@ def _parse_column(provider, column: str, dim, parse):
     return texts, vecs, np.asarray(rows, np.int64), dim
 
 
-def build_ivf_index(provider, column: str, options: dict) -> IvfIndex:
-    dim = int(options.get("dim", 0)) or None
-    texts, vecs, rows, dim = _parse_column(provider, column, dim,
-                                           parse_vector)
-    n = len(texts)
-    dim = dim or 1
-    nv = len(vecs)
-    mat = np.stack(vecs).astype(np.float32) if nv \
-        else np.zeros((0, dim), np.float32)
-    lists = int(options.get("lists", options.get("nlist", DEFAULT_LISTS)))
-    lists = max(1, min(lists, max(nv, 1)))
+def _vector_matrix(provider, column: str, dim, start: int = 0):
+    """(matrix (n, dim) float32 over EVERY row from `start`, validity or
+    None, dim): a VECTOR(n) column's own array as it stands — no per-row
+    Python, no copy — or a JSON-text column parsed once into the same
+    shape (NULL rows zeros under their validity bit)."""
+    col = provider.full_batch([column]).column(column)
+    if start:
+        col = col.slice(start, len(col))
+    if col.type.is_vector:
+        if dim is not None and dim != col.type.dim:
+            raise errors.SqlError(
+                errors.DATATYPE_MISMATCH,
+                f"expected {dim} dimensions, got {col.type.dim}")
+        return col.data, col.validity, col.type.dim
+    if not col.type.is_string:
+        raise errors.SqlError(errors.DATATYPE_MISMATCH,
+                              "vector index requires a VECTOR(n) or a "
+                              "JSON-array vector column")
+    texts = col.to_pylist()
+    parsed = [parse_vector(t, dim) if t is not None else None
+              for t in texts]
+    dim = dim or next((len(v) for v in parsed if v is not None), None) or 1
+    mat = np.zeros((len(texts), dim), np.float32)
+    valid = np.zeros(len(texts), bool)
+    for i, v in enumerate(parsed):
+        if v is not None:
+            if len(v) != dim:
+                raise errors.SqlError(
+                    errors.DATATYPE_MISMATCH,
+                    f"expected {dim} dimensions, got {len(v)}")
+            mat[i] = v
+            valid[i] = True
+    return mat, None if valid.all() else valid, dim
+
+
+def _metric_option(options: dict) -> str:
     metric = str(options.get("metric", "l2")).lower()
+    metric = {"cosine": "cos", "dot_product": "ip", "l2_norm": "l2",
+              "inner_product": "ip"}.get(metric, metric)
     if metric not in ("l2", "ip", "cos"):
         raise errors.unsupported(f"ivf metric {metric}")
+    return metric
+
+
+def is_flat(options: dict) -> bool:
+    """`WITH (type = 'flat')`: the one spelling of an exact index."""
+    return str(options.get("type", "ivf")).lower() == "flat"
+
+
+def build_flat_index(provider, column: str, options: dict) -> IvfIndex:
+    """The exact index: every row, as the column holds it, in one
+    `FlatSegment`; uploaded and its program set built before it is
+    published (`VPOOL.flat_prebuild`)."""
+    dim = int(options.get("dim", 0)) or None
+    mat, valid, dim = _vector_matrix(provider, column, dim)
+    idx = IvfIndex(
+        column=column, dim=dim, lists=1, metric=_metric_option(options),
+        centroids=np.zeros((1, dim), np.float32),
+        segs=[FlatSegment(mat, 0, valid)] if len(mat) else [],
+        num_rows=len(mat), data_version=provider.data_version,
+        mutation_epoch=getattr(provider, "mutation_epoch", 0),
+        options=dict(options), flat=True)
+    VPOOL.flat_prebuild(idx)
+    return idx
+
+
+def build_ivf_index(provider, column: str, options: dict) -> IvfIndex:
+    if is_flat(options):
+        return build_flat_index(provider, column, options)
+    dim = int(options.get("dim", 0)) or None
+    full, valid, dim = _vector_matrix(provider, column, dim)
+    n = len(full)
+    rows = np.arange(n, dtype=np.int64) if valid is None \
+        else np.flatnonzero(valid).astype(np.int64)
+    mat = full if valid is None else full[rows]
+    nv = len(mat)
+    lists = int(options.get("lists", options.get("nlist", DEFAULT_LISTS)))
+    lists = max(1, min(lists, max(nv, 1)))
+    metric = _metric_option(options)
     train = mat if nv else np.zeros((1, dim), np.float32)
     init = vops.init_centroids(train, lists)
     centroids = np.asarray(vops.kmeans_fit(
         jnp.asarray(vops.pad_rows(train)), jnp.asarray(init), lists,
         KMEANS_ITERS))
-    host = np.zeros((max(n, 1), dim), np.float32)
-    if nv:
-        host[rows] = mat
     quant = str(options.get("quantization",
                             options.get("quantizer", ""))).lower()
     quantized = quant in ("sq8", "int8")
+    host = None
+    if quantized:
+        host = np.zeros((max(n, 1), dim), np.float32)
+        if nv:
+            host[rows] = mat
     lo = scale = None
     vals = mat
     if quantized:
@@ -454,8 +537,7 @@ def build_ivf_index(provider, column: str, options: dict) -> IvfIndex:
         data_version=provider.data_version,
         mutation_epoch=getattr(provider, "mutation_epoch", 0),
         options=dict(options), quantized=quantized,
-        host_vectors=host if quantized else None,
-        sq8_lo=lo, sq8_scale=scale)
+        host_vectors=host, sq8_lo=lo, sq8_scale=scale)
 
 
 def build_maxsim_index(provider, column: str, options: dict,
@@ -509,19 +591,19 @@ def refresh_ivf_index(provider, idx: IvfIndex) -> IvfIndex:
         return build_ivf_index(provider, idx.column, idx.options)
     if n_rows == idx.num_rows:
         return _clone_ivf(idx, n_rows, epoch)
-    # pure append: parse the tail only, keep centroids and segments
-    col = provider.full_batch([idx.column]).column(idx.column)
-    texts = col.slice(idx.num_rows, n_rows).to_pylist()
-    vecs, rows = [], []
-    for i, t in enumerate(texts):
-        v = parse_vector(t, idx.dim) if t is not None else None
-        if v is not None:
-            vecs.append(v)
-            rows.append(idx.num_rows + i)
+    # pure append: read the tail only, keep centroids and segments
+    tail, valid, _ = _vector_matrix(provider, idx.column, idx.dim,
+                                    start=idx.num_rows)
     new = _clone_ivf(idx, n_rows, epoch)
-    if vecs:
-        mat = np.stack(vecs).astype(np.float32)
-        rows = np.asarray(rows, np.int64)
+    if idx.flat:
+        new.segs.append(FlatSegment(tail, idx.num_rows, valid))
+        VPOOL.flat_prebuild(new)
+        return new
+    rows = idx.num_rows + (np.arange(len(tail), dtype=np.int64)
+                           if valid is None
+                           else np.flatnonzero(valid).astype(np.int64))
+    if len(rows):
+        mat = tail if valid is None else tail[valid]
         vals = mat
         if idx.quantized:
             q8 = np.clip(np.round((mat - idx.sq8_lo) / idx.sq8_scale
@@ -545,7 +627,17 @@ def _clone_ivf(idx: IvfIndex, n_rows: int, epoch: int) -> IvfIndex:
         num_rows=n_rows, data_version=idx.data_version,
         mutation_epoch=epoch, options=idx.options,
         quantized=idx.quantized, host_vectors=idx.host_vectors,
-        sq8_lo=idx.sq8_lo, sq8_scale=idx.sq8_scale)
+        sq8_lo=idx.sq8_lo, sq8_scale=idx.sq8_scale, flat=idx.flat)
+
+
+def declared_ivf_index(provider, column: str) -> Optional[IvfIndex]:
+    """The column's IVF / flat index as DECLARED, fresh or stale: what
+    names the metric and the type of a knn search (a stale index is still
+    the mapping's word on both; the scan then runs on the host)."""
+    for idx in getattr(provider, "indexes", {}).values():
+        if isinstance(idx, IvfIndex) and idx.column == column:
+            return idx
+    return None
 
 
 def find_ivf_index(provider, column: str) -> Optional[IvfIndex]:

@@ -17,6 +17,13 @@ sit row-padded in whole pages (rows-per-page = PAGE_F32 / pow2(dim)),
 so a segment append writes ONLY the new segment's pages — the base
 segments stay hot (the zone-map tail trick, device edition).
 
+Flat indexes (exact scan, `ops.vector.flat_scan_program`) live in the
+SAME pool — one LRU, one weakref reclamation, one stats surface — but
+not in pages: a flat segment is one contiguous (rows, dim) float32
+device array, unpadded in both axes, beside its per-row factor. Their
+byte budget comes from the device (`memory_stats()["bytes_limit"]`),
+not from `serene_vector_pages`, which bounds only the paged region.
+
 Bit-parity: resident, cold (pool off / starved / dim > page) and
 brute-oracle paths all run the same `ops.vector` program bodies whose
 distance expression is a fixed f32 add chain mirrored by
@@ -145,12 +152,14 @@ def _write_program(region, slots, stage):
 class _Entry:
     """One resident index segment: its page list, row count, padded
     width, write stamp (descriptor-validity token) and the hit/idle
-    signals the LRU and sdb_vector_pool read."""
+    signals the LRU and sdb_vector_pool read. A flat segment holds no
+    pages: `arrays` = (vectors (n, dim), per-row factor (n,)) on the
+    device, `nbytes` of them."""
 
     __slots__ = ("key", "slots", "n", "dp", "stamp", "pub", "hits",
-                 "last_ns")
+                 "last_ns", "arrays", "nbytes")
 
-    def __init__(self, key, slots, n, dp, stamp, pub):
+    def __init__(self, key, slots, n, dp, stamp, pub, arrays=None):
         self.key = key
         self.slots = slots
         self.n = n
@@ -159,6 +168,34 @@ class _Entry:
         self.pub = pub
         self.hits = 0
         self.last_ns = time.perf_counter_ns()
+        self.arrays = arrays
+        self.nbytes = 0 if arrays is None else \
+            sum(int(a.size) * a.dtype.itemsize for a in arrays)
+
+
+_NO_PAGES = np.zeros(0, np.int32)
+
+#: share of the device's memory flat segments may hold: the rest is the
+#: posting store's, the column cache's and the programs' own
+_FLAT_SHARE = 0.6
+
+
+def _flat_budget_bytes() -> int:
+    """What flat segments may hold, from the device itself: a share of
+    `memory_stats()["bytes_limit"]`. A backend that reports none (the
+    CPU) falls back to the `serene_device_cache_mb` envelope."""
+    import jax
+    try:
+        stats = jax.local_devices()[0].memory_stats() or {}
+    except Exception:  # noqa: BLE001 — a backend without the call
+        stats = {}
+    limit = int(stats.get("bytes_limit", 0))
+    if limit > 0:
+        return int(limit * _FLAT_SHARE)
+    try:
+        return int(_settings.get_global("serene_device_cache_mb")) << 20
+    except KeyError:  # pragma: no cover — registry declares it
+        return 256 << 20
 
 
 class VectorPool:
@@ -194,10 +231,17 @@ class VectorPool:
             e = self._entries.pop(uid, None)
             if e is not None:
                 self._free.extend(e.slots.tolist())
-                if self._n_pages:
-                    used = self._n_pages - len(self._free)
-                    metrics.VECTOR_BYTES_RESIDENT.set(
-                        used * PAGE_F32 * 4)
+                self._set_resident()
+
+    def _flat_bytes(self) -> int:
+        return sum(e.nbytes for e in self._entries.values())
+
+    def _set_resident(self) -> None:
+        """Caller holds the lock."""
+        pages = (self._n_pages - len(self._free)) \
+            if self._region_arr is not None else 0
+        metrics.VECTOR_BYTES_RESIDENT.set(
+            pages * PAGE_F32 * 4 + self._flat_bytes())
 
     # -- region -----------------------------------------------------------
 
@@ -209,10 +253,12 @@ class VectorPool:
         if self._region_arr is None or self._n_pages != budget:
             self._region_arr = jnp.zeros((budget, PAGE_F32), jnp.float32)
             self._n_pages = budget
-            self._entries.clear()
+            for key in [k for k, e in self._entries.items()
+                        if not e.nbytes]:      # flat segments hold no page
+                del self._entries[key]
             self._free = list(range(budget - 1, -1, -1))
             self._seq += 1
-            metrics.VECTOR_BYTES_RESIDENT.set(0)
+            self._set_resident()
 
     def clear(self) -> None:
         """Drop the region and every entry (tests / budget
@@ -234,8 +280,8 @@ class VectorPool:
             return None
         while len(self._free) < need:
             victim = None
-            for key in list(self._entries):
-                if key not in busy:
+            for key, e in self._entries.items():
+                if key not in busy and not e.nbytes:
                     victim = key
                     break
             if victim is None:
@@ -325,8 +371,7 @@ class VectorPool:
                 ents.append(e)
             if writes:
                 self._write(writes)
-            used = self._n_pages - len(self._free)
-            metrics.VECTOR_BYTES_RESIDENT.set(used * PAGE_F32 * 4)
+            self._set_resident()
             # snapshot capture: immutable arrays stay consistent for
             # the dispatch below even if another thread evicts pages
             return (self._region_arr, self._seq, self._n_pages, ents)
@@ -510,6 +555,8 @@ class VectorPool:
                    time.perf_counter_ns(), queries=nq, nprobe=nprobe,
                    kind=kind, resident=res is not None)
         metrics.VECTOR_SEARCH_QUERIES.add(nq)
+        if kind == "ivf":
+            metrics.VECTOR_QUERIES_SCORED_PROBE.add(nq)
         metrics.VECTOR_SEARCH_DISPATCHES.add()
         metrics.VECTOR_PROBED_CLUSTERS.add(nq * nprobe)
         return d[:nq, :kk], r[:nq, :kk]
@@ -543,6 +590,159 @@ class VectorPool:
                                                    np.float32))}
         idx._vpool_brute_desc = hit
         return hit
+
+    # -- flat scan ----------------------------------------------------------
+
+    def _flat_upload(self, seg, metric: str):
+        """(vectors, per-row factor) of one flat segment on the device:
+        the segment's own (n, dim) array as it stands — no pad, no
+        second host copy — and the factor `flat_aux_program` computes
+        from it there."""
+        from ..columnar.device import commit_host_array
+        t0 = time.perf_counter_ns()
+        vecs = commit_host_array(seg.vals)
+        live = np.ones(len(seg.vals), bool) if seg.valid is None \
+            else np.ascontiguousarray(seg.valid, bool)
+        prog = obs_device.compiled(
+            "knn_flat_aux", (metric, vecs.shape),
+            lambda: vops.flat_aux_program(metric))
+        aux = prog(vecs, commit_host_array(live))
+        tr = current_trace()
+        if tr is not None:
+            tr.add("vector_upload", "device", t0, time.perf_counter_ns(),
+                   bytes=int(seg.vals.nbytes))
+        return vecs, aux
+
+    def _ensure_flat(self, idx, seg) -> Optional[_Entry]:
+        """The segment's resident entry, uploading it on first touch and
+        evicting least-recently-used FLAT entries past the device's
+        budget; None when it cannot fit at all (the caller scans a
+        per-call upload with the same program)."""
+        uid = self.seg_uid(seg)
+        need = int(seg.vals.nbytes) + 4 * len(seg.vals)
+        with self._lock:
+            e = self._entries.get(uid)
+            if e is not None:
+                metrics.VECTOR_POOL_HITS.add()
+                e.hits += 1
+                e.last_ns = time.perf_counter_ns()
+                self._entries.move_to_end(uid)
+                return e
+            budget = _flat_budget_bytes()
+            if need > budget:
+                return None
+            while self._flat_bytes() + need > budget:
+                victim = next((k for k, v in self._entries.items()
+                               if v.nbytes), None)
+                if victim is None:
+                    return None
+                self._entries.pop(victim)
+                metrics.VECTOR_POOL_EVICTIONS.add()
+            e = _Entry(uid, _NO_PAGES, len(seg.vals), int(idx.dim),
+                       next(self._stamp), getattr(idx, "_pool_pub", None),
+                       arrays=self._flat_upload(seg, idx.metric))
+            self._entries[uid] = e
+            metrics.VECTOR_POOL_MISSES.add()
+            self._set_resident()
+            return e
+
+    def _flat_program(self, idx, n: int, rung: int, kkp: int):
+        return obs_device.compiled(
+            "knn_flat_scan", (idx.metric, n, int(idx.dim), kkp, rung),
+            lambda: vops.flat_scan_program(idx.metric, n, int(idx.dim),
+                                           kkp))
+
+    def flat_prebuild(self, idx, k: int = 10) -> int:
+        """Upload every segment of a flat index and build the closed set
+        of programs its searches dispatch — one per batch rung at the
+        first top-k bucket — by running each once on a zero query, so
+        that no search builds one. Returns how many this call built."""
+        import jax
+        if not enabled():
+            return 0
+        kkp = _pow2(max(int(k), 1), 8)
+        built = 0
+        with obs_device.announced_builds():
+            for seg in idx.segs:
+                e = self._ensure_flat(idx, seg) if len(seg.vals) else None
+                if e is None:
+                    continue
+                for rung in vops.FLAT_RUNGS:
+                    prog = self._flat_program(idx, e.n, rung, kkp)
+                    if not prog.called:
+                        jax.block_until_ready(prog(
+                            *e.arrays,
+                            np.zeros((rung, int(idx.dim)), np.float32)))
+                        built += 1
+        metrics.VECTOR_PROGRAMS_PREBUILT.add(built)
+        return built
+
+    def flat_search(self, idx, queries: np.ndarray, k: int):
+        """Exact top-k over every row of a flat index: per segment ONE
+        dispatch of the prebuilt program of the batch's rung (a batch
+        past the largest rung is split), the segments' top-k merged on
+        the host by (distance, row). Returns (dists (nq, kk) f32, rows
+        (nq, kk) i32); dead lanes carry (+inf, _PAD_ROW)."""
+        from ..columnar.device import commit_host_array
+        from ..obs.trace import stage
+        faults.if_failure("vector_dispatch")
+        nq = queries.shape[0]
+        ntot = sum(len(s.vals) for s in idx.segs)
+        kk = min(max(int(k), 1), max(ntot, 1))
+        kkp = _pow2(kk, 8)
+        top = vops.FLAT_RUNGS[-1]
+        parts_d, parts_r = [], []
+        with stage("device_prepare"):
+            ents = []
+            for seg in idx.segs:
+                if not len(seg.vals):
+                    continue
+                e = self._ensure_flat(idx, seg) if enabled() else None
+                ents.append((seg, e.arrays if e is not None else
+                             self._flat_upload(seg, idx.metric)))
+        for at in range(0, nq, top):
+            chunk = queries[at:at + top]
+            with stage("device_prepare"):
+                rung = vops.flat_rung(len(chunk))
+                q = np.zeros((rung, int(idx.dim)), np.float32)
+                q[:len(chunk)] = chunk
+                qd = commit_host_array(q)
+            t0 = time.perf_counter_ns()
+            outs = []
+            for seg, arrays in ents:
+                prog = self._flat_program(idx, len(seg.vals), rung, kkp)
+                outs.append(prog(*arrays, qd))
+                metrics.VECTOR_SEARCH_DISPATCHES.add()
+                metrics.VECTOR_ROWS_SCANNED.add(len(seg.vals))
+            got = [obs_device.fetch_all(o) for o in outs]
+            with stage("device_finalize"):
+                ds, rs = [], []
+                for (seg, _), (d, r) in zip(ents, got):
+                    live = r != _PAD_ROW
+                    ds.append(d[:len(chunk)])
+                    rs.append(np.where(live, r + np.int32(seg.base),
+                                       _PAD_ROW)[:len(chunk)])
+                if len(ds) == 1:
+                    d, r = ds[0], rs[0]
+                elif ds:
+                    d = np.concatenate(ds, axis=1)
+                    r = np.concatenate(rs, axis=1)
+                    order = np.lexsort((r, d), axis=1)
+                    d = np.take_along_axis(d, order, axis=1)
+                    r = np.take_along_axis(r, order, axis=1)
+                else:
+                    d = np.full((len(chunk), kk), np.inf, np.float32)
+                    r = np.full((len(chunk), kk), _PAD_ROW, np.int32)
+                parts_d.append(d[:, :kk])
+                parts_r.append(r[:, :kk])
+            tr = current_trace()
+            if tr is not None:
+                tr.add("vector_dispatch", "device", t0,
+                       time.perf_counter_ns(), queries=len(chunk),
+                       kind="flat", rung=rung)
+        metrics.VECTOR_SEARCH_QUERIES.add(nq)
+        metrics.VECTOR_QUERIES_SCORED_FLAT.add(nq)
+        return np.concatenate(parts_d), np.concatenate(parts_r)
 
     # -- MaxSim -----------------------------------------------------------
 
@@ -596,6 +796,7 @@ class VectorPool:
                    time.perf_counter_ns(), queries=b, kind="maxsim",
                    resident=res is not None)
         metrics.VECTOR_SEARCH_QUERIES.add(b)
+        metrics.VECTOR_QUERIES_SCORED_PROBE.add(b)
         metrics.VECTOR_SEARCH_DISPATCHES.add()
         metrics.VECTOR_PROBED_CLUSTERS.add(b * ndocs)
         return keys[:b, :kk], rows[:b, :kk]
@@ -626,10 +827,12 @@ class VectorPool:
         """Region HBM bytes per holding device — merged into the
         sdb_device() hbm_bytes_est column (obs/device.device_rows)."""
         with self._lock:
-            if self._region_arr is None:
+            flat = self._flat_bytes()
+            if self._region_arr is None and not flat:
                 return {}
-            ids = obs_device.array_device_ids(self._region_arr) or (0,)
-            total = self._n_pages * PAGE_F32 * 4
+            ids = (0,) if self._region_arr is None else \
+                obs_device.array_device_ids(self._region_arr) or (0,)
+            total = self._n_pages * PAGE_F32 * 4 + flat
             return {int(i): total // len(ids) for i in ids}
 
     def snapshot(self) -> list[dict]:
@@ -647,7 +850,7 @@ class VectorPool:
                     "segment": uid,
                     "vectors": int(e.n),
                     "pages": len(e.slots),
-                    "bytes": len(e.slots) * PAGE_F32 * 4,
+                    "bytes": len(e.slots) * PAGE_F32 * 4 + e.nbytes,
                     "hits": int(e.hits),
                     "idle_ms": round((now - e.last_ns) / 1e6, 3)})
         rows.sort(key=lambda r: (r["token"], r["segment"]))
@@ -668,7 +871,8 @@ class VectorPool:
         or None when the pool is empty."""
         with self._lock:
             for e in self._entries.values():
-                return time.perf_counter_ns() - e.last_ns
+                if not e.nbytes:
+                    return time.perf_counter_ns() - e.last_ns
             return None
 
     def shed_colder(self, idle_ns: int, need_bytes: int) -> int:
@@ -683,6 +887,8 @@ class VectorPool:
             while freed < need_bytes:
                 victim = None
                 for key, e in self._entries.items():
+                    if e.nbytes:    # flat: not of this envelope
+                        continue
                     if now - e.last_ns > idle_ns:
                         victim = key
                     break           # LRU head only: warmer head ends it
@@ -690,11 +896,10 @@ class VectorPool:
                     break
                 e = self._entries.pop(victim)
                 self._free.extend(e.slots.tolist())
-                freed += len(e.slots) * PAGE_F32 * 4
+                freed += len(e.slots) * PAGE_F32 * 4 + e.nbytes
                 metrics.VECTOR_POOL_EVICTIONS.add()
-            if freed and self._n_pages:
-                used = self._n_pages - len(self._free)
-                metrics.VECTOR_BYTES_RESIDENT.set(used * PAGE_F32 * 4)
+            if freed:
+                self._set_resident()
         return freed
 
     def stats(self) -> dict:
@@ -706,6 +911,16 @@ class VectorPool:
                     "pages_used": used,
                     "page_bytes": PAGE_F32 * 4,
                     "resident_segments": len(self._entries),
+                    "flat_bytes": self._flat_bytes(),
+                    "flat_budget_bytes": _flat_budget_bytes(),
+                    "queries_flat": int(
+                        metrics.VECTOR_QUERIES_SCORED_FLAT.value),
+                    "queries_probe": int(
+                        metrics.VECTOR_QUERIES_SCORED_PROBE.value),
+                    "rows_scanned": int(
+                        metrics.VECTOR_ROWS_SCANNED.value),
+                    "programs_prebuilt": int(
+                        metrics.VECTOR_PROGRAMS_PREBUILT.value),
                     "hits": int(metrics.VECTOR_POOL_HITS.value),
                     "misses": int(metrics.VECTOR_POOL_MISSES.value),
                     "evictions": int(

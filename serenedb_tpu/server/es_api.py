@@ -117,15 +117,37 @@ class EsApi:
                 .get("properties", {}) or {}
             t = self._table(index)
             for fname, fdef in props.items():
-                ftype = (fdef or {}).get("type", "text")
-                self._ensure_column(t, fname, _es_type_to_sql(ftype),
-                                    text_index=(ftype != "dense_vector"))
-                if ftype == "dense_vector":
-                    dims = int((fdef or {}).get("dims", 0))
-                    opts = f" WITH (dim = {dims})" if dims else ""
-                    self.conn.execute(
-                        f'CREATE INDEX ON {_ident(t.name)} USING ivf '
-                        f'({_ident(fname)}){opts}')
+                fdef = fdef or {}
+                ftype = fdef.get("type", "text")
+                if ftype != "dense_vector":
+                    self._ensure_column(t, fname, _es_type_to_sql(ftype))
+                    continue
+                # dense_vector: `dims`, `similarity` and
+                # `index_options.type` as declared. `flat` (ES's exact
+                # brute-force index) is a typed VECTOR(dims) column under
+                # a flat index, cosine unless told otherwise (ES's
+                # default); anything else keeps the JSON-text column and
+                # the IVF index with its defaults (64 lists, l2)
+                dims = int(fdef.get("dims", 0))
+                sim = fdef.get("similarity")
+                if sim is not None and sim not in _ES_SIMILARITY:
+                    raise EsError(400, "mapper_parsing_exception",
+                                  f"unknown similarity [{sim}]")
+                flat = (fdef.get("index_options") or {}).get("type") \
+                    == "flat" and dims > 0
+                self._ensure_column(
+                    t, fname, dt.vector_of(dims) if flat else dt.VARCHAR,
+                    text_index=False)
+                opts = [f"dim = {dims}"] if dims else []
+                if flat:
+                    opts.append("type = 'flat'")
+                    sim = sim or "cosine"
+                if sim is not None:
+                    opts.append(f"metric = '{_ES_SIMILARITY[sim]}'")
+                self.conn.execute(
+                    f'CREATE INDEX ON {_ident(t.name)} USING ivf '
+                    f'({_ident(fname)})'
+                    + (f" WITH ({', '.join(opts)})" if opts else ""))
         return {"acknowledged": True, "shards_acknowledged": True,
                 "index": index}
 
@@ -148,6 +170,8 @@ class EsApi:
             if name.startswith("_"):
                 continue
             props[name] = {"type": _sql_type_to_es(typ)}
+            if typ.is_vector:
+                props[name]["dims"] = typ.dim
         return {index: {"mappings": {"properties": props}}}
 
     def _ensure_column(self, t: MemTable, name: str, typ: dt.SqlType,
@@ -398,7 +422,7 @@ class EsApi:
         size = int(body.get("size", 10))
         from_ = int(body.get("from", 0))
         if "knn" in body:
-            return self._search_knn(index, body, size, from_)
+            return self._search_knn(index, body, size, from_, trace)
         if "_id" not in t.column_names or "_source" not in t.column_names:
             # a plain SQL table is not an ES document index — surface a
             # clear contract error instead of a cryptic 42703
@@ -522,41 +546,72 @@ class EsApi:
         return seq[:needed]
 
     def _search_knn(self, index: str, body: dict, size: int,
-                    from_: int) -> dict:
+                    from_: int, trace=None) -> dict:
         """kNN search, optionally hybrid with a text query via RRF fusion
-        (reference BASELINE config 5: BM25 + kNN with RRF top-k)."""
+        (reference BASELINE config 5: BM25 + kNN with RRF top-k). The
+        metric is the INDEX's (`similarity` of the mapping), `_score` is
+        ES's for it, and the query vector reaches the scan as an array
+        parameter, never as SQL text."""
+        import numpy as np
+        from ..obs.trace import stage_of
+        from ..search.ivf import declared_ivf_index
         knn = body["knn"]
         field = knn.get("field")
-        qvec = json.dumps(knn.get("query_vector", []))
+        t = self._table(index)
+        with stage_of(trace, "fd_parse"):
+            try:
+                qvec = np.asarray(knn.get("query_vector", []),
+                                  dtype=np.float32)
+            except (ValueError, TypeError):
+                qvec = None
+        if qvec is None or qvec.ndim != 1:
+            raise EsError(400, "illegal_argument_exception",
+                          "[query_vector] must be a flat array of numbers")
         k = int(knn.get("k", size))
         cand = max(k, int(knn.get("num_candidates", k * 4)))
-        dist = f"vec_l2({_ident(field)}, {_sql_str(qvec)})"
+        idx = declared_ivf_index(t, field)
+        metric = idx.metric if idx is not None else "l2"
+        hybrid = body.get("query") is not None
+        # an exact index has no candidates to widen: the page is all a
+        # pure knn needs, `_source` included
+        limit = min(k, from_ + size) \
+            if idx is not None and idx.flat and not hybrid else cand
+        dist = f"{_KNN_FUNC[metric]}({_ident(field)}, $1)"
         # no IS NOT NULL guard: it would block the IvfScan pushdown, and
         # both paths already handle NULL vectors (valid mask / NULLS LAST)
         sql = (f'SELECT "_id", "_source", {dist} AS _dist FROM '
                f'{_ident(index)} '
-               f"ORDER BY _dist LIMIT {cand}")
+               f"ORDER BY _dist LIMIT {limit}")
         nprobe = knn.get("nprobe")
         conn = self._rconn()
         if nprobe is not None:
             conn.execute(f"SET serene_nprobe = {int(nprobe)}")
         try:
-            knn_rows = [r for r in conn.execute(sql).rows()
-                        if r[2] is not None]
+            from ..sql import parser
+            with stage_of(trace, "fd_parse"):
+                (st,) = parser.parse(sql)
+            res = conn.execute_statement(st, [qvec], sql_text=sql,
+                                         trace=trace)
+            with stage_of(trace, "fd_encode"):
+                knn_rows = [r for r in res.rows() if r[2] is not None]
         finally:
             if nprobe is not None:
                 # 0 = back to the sdb_nprobe / built-in default chain
                 conn.execute("SET serene_nprobe = 0")
         knn_ranked = [(r[0], r[1]) for r in knn_rows]
-        if body.get("query") is None:
+        if not hybrid:
             hits = []
             page = knn_ranked[:k][from_:from_ + size]
-            for off, (doc_id, src) in enumerate(page):
-                d = float(knn_rows[from_ + off][2])
-                hits.append({"_index": index, "_id": doc_id,
-                             "_score": 1.0 / (1.0 + d),
-                             "_source": json.loads(src) if src else {}})
-            return _hits_response(hits, min(len(knn_ranked), k))
+            with stage_of(trace, "fd_encode"):
+                for off, (doc_id, src) in enumerate(page):
+                    d = float(knn_rows[from_ + off][2])
+                    hits.append({"_index": index, "_id": doc_id,
+                                 "_score": _knn_score(metric, d),
+                                 "_source": json.loads(src) if src
+                                 else {}})
+            return _hits_response(
+                hits, min(len(knn_ranked), k) if limit >= k
+                else min(k, idx.num_rows))
         # hybrid: text query ranking + knn ranking → reciprocal rank fusion
         text_res = self.search(index, {"query": body["query"],
                                        "size": cand, "from": 0})
@@ -1144,6 +1199,22 @@ def _value_sql_type(v) -> dt.SqlType:
     return dt.VARCHAR
 
 
+#: ES `similarity` of a dense_vector → the index's metric
+_ES_SIMILARITY = {"cosine": "cos", "dot_product": "ip", "l2_norm": "l2"}
+_KNN_FUNC = {"l2": "vec_l2", "ip": "vec_ip", "cos": "vec_cos"}
+
+
+def _knn_score(metric: str, d: float) -> float:
+    """ES's `_score` of a knn hit from the scan's distance: cosine
+    (d = 1 - cos) → (1 + cos) / 2; dot_product (d = -dot) →
+    (1 + dot) / 2; l2_norm (d = squared L2) → 1 / (1 + d)."""
+    if metric == "cos":
+        return (2.0 - d) / 2.0
+    if metric == "ip":
+        return (1.0 - d) / 2.0
+    return 1.0 / (1.0 + d)
+
+
 def _es_type_to_sql(es_type: str) -> dt.SqlType:
     return {
         "text": dt.VARCHAR, "keyword": dt.VARCHAR, "long": dt.BIGINT,
@@ -1160,6 +1231,7 @@ def _sql_type_to_es(t: dt.SqlType) -> str:
         dt.TypeId.TINYINT: "byte", dt.TypeId.DOUBLE: "double",
         dt.TypeId.FLOAT: "float", dt.TypeId.BOOL: "boolean",
         dt.TypeId.TIMESTAMP: "date", dt.TypeId.DATE: "date",
+        dt.TypeId.VECTOR: "dense_vector",
     }.get(t.id, "text")
 
 

@@ -304,13 +304,15 @@ class Router:
             return 200, es.update_doc(index, rest[1],
                                       _json_body(body) or {}), JSON_CTYPE
         if verb == "_search":
-            b = _json_body(body)
             if "scroll" in q:
                 return 200, es.search_scroll_start(
-                    index, b, q["scroll"][0]), JSON_CTYPE
-            return 200, es.search(
-                index, b, es.begin_request(f"{method} /{index}/_search",
-                                           clock)), JSON_CTYPE
+                    index, _json_body(body), q["scroll"][0]), JSON_CTYPE
+            # the body's JSON (9 KB of it under a knn query vector) is
+            # the request's `fd_parse`, as the SQL text's parse is
+            tr = es.begin_request(f"{method} /{index}/_search", clock)
+            with stage_of(tr, "fd_parse"):
+                b = _json_body(body)
+            return 200, es.search(index, b, tr), JSON_CTYPE
         if verb == "_mget" and method == "POST":
             return 200, es.mget(index, _json_body(body) or {}), JSON_CTYPE
         if verb == "_msearch" and method == "POST":

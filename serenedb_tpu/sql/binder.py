@@ -154,6 +154,10 @@ def literal_type(v) -> dt.SqlType:
         return dt.BOOL
     if isinstance(v, int):
         return dt.INT if -2**31 <= v < 2**31 else dt.BIGINT
+    if isinstance(v, np.ndarray) and v.ndim == 1:
+        # a vector handed over as a parameter (the ES knn route): never
+        # printed into SQL text and parsed back
+        return dt.vector_of(len(v))
     return _LIT_TYPE.get(type(v), dt.VARCHAR)
 
 
@@ -1054,6 +1058,22 @@ def cast_column(col: Column, target: dt.SqlType) -> Column:
         raise errors.SqlError(
             "42846", f"cannot cast type {src} to {target}")
     validity = col.validity
+    if target.is_vector:
+        # text ('[v1,...]', pgvector's form and a JSON array), another
+        # VECTOR of the same size, or an ARRAY's JSON payload
+        if src.is_vector:
+            if src.dim != target.dim:
+                raise errors.SqlError(
+                    errors.DATATYPE_MISMATCH,
+                    f"expected {target.dim} dimensions, got {src.dim}")
+            return Column(target, col.data, validity)
+        if src.is_string:
+            return Column.from_pylist(col.to_pylist(), target)
+        raise errors.SqlError(
+            "42846", f"cannot cast type {src} to {target}")
+    if src.is_vector and not target.is_string:
+        raise errors.SqlError(
+            "42846", f"cannot cast type {src} to {target}")
     _REG = (dt.TypeId.REGCLASS, dt.TypeId.REGTYPE, dt.TypeId.REGPROC,
             dt.TypeId.REGNAMESPACE)
     if target.id in _REG and src.is_string:
@@ -1244,6 +1264,8 @@ def _cast_text_to(v: str, target: dt.SqlType):
             return int(d64.astype(np.int64))
         if target.id is dt.TypeId.INTERVAL:
             return parse_interval(s)
+        if target.is_vector:
+            return s              # Column.from_pylist parses the text
     except ValueError:
         raise errors.SqlError(errors.INVALID_TEXT_REPRESENTATION,
                               f'invalid input syntax for type {target}: "{v}"')

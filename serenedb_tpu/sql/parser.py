@@ -831,13 +831,19 @@ class Parser:
             self.expect_kw("TIME")
             self.expect_kw("ZONE")
         if self.accept_op("("):  # VARCHAR(n), DECIMAL(p,s) — swallow params
+            params = []
             while not self.at_op(")"):
-                self.next()
+                params.append(str(self.next().value))
             self.expect_op(")")
-        if self.at_op("["):      # INT[] array type
+            if name.upper() == "VECTOR":    # VECTOR(n): n is the type
+                name = f"VECTOR({''.join(params)})"
+        if self.at_op("["):      # INT[] array type; FLOAT4[n] = VECTOR(n)
             self.next()
+            size = ""
+            if not self.at_op("]"):
+                size = str(self.next().value)
             self.expect_op("]")
-            name = name + "[]"
+            name = f"{name}[{size}]"
         return name
 
     def parse_primary(self) -> ast.Expr:

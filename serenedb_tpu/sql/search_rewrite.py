@@ -19,6 +19,7 @@ from typing import Optional
 
 import numpy as np
 
+from .. import errors
 from ..columnar import dtypes as dt
 from ..exec.plan import (AggregateNode, DropColumnsNode, FilterNode, JoinNode,
                          LimitNode, PlanNode, ProjectNode, ScanNode, SortNode)
@@ -135,7 +136,8 @@ def _match_ann_topk(plan: PlanNode, limit, sort, proj,
         return None
     col, lit = key_expr.args
     if not (isinstance(col, BoundColumn) and
-            isinstance(lit, BoundLiteral) and isinstance(lit.value, str)):
+            isinstance(lit, BoundLiteral) and
+            isinstance(lit.value, (str, np.ndarray))):
         return None
     if not isinstance(proj.child, ScanNode):
         return None
@@ -149,7 +151,14 @@ def _match_ann_topk(plan: PlanNode, limit, sort, proj,
     metric = {"vec_l2": "l2", "vec_ip": "ip", "vec_cos": "cos"}[key_expr.name]
     if idx.metric != metric:
         return None
-    qvec = parse_vector(lit.value, idx.dim)
+    if isinstance(lit.value, str):
+        qvec = parse_vector(lit.value, idx.dim)
+    else:                       # an array parameter: as it stands
+        if len(lit.value) != idx.dim:
+            raise errors.SqlError(
+                errors.DATATYPE_MISMATCH,
+                f"expected {idx.dim} dimensions, got {len(lit.value)}")
+        qvec = np.ascontiguousarray(lit.value, np.float32)
     k = limit.limit + limit.offset
     node = IvfScanNode(scan.provider, scan.columns, scan.alias, vec_col,
                        qvec, k)
@@ -163,7 +172,9 @@ def _match_ann_topk(plan: PlanNode, limit, sort, proj,
                     isinstance(e.args[0], BoundColumn) and \
                     e.args[0].index == col.index and \
                     isinstance(e.args[1], BoundLiteral) and \
-                    e.args[1].value == lit.value:
+                    (e.args[1].value is lit.value or (
+                        isinstance(lit.value, str) and
+                        e.args[1].value == lit.value)):
                 return dist_ref
             e.args = [rec(a) for a in e.args]
         return e

@@ -381,10 +381,30 @@ VECTOR_PROBED_CLUSTERS = REGISTRY.gauge(
     "VectorProbedClusters",
     "IVF cluster lists probed across all vector queries (queries x "
     "effective nprobe) — the work that scales with nprobe, not N")
+VECTOR_QUERIES_SCORED_FLAT = REGISTRY.gauge(
+    "VectorQueriesScoredFlat",
+    "knn queries scored by the exact flat scan (family knn_flat_scan: "
+    "every row of a flat index read once a dispatch); with "
+    "VectorQueriesScoredProbe a partition of the knn queries scored")
+VECTOR_QUERIES_SCORED_PROBE = REGISTRY.gauge(
+    "VectorQueriesScoredProbe",
+    "knn queries scored by the IVF cluster probe (vector_probe) or the "
+    "MaxSim program")
+VECTOR_ROWS_SCANNED = REGISTRY.gauge(
+    "VectorRowsScanned",
+    "rows a flat-scan dispatch read from the resident segment, counted "
+    "once per DISPATCH, not per query: the bytes knn_roofline divides "
+    "by the HBM peak are these rows x dims x 4")
+VECTOR_PROGRAMS_PREBUILT = REGISTRY.gauge(
+    "VectorProgramsPrebuilt",
+    "flat-scan programs (one per batch rung) built by CREATE INDEX or a "
+    "refresh before the index answered a search "
+    "(SearchProgramsPrebuilt's sibling)")
 VECTOR_BYTES_RESIDENT = REGISTRY.gauge(
     "VectorBytesResident",
-    "bytes of the device vector region currently occupied by resident "
-    "segments (live pages x page size; budget is serene_vector_pages)")
+    "bytes of device memory the vector pool's resident segments hold: "
+    "live pages x page size of the paged region (IVF, MaxSim) plus the "
+    "unpadded arrays of flat segments")
 VECTOR_POOL_HITS = REGISTRY.gauge(
     "VectorPoolHits",
     "vector-pool segment lookups served by pages already resident in "
