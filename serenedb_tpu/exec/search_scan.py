@@ -65,8 +65,9 @@ class SearchScanNode(PlanNode):
     def _matching_docs(self, searcher) -> np.ndarray:
         """Doc selection with PG NULL semantics: a predicate over a NULL
         text value is NULL, never true — negation queries must not surface
-        NULL rows. The count fast path shares this exact logic. The
-        doc-set algebra runs on the host: the request's `host_scan`."""
+        NULL rows. The count fast path (`count_matching`) keeps the same
+        rule without building the set. The doc-set algebra runs on the
+        host: the request's `host_scan`."""
         from ..obs.trace import stage
         with stage("host_scan"):
             docs = searcher.eval_filter(self.qnode)
@@ -77,13 +78,20 @@ class SearchScanNode(PlanNode):
 
     def count_matching(self):
         """Row count without materialization (reference: ScanMode::Count);
-        None when not applicable (top-k or residual present)."""
+        None when not applicable (top-k or residual present). The
+        searcher COUNTS (`count_filter`): a union of posting lists is
+        OR-ed doc bitsets and a popcount, no sorted doc set; the column's
+        validity goes with it, so `_matching_docs`' NULL rule holds.
+        Still the request's `host_scan`."""
         if self.residual is not None or self.topk is not None:
             return None
         searcher = self._searcher()
         if searcher is None:
             return None
-        return len(self._matching_docs(searcher))
+        from ..obs.trace import stage
+        with stage("host_scan"):
+            col = self.provider.host_column(self.search_column)
+            return searcher.count_filter(self.qnode, col.validity)
 
     def batches(self, ctx):
         from .plan import check_cancel

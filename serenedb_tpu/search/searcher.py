@@ -16,6 +16,7 @@ included); NOT-subtrees and phrase adjacency affect *matching* only.
 
 from __future__ import annotations
 
+import functools
 import threading
 import time
 from dataclasses import dataclass
@@ -63,12 +64,31 @@ def _maxscore_split(plan) -> set:
     return non_ess
 
 
+def _bits_of(flags: np.ndarray) -> np.ndarray:
+    """Bool flags packed into a doc bitset: `ceil(len / 64)` uint64 words,
+    doc d at bit (d & 7) of BYTE d >> 3 (so a bit test reads the byte
+    view and no word order enters)."""
+    packed = np.packbits(flags, bitorder="little")
+    out = np.zeros(-(-len(flags) // 64), dtype=np.uint64)
+    out.view(np.uint8)[:len(packed)] = packed
+    return out
+
+
+def _bits_at(bits: np.ndarray, ids: np.ndarray) -> np.ndarray:
+    """Bool per id: is its bit set in the doc bitset."""
+    return (bits.view(np.uint8)[ids >> 3] >> (ids & 7).astype(np.uint8)) \
+        & 1 != 0
+
+
 class SegmentSearcher:
     def __init__(self, index: FieldIndex, analyzer: Analyzer, num_docs: int):
         self.index = index
         self.analyzer = analyzer
         self.num_docs = num_docs
         self._dev = None
+        # tid → doc bitset of a dense term (`count_filter`)
+        self._doc_bits: dict[int, np.ndarray] = {}
+        self._doc_bits_lock = threading.Lock()
 
     # -- device posting store (lazy, cached) ------------------------------
 
@@ -166,6 +186,109 @@ class SegmentSearcher:
         parts = [self.index.postings(t)[0] for t in tids]
         return np.unique(np.concatenate(parts)) if parts \
             else np.empty(0, dtype=np.int32)
+
+    # -- counting (the exact total of a search, no doc set built) ---------
+
+    def _union_term_ids(self, node: QNode) -> Optional[list]:
+        """Term ids whose posting lists' union IS the node's doc set, or
+        None where the node is not such a union (conjunction, negation,
+        phrase, an unknown shape)."""
+        if isinstance(node, QTerm):
+            tid = self.index.term_id(node.term)
+            return [tid] if tid >= 0 else []
+        if isinstance(node, QPrefix):
+            return list(self.index.prefix_term_ids(node.prefix))
+        if isinstance(node, QFuzzy):
+            return list(self._fuzzy_term_ids(node))
+        if isinstance(node, QRegex):
+            return list(self._regex_term_ids(node))
+        if isinstance(node, QNothing):
+            return []
+        if isinstance(node, QOr):
+            out: list = []
+            for a in node.args:
+                tids = self._union_term_ids(a)
+                if tids is None:
+                    return None
+                out.extend(tids)
+            return out
+        return None
+
+    def _dense_terms(self, tids: np.ndarray) -> np.ndarray:
+        """Bool per term id: its doc bitset is no larger than its own
+        int32 posting list (`doc_freq * 32 >= num_docs`, in whole
+        words). Those keep a bitset, so all of a segment's bitsets
+        together never exceed the bytes of `post_docs`."""
+        row_bytes = -(-self.num_docs // 64) * 8
+        return self.index.doc_freq[tids].astype(np.int64) * 4 >= row_bytes
+
+    def _term_bits(self, tid: int) -> np.ndarray:
+        """A dense term's doc bitset, built once: segments are immutable
+        (the pattern is `FieldIndex.ctf`'s). `prebuild` builds them all;
+        a segment that never went through it builds on first use."""
+        row = self._doc_bits.get(tid)
+        if row is None:
+            flags = np.zeros(self.num_docs, dtype=bool)
+            flags[self.index.postings(tid)[0]] = True
+            row = _bits_of(flags)
+            with self._doc_bits_lock:
+                if tid in self._doc_bits:
+                    return self._doc_bits[tid]
+                self._doc_bits[tid] = row
+            metrics.SEARCH_COUNT_BITSET_BYTES.add(row.nbytes)
+        return row
+
+    @property
+    def count_bitset_bytes(self) -> int:
+        return sum(r.nbytes for r in list(self._doc_bits.values()))
+
+    def count_filter(self, node: QNode,
+                     valid_bits: Optional[np.ndarray] = None) -> int:
+        """`len(eval_filter(node))` without the doc set, where the node
+        is a union of posting lists (a term, a disjunction of such, a
+        prefix / fuzzy / regex expansion): the dense terms' bitsets are
+        OR-ed into one accumulator and its set bits counted, then the
+        sparse terms' (short) lists add the ids whose bit is not set.
+        Every other shape takes the length of its doc set, as before,
+        and so does a union whose doc set the fragment cache already
+        holds (a Stream scan left it: that is the cache's hit).
+        `valid_bits`: the doc bitset of the column's non-NULL rows in
+        this segment's doc space, AND-ed in (a predicate over a NULL
+        text is never true)."""
+        from ..cache.fragments import FRAGMENTS, qnode_sig
+        tids = self._union_term_ids(node)
+        held = None
+        if tids is not None:
+            sig = qnode_sig(node)
+            held = FRAGMENTS.probe(
+                self, None if sig is None else ("filter", sig))
+        if tids is None or held is not None:
+            metrics.SEARCH_COUNT_MATERIALIZED.add()
+            if held is not None:
+                FRAGMENTS.count_hits(1)
+            docs = held if held is not None else self.eval_filter(node)
+            if valid_bits is not None and len(docs):
+                return int(_bits_at(valid_bits, docs).sum())
+            return len(docs)
+        metrics.SEARCH_COUNT_BITSET.add()
+        # a term given twice counts once
+        tids = np.unique(np.asarray(tids, dtype=np.int64))
+        dense = self._dense_terms(tids)
+        acc = functools.reduce(
+            np.bitwise_or, [self._term_bits(int(t)) for t in tids[dense]],
+            np.zeros(-(-self.num_docs // 64), dtype=np.uint64))
+        if valid_bits is not None:
+            acc &= valid_bits
+        n = int(np.bitwise_count(acc).sum())
+        sparse = [self.index.postings(int(t))[0] for t in tids[~dense]]
+        if sparse:
+            ids = sparse[0] if len(sparse) == 1 \
+                else np.unique(np.concatenate(sparse))
+            fresh = ~_bits_at(acc, ids)
+            if valid_bits is not None:
+                fresh &= _bits_at(valid_bits, ids)
+            n += int(fresh.sum())
+        return n
 
     def _eval_phrase(self, groups: list[list[str]],
                      slop: int = 0) -> np.ndarray:
@@ -393,10 +516,14 @@ class SegmentSearcher:
         of programs its searches dispatch (ops/bm25.py), so that none is
         built by a search: the dense steps where the saturation matrix
         fits, else the plane kernel's accumulate and top-k steps, for
-        every rung up to the batcher's cap at the first top-k bucket.
+        every rung up to the batcher's cap at the first top-k bucket;
+        and the dense terms' doc bitsets that `count_filter` ORs.
         Returns how many programs this call built."""
         if self.num_docs == 0:
             return 0
+        every = np.arange(len(self.index.doc_freq))
+        for tid in every[self._dense_terms(every)]:
+            self._term_bits(int(tid))
         store = self._device_store()
         avgdl = self.index.avgdl if avgdl is None else avgdl
         kk = min(bm25_ops.pad_k(1), store.ndocs_pad)
@@ -962,6 +1089,8 @@ class MultiSearcher:
     def __init__(self, analyzer: Analyzer):
         self.analyzer = analyzer
         self.segments: list[tuple[SegmentSearcher, int]] = []  # (seg, base)
+        # (a column's validity array, its doc bitset per segment)
+        self._valid_bits: Optional[tuple] = None
 
     def add_segment(self, searcher: SegmentSearcher, base_row: int):
         self.segments.append((searcher, base_row))
@@ -1000,6 +1129,24 @@ class MultiSearcher:
                 parts.append(local.astype(np.int64) + base)
         return np.concatenate(parts).astype(np.int64) if parts \
             else np.empty(0, dtype=np.int64)
+
+    def count_filter(self, node: QNode,
+                     validity: Optional[np.ndarray] = None) -> int:
+        """`len(eval_filter(node))` over the rows `validity` (the
+        column's, by global row; None: every row) does not mark NULL:
+        the sum of the segments' counts, their doc spaces being
+        disjoint. The validity is packed into per-segment doc bitsets
+        once per validity array (a pinned column keeps its own)."""
+        bits = [None] * len(self.segments)
+        if validity is not None:
+            cached = self._valid_bits
+            if cached is None or cached[0] is not validity:
+                cached = self._valid_bits = (validity, [
+                    _bits_of(validity[base:base + s.num_docs])
+                    for s, base in self.segments])
+            bits = cached[1]
+        return sum(s.count_filter(node, b)
+                   for (s, _), b in zip(self.segments, bits))
 
     def topk(self, node: QNode, k: int, scorer: str = "bm25",
              mesh_n: int = 0) -> tuple[np.ndarray, np.ndarray]:

@@ -368,6 +368,22 @@ SEARCH_PROGRAMS_PREBUILT = REGISTRY.gauge(
     "SearchProgramsPrebuilt",
     "scoring programs built by an index build or refresh before the "
     "index answered a search (SegmentSearcher.prebuild)")
+SEARCH_COUNT_BITSET = REGISTRY.gauge(
+    "SearchCountBitset",
+    "exact totals of a search answered by OR-ing doc bitsets "
+    "(SegmentSearcher.count_filter: the query is a union of posting "
+    "lists), one per segment asked; with SearchCountMaterialized a "
+    "partition of the segment-level counts")
+SEARCH_COUNT_MATERIALIZED = REGISTRY.gauge(
+    "SearchCountMaterialized",
+    "exact totals of a search taken as the length of the sorted doc "
+    "set: built for the count (conjunctions, negations, phrases) or "
+    "found in the fragment cache; one per segment asked")
+SEARCH_COUNT_BITSET_BYTES = REGISTRY.gauge(
+    "SearchCountBitsetBytes",
+    "bytes of dense-term doc bitsets built for count_filter (a term "
+    "whose bitset is no larger than its posting list keeps one for the "
+    "segment's life); accumulates over every segment built")
 VECTOR_SEARCH_QUERIES = REGISTRY.gauge(
     "VectorSearchQueries",
     "knn / MaxSim queries scored by the vector subsystem "

@@ -558,3 +558,76 @@ def test_delete_by_query_max_docs_and_bad_body(srv):
     assert body["count"] == 2
     st, _ = req(srv, "POST", "/dbm/_delete_by_query", "[1, 2]")
     assert st == 400
+
+
+@pytest.mark.parametrize("result_cache", [False, True],
+                         ids=["result_cache_off", "result_cache_on"])
+def test_the_total_of_a_match_is_one_number_on_every_route(srv,
+                                                           result_cache):
+    """`hits.total.value` of `_search`, `_count`, SQL count(*) and the
+    length of the Stream-mode row set agree for the same `match`, before
+    and after a refresh that adds a segment: the count is taken from doc
+    bitsets (`count_filter`), the rows from `eval_filter`."""
+    from serenedb_tpu.utils import metrics
+    from serenedb_tpu.utils.config import REGISTRY as SETTINGS
+    name = f"tot{int(result_cache)}"
+    # word → the rows that hold it: every d-th; "zephyr" is a sparse term
+    # (a handful of rows), the first ones dense
+    words = {"alpha": 1, "beta": 2, "river": 3, "stone": 5, "amber": 7,
+             "quill": 13, "zephyr": 97}
+
+    def body_of(i):
+        return " ".join(w for w, d in words.items() if i % d == 0)
+
+    c = srv.db.connect()
+    c.execute(f'CREATE TABLE {name} ("_id" VARCHAR, "_source" VARCHAR, '
+              "body VARCHAR)")
+
+    def insert(lo, hi):
+        c.execute(f"INSERT INTO {name} VALUES " + ", ".join(
+            f"('{i}', '{{}}', " +
+            ("NULL" if i % 11 == 5 else f"'{body_of(i)}'") + ")"
+            for i in range(lo, hi)))
+
+    def totals(question):
+        expected = sum(
+            1 for i in range(n_rows) if i % 11 != 5 and
+            set(question.split()) & set(body_of(i).split()))
+        st, res = req(srv, "POST", f"/{name}/_search",
+                      {"query": {"match": {"body": question}}, "size": 3})
+        assert st == 200 and res["hits"]["total"]["relation"] == "eq"
+        st, cnt = req(srv, "POST", f"/{name}/_count",
+                      {"query": {"match": {"body": question}}})
+        assert st == 200
+        pred = "body @@ '" + " | ".join(question.split()) + "'"
+        sql = c.execute(f"SELECT count(*) FROM {name} WHERE {pred}").scalar()
+        rows = c.execute(f'SELECT "_id" FROM {name} WHERE {pred}').rows()
+        assert res["hits"]["total"]["value"] == cnt["count"] == sql == \
+            len(rows) == expected
+        return expected
+
+    prior = SETTINGS.get_global("serene_result_cache")
+    SETTINGS.set_global("serene_result_cache", result_cache)
+    try:
+        n_rows = 260
+        insert(0, n_rows)
+        c.execute(f"CREATE INDEX {name}_body ON {name} USING inverted (body)")
+        b0 = metrics.SEARCH_COUNT_BITSET.value
+        m0 = metrics.SEARCH_COUNT_MATERIALIZED.value
+        for question in ("beta zephyr", "quill amber nosuchword", "zephyr"):
+            assert totals(question) > 0
+        assert metrics.SEARCH_COUNT_BITSET.value > b0
+        n_rows = 400
+        insert(260, n_rows)
+        req(srv, "POST", f"/{name}/_refresh")
+        from serenedb_tpu.search.index import find_index
+        t = srv.db.schemas["main"].tables[name]
+        assert len(find_index(t, "body").searcher("body").segments) == 2
+        for question in ("beta zephyr", "quill amber nosuchword", "zephyr"):
+            assert totals(question) > 0
+        # a disjunction of terms never builds its doc set to be counted
+        # (with the cache on, the Stream statement's set is found there)
+        if not result_cache:
+            assert metrics.SEARCH_COUNT_MATERIALIZED.value == m0
+    finally:
+        SETTINGS.set_global("serene_result_cache", prior)
