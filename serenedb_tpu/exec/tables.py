@@ -299,7 +299,10 @@ def _arrow_to_column(arr) -> Column:
             enc = enc.combine_chunks()
         codes = np.asarray(enc.indices, dtype=np.int64)
         dictionary = np.asarray(enc.dictionary.to_pylist(), dtype=object)
-        order = np.argsort(dictionary.astype(str), kind="stable")
+        # arrow sorts by UTF-8 bytes, which is code-point order, numpy's
+        # and Python's; numpy would sort a fixed-width unicode copy, 4 B x
+        # the LONGEST value for every value: tens of GiB for articles
+        order = np.asarray(pc.sort_indices(enc.dictionary)).astype(np.int64)
         remap = np.empty(len(order), dtype=np.int32)
         remap[order] = np.arange(len(order), dtype=np.int32)
         sorted_dict = dictionary[order]

@@ -239,7 +239,8 @@ TRACE_RING_CAP = 8192
 STAGES = ("fd_parse", "fd_queue", "fd_encode", "cache_probe", "plan",
           "device_prepare", "device_enqueue", "device_wait",
           "device_finalize", "host_scan", "host_concat", "host_group",
-          "host_sort", "batch_wait", "search_plan", "search_host_score")
+          "host_sort", "batch_wait", "search_plan", "search_phrase",
+          "search_host_score")
 
 _TRACE_IDS = itertools.count(1)
 

@@ -709,16 +709,23 @@ def accumulate_body(rung: Rung, ndocs_pad: int, with_hits: bool,
     return step
 
 
-def topk_body(ndocs_pad: int, nq: int, with_hits: bool, k: int):
-    """The traced body of the top-k step: require-mask, per-query top-k."""
-    def step(require, scores, *hits):
+def topk_body(ndocs_pad: int, nq: int, with_hits: bool, with_mask: bool,
+              k: int):
+    """The traced body of the top-k step: require-mask, then the
+    queries' own doc masks (`with_mask`: (nq, ndocs_pad) uint8 flags, a
+    phrase's match set; a row of ones for a query that has none), then
+    the per-query top-k. A masked-out document scores 0, as one that
+    misses a conjunction does."""
+    def step(require, scores, *rest):
         scores = scores.reshape(nq, ndocs_pad)
         if with_hits:
             need = require[:, None]
             scores = jnp.where(
                 jnp.logical_or(need <= 0,
-                               hits[0].reshape(nq, ndocs_pad) >= need),
+                               rest[0].reshape(nq, ndocs_pad) >= need),
                 scores, 0.0)
+        if with_mask:
+            scores = jnp.where(rest[-1] != 0, scores, 0.0)
         return jax.lax.top_k(scores, k)
 
     return step
@@ -738,29 +745,47 @@ def _accumulate_program(store: BlockStore, rung: Rung, with_hits: bool,
         tuple(range(11, 13 if with_hits else 12)))
 
 
-def _topk_program(ndocs_pad: int, nq: int, with_hits: bool, k: int):
+def _topk_program(ndocs_pad: int, nq: int, with_hits: bool, k: int,
+                  with_mask: bool = False):
     return obs_device.compiled(
-        "bm25_topk", (ndocs_pad, nq, with_hits, k),
-        lambda: topk_body(ndocs_pad, nq, with_hits, k))
+        "bm25_topk", (ndocs_pad, nq, with_hits, with_mask, k),
+        lambda: topk_body(ndocs_pad, nq, with_hits, with_mask, k))
+
+
+#: `first` of a program key that names the top-k step under doc masks
+MASKED = "masked"
 
 
 def plane_program_keys(rungs: tuple[Rung, ...]) -> list[tuple]:
     """Every plane-kernel program a batch that fits one of `rungs` can
     dispatch: (rung, with_hits, first) per accumulate step, first = None
-    for the rung's top-k step."""
+    for the rung's top-k step and MASKED for its top-k step under doc
+    masks."""
     return [(rung, with_hits, first)
             for rung in rungs
             for with_hits in (False, True)
-            for first in (True, False, None)]
+            for first in (True, False, None, MASKED)]
+
+
+def doc_masks(masks: dict, nq: int, ndocs_pad: int) -> np.ndarray:
+    """The (nq, ndocs_pad) uint8 flags a masked top-k step reads:
+    `masks` = {query row: sorted doc ids it may return}; a row without
+    an entry admits every document."""
+    flags = np.ones((nq, ndocs_pad), dtype=np.uint8)
+    for qi, docs in masks.items():
+        flags[qi] = 0
+        flags[qi, docs] = 1
+    return flags
 
 
 def score_topk_planes(store: BlockStore, qb: QueryBatch, rung: Rung,
                       k: int, k1: float, b: float, avgdl: float,
-                      scorer: str):
+                      scorer: str, masks: Optional[dict] = None):
     """One dispatch of a batch that fits `rung`: its accumulate steps in
-    sequence on the donated planes, then the top-k step. Returns the
-    device (vals, docs), each (rung.nq, k): rows past qb.n_queries are
-    padding."""
+    sequence on the donated planes, then the top-k step — under the
+    queries' doc masks where `masks` ({query row: doc ids}) holds any.
+    Returns the device (vals, docs), each (rung.nq, k): rows past
+    qb.n_queries are padding."""
     with_hits = bool(qb.require.any())
     planes: tuple = ()
     for ints, floats in query_chunks(qb, rung, store.n_packed, store.n_raw):
@@ -769,7 +794,10 @@ def score_topk_planes(store: BlockStore, qb: QueryBatch, rung: Rung,
         planes = prog(store.block_base, store.block_gaps, store.block_tfs8,
                       store.raw_docs, store.raw_tfs, store.norms,
                       ints, floats, k1, b, avgdl, *planes)
-    return _topk_program(store.ndocs_pad, rung.nq, with_hits, k)(
+    if masks:
+        planes += (doc_masks(masks, rung.nq, store.ndocs_pad),)
+    return _topk_program(store.ndocs_pad, rung.nq, with_hits, k,
+                         bool(masks))(
         _pad_to(qb.require, rung.nq, 0), *planes)
 
 
@@ -777,14 +805,15 @@ def prebuild_plane_programs(store: BlockStore, rungs: tuple[Rung, ...],
                             k: int, scorer: str) -> int:
     """Build every program of `plane_program_keys` by running it once on
     an all-padding step — first step, next step on the planes it gave,
-    top-k — so that no search has to. The (rung, conjunction) chains are
-    independent and compile side by side. Returns how many programs this
-    call built."""
+    top-k, masked top-k — so that no search has to. The (rung,
+    conjunction) chains are independent and compile side by side.
+    Returns how many programs this call built."""
     from concurrent.futures import ThreadPoolExecutor
     chains: dict = {}
     for rung, with_hits, first in plane_program_keys(rungs):
-        prog = _topk_program(store.ndocs_pad, rung.nq, with_hits, k) \
-            if first is None else \
+        prog = _topk_program(store.ndocs_pad, rung.nq, with_hits, k,
+                             first == MASKED) \
+            if first in (None, MASKED) else \
             _accumulate_program(store, rung, with_hits, first, scorer)
         chains.setdefault((rung, with_hits), []).append((first, prog))
     todo = {key: steps for key, steps in chains.items()
@@ -795,15 +824,19 @@ def prebuild_plane_programs(store: BlockStore, rungs: tuple[Rung, ...],
         (ints, floats), = query_chunks(_NO_QUERIES, rung, store.n_packed,
                                        store.n_raw, min_steps=1)
         planes: tuple = ()
+        tops = []
         for first, prog in steps:
-            if first is None:
-                planes = prog(np.zeros(rung.nq, dtype=np.int32), *planes)
+            if first in (None, MASKED):
+                tops.append(prog(
+                    np.zeros(rung.nq, dtype=np.int32), *planes,
+                    *((doc_masks({}, rung.nq, store.ndocs_pad),)
+                      if first == MASKED else ())))
             else:
                 planes = prog(store.block_base, store.block_gaps,
                               store.block_tfs8, store.raw_docs,
                               store.raw_tfs, store.norms, ints, floats,
                               1.2, 0.75, 1.0, *planes)
-        jax.block_until_ready(planes)
+        jax.block_until_ready(tops)
 
     built = sum(not prog.called for steps in todo.values()
                 for _, prog in steps)
@@ -1091,14 +1124,19 @@ def build_dense_store(store: BlockStore, doc_freq: np.ndarray,
     return DenseStore(St=St, ndocs_pad=nd_pad, v_pad=v_pad)
 
 
-def dense_body(nq: int, first: bool, last: bool, k: int):
+def dense_body(nq: int, first: bool, last: bool, k: int,
+               with_mask: bool = False):
     """The traced body of one dense step: scores[q, d] += Σ_j w[q, j] ·
     St[tids[q, j], d] over this step's DENSE_SLOTS slots, added in slot
     order j (the query's own term order; pad slots carry w = 0 and add
     exactly 0.0), counting the slots that hit; planes made here when
     `first`, else the donated ones. The `last` step masks conjunctions
-    and returns the exact per-query top-k instead of the planes."""
+    (and, `with_mask`, applies the queries' doc masks, the first of
+    `planes`: `doc_masks`) and returns the exact per-query top-k instead
+    of the planes."""
     def step(St, tids, w, require, *planes):
+        if with_mask:
+            mask, planes = planes[0], planes[1:]
         nd = St.shape[1]
 
         def add_slot(j, carry):
@@ -1118,33 +1156,40 @@ def dense_body(nq: int, first: bool, last: bool, k: int):
         need = require[:, None]
         scores = jnp.where(jnp.logical_or(need <= 0, hits >= need),
                            scores, 0.0)
+        if with_mask:
+            scores = jnp.where(mask != 0, scores, 0.0)
         return jax.lax.top_k(scores, k)
 
     return step
 
 
-def _dense_program(ds: DenseStore, nq: int, first: bool, last: bool,
+def _dense_program(ds: DenseStore, nq: int, first: bool, last,
                    k: int):
+    """`last`: False, True, or MASKED (a last step under doc masks)."""
     return obs_device.compiled(
         "dense_topk", (ds.v_pad, ds.ndocs_pad, nq, first, last, k),
-        lambda: dense_body(nq, first, last, k),
+        lambda: dense_body(nq, first, bool(last), k, last == MASKED),
         donate_argnums=() if first or last else (4, 5))
 
 
 def dense_program_keys(rungs: tuple[Rung, ...]) -> list[tuple]:
     """Every dense step a batch that fits one of `rungs` can dispatch:
-    (queries, first, last), on the ladder the plane kernel climbs."""
+    (queries, first, last), on the ladder the plane kernel climbs; last
+    = MASKED names the last step under doc masks."""
     return [(rung.nq, first, last)
             for rung in rungs
-            for first in (True, False) for last in (False, True)]
+            for first in (True, False) for last in (False, True, MASKED)]
 
 
 def dense_score_topk(ds: DenseStore, slots: list[tuple[np.ndarray,
                                                        np.ndarray]],
-                     require: np.ndarray, nq: int, k: int):
+                     require: np.ndarray, nq: int, k: int,
+                     masks: Optional[dict] = None):
     """One dense dispatch: the queries' (term id, weight) slots, cut into
-    steps of DENSE_SLOTS and added in sequence. Returns the device
-    (vals, docs), each (nq, k): rows past len(slots) are padding."""
+    steps of DENSE_SLOTS and added in sequence, the last under the
+    queries' doc masks where `masks` ({query row: doc ids}) holds any.
+    Returns the device (vals, docs), each (nq, k): rows past len(slots)
+    are padding."""
     n_steps = max(1, -(-max((len(t) for t, _ in slots), default=0)
                        // DENSE_SLOTS))
     require = _pad_to(require, nq, 0)
@@ -1156,7 +1201,11 @@ def dense_score_topk(ds: DenseStore, slots: list[tuple[np.ndarray,
             part = slice(c * DENSE_SLOTS, (c + 1) * DENSE_SLOTS)
             tids[qi, :len(t[part])] = t[part]
             w[qi, :len(t[part])] = wq[part]
-        out = _dense_program(ds, nq, c == 0, c == n_steps - 1, k)(
+        last = c == n_steps - 1
+        if last and masks:
+            out = (doc_masks(masks, nq, ds.ndocs_pad),) + out
+            last = MASKED
+        out = _dense_program(ds, nq, c == 0, last, k)(
             ds.St, tids, w, require, *out)
     return out
 
@@ -1176,11 +1225,14 @@ def prebuild_dense_programs(ds: DenseStore, rungs: tuple[Rung, ...],
         tids = np.zeros((nq, DENSE_SLOTS), dtype=np.int32)
         w = np.zeros((nq, DENSE_SLOTS), dtype=np.float32)
         req = np.zeros(nq, dtype=np.int32)
+        mask = doc_masks({}, nq, ds.ndocs_pad)
         planes = progs[(True, False)](ds.St, tids, w, req)
         planes = progs[(False, False)](ds.St, tids, w, req, *planes)
         jax.block_until_ready((
             progs[(False, True)](ds.St, tids, w, req, *planes),
-            progs[(True, True)](ds.St, tids, w, req)))
+            progs[(False, MASKED)](ds.St, tids, w, req, mask, *planes),
+            progs[(True, True)](ds.St, tids, w, req),
+            progs[(True, MASKED)](ds.St, tids, w, req, mask)))
     return built
 
 

@@ -44,6 +44,7 @@ from typing import Optional
 
 from ..utils import metrics
 from ..utils.config import REGISTRY as _settings_registry
+from .searcher import REQUEST_MATCHES, MatchMemo
 
 #: process-wide dispatch sequence: every coalesced dispatch gets one id,
 #: stamped into each member's batch_dispatch span so a timeline reader
@@ -54,7 +55,7 @@ _DISPATCH_SEQ = itertools.count(1)
 class _Entry:
     __slots__ = ("node", "done", "retry", "result", "n_batch",
                  "window_ns", "scoring_ns", "t_submit_ns", "t_scored_ns",
-                 "trace", "span")
+                 "trace", "span", "matches")
 
     def __init__(self, node):
         self.node = node
@@ -74,6 +75,10 @@ class _Entry:
         from ..obs.trace import current_trace
         self.trace = current_trace()
         self.span = self.trace.current_span() if self.trace is not None else 0
+        # the submitter's request memo of phrase match sets (None outside
+        # a request that keeps one): the dispatch that scores this query
+        # reads and fills it, on whichever thread it runs
+        self.matches = REQUEST_MATCHES.get()
 
 
 class _Group:
@@ -191,6 +196,8 @@ class SearchBatcher:
         from ..obs.trace import stage_sink
         t0 = time.perf_counter_ns()
         outs = None
+        memo = REQUEST_MATCHES.set(
+            MatchMemo.joined([x.matches for x in batch]))
         # the dispatch's stages (search_plan, device_enqueue,
         # device_wait, search_host_score) land in THIS thread's trace,
         # the claimer's (batch[0]); the sink notes them for the others
@@ -200,6 +207,7 @@ class SearchBatcher:
                                              scorer, mesh_n=mesh_n)
             except BaseException:
                 outs = None   # members retry serially; the bad re-raises
+        REQUEST_MATCHES.reset(memo)
         t1 = time.perf_counter_ns()
         wait_ns = 0
         seq = next(_DISPATCH_SEQ) if outs is not None else 0

@@ -16,6 +16,8 @@ included); NOT-subtrees and phrase adjacency affect *matching* only.
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import functools
 import threading
 import time
@@ -62,6 +64,77 @@ def _maxscore_split(plan) -> set:
         else:
             break
     return non_ess
+
+
+class MatchMemo:
+    """The phrase match sets one request has evaluated, by (segment
+    searcher, phrase signature): sorted doc ids. It lives as long as the
+    request that opened it (`request_matches`) and holds nothing of any
+    other — no cache: the page and the total of one `_search` read one
+    evaluation. `joined` is the view a coalesced dispatch works under:
+    it reads and fills every member's own memo (the key is structural,
+    so two members asking the same phrase share one join)."""
+
+    __slots__ = ("parts",)
+
+    def __init__(self, parts: Optional[list] = None):
+        self.parts = [{}] if parts is None else parts
+
+    @classmethod
+    def joined(cls, memos: list) -> Optional["MatchMemo"]:
+        parts = [p for m in memos if m is not None for p in m.parts]
+        return cls(parts) if parts else None
+
+    def get(self, key):
+        for p in self.parts:
+            hit = p.get(key)
+            if hit is not None:
+                return hit
+        return None
+
+    def put(self, key, docs: np.ndarray) -> None:
+        for p in self.parts:
+            p[key] = docs
+
+
+#: the MatchMemo of the request this context serves, or None. Pool tasks
+#: copy the context; the batcher carries each member's into the dispatch
+#: that scores it (search/batcher.py).
+REQUEST_MATCHES: contextvars.ContextVar = contextvars.ContextVar(
+    "sdb_request_matches", default=None)
+
+
+@contextlib.contextmanager
+def request_matches():
+    """`with request_matches():` — the statements run inside belong to
+    one request and share its phrase match sets."""
+    tok = REQUEST_MATCHES.set(MatchMemo())
+    try:
+        yield
+    finally:
+        REQUEST_MATCHES.reset(tok)
+
+
+def _plain_phrase(node: QNode) -> bool:
+    """A phrase of plain terms, adjacent (slop 0, one alternative per
+    slot): what the array join matches. Sloppy phrases and synonym
+    groups keep the per-document matcher."""
+    return isinstance(node, QPhrase) and node.slop == 0 and \
+        len(node.groups) > 1 and all(len(g) == 1 for g in node.groups)
+
+
+def _conjunction_of_terms(node: QNode) -> bool:
+    return isinstance(node, QAnd) and len(node.args) > 0 and \
+        all(isinstance(a, QTerm) for a in node.args)
+
+
+def _in_sorted(hay: np.ndarray, needles: np.ndarray) -> np.ndarray:
+    """Bool per needle: is it in the sorted array `hay`."""
+    if not len(hay):
+        return np.zeros(len(needles), dtype=bool)
+    ix = np.searchsorted(hay, needles)
+    ix[ix == len(hay)] = len(hay) - 1
+    return hay[ix] == needles
 
 
 def _bits_of(flags: np.ndarray) -> np.ndarray:
@@ -151,6 +224,8 @@ class SegmentSearcher:
         if isinstance(node, QRegex):
             return self._union_postings(self._regex_term_ids(node))
         if isinstance(node, QPhrase):
+            if _plain_phrase(node):
+                return self._phrase_docs(node)
             return self._eval_phrase(node.groups, node.slop)
         if isinstance(node, QNothing):
             return np.empty(0, dtype=np.int32)
@@ -244,32 +319,46 @@ class SegmentSearcher:
 
     def count_filter(self, node: QNode,
                      valid_bits: Optional[np.ndarray] = None) -> int:
-        """`len(eval_filter(node))` without the doc set, where the node
-        is a union of posting lists (a term, a disjunction of such, a
-        prefix / fuzzy / regex expansion): the dense terms' bitsets are
-        OR-ed into one accumulator and its set bits counted, then the
-        sparse terms' (short) lists add the ids whose bit is not set.
+        """`len(eval_filter(node))` without a sorted doc set built for
+        the count, where the node's shape allows:
+        - a union of posting lists (a term, a disjunction of such, a
+          prefix / fuzzy / regex expansion): the dense terms' bitsets
+          are OR-ed into one accumulator and its set bits counted, then
+          the sparse terms' (short) lists add the ids whose bit is not
+          set;
+        - a conjunction of terms: the dense terms' bitsets AND-ed, the
+          sparse terms' lists intersected rarest first and probed
+          against them (`_count_conjunction`);
+        - a phrase of plain terms: the size of its positional join
+          (`_phrase_docs`: the request's own, where its page already
+          ran it).
         Every other shape takes the length of its doc set, as before,
-        and so does a union whose doc set the fragment cache already
-        holds (a Stream scan left it: that is the cache's hit).
+        and so does one whose doc set the fragment cache already holds
+        (a Stream scan left it: that is the cache's hit).
         `valid_bits`: the doc bitset of the column's non-NULL rows in
         this segment's doc space, AND-ed in (a predicate over a NULL
         text is never true)."""
         from ..cache.fragments import FRAGMENTS, qnode_sig
         tids = self._union_term_ids(node)
+        counted = tids is not None or _conjunction_of_terms(node) or \
+            _plain_phrase(node)
         held = None
-        if tids is not None:
+        if counted:
             sig = qnode_sig(node)
             held = FRAGMENTS.probe(
                 self, None if sig is None else ("filter", sig))
-        if tids is None or held is not None:
+        if not counted or held is not None:
             metrics.SEARCH_COUNT_MATERIALIZED.add()
             if held is not None:
                 FRAGMENTS.count_hits(1)
             docs = held if held is not None else self.eval_filter(node)
-            if valid_bits is not None and len(docs):
-                return int(_bits_at(valid_bits, docs).sum())
-            return len(docs)
+            return self._count_valid(docs, valid_bits)
+        if tids is None:
+            metrics.SEARCH_COUNT_INTERSECTED.add()
+            if isinstance(node, QPhrase):
+                return self._count_valid(self._phrase_docs(node),
+                                         valid_bits)
+            return self._count_conjunction(node, valid_bits)
         metrics.SEARCH_COUNT_BITSET.add()
         # a term given twice counts once
         tids = np.unique(np.asarray(tids, dtype=np.int64))
@@ -290,13 +379,116 @@ class SegmentSearcher:
             n += int(fresh.sum())
         return n
 
+    @staticmethod
+    def _count_valid(docs: np.ndarray,
+                     valid_bits: Optional[np.ndarray]) -> int:
+        if valid_bits is not None and len(docs):
+            return int(_bits_at(valid_bits, docs).sum())
+        return len(docs)
+
+    def _count_conjunction(self, node: QAnd,
+                           valid_bits: Optional[np.ndarray]) -> int:
+        """How many documents hold every term of a conjunction of terms:
+        no list of a dense term is read."""
+        tids = [self.index.term_id(a.term) for a in node.args]
+        if min(tids) < 0:
+            return 0
+        tids = np.unique(np.asarray(tids, dtype=np.int64))
+        dense = self._dense_terms(tids)
+        rows = [self._term_bits(int(t)) for t in tids[dense]]
+        if valid_bits is not None:
+            rows.append(valid_bits)
+        acc = functools.reduce(np.bitwise_and, rows) if rows else None
+        if dense.all():
+            return int(np.bitwise_count(acc).sum())
+        docs = self._conjunction_docs(tids[~dense])
+        return self._count_valid(docs, acc)
+
+    # -- phrases (the positional join, on arrays) -------------------------
+
+    def _conjunction_docs(self, tids) -> np.ndarray:
+        """Sorted doc ids that hold every term of `tids`: the posting
+        lists intersected rarest first, each step one searchsorted of
+        the survivors into the next list."""
+        fi = self.index
+        tids = sorted({int(t) for t in tids},
+                      key=lambda t: int(fi.doc_freq[t]))
+        docs = fi.postings(tids[0])[0]
+        for t in tids[1:]:
+            if not len(docs):
+                break
+            docs = docs[_in_sorted(fi.postings(t)[0], docs)]
+        return docs
+
+    def _slot_keys(self, tid: int, docs: np.ndarray,
+                   shift: int) -> np.ndarray:
+        """`doc << 32 | position - shift` of every occurrence of term
+        `tid` in `docs` (sorted; each holds the term), ascending — the
+        key of the phrase start that would put this occurrence in slot
+        `shift`. Occurrences before position `shift` start none."""
+        fi = self.index
+        s = int(fi.offsets[tid])
+        at = s + np.searchsorted(fi.post_docs[s:int(fi.offsets[tid + 1])],
+                                 docs)
+        lo = fi.pos_offsets[at]
+        n = fi.pos_offsets[at + 1] - lo
+        # the postings' position runs gathered flat: run i starts at lo[i]
+        flat = np.repeat(lo - (np.cumsum(n) - n), n) + \
+            np.arange(int(n.sum()), dtype=np.int64)
+        pos = fi.positions[flat].astype(np.int64) - shift
+        keys = np.repeat(docs.astype(np.int64) << 32, n) + pos
+        return keys[pos >= 0] if shift else keys
+
+    def _phrase_join(self, terms: list) -> np.ndarray:
+        """Sorted doc ids in which `terms` stand at consecutive
+        positions: a join of (document, position) keys, slot j's shifted
+        back by j, over the documents that hold every term — rarest
+        slot first, each further slot read only in the documents still
+        alive. No per-document Python."""
+        fi = self.index
+        tids = [fi.term_id(t) for t in terms]
+        if min(tids) < 0:
+            return np.empty(0, dtype=np.int32)
+        docs = self._conjunction_docs(tids)
+        metrics.SEARCH_PHRASE_CANDIDATES.add(len(docs))
+        keys = np.empty(0, dtype=np.int64)
+        for n, j in enumerate(sorted(range(len(tids)),
+                                     key=lambda j: int(fi.doc_freq[tids[j]]))):
+            if not len(docs):
+                break
+            slot = self._slot_keys(tids[j], docs, j)
+            keys = keys[_in_sorted(slot, keys)] if n else slot
+            docs = np.unique(keys >> 32).astype(np.int32)
+        metrics.SEARCH_PHRASE_MATCHES.add(len(docs))
+        return docs
+
+    def _phrase_docs(self, node: QPhrase) -> np.ndarray:
+        """The match set of a phrase of plain terms (`_plain_phrase`):
+        from the request's memo where this request already joined it,
+        else joined now, under the stage `search_phrase`."""
+        from ..cache.fragments import qnode_sig
+        memo = REQUEST_MATCHES.get()
+        key = (self, qnode_sig(node))
+        if memo is not None:
+            hit = memo.get(key)
+            if hit is not None:
+                return hit
+        with stage("search_phrase"):
+            docs = self._phrase_join([g[0] for g in node.groups])
+        if memo is not None:
+            memo.put(key, docs)
+        return docs
+
     def _eval_phrase(self, groups: list[list[str]],
                      slop: int = 0) -> np.ndarray:
         """Phrase over per-position alternative groups: each slot is the
         union of its alternatives' postings (synonym expansions), slots
         must land on consecutive doc positions — or, with slop > 0, in
         order with total extra gap <= slop (Lucene `"..."~N`, minus its
-        bounded-reorder allowance; same contract as query._sloppy_match)."""
+        bounded-reorder allowance; same contract as query._sloppy_match).
+        Per document, in Python: what sloppy phrases and synonym groups
+        take. A phrase of plain terms with slop 0 never comes here
+        (`_phrase_docs`)."""
         if not groups:
             return np.empty(0, dtype=np.int32)
         gtids = [[t for t in (self.index.term_id(a) for a in g) if t >= 0]
@@ -515,8 +707,9 @@ class SegmentSearcher:
         """Upload this segment's posting store and build the closed set
         of programs its searches dispatch (ops/bm25.py), so that none is
         built by a search: the dense steps where the saturation matrix
-        fits, else the plane kernel's accumulate and top-k steps, for
-        every rung up to the batcher's cap at the first top-k bucket;
+        fits, else the plane kernel's accumulate and top-k steps (the
+        top-k under doc masks among them, a phrase's), for every rung up
+        to the batcher's cap at the first top-k bucket;
         and the dense terms' doc bitsets that `count_filter` ORs.
         Returns how many programs this call built."""
         if self.num_docs == 0:
@@ -546,25 +739,35 @@ class SegmentSearcher:
         dispatch (amortizes dispatch latency — the QPS regime): the
         batch is fitted to a rung of the store's closed program set
         (ops/bm25.py), and what exceeds the largest rung is split into
-        several dispatches. Pure term disjunctions/conjunctions run fully
-        on device; other shapes get an exact-match CPU mask applied to
-        the device scores. `tiers`, when given, is filled per query with
-        "device" or "host": where its top-k was scored.
+        several dispatches. Pure term disjunctions/conjunctions and
+        phrases of plain terms run fully on the ladder; other shapes
+        (NOT, nested booleans, sloppy and synonym phrases) get an
+        exact-match CPU mask applied to the device scores. `tiers`, when
+        given, is filled per query with "device" or "host": where its
+        top-k was scored.
 
         The ladder — where a question is scored is chosen from the store
         and the question, never from the backend:
-        1. mesh: `mesh_n` > 1, that many devices and no conjunction in
-           the batch — posting rows shard across the mesh
-           (`score_topk_mesh`);
+        1. mesh: `mesh_n` > 1, that many devices and neither a
+           conjunction nor a doc mask in the batch — posting rows shard
+           across the mesh (`score_topk_mesh`);
         2. dense: the saturation matrix fits (`_use_dense`) — row
            gathers over the `DenseStore`, no host planning;
-        3. MaxScore on the host: a prunable disjunction whose essential
-           terms leave at most MAXSCORE_CAND_CAP candidates is scored by
-           `_cpu_score` and takes no slot of the dispatch;
+        3. candidates on the host: a prunable disjunction whose
+           essential terms (MaxScore) leave at most MAXSCORE_CAND_CAP
+           candidates, or a phrase whose match set is no larger, is
+           scored by `_cpu_score` and takes no slot of the dispatch;
         4. plane: everything else accumulates WAND-kept posting rows
            into the score plane and takes its top-k
            (`score_topk_planes`), both programs of the set `prebuild`
            built.
+        A conjunction of terms rides rungs 2 and 4 in their `require`
+        form (the hits plane). A phrase is a conjunction with a narrower
+        match set, joined once per request (`_phrase_docs`), and the set
+        bounds its top-k INSIDE the ladder: as rung 3's candidate list,
+        or as a doc mask the top-k step of rungs 2 and 4 applies (which
+        makes the hits plane needless: it scores as the union of its
+        terms). Its device top-k is final; nothing is scored again.
         `cpu_topk_wand` is the host reference the tests compare with;
         no search is served by it."""
         if tiers is None:
@@ -611,8 +814,22 @@ class SegmentSearcher:
             prunable = [req == 0 and not needs_mask and not empty and
                         scorer not in bm25_ops.LM_SCORERS
                         for _, req, needs_mask, empty in shapes]
+            masks: dict[int, np.ndarray] = {}
+            for qi, node in enumerate(nodes):
+                tids = shapes[qi][0]
+                if not (tids and _plain_phrase(node)):
+                    continue
+                match = self._phrase_docs(node)
+                shapes[qi] = (tids, 0, False, False)   # no mask afterwards
+                if len(match) > self.MAXSCORE_CAND_CAP:
+                    masks[qi] = match
+                    continue
+                with stage("search_host_score"):
+                    host_results[qi] = self._cpu_score(
+                        match, tids, k, scorer, idf_of, avgdl_override)
+                queries[qi] = (np.empty(0, dtype=np.int64), 0)
             use_mesh = mesh_n > 1 and len(jax.devices()) >= mesh_n and \
-                not any(req for _, req in queries)
+                not masks and not any(req for _, req in queries)
             use_dense = not use_mesh and self._use_dense(store, scorer,
                                                          avgdl)
             if not use_mesh and not use_dense and \
@@ -668,11 +885,12 @@ class SegmentSearcher:
                 # planning needed (the dense kernel is not scatter-bound)
                 out = bm25_ops.dense_score_topk(
                     self._dense_store(scorer, avgdl), slots, require,
-                    bm25_ops.rung_for(rungs, len(queries)).nq, kk)
+                    bm25_ops.rung_for(rungs, len(queries)).nq, kk, masks)
             else:
                 out = bm25_ops.score_topk_planes(
                     store, qb, bm25_ops.rung_for(rungs, len(queries)), kk,
-                    bm25_ops.scorer_param(scorer, K1), B, avgdl, scorer)
+                    bm25_ops.scorer_param(scorer, K1), B, avgdl, scorer,
+                    masks)
             vals, docs = obs_device.fetch_all(out)
             metrics.DEVICE_DISPATCH_HIST.observe_ns(
                 time.perf_counter_ns() - t_d)
@@ -723,6 +941,8 @@ class SegmentSearcher:
                     scores, dd = self._cpu_score(match, tids, k, scorer,
                                                  idf_of, avgdl_override)
                     tiers[qi] = "host"
+                    if isinstance(node, QPhrase):
+                        metrics.SEARCH_PHRASE_RESCORED.add()
                 else:
                     scores, dd = scores[ok], dd[ok]
             keep = scores > 0.0
@@ -758,13 +978,8 @@ class SegmentSearcher:
         avgdl = max(avgdl_override if avgdl_override is not None
                     else fi.avgdl, 1e-9)
         if require_all > 0:
-            docs = None
-            for tid in tids:
-                pd = fi.postings(int(tid))[0]
-                docs = pd if docs is None else \
-                    np.intersect1d(docs, pd, assume_unique=True)
-            if docs is None:
-                docs = np.empty(0, dtype=np.int32)
+            docs = self._conjunction_docs(tids) if len(tids) else \
+                np.empty(0, dtype=np.int32)
             return self._cpu_score(docs, tids, k, scorer, idf_of,
                                    avgdl_override)
         plan = None

@@ -97,7 +97,11 @@ class FieldIndex:
         return self.post_docs[s:e], self.post_tfs[s:e]
 
     def positions_of(self, tid: int, within_docs: np.ndarray) -> dict[int, np.ndarray]:
-        """doc id → positions array, for the given docs (phrase check)."""
+        """doc id → positions array, for the given docs: the per-document
+        phrase check of sloppy phrases and synonym groups
+        (`SegmentSearcher._eval_phrase`). A phrase of plain terms with
+        slop 0 is joined on `positions` / `pos_offsets` as arrays
+        (`SegmentSearcher._phrase_join`) and never calls this."""
         s, e = int(self.offsets[tid]), int(self.offsets[tid + 1])
         docs = self.post_docs[s:e]
         idx = np.searchsorted(docs, within_docs)

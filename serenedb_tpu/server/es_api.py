@@ -9,6 +9,13 @@ documents (`_id` TEXT + `_source` TEXT + one column per scalar field);
 text fields get inverted indexes and the DSL translates onto the engine's
 search surface (match → `@@` OR-query, match_phrase → `##`, bool →
 AND/OR/NOT, range/term → SQL predicates) with BM25 scores.
+
+`_search` answers what the body asks for and runs nothing else:
+`track_total_hits` true (the default here) or an integer gives the exact
+`hits.total` (`relation: eq` — a valid answer to a bound, too), false
+runs no count and leaves `hits.total` out, as Elasticsearch does;
+`size: 0` runs no scored statement. A page and a total of one request
+share its phrase match sets (search/searcher.py: `request_matches`).
 """
 
 from __future__ import annotations
@@ -432,6 +439,9 @@ class EsApi:
                 "query it over the PG wire, or ingest documents through "
                 "the ES API (_doc/_bulk) to search here")
         where, score_col = self._translate_query(body.get("query"))
+        want_hits = size > 0
+        want_total = body.get("track_total_hits", True) is not False
+        _count_request(body.get("query"), want_hits, want_total)
         multi_claims = score_col if isinstance(score_col, list) else None
         cols = '"_id", "_source"'
         order = ""
@@ -443,8 +453,11 @@ class EsApi:
             order = " ORDER BY " + ", ".join(_sort_clause(s) for s in sort)
             multi_claims = None     # explicit sort: no score ordering
         sql = f'SELECT {cols} FROM "{index}"'
+        total_sql = f'SELECT count(*) FROM "{index}"'
         if where:
             sql += f" WHERE {where}"
+            total_sql += f" WHERE {where}"
+        rows, total = [], None
         if multi_claims is not None:
             # multi-field scoring, rank-first (Lucene BooleanQuery: doc
             # score = sum of its matching clauses' scores): one scored
@@ -454,19 +467,17 @@ class EsApi:
             # and the zero-score tail pages through ORDER BY/LIMIT. No
             # whole-table id fetch, whatever the index size.
             scores: dict[str, float] = {}
-            for f, w, pred in multi_claims:
+            for f, w, pred in multi_claims if want_hits else ():
                 pass_sql = (f'SELECT "_id", bm25({_ident(f)}) '
                             f'FROM "{index}" WHERE {pred}')
                 for did, sc in self._rconn().execute(pass_sql).rows():
                     if sc:
                         scores[did] = scores.get(did, 0.0) + w * float(sc)
-            total_sql = f'SELECT count(*) FROM "{index}"'
-            if where:
-                total_sql += f" WHERE {where}"
-            total = int(self._rconn().execute(total_sql).scalar())
-            page = self._multi_claim_page(index, where, scores,
-                                          from_ + size)[from_:from_ + size]
-            rows = []
+            if want_total:
+                total = int(self._rconn().execute(total_sql).scalar())
+            page = self._multi_claim_page(
+                index, where, scores, from_ + size)[from_:from_ + size] \
+                if want_hits else []
             if page:
                 lits = ", ".join(_sql_str(d) for d in page)
                 src = dict(self._rconn().execute(
@@ -475,12 +486,13 @@ class EsApi:
                 rows = [(d, src.get(d), scores.get(d, 0.0)) for d in page]
             score_col = "multi"
         else:
-            sql += order + f" LIMIT {size} OFFSET {from_}"
-            rows = list(self._read(sql, trace).rows())
-            total_sql = f'SELECT count(*) FROM "{index}"'
-            if where:
-                total_sql += f" WHERE {where}"
-            total = int(self._read(total_sql, trace).scalar())
+            from ..search.searcher import request_matches
+            with request_matches():
+                if want_hits:
+                    sql += order + f" LIMIT {size} OFFSET {from_}"
+                    rows = list(self._read(sql, trace).rows())
+                if want_total:
+                    total = int(self._read(total_sql, trace).scalar())
         hits = []
         max_score = 0.0
         for row in rows:
@@ -489,13 +501,14 @@ class EsApi:
             max_score = max(max_score, score)
             hits.append({"_index": index, "_id": row[0], "_score": score,
                          "_source": json.loads(row[1]) if row[1] else {}})
+        found = {"max_score": max_score if hits else None, "hits": hits}
+        if total is not None:
+            found = {"total": {"value": total, "relation": "eq"}, **found}
         return {
             "took": 1, "timed_out": False,
             "_shards": {"total": 1, "successful": 1, "skipped": 0,
                         "failed": 0},
-            "hits": {"total": {"value": total, "relation": "eq"},
-                     "max_score": max_score if hits else None,
-                     "hits": hits},
+            "hits": found,
         }
 
     def _multi_claim_page(self, index: str, where: str,
@@ -663,6 +676,7 @@ class EsApi:
         # pagination: the window must cover every hit, not a cap)
         body["size"] = max(t.row_count(), 1)
         body["from"] = 0
+        body["track_total_hits"] = True     # every page repeats the total
         res = self.search(index, body)
         hits = res["hits"]["hits"]
         sid = _gen_id()
@@ -1052,6 +1066,34 @@ class EsApi:
                     f'{_sql_str(json.dumps(shape))})')
         raise EsError(400, "parsing_exception",
                       f"unsupported query type [{kind}]")
+
+
+def _count_request(q, want_hits: bool, want_total: bool) -> None:
+    """One `_search` request with a query, counted by the shape of a
+    match / match_phrase query (`SearchQueries*`) and by what it asked
+    for (`SearchRequests*`)."""
+    from ..utils import metrics
+    kind, spec = next(iter(q.items())) if isinstance(q, dict) and \
+        len(q) == 1 else (None, None)
+    if kind in ("match", "match_phrase") and isinstance(spec, dict) and \
+            len(spec) == 1:
+        spec = next(iter(spec.values()))
+        text, op = (spec.get("query"), spec.get("operator", "or")) \
+            if isinstance(spec, dict) else (spec, "or")
+        if len(re.findall(r"\w+", str(text))) < 2:
+            metrics.SEARCH_QUERIES_TERM.add()
+        elif kind == "match_phrase":
+            metrics.SEARCH_QUERIES_PHRASE.add()
+        elif str(op).lower() == "and":
+            metrics.SEARCH_QUERIES_CONJUNCTION.add()
+        else:
+            metrics.SEARCH_QUERIES_UNION.add()
+    if want_hits and want_total:
+        metrics.SEARCH_REQUESTS_HITS_AND_COUNT.add()
+    elif want_hits:
+        metrics.SEARCH_REQUESTS_HITS_ONLY.add()
+    elif want_total:
+        metrics.SEARCH_REQUESTS_COUNT_ONLY.add()
 
 
 def _as_list(v) -> list:

@@ -359,6 +359,39 @@ SEARCH_QUERIES_SCORED_HOST = REGISTRY.gauge(
     "top-k queries whose top-k came out of a host tier in every "
     "segment: _cpu_score over MaxScore candidates or an exact-match "
     "rescore, or no scoring at all (no term of the query is indexed)")
+SEARCH_QUERIES_TERM, SEARCH_QUERIES_UNION, SEARCH_QUERIES_CONJUNCTION, \
+    SEARCH_QUERIES_PHRASE = (REGISTRY.gauge(
+        "SearchQueries" + shape,
+        "`_search` requests whose `query` is " + what + "; the four "
+        "shapes partition the requests whose query is a match or a "
+        "match_phrase (server/es_api.py: search)")
+        for shape, what in (
+            ("Term", "a match or match_phrase of one word"),
+            ("Union", "a match of several words, operator or"),
+            ("Conjunction", "a match of several words, operator and"),
+            ("Phrase", "a match_phrase of several words")))
+SEARCH_REQUESTS_COUNT_ONLY, SEARCH_REQUESTS_HITS_ONLY, \
+    SEARCH_REQUESTS_HITS_AND_COUNT = (REGISTRY.gauge(
+        "SearchRequests" + asked,
+        "`_search` requests with a query that asked for " + what + "; a "
+        "partition of those that asked for anything")
+        for asked, what in (
+            ("CountOnly", "the exact total and no hits (size 0)"),
+            ("HitsOnly", "hits and no total (track_total_hits false)"),
+            ("HitsAndCount", "hits and the exact total")))
+SEARCH_PHRASE_CANDIDATES = REGISTRY.gauge(
+    "SearchPhraseCandidates",
+    "documents that hold every term of a phrase whose positional join "
+    "ran: what the join read positions of")
+SEARCH_PHRASE_MATCHES = REGISTRY.gauge(
+    "SearchPhraseMatches",
+    "documents those joins found to hold the phrase")
+SEARCH_PHRASE_RESCORED = REGISTRY.gauge(
+    "SearchPhraseRescored",
+    "phrase top-k queries whose device top-k held a document outside "
+    "the phrase's match set and were scored again by _cpu_score; a "
+    "phrase of plain terms with slop 0 never is (its match set bounds "
+    "the top-k inside the program), one per segment")
 SEARCH_POSTINGS_DISPATCHED = REGISTRY.gauge(
     "SearchPostingsDispatched",
     "valid (non-padding) postings in the block rows and light-term "
@@ -372,13 +405,22 @@ SEARCH_COUNT_BITSET = REGISTRY.gauge(
     "SearchCountBitset",
     "exact totals of a search answered by OR-ing doc bitsets "
     "(SegmentSearcher.count_filter: the query is a union of posting "
-    "lists), one per segment asked; with SearchCountMaterialized a "
-    "partition of the segment-level counts")
+    "lists), one per segment asked; with SearchCountIntersected and "
+    "SearchCountMaterialized a partition of the segment-level counts")
 SEARCH_COUNT_MATERIALIZED = REGISTRY.gauge(
     "SearchCountMaterialized",
     "exact totals of a search taken as the length of the sorted doc "
-    "set: built for the count (conjunctions, negations, phrases) or "
-    "found in the fragment cache; one per segment asked")
+    "set: built for the count (negations, nested booleans, sloppy and "
+    "synonym phrases) or found in the fragment cache; one per segment "
+    "asked")
+SEARCH_COUNT_INTERSECTED = REGISTRY.gauge(
+    "SearchCountIntersected",
+    "exact totals of a conjunction of terms (the dense terms' doc "
+    "bitsets AND-ed, the sparse terms' lists intersected rarest first "
+    "and probed against them) or of a phrase of plain terms (the size "
+    "of its positional join); one per segment asked. With "
+    "SearchCountBitset and SearchCountMaterialized a partition of the "
+    "segment-level counts")
 SEARCH_COUNT_BITSET_BYTES = REGISTRY.gauge(
     "SearchCountBitsetBytes",
     "bytes of dense-term doc bitsets built for count_filter (a term "
@@ -591,6 +633,10 @@ STAGE_HISTS = {name: REGISTRY.histogram(hist, desc) for name, hist, desc in (
     ("search_plan", "StageSearchPlan",
      "host planning of a scoring dispatch: query shapes, block-max WAND "
      "plans, MaxScore candidates, batch assembly and packing"),
+    ("search_phrase", "StageSearchPhrase",
+     "the positional join of a phrase: the (document, position) keys of "
+     "its slots intersected over the documents that hold all its terms "
+     "(SegmentSearcher._phrase_docs); once per request and segment"),
     ("search_host_score", "StageSearchHostScore",
      "host scoring: _cpu_score over candidates, exact-match masks and "
      "the result postprocessing of a scoring dispatch"),

@@ -62,7 +62,9 @@ STORES = {
     "null_rows": lambda: _store([500, 93], nulls=True),
 }
 
-#: name → (node, counted through bitsets?)
+#: name → (node, the counter its count ticks: True the bitsets' (a union),
+#: None the intersections' (a conjunction of terms, a plain phrase), False
+#: the materialized doc set's)
 NODES = {
     "one_term": (QTerm("w0"), True),
     "or_dense_and_sparse": (QOr([QTerm("w0"), QTerm("w1"), QTerm("w90"),
@@ -81,9 +83,20 @@ NODES = {
     "or_of_prefix_and_term": (QOr([QPrefix("w9"), QTerm("w4")]), True),
     "fuzzy": (QFuzzy("w11", 1), True),
     "regex": (QRegex("w[0-3]"), True),
-    "and": (QAnd([QTerm("w0"), QTerm("w1")]), False),
+    "and": (QAnd([QTerm("w0"), QTerm("w1")]), None),
+    "and_dense_and_sparse": (QAnd([QTerm("w0"), QTerm("w90"), QTerm("w1"),
+                                   QTerm("w100")]), None),
+    "and_all_sparse": (QAnd([QTerm("w80"), QTerm("w95")]), None),
+    "and_a_term_twice": (QAnd([QTerm("w1"), QTerm("w70"), QTerm("w1")]),
+                         None),
+    "and_an_absent_term": (QAnd([QTerm("w2"), QTerm("nosuchword")]), None),
+    "and_with_not": (QAnd([QTerm("w0"), QNot(QTerm("w1"))]), False),
     "not": (QNot(QTerm("w0")), False),
-    "phrase": (QPhrase(["w0", "w1"]), False),
+    "phrase": (QPhrase(["w0", "w1"]), None),
+    "phrase_of_three": (QPhrase(["w1", "w0", "w2"]), None),
+    "phrase_a_term_twice": (QPhrase(["w0", "w0"]), None),
+    "phrase_an_absent_term": (QPhrase(["w0", "nosuchword"]), None),
+    "sloppy_phrase": (QPhrase(["w0", "w1"], slop=2), False),
     "or_over_and": (QOr([QTerm("w5"), QAnd([QTerm("w0"), QTerm("w2")])]),
                     False),
 }
@@ -117,15 +130,15 @@ def result_cache_off():
 def test_count_filter_is_the_length_of_eval_filter(stores, store, shape):
     ms, validity = stores[store]
     node, by_bitset = NODES[shape]
-    b0 = metrics.SEARCH_COUNT_BITSET.value
-    m0 = metrics.SEARCH_COUNT_MATERIALIZED.value
+    sides = (metrics.SEARCH_COUNT_BITSET, metrics.SEARCH_COUNT_INTERSECTED,
+             metrics.SEARCH_COUNT_MATERIALIZED)
+    before = [g.value for g in sides]
     got = ms.count_filter(node, validity)
     assert got == _expected(ms, node, validity)
     # one tick per segment asked, on the side the node's shape decides
     n = len(ms.segments)
-    assert metrics.SEARCH_COUNT_BITSET.value - b0 == (n if by_bitset else 0)
-    assert metrics.SEARCH_COUNT_MATERIALIZED.value - m0 == \
-        (0 if by_bitset else n)
+    assert [g.value - b for g, b in zip(sides, before)] == \
+        [n if by_bitset is side else 0 for side in (True, None, False)]
 
 
 def test_null_rows_change_a_negations_count(stores):
