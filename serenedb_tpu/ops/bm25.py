@@ -8,8 +8,9 @@ formats/posting/wand_writer.hpp).
 TPU re-formulation (zero per-query posting transfers):
 
 - At index-build time, postings of *heavy* terms (df ≥ HEAVY_DF) are packed
-  into device-resident (n_blocks, 128) doc/tf tiles — the block_128 layout
-  is exactly one TPU lane row. Light terms stay in the flat arrays.
+  into device-resident (n_blocks, 128) doc / tf / doc-length tiles — the
+  block_128 layout is exactly one TPU lane row. Light terms stay in the
+  flat arrays.
 - A query ships only: the block-row indices of its heavy terms (a few KB),
   a gathered tail array for its light terms, and per-term idf weights.
 - One fused XLA program gathers the tiles, computes BM25 contributions,
@@ -97,18 +98,27 @@ class BlockStore:
     HBM layout (the reference's block_128 bitpacked format re-expressed for
     TPU lanes, formats/posting/format_block_128.cpp): each 128-posting row
     of a heavy term is COMPRESSED as one int32 base doc + 128 uint16
-    doc-gaps + 128 uint8 tfs (7 bytes/posting → vs 8 raw ≈ 2.3×) and
-    decoded INSIDE the scoring kernel (cumsum along the lane axis — a
-    log-step scan the VPU handles without leaving registers). Rows that
-    don't fit (a doc gap ≥ 2^16 or a tf ≥ 2^8) stay in a small raw int32
-    exception plane, mirroring streamvbyte's escape path."""
+    doc-gaps + 128 uint8 tfs + 128 uint16 document lengths (5 B a
+    posting slot and 4 B a row, against 12 B raw) and decoded INSIDE the
+    scoring kernel (cumsum along the lane axis — a log-step scan the
+    VPU handles without leaving registers). Rows that don't fit (a doc
+    gap or a length ≥ 2^16, or a tf ≥ 2^8) stay in a small raw int32
+    exception plane, mirroring streamvbyte's escape path.
+
+    Every posting slot carries its document's length (`block_dls`,
+    `raw_dls`: the same integers as `norms[doc]`, lane for lane with the
+    tfs), so the accumulate step reads a length as it reads a tf, by
+    row, and gathers nothing per element; `norms` is read only by
+    `_build_dense`, linearly."""
 
     block_base: jax.Array      # (NP+1,) int32 — first doc of each packed row
     block_gaps: jax.Array      # (NP+1, 128) uint16 — doc deltas, slot0 = 0
     block_tfs8: jax.Array      # (NP+1, 128) uint8 — tf, 0 marks padding
+    block_dls: jax.Array       # (NP+1, 128) uint16 — doc length, padding 0
     raw_docs: jax.Array        # (NR+1, 128) int32, -1 padding
     raw_tfs: jax.Array         # (NR+1, 128) int32
-    norms: jax.Array           # (ndocs_pad,) int32
+    raw_dls: jax.Array         # (NR+1, 128) int32 — doc length, padding 0
+    norms: jax.Array           # (ndocs_pad,) int32 — `_build_dense` only
     block_offsets: np.ndarray  # (T+1,) int64 — heavy terms' GLOBAL row spans
     heavy: np.ndarray          # (T,) bool
     flat_docs: np.ndarray      # host copies for the light-term tail
@@ -132,17 +142,29 @@ class BlockStore:
     row_count: np.ndarray = None       # (NB_total+1,) int32
 
     @property
+    def tiles(self) -> tuple:
+        """The device arrays a scoring program takes, in its order."""
+        return (self.block_base, self.block_gaps, self.block_tfs8,
+                self.block_dls, self.raw_docs, self.raw_tfs, self.raw_dls)
+
+    @property
     def hbm_bytes(self) -> int:
-        """Posting-tile HBM footprint (norms excluded — shared)."""
-        return sum(int(np.prod(a.shape)) * a.dtype.itemsize
-                   for a in (self.block_base, self.block_gaps,
-                             self.block_tfs8, self.raw_docs, self.raw_tfs))
+        """Posting-tile HBM footprint, the per-posting lengths among it
+        (the `norms` table, 4 B a document, is not a tile and not
+        counted)."""
+        return sum(a.nbytes for a in self.tiles)
+
+    @property
+    def length_bytes(self) -> int:
+        """HBM held by the per-posting document lengths."""
+        return self.block_dls.nbytes + self.raw_dls.nbytes
 
     @property
     def hbm_bytes_raw_equiv(self) -> int:
-        """What the same rows would cost as raw int32 doc+tf tiles."""
+        """What the same rows would cost as raw int32 doc+tf+length
+        tiles."""
         n_rows = len(self.row_plane)
-        return n_rows * BLOCK * 8
+        return n_rows * BLOCK * 12
 
 
 def _bucket(n: int, floor: int, align: int = 1) -> int:
@@ -184,24 +206,29 @@ def build_block_store(offsets: np.ndarray, post_docs: np.ndarray,
         btfs[grow, lane] = post_tfs[src]
     bmax_tf = btfs.max(axis=1).astype(np.int32)
     row_count = np.count_nonzero(btfs, axis=1).astype(np.int32)
-    # bmin_dl without a full-size dl temporary: mask pads to int32-max
-    dl_vals = norms_h[np.clip(bdocs, 0, None)] if num_docs \
+    # every slot's document length, lane for lane with the tfs: what the
+    # accumulate step reads in place of norms[doc]. bmin_dl in place, no
+    # second full-size temporary: pads to int32-max for the min, then 0
+    bdls = norms_h[np.clip(bdocs, 0, None)] if num_docs \
         else np.zeros_like(bdocs)
-    np.putmask(dl_vals, bdocs < 0, np.iinfo(np.int32).max)
-    bmin_dl = dl_vals.min(axis=1).astype(np.int32)
-    del dl_vals
+    pad = bdocs < 0
+    np.putmask(bdls, pad, np.iinfo(np.int32).max)
+    bmin_dl = bdls.min(axis=1).astype(np.int32)
+    np.putmask(bdls, pad, 0)
+    del pad
     bmin_dl[-1] = np.iinfo(np.int32).max   # all-pad row
 
     # Pack: forward-fill pads with the last real doc so gaps stay small,
-    # then delta-encode along the lane axis (in place — the build holds at
-    # most two full-size temporaries at a time; tiles reach GBs at 8M
-    # documents).
+    # then delta-encode along the lane axis (in place — beside the three
+    # tiles the build holds one full-size temporary at a time; tiles
+    # reach GBs at 8M documents).
     docs_ff = np.maximum.accumulate(bdocs, axis=1)
     base = docs_ff[:, 0].copy()
     docs_ff[:, 1:] = docs_ff[:, 1:] - docs_ff[:, :-1]
     docs_ff[:, 0] = 0
     gaps = docs_ff                      # reuse: docs_ff IS the gap array now
     packable = ((gaps.max(axis=1) < (1 << 16)) &
+                (bdls.max(axis=1) < (1 << 16)) &
                 (bmax_tf < (1 << 8)) & (base >= 0))
     packable[-1] = False     # keep the global pad row in the raw plane
     row_plane = np.where(packable, 0, 1).astype(np.uint8)
@@ -228,6 +255,11 @@ def build_block_store(offsets: np.ndarray, post_docs: np.ndarray,
     pk_tfs[:n_packed] = btfs[packable].astype(np.uint8)
     r_tfs[:n_raw] = btfs[~packable]
     del btfs
+    pk_dls = np.zeros((np_rows, BLOCK), dtype=np.uint16)
+    r_dls = np.zeros((nr_rows, BLOCK), dtype=np.int32)
+    pk_dls[:n_packed] = bdls[packable].astype(np.uint16)
+    r_dls[:n_raw] = bdls[~packable]
+    del bdls
 
     nd_pad = _bucket(num_docs, 1024, 1024)
     norms_pad = np.zeros(nd_pad, dtype=np.int32)
@@ -236,8 +268,10 @@ def build_block_store(offsets: np.ndarray, post_docs: np.ndarray,
         block_base=jnp.asarray(pk_base),
         block_gaps=jnp.asarray(pk_gaps),
         block_tfs8=jnp.asarray(pk_tfs),
+        block_dls=jnp.asarray(pk_dls),
         raw_docs=jnp.asarray(r_docs),
         raw_tfs=jnp.asarray(r_tfs),
+        raw_dls=jnp.asarray(r_dls),
         norms=jnp.asarray(norms_pad),
         block_offsets=block_offsets,
         heavy=heavy,
@@ -273,6 +307,7 @@ class QueryBatch:
     raw_qid: np.ndarray    # (NR,) int32
     tail_docs: np.ndarray  # (TT,) int32 light-term postings (docs)
     tail_tfs: np.ndarray   # (TT,) int32
+    tail_dls: np.ndarray   # (TT,) int32 their documents' lengths
     tail_w: np.ndarray     # (TT,) f32
     tail_qid: np.ndarray   # (TT,) int32
     require: np.ndarray    # (B,) int32 — 0 = disjunction, else min hits
@@ -557,6 +592,7 @@ def assemble_query_batch(store: BlockStore, n_docs: int,
         return np.concatenate(parts).astype(dtype, copy=False) if parts \
             else np.empty(0, dtype=dtype)
 
+    tail_docs = cat(tails_d, np.int32)
     return QueryBatch(
         row_idx=cat(rows, np.int32),
         row_w=cat(row_w, np.float32),
@@ -564,8 +600,9 @@ def assemble_query_batch(store: BlockStore, n_docs: int,
         raw_idx=cat(rrows, np.int32),
         raw_w=cat(rrow_w, np.float32),
         raw_qid=cat(rrow_q, np.int32),
-        tail_docs=cat(tails_d, np.int32),
+        tail_docs=tail_docs,
         tail_tfs=cat(tails_f, np.int32),
+        tail_dls=store.norms_host[tail_docs],
         tail_w=cat(tails_w, np.float32),
         tail_qid=cat(tails_q, np.int32),
         require=np.asarray(require, dtype=np.int32),
@@ -643,7 +680,7 @@ def query_chunks(qb: QueryBatch, rung: Rung, pad_packed: int,
     buffer (two host->device transfers), each of the rung's fixed size.
 
     ints: [row_idx | row_qid | raw_idx | raw_qid
-           | tail_docs | tail_tfs | tail_qid]
+           | tail_docs | tail_tfs | tail_dls | tail_qid]
     floats: [row_w | raw_w | tail_w]
 
     Packed rows fill steps from the first; raw rows start in the step
@@ -668,6 +705,7 @@ def query_chunks(qb: QueryBatch, rung: Rung, pad_packed: int,
             _pad_to(qb.raw_qid[r], rung.nr, 0),
             _pad_to(qb.tail_docs[t], rung.tt, -1),
             _pad_to(qb.tail_tfs[t], rung.tt, 0),
+            _pad_to(qb.tail_dls[t], rung.tt, 0),
             _pad_to(qb.tail_qid[t], rung.tt, 0)]).astype(np.int32)
         floats = np.concatenate([
             _pad_to(qb.row_w[p], rung.nb, 0.0),
@@ -680,7 +718,7 @@ def query_chunks(qb: QueryBatch, rung: Rung, pad_packed: int,
 _NO_QUERIES = QueryBatch(
     *(np.empty(0, dtype=t) for t in (
         np.int32, np.float32, np.int32, np.int32, np.float32, np.int32,
-        np.int32, np.int32, np.float32, np.int32, np.int32)), 0)
+        np.int32, np.int32, np.int32, np.float32, np.int32, np.int32)), 0)
 
 
 def accumulate_body(rung: Rung, ndocs_pad: int, with_hits: bool,
@@ -691,16 +729,18 @@ def accumulate_body(rung: Rung, ndocs_pad: int, with_hits: bool,
     donated ones handed in."""
     nb, nr, tt = rung.nb, rung.nr, rung.tt
 
-    def step(block_base, block_gaps, block_tfs8, raw_docs, raw_tfs, norms,
-             ints, floats, k1, b, avgdl, *planes):
+    def step(block_base, block_gaps, block_tfs8, block_dls, raw_docs,
+             raw_tfs, raw_dls, ints, floats, k1, b, avgdl, *planes):
         o = 2 * nb + 2 * nr
         scores, hits = _accumulate_scores(
-            block_base, block_gaps, block_tfs8, raw_docs, raw_tfs, norms,
+            block_base, block_gaps, block_tfs8, block_dls, raw_docs,
+            raw_tfs, raw_dls,
             ints[:nb], floats[:nb], ints[nb:2 * nb],
             ints[2 * nb:2 * nb + nr], floats[nb:nb + nr],
             ints[2 * nb + nr:o],
             ints[o:o + tt], ints[o + tt:o + 2 * tt],
-            floats[nb + nr:nb + nr + tt], ints[o + 2 * tt:o + 3 * tt],
+            ints[o + 2 * tt:o + 3 * tt],
+            floats[nb + nr:nb + nr + tt], ints[o + 3 * tt:o + 4 * tt],
             ndocs_pad, rung.nq, with_hits, k1, b, avgdl, scorer,
             None if first else planes[0],
             None if first or not with_hits else planes[1])
@@ -742,7 +782,7 @@ def _accumulate_program(store: BlockStore, rung: Rung, with_hits: bool,
         lambda: accumulate_body(rung, store.ndocs_pad, with_hits, first,
                                 scorer),
         donate_argnums=() if first else
-        tuple(range(11, 13 if with_hits else 12)))
+        tuple(range(12, 14 if with_hits else 13)))
 
 
 def _topk_program(ndocs_pad: int, nq: int, with_hits: bool, k: int,
@@ -791,9 +831,7 @@ def score_topk_planes(store: BlockStore, qb: QueryBatch, rung: Rung,
     for ints, floats in query_chunks(qb, rung, store.n_packed, store.n_raw):
         prog = _accumulate_program(store, rung, with_hits, not planes,
                                    scorer)
-        planes = prog(store.block_base, store.block_gaps, store.block_tfs8,
-                      store.raw_docs, store.raw_tfs, store.norms,
-                      ints, floats, k1, b, avgdl, *planes)
+        planes = prog(*store.tiles, ints, floats, k1, b, avgdl, *planes)
     if masks:
         planes += (doc_masks(masks, rung.nq, store.ndocs_pad),)
     return _topk_program(store.ndocs_pad, rung.nq, with_hits, k,
@@ -832,10 +870,8 @@ def prebuild_plane_programs(store: BlockStore, rungs: tuple[Rung, ...],
                     *((doc_masks({}, rung.nq, store.ndocs_pad),)
                       if first == MASKED else ())))
             else:
-                planes = prog(store.block_base, store.block_gaps,
-                              store.block_tfs8, store.raw_docs,
-                              store.raw_tfs, store.norms, ints, floats,
-                              1.2, 0.75, 1.0, *planes)
+                planes = prog(*store.tiles, ints, floats, 1.2, 0.75, 1.0,
+                              *planes)
         jax.block_until_ready(tops)
 
     built = sum(not prog.called for steps in todo.values()
@@ -858,20 +894,23 @@ def _decode_rows(block_base, block_gaps, block_tfs8, row_idx):
     return jnp.where(valid, docs, -1), tfs
 
 
-def _accumulate_scores(block_base, block_gaps, block_tfs8, raw_docs,
-                       raw_tfs, norms, row_idx, row_w, row_qid, raw_idx,
-                       raw_w, raw_qid, tail_docs, tail_tfs, tail_w,
-                       tail_qid, ndocs_pad: int, n_queries: int,
-                       with_hits: bool, k1: float, b: float, avgdl,
-                       scorer: str = "bm25", scores=None, hits=None):
+def _accumulate_scores(block_base, block_gaps, block_tfs8, block_dls,
+                       raw_docs, raw_tfs, raw_dls, row_idx, row_w, row_qid,
+                       raw_idx, raw_w, raw_qid, tail_docs, tail_tfs,
+                       tail_dls, tail_w, tail_qid, ndocs_pad: int,
+                       n_queries: int, with_hits: bool, k1: float,
+                       b: float, avgdl, scorer: str = "bm25", scores=None,
+                       hits=None):
     """Fused gather+decode → score → batched scatter-accumulate into
     flat (B x ndocs,) score planes (+ hit counts when with_hits): onto
-    the planes handed in, or fresh ones. Shared by the accumulate step
-    and the mesh-sharded path, whose shards each accumulate their
-    posting-row slice before a psum merge."""
+    the planes handed in, or fresh ones. Every posting comes with its
+    document's length, read by ROW beside its tf (`block_dls`,
+    `raw_dls`, `tail_dls`): nothing is gathered per element. Shared by
+    the accumulate step and the mesh-sharded path, whose shards each
+    accumulate their posting-row slice before a psum merge."""
     avg = jnp.maximum(jnp.float32(avgdl), 1e-9)
 
-    def contrib_of(docs, tfs, w):
+    def contrib_of(docs, tfs, dl, w):
         valid = jnp.logical_and(docs >= 0, tfs > 0)
         safe_docs = jnp.where(valid, docs, 0)
         tfsf = tfs.astype(jnp.float32)
@@ -881,7 +920,7 @@ def _accumulate_scores(block_base, block_gaps, block_tfs8, raw_docs,
             # w = p_t (collection probability), k1 slot = µ. Lucene
             # LMDirichletSimilarity shape, clamped at 0
             # (reference: lm_dirichlet.cpp)
-            dl = norms[safe_docs].astype(jnp.float32)
+            dl = dl.astype(jnp.float32)
             mu = k1
             c = (jnp.log1p(tfsf / (mu * w)) +
                  jnp.log(mu / (dl + mu)))
@@ -890,7 +929,7 @@ def _accumulate_scores(block_base, block_gaps, block_tfs8, raw_docs,
             c = jnp.maximum(c, 0.0) + MATCH_EPS
         elif scorer == "jelinek_mercer":
             # w = p_t, k1 slot = λ (reference: jelinek_mercer smoothing)
-            dl = norms[safe_docs].astype(jnp.float32)
+            dl = dl.astype(jnp.float32)
             lam = k1
             c = jnp.log1p(((1.0 - lam) * tfsf / jnp.maximum(dl, 1.0)) /
                           (lam * w))
@@ -898,12 +937,12 @@ def _accumulate_scores(block_base, block_gaps, block_tfs8, raw_docs,
             # divergence from independence: expected tf under independence
             # is e = p_t·dl; score the standardized excess
             # (reference: dfi.cpp)
-            dl = norms[safe_docs].astype(jnp.float32)
+            dl = dl.astype(jnp.float32)
             e = w * dl
             excess = (tfsf - e) / jnp.sqrt(jnp.maximum(e, 1e-9))
             c = jnp.where(tfsf > e, jnp.log2(1.0 + excess), 0.0) + MATCH_EPS
         else:
-            dl = norms[safe_docs].astype(jnp.float32)
+            dl = dl.astype(jnp.float32)
             denom = tfsf + k1 * (1.0 - b + b * dl / avg)
             c = w * (k1 + 1.0) * tfsf / jnp.maximum(denom, 1e-9)
         return jnp.where(valid, c, 0.0), valid, safe_docs
@@ -912,19 +951,25 @@ def _accumulate_scores(block_base, block_gaps, block_tfs8, raw_docs,
         scores = jnp.zeros((n_queries * ndocs_pad,), dtype=jnp.float32)
     if with_hits and hits is None:
         hits = jnp.zeros((n_queries * ndocs_pad,), dtype=jnp.int32)
+    reads_dl = scorer != "tfidf"   # tfidf gathers no length plane
     # packed plane: gather + in-kernel delta decode
     pdocs, ptfs = _decode_rows(block_base, block_gaps, block_tfs8, row_idx)
-    wc, valid_b, safe_b = contrib_of(pdocs, ptfs, row_w[:, None])
+    wc, valid_b, safe_b = contrib_of(
+        pdocs, ptfs, block_dls[row_idx] if reads_dl else None,
+        row_w[:, None])
     bidx = (row_qid[:, None] * ndocs_pad + safe_b).reshape(-1)
     scores = scores.at[bidx].add(wc.reshape(-1))
-    # raw exception plane (rows whose gaps/tfs overflow the packed widths)
+    # raw exception plane (rows whose gaps/tfs/lengths overflow the
+    # packed widths)
     rdocs = raw_docs[raw_idx]
     rtfs = raw_tfs[raw_idx]
-    rc, valid_r, safe_r = contrib_of(rdocs, rtfs, raw_w[:, None])
+    rc, valid_r, safe_r = contrib_of(
+        rdocs, rtfs, raw_dls[raw_idx] if reads_dl else None,
+        raw_w[:, None])
     ridx = (raw_qid[:, None] * ndocs_pad + safe_r).reshape(-1)
     scores = scores.at[ridx].add(rc.reshape(-1))
     # light-term tails
-    tc, valid_t, safe_t = contrib_of(tail_docs, tail_tfs, tail_w)
+    tc, valid_t, safe_t = contrib_of(tail_docs, tail_tfs, tail_dls, tail_w)
     tidx = tail_qid * ndocs_pad + safe_t
     scores = scores.at[tidx].add(tc)
     if with_hits:
@@ -953,17 +998,18 @@ def _mesh_score_fn(mesh_n: int, ndocs_pad: int, k: int, n_queries: int,
 
         @functools.partial(
             shard_map, mesh=mesh,
-            in_specs=((P(),) * 6 + (P(), ) +        # store + avgdl
-                      (P(AXIS),) * 10),             # posting-row sections
+            in_specs=((P(),) * 7 + (P(), ) +        # tiles + avgdl
+                      (P(AXIS),) * 11),             # posting-row sections
             out_specs=(P(), P()))
-        def step(block_base, block_gaps, block_tfs8, raw_docs, raw_tfs,
-                 norms, avgdl, row_idx, row_w, row_qid, raw_idx, raw_w,
-                 raw_qid, tail_docs, tail_tfs, tail_w, tail_qid):
+        def step(block_base, block_gaps, block_tfs8, block_dls, raw_docs,
+                 raw_tfs, raw_dls, avgdl, row_idx, row_w, row_qid, raw_idx,
+                 raw_w, raw_qid, tail_docs, tail_tfs, tail_dls, tail_w,
+                 tail_qid):
             scores, _ = _accumulate_scores(
-                block_base, block_gaps, block_tfs8, raw_docs, raw_tfs,
-                norms, row_idx, row_w, row_qid, raw_idx, raw_w, raw_qid,
-                tail_docs, tail_tfs, tail_w, tail_qid, ndocs_pad,
-                n_queries, False, k1, b, avgdl, scorer)
+                block_base, block_gaps, block_tfs8, block_dls, raw_docs,
+                raw_tfs, raw_dls, row_idx, row_w, row_qid, raw_idx, raw_w,
+                raw_qid, tail_docs, tail_tfs, tail_dls, tail_w, tail_qid,
+                ndocs_pad, n_queries, False, k1, b, avgdl, scorer)
             scores = jax.lax.psum(scores, AXIS)
             return jax.lax.top_k(scores.reshape(n_queries, ndocs_pad), k)
 
@@ -990,9 +1036,7 @@ def score_topk_mesh(store, qb: "QueryBatch", ndocs_pad: int, k: int,
 
     fn = _mesh_score_fn(mesh_n, ndocs_pad, k, qb.n_queries, scorer,
                         float(k1), float(b))
-    return fn(store.block_base, store.block_gaps, store.block_tfs8,
-              store.raw_docs, store.raw_tfs, store.norms,
-              jnp.float32(avgdl),
+    return fn(*store.tiles, jnp.float32(avgdl),
               jnp.asarray(pad_sec(qb.row_idx, store.n_packed)),
               jnp.asarray(pad_sec(qb.row_w, np.float32(0.0))),
               jnp.asarray(pad_sec(qb.row_qid, 0)),
@@ -1001,6 +1045,7 @@ def score_topk_mesh(store, qb: "QueryBatch", ndocs_pad: int, k: int,
               jnp.asarray(pad_sec(qb.raw_qid, 0)),
               jnp.asarray(pad_sec(qb.tail_docs, -1, BLOCK)),
               jnp.asarray(pad_sec(qb.tail_tfs, 0, BLOCK)),
+              jnp.asarray(pad_sec(qb.tail_dls, 0, BLOCK)),
               jnp.asarray(pad_sec(qb.tail_w, np.float32(0.0), BLOCK)),
               jnp.asarray(pad_sec(qb.tail_qid, 0, BLOCK)))
 

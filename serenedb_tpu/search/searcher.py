@@ -21,6 +21,7 @@ import contextvars
 import functools
 import threading
 import time
+import weakref
 from dataclasses import dataclass
 from typing import Optional
 
@@ -171,6 +172,10 @@ class SegmentSearcher:
                 self.index.offsets, self.index.post_docs,
                 self.index.post_tfs, self.index.doc_freq,
                 self.index.norms, self.num_docs)
+            held = self._dev.length_bytes
+            metrics.SEARCH_POSTING_LENGTH_BYTES.add(held)
+            weakref.finalize(self._dev,
+                             metrics.SEARCH_POSTING_LENGTH_BYTES.sub, held)
         return self._dev
 
     def _dense_store(self, scorer: str,

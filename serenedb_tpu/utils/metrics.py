@@ -426,6 +426,12 @@ SEARCH_COUNT_BITSET_BYTES = REGISTRY.gauge(
     "bytes of dense-term doc bitsets built for count_filter (a term "
     "whose bitset is no larger than its posting list keeps one for the "
     "segment's life); accumulates over every segment built")
+SEARCH_POSTING_LENGTH_BYTES = REGISTRY.gauge(
+    "SearchPostingLengthBytes",
+    "HBM bytes of the per-posting document lengths of the resident "
+    "posting stores (BlockStore.block_dls + raw_dls: what the BM25 "
+    "accumulate step reads by row in place of a gather from the norms "
+    "table); falls when a segment's store is released")
 VECTOR_SEARCH_QUERIES = REGISTRY.gauge(
     "VectorSearchQueries",
     "knn / MaxSim queries scored by the vector subsystem "

@@ -482,3 +482,72 @@ def test_packed_store_exception_rows_and_compression():
         for j, (a, b) in enumerate(zip(dev_d.tolist(), ref_d.tolist())):
             if a != b:
                 assert abs(float(dev_s[j]) - float(ref_s[j])) < 1e-3
+
+
+@pytest.fixture(scope="module")
+def long_doc_segment():
+    """600 short documents and one of 70,000 tokens that holds every
+    word 35 times: its tfs and gaps fit the packed widths, its LENGTH
+    does not."""
+    rng = np.random.default_rng(8)
+    vocab = 2000
+    p = 1.0 / np.arange(1, vocab + 1) ** 0.9
+    p /= p.sum()
+    docs = [" ".join(f"w{t}" for t in rng.choice(vocab, int(n), p=p))
+            for n in rng.integers(20, 200, 600)]
+    docs.insert(300, " ".join(f"w{i % vocab}" for i in range(70_000)))
+    an = get_analyzer("simple")
+    return SegmentSearcher(build_field_index(docs, an), an, len(docs))
+
+
+def test_a_row_with_a_length_past_uint16_takes_the_raw_plane(
+        long_doc_segment):
+    from serenedb_tpu.ops import bm25 as bm25_ops
+    s = long_doc_segment
+    assert int(s.index.norms[300]) == 70_000
+    store = s._device_store()
+    assert int(np.asarray(store.raw_dls).max()) == 70_000
+    assert int(np.asarray(store.block_dls).max()) < 1 << 16
+    assert store.n_packed > 0
+    heavy = np.flatnonzero(store.heavy)
+    assert len(heavy) > 100
+    for tid in heavy:
+        b0, b1 = store.block_offsets[tid], store.block_offsets[tid + 1]
+        pd = s.index.postings(int(tid))[0]
+        at = b0 + int(np.searchsorted(pd, 300)) // bm25_ops.BLOCK
+        planes = store.row_plane[b0:b1]
+        # the row that holds document 300 is raw for its length alone
+        # (its tf is 35, its gaps are small); the term's other rows pack
+        assert planes[at - b0] == 1
+        assert store.block_bmax_tf[at] < 1 << 8
+        assert not np.delete(planes, at - b0).any()
+        slot = store.row_slot[at]
+        lane = np.flatnonzero(np.asarray(store.raw_docs[slot]) == 300)
+        assert np.asarray(store.raw_dls[slot])[lane].tolist() == [70_000]
+
+
+@pytest.mark.parametrize("scorer", ["bm25", "tfidf", "lm_dirichlet",
+                                    "jelinek_mercer", "dfi"])
+def test_topk_over_raw_length_rows_is_the_reference(
+        long_doc_segment, scorer, monkeypatch):
+    from serenedb_tpu.ops import bm25 as bm25_ops
+    monkeypatch.setattr(bm25_ops, "DENSE_HBM_BUDGET", 0)
+    s = long_doc_segment
+    for text in ["w0 & w3", "w1 & w2 & w40", "w5 & w700"]:
+        q = parse_query(text, s.analyzer)
+        tids = s.scoring_terms(q)
+        tiers = [None]
+        dev_s, dev_d = s.topk_batch([q], 10, scorer, tiers=tiers)[0]
+        assert tiers == ["device"]
+        match = s.eval_filter(q)
+        assert 300 in match.tolist()
+        ref_s, ref_d = s._cpu_score(match, tids, 10, scorer)
+        keep = ref_s > 0
+        ref_s, ref_d = ref_s[keep][:10], ref_d[keep][:10]
+        np.testing.assert_allclose(dev_s, ref_s, rtol=2e-5)
+        for j, (a, b) in enumerate(zip(dev_d.tolist(), ref_d.tolist())):
+            assert a == b or np.isclose(dev_s[j], ref_s[j], rtol=2e-5)
+        # the long document is scored by its own length: the same term
+        # counts in a document of the median length would rank first
+        if scorer == "bm25":
+            assert 300 not in dev_d.tolist()

@@ -325,6 +325,231 @@ def test_postings_dispatched_counts_what_the_steps_carry(plane):
     assert metrics.SEARCH_POSTINGS_DISPATCHED.value - before == df
 
 
+# ---- every posting brings its document's length: no norms gather (PR 34)
+
+SCORERS = ("bm25", "tfidf", "lm_dirichlet", "jelinek_mercer", "dfi")
+
+
+def _step_jaxpr(store, rung, scorer, with_hits=False):
+    import jax
+    (ints, floats), = bm25_ops.query_chunks(
+        bm25_ops._NO_QUERIES, rung, store.n_packed, store.n_raw,
+        min_steps=1)
+    body = bm25_ops.accumulate_body(rung, store.ndocs_pad, with_hits, True,
+                                    scorer)
+    return jax.make_jaxpr(body)(*store.tiles, ints, floats, 1.2, 0.75, 9.0)
+
+
+def _gathers(jaxpr):
+    """Every gather of the jaxpr, those of its nested jaxprs too."""
+    import jax
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "gather":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _gathers(sub)
+
+
+@pytest.mark.parametrize("nq", [1, 8, 32])
+@pytest.mark.parametrize("with_hits", [False, True])
+def test_accumulate_step_takes_no_norms_and_gathers_rows_only(
+        plane, nq, with_hits):
+    """The step has no (ndocs_pad,) operand, and all it gathers is rows:
+    a 128-lane row of a tile plane per index, or one base doc a row —
+    never an element a posting."""
+    ms, seg, _ = plane
+    store = seg._device_store()
+    rung = next(r for r in seg._rungs(store) if r.nq == nq)
+    nd = store.ndocs_pad
+    assert nd not in (store.block_base.shape[0], store.raw_docs.shape[0])
+    closed = _step_jaxpr(store, rung, "bm25", with_hits)
+    assert all(v.aval.shape != (nd,) for v in closed.jaxpr.invars)
+    seen = list(_gathers(closed.jaxpr))
+    # gaps, base, tfs, lengths of the packed plane; docs, tfs, lengths
+    # of the raw plane; the tails are gathered on the host
+    assert len(seen) == 7
+    for eqn in seen:
+        operand, idx = (v.aval for v in eqn.invars)
+        assert operand.shape != (nd,)
+        rows = idx.shape[0]
+        assert rows in (rung.nb, rung.nr)              # an index a ROW
+        assert eqn.outvars[0].aval.shape in ((rows,), (rows, bm25_ops.BLOCK))
+
+
+@pytest.mark.parametrize("scorer", SCORERS)
+def test_only_a_scorer_that_reads_lengths_gathers_the_length_planes(
+        plane, scorer):
+    ms, seg, _ = plane
+    store = seg._device_store()
+    rung = seg._rungs(store)[0]
+    closed = _step_jaxpr(store, rung, scorer)
+    ops = [eqn.invars[0] for eqn in _gathers(closed.jaxpr)]
+    block_dls, raw_dls = closed.jaxpr.invars[3], closed.jaxpr.invars[6]
+    assert (block_dls.aval.dtype, raw_dls.aval.dtype) == ("uint16", "int32")
+    want = 0 if scorer == "tfidf" else 1
+    assert sum(v is block_dls for v in ops) == want
+    assert sum(v is raw_dls for v in ops) == want
+
+
+def _norms_gather_step(rung, ndocs_pad, with_hits, scorer):
+    """The accumulate step as it was before the length planes: `dl` of
+    every posting gathered from the (ndocs_pad,) norms table by document
+    id — the old expressions, kept here as the bits to match."""
+    import jax.numpy as jnp
+    nb, nr, tt = rung.nb, rung.nr, rung.tt
+
+    def contrib_of(norms, docs, tfs, w, k1, b, avg):
+        valid = jnp.logical_and(docs >= 0, tfs > 0)
+        safe_docs = jnp.where(valid, docs, 0)
+        tfsf = tfs.astype(jnp.float32)
+        dl = norms[safe_docs].astype(jnp.float32)
+        if scorer == "tfidf":
+            c = w * jnp.sqrt(tfsf)
+        elif scorer == "lm_dirichlet":
+            c = jnp.log1p(tfsf / (k1 * w)) + jnp.log(k1 / (dl + k1))
+            c = jnp.maximum(c, 0.0) + bm25_ops.MATCH_EPS
+        elif scorer == "jelinek_mercer":
+            c = jnp.log1p(((1.0 - k1) * tfsf / jnp.maximum(dl, 1.0)) /
+                          (k1 * w))
+        elif scorer == "dfi":
+            e = w * dl
+            excess = (tfsf - e) / jnp.sqrt(jnp.maximum(e, 1e-9))
+            c = jnp.where(tfsf > e, jnp.log2(1.0 + excess), 0.0) + \
+                bm25_ops.MATCH_EPS
+        else:
+            denom = tfsf + k1 * (1.0 - b + b * dl / avg)
+            c = w * (k1 + 1.0) * tfsf / jnp.maximum(denom, 1e-9)
+        return jnp.where(valid, c, 0.0), valid, safe_docs
+
+    def step(block_base, block_gaps, block_tfs8, _block_dls, raw_docs,
+             raw_tfs, _raw_dls, norms, ints, floats, k1, b, avgdl, scores,
+             hits):
+        avg = jnp.maximum(jnp.float32(avgdl), 1e-9)
+        o = 2 * nb + 2 * nr
+        sections = (
+            (*bm25_ops._decode_rows(block_base, block_gaps, block_tfs8,
+                                    ints[:nb]),
+             floats[:nb, None], ints[nb:2 * nb, None]),
+            (raw_docs[ints[2 * nb:2 * nb + nr]],
+             raw_tfs[ints[2 * nb:2 * nb + nr]],
+             floats[nb:nb + nr, None], ints[2 * nb + nr:o, None]),
+            (ints[o:o + tt], ints[o + tt:o + 2 * tt],
+             floats[nb + nr:], ints[o + 3 * tt:]))
+        for docs, tfs, w, qid in sections:
+            c, valid, safe = contrib_of(norms, docs, tfs, w, k1, b, avg)
+            at = (qid * ndocs_pad + safe).reshape(-1)
+            scores = scores.at[at].add(c.reshape(-1))
+            hits = hits.at[at].add(valid.reshape(-1).astype(jnp.int32))
+        return scores, hits
+
+    return step
+
+
+@pytest.mark.parametrize("scorer", SCORERS)
+def test_scores_are_bit_for_bit_those_of_the_norms_gather(plane, scorer):
+    """(vals, docs) of the plane rung equal, bit for bit, what the same
+    store scores with every length gathered from `norms` — disjunctions
+    and a conjunction, packed rows, the raw row and tails, cut into
+    several steps — and stay within f32 of the host's float64."""
+    import jax
+    import jax.numpy as jnp
+    ms, seg, _ = plane
+    store = seg._device_store()
+    nodes = _questions(41, 6) + [QOr([QTerm("w3"), QTerm("w390")]),
+                                 QAnd([QTerm("w3"), QTerm("w5")])]
+    shapes = [seg._query_shape(q) for q in nodes]
+    queries = [(np.asarray(tids, dtype=np.int64), req)
+               for tids, req, _, _ in shapes]
+    idf_of = None
+    if scorer in bm25_ops.LM_SCORERS:
+        def idf_of(tids):
+            return bm25_ops.term_weight_for(
+                scorer, seg.num_docs, None, seg.index.ctf[tids],
+                float(seg.index.total_tokens))
+    qb = bm25_ops.assemble_query_batch(
+        store, seg.num_docs, queries, seg.index.doc_freq, scorer,
+        idf_of=idf_of)
+    assert len(qb.row_idx) and len(qb.raw_idx) and len(qb.tail_docs)
+    np.testing.assert_array_equal(qb.tail_dls,
+                                  seg.index.norms[qb.tail_docs])
+    rung = bm25_ops.Rung(8, 64, 1, 16)        # several steps a section
+    k1 = bm25_ops.scorer_param(scorer, 1.2)
+    avgdl = seg.index.avgdl
+    nd = store.ndocs_pad
+    chunks = bm25_ops.query_chunks(qb, rung, store.n_packed, store.n_raw)
+    assert len(chunks) > 3
+
+    new_first = jax.jit(bm25_ops.accumulate_body(rung, nd, True, True,
+                                                 scorer))
+    new_next = jax.jit(bm25_ops.accumulate_body(rung, nd, True, False,
+                                                scorer))
+    old = jax.jit(_norms_gather_step(rung, nd, True, scorer))
+    topk = jax.jit(bm25_ops.topk_body(nd, rung.nq, True, False, 10))
+    planes = ()
+    ref = (jnp.zeros(rung.nq * nd, jnp.float32),
+           jnp.zeros(rung.nq * nd, jnp.int32))
+    for ints, floats in chunks:
+        planes = (new_next if planes else new_first)(
+            *store.tiles, ints, floats, k1, 0.75, avgdl, *planes)
+        ref = old(*store.tiles, store.norms, ints, floats, k1, 0.75,
+                  avgdl, *ref)
+    np.testing.assert_array_equal(
+        np.asarray(planes[0]).view(np.uint32),
+        np.asarray(ref[0]).view(np.uint32))
+    np.testing.assert_array_equal(np.asarray(planes[1]), np.asarray(ref[1]))
+    require = bm25_ops._pad_to(qb.require, rung.nq, 0)
+    got_v, got_d = (np.asarray(a) for a in topk(require, *planes))
+    ref_v, ref_d = (np.asarray(a) for a in topk(require, *ref))
+    assert got_v.view(np.uint32).tolist() == ref_v.view(np.uint32).tolist()
+    assert got_d.tolist() == ref_d.tolist()
+    assert (got_v[:len(nodes), 0] > 0).sum() >= len(nodes) - 2
+    # and the host's float64 over the same candidates
+    for qi, (tids, req, _, _) in enumerate(shapes):
+        keep = got_v[qi] > 0
+        hs, hd = seg._cpu_score(got_d[qi][keep], tids, 10, scorer)
+        order = np.argsort(got_d[qi][keep])
+        np.testing.assert_allclose(
+            got_v[qi][keep][order], hs[np.argsort(hd)], rtol=2e-5)
+
+
+def test_length_planes_are_counted_where_the_store_is(plane):
+    """`hbm_bytes` holds the planes, the gauge what is resident of them,
+    and the store still prebuilds 24 programs."""
+    import gc
+    ms, seg, _ = plane
+    store = seg._device_store()
+    n_p, n_r = store.block_base.shape[0], store.raw_docs.shape[0]
+    assert store.block_dls.shape == store.block_tfs8.shape == (n_p, 128)
+    assert store.raw_dls.shape == store.raw_tfs.shape == (n_r, 128)
+    assert store.length_bytes == n_p * 128 * 2 + n_r * 128 * 4
+    assert store.hbm_bytes == n_p * (4 + 128 * 5) + n_r * 128 * 12
+    assert len(bm25_ops.plane_program_keys(seg._rungs(store))) == 24
+    # lane for lane the norms of the decoded documents, 0 on padding
+    docs, tfs = bm25_ops._decode_rows(
+        store.block_base, store.block_gaps, store.block_tfs8,
+        np.arange(n_p, dtype=np.int32))
+    docs, dls = np.asarray(docs), np.asarray(store.block_dls)
+    norms = np.asarray(store.norms)
+    assert (dls[docs >= 0] == norms[docs[docs >= 0]]).all()
+    assert not dls[docs < 0].any()
+    rdocs, rdls = np.asarray(store.raw_docs), np.asarray(store.raw_dls)
+    assert (rdls[rdocs >= 0] == norms[rdocs[rdocs >= 0]]).all()
+    assert not rdls[rdocs < 0].any()
+    # the gauge: up by a new segment's planes, down when it goes
+    an = get_analyzer("simple")
+    docs = _corpus(seed=6)[:500]
+    g0 = metrics.SEARCH_POSTING_LENGTH_BYTES.value
+    other = SegmentSearcher(build_field_index(docs, an), an, len(docs))
+    held = other._device_store().length_bytes
+    assert held > 0
+    assert metrics.SEARCH_POSTING_LENGTH_BYTES.value - g0 == held
+    assert metrics.REGISTRY.snapshot()["SearchPostingLengthBytes"] == \
+        g0 + held                                  # what /metrics renders
+    del other
+    gc.collect()
+    assert metrics.SEARCH_POSTING_LENGTH_BYTES.value == g0
+
+
 def test_dense_path_is_a_closed_set_too(monkeypatch):
     """A small corpus answers from the dense steps: built by CREATE INDEX,
     none by a search; a 17-term query takes two steps, a 33-term three."""
