@@ -1819,9 +1819,9 @@ class Connection:
         if hit is None:
             with stage("plan"):
                 plan = self._plan(sel, params)
-            ctx = self._exec_ctx(params)
-            if ctx.profile is not None:
-                self._active_plan = plan
+                ctx = self._exec_ctx(params)
+                if ctx.profile is not None:
+                    self._active_plan = plan
             if probe is not None:
                 with stage("cache_probe"):
                     probe.prepare(plan)
@@ -1875,7 +1875,6 @@ class Connection:
             metrics.MEM_ACCOUNT_EVENTS.add(mem.event_count())
         if not self._profile_enabled():
             return
-        metrics.QUERY_TIME_NS.add(elapsed_ns)
         metrics.QUERIES_EXECUTED.add()
         pruned = 0
         if profile is not None:

@@ -21,6 +21,7 @@ import numpy as np
 
 from ..columnar import dtypes as dt
 from ..columnar.column import Batch, Column
+from ..obs.trace import stage
 from ..search.query import QNode
 from ..sql.expr import BoundExpr
 from .plan import PlanNode
@@ -68,7 +69,6 @@ class SearchScanNode(PlanNode):
         NULL rows. The count fast path (`count_matching`) keeps the same
         rule without building the set. The doc-set algebra runs on the
         host: the request's `host_scan`."""
-        from ..obs.trace import stage
         with stage("host_scan"):
             docs = searcher.eval_filter(self.qnode)
             col = self.provider.host_column(self.search_column)
@@ -88,7 +88,6 @@ class SearchScanNode(PlanNode):
         searcher = self._searcher()
         if searcher is None:
             return None
-        from ..obs.trace import stage
         with stage("host_scan"):
             col = self.provider.host_column(self.search_column)
             return searcher.count_filter(self.qnode, col.validity)
@@ -96,21 +95,25 @@ class SearchScanNode(PlanNode):
     def batches(self, ctx):
         from .plan import check_cancel
         check_cancel()
-        searcher = self._searcher()
-        if searcher is None:
-            raise RuntimeError("search index disappeared under the plan "
-                               "(stale rewrite)")
-        # ONE publication observation: the batch being materialized and
-        # the zone-map verdicts pruning its candidate docs must come
-        # from the same pin, or a racing publish could prune docs whose
-        # values in the batch actually being scanned still match
-        pin = self.provider.try_pin()
-        if pin is not None and all(c in pin[0] for c in self.columns):
-            full = Batch(list(self.columns),
-                         [pin[0].column(c) for c in self.columns])
-        else:
-            full = self.provider.full_batch(self.columns)
-        mesh_n = int(ctx.settings.get("serene_mesh") or 0)
+        # the scan's own set-up and, below, the take of the page's rows
+        # are the request's `host_scan`, as the vector scan's are
+        with stage("host_scan"):
+            searcher = self._searcher()
+            if searcher is None:
+                raise RuntimeError("search index disappeared under the "
+                                   "plan (stale rewrite)")
+            # ONE publication observation: the batch being materialized
+            # and the zone-map verdicts pruning its candidate docs must
+            # come from the same pin, or a racing publish could prune
+            # docs whose values in the batch actually being scanned
+            # still match
+            pin = self.provider.try_pin()
+            if pin is not None and all(c in pin[0] for c in self.columns):
+                full = Batch(list(self.columns),
+                             [pin[0].column(c) for c in self.columns])
+            else:
+                full = self.provider.full_batch(self.columns)
+            mesh_n = int(ctx.settings.get("serene_mesh") or 0)
         if self.topk is not None:
             # all serving paths (SQL @@@/bm25 scans, ES _search/_msearch)
             # funnel through this scan — the batcher coalesces concurrent
@@ -120,16 +123,17 @@ class SearchScanNode(PlanNode):
             (scores, docs), bstats = batched_topk(
                 searcher, self.qnode, self.topk, self.scorer, mesh_n,
                 ctx.settings)
-            self._stamp_batch(ctx, bstats)
-            self._stamp_shards(ctx, searcher)
-            out = full.take(docs.astype(np.int64))
-            if self.with_score:
-                out = Batch(list(self.names),
-                            out.columns + [Column(dt.FLOAT,
-                                                  scores.astype(np.float32))])
-            if self.residual is not None:
-                c = self.residual.eval(out)
-                out = out.filter(c.data.astype(bool) & c.valid_mask())
+            with stage("host_scan"):
+                self._stamp_batch(ctx, bstats)
+                self._stamp_shards(ctx, searcher)
+                out = full.take(docs.astype(np.int64))
+                if self.with_score:
+                    out = Batch(list(self.names),
+                                out.columns + [Column(
+                                    dt.FLOAT, scores.astype(np.float32))])
+                if self.residual is not None:
+                    c = self.residual.eval(out)
+                    out = out.filter(c.data.astype(bool) & c.valid_mask())
             yield out
             return
         docs = self._matching_docs(searcher)
@@ -143,9 +147,10 @@ class SearchScanNode(PlanNode):
         # materialization, and residual evaluation is skipped entirely
         # when every surviving doc sits in an all-match block (stream
         # mode only — top-k applies its residual after ranking)
-        docs, residual_decided = self._prune_docs_by_zones(ctx, full, docs,
-                                                           pin)
-        out = full.take(docs.astype(np.int64))
+        with stage("host_scan"):
+            docs, residual_decided = self._prune_docs_by_zones(
+                ctx, full, docs, pin)
+            out = full.take(docs.astype(np.int64))
         if self.with_score:
             from ..search.batcher import batched_topk
             (scores, sdocs), bstats = batched_topk(
@@ -256,7 +261,6 @@ class IvfScanNode(PlanNode):
     def batches(self, ctx):
         from .plan import check_cancel
         check_cancel()
-        from ..obs.trace import stage
         from ..search import vector_store
         from ..search.ivf import find_ivf_index
         with stage("device_prepare"):

@@ -59,6 +59,8 @@ import time
 from collections import OrderedDict, deque
 from typing import Callable, Optional
 
+import numpy as np
+
 from ..utils import log, metrics
 from ..utils.config import REGISTRY as _settings
 from .trace import current_trace, stage
@@ -212,7 +214,6 @@ def fetch_all(outs) -> list:
     it is the request's `device_wait` stage (device execution + D2H).
     Conversion is what every call site did anyway — telemetry adds only
     the clock reads and one ledger bump."""
-    import numpy as np
     with stage("device_wait"):
         if not enabled():
             return [np.asarray(o) for o in outs]
@@ -247,20 +248,38 @@ def dispatch(prog, args, profile=None, node_key=None,
     return arrs
 
 
-def commit(x, target):
+def commit(x, target=None):
     """`jax.device_put` with upload accounting — the direct-commit
     sites that bypass DEVICE_CACHE (the sharded search merge's
-    candidate planes)."""
+    candidate planes, a program call's host operands). `x` is one
+    array or a tuple of them, put in one call and accounted as one
+    ledger entry of their summed bytes."""
     import jax
     if not enabled():
         return jax.device_put(x, target)
     t0 = time.perf_counter_ns()
-    arr = jax.device_put(x, target)
-    LEDGER.note_upload(int(arr.size * arr.dtype.itemsize),
-                       array_device_ids(arr),
-                       time.perf_counter_ns() - t0)
-    metrics.DEVICE_TRANSFERS_UP.add()
-    return arr
+    out = jax.device_put(x, target)
+    arrs = out if isinstance(out, tuple) else (out,)
+    LEDGER.note_upload(
+        sum(int(a.size * a.dtype.itemsize) for a in arrs),
+        array_device_ids(arrs[0]),
+        time.perf_counter_ns() - t0)
+    metrics.DEVICE_TRANSFERS_UP.add(len(arrs))
+    return out
+
+
+#: what `CompiledProgram.__call__` commits before the call; anything
+#: else (a `jax.Array`, None, a pytree) is the jitted function's own
+_HOST_OPERAND = (np.ndarray, np.generic, float, int)
+
+#: a Python scalar operand (k1, b, avgdl: the same few values call after
+#: call) is committed ONCE per value and kept: on the chip every
+#: transfer costs the host about 0.2 ms whatever its size (PERF.md §6,
+#: PR 35), so three floats a call were most of a call. Weak-typed like
+#: the Python value, so the program traced with one is the program
+#: called with the other.
+_SCALARS: dict = {}
+_SCALARS_MAX = 256
 
 
 # -- provider-token naming (sdb_device_cache's table column) ------------------
@@ -297,35 +316,82 @@ class CompiledProgram:
     feeds the `DeviceCompile` histogram + family stats, stamps a
     `device_compile` trace span so flight-recorder timelines attribute
     first-query compile stalls, and counts a per-device dispatch on
-    every call. Steady-state overhead is one flag read + one enabled()
-    check per dispatch."""
+    every call.
 
-    __slots__ = ("fn", "family", "compile_ns", "_timed")
+    A call's HOST operands — numpy arrays, numpy and Python scalars —
+    are committed to the device before the call, under the request's
+    `device_upload` stage, in one `commit` of the tuple; a Python
+    scalar crosses once per value and is kept (`_SCALARS`; never put
+    into a donated position). The jitted function sees `jax.Array`s
+    only, so `device_enqueue` is the call returning on resident
+    operands. The same bytes cross as inside the call, in at most as
+    many transfers; nothing blocks. Steady-state overhead is one
+    isinstance pass over the operands, two clock reads and one
+    enabled() check per call."""
 
-    def __init__(self, fn: Callable, family: str):
+    __slots__ = ("fn", "family", "compile_ns", "_timed", "_donated")
+
+    def __init__(self, fn: Callable, family: str,
+                 donate_argnums: tuple = ()):
         self.fn = fn
         self.family = family
         self.compile_ns: Optional[int] = None
         self._timed = False
+        self._donated = frozenset(donate_argnums)
 
     @property
     def called(self) -> bool:
         """Has run at least once, so its first shape is built."""
         return self._timed
 
+    def _upload(self, args: tuple) -> tuple:
+        """`args` with every host operand replaced, in place, by a
+        device array."""
+        host = [i for i, a in enumerate(args)
+                if isinstance(a, _HOST_OPERAND)]
+        if not host:
+            return args
+        args = list(args)
+        put, keys = [], {}
+        for i in host:
+            a = args[i]
+            # a Python scalar is kept once it has crossed — but a donated
+            # position would invalidate the kept buffer: put afresh there
+            if isinstance(a, (float, int)) and i not in self._donated:
+                keys[i] = (type(a), a)
+                kept = _SCALARS.get(keys[i])
+                if kept is not None:
+                    args[i] = kept
+                    continue
+            put.append(i)
+        if put:
+            with stage("device_upload", family=self.family):
+                arrs = commit(tuple(args[i] for i in put))
+                for i, arr in zip(put, arrs):
+                    if i in keys:
+                        if len(_SCALARS) >= _SCALARS_MAX:
+                            _SCALARS.clear()   # values that never repeat
+                        _SCALARS[keys[i]] = arr
+                    args[i] = arr
+        return tuple(args)
+
     def __call__(self, *args):
         # first call: benign race — two threads may both time; the
         # ledger records both observations, results are identical
         first, self._timed = not self._timed, True
         t0 = time.perf_counter_ns()
+        args = self._upload(args)
         # the call returning IS the request's `device_enqueue` stage
         # (the first call: trace + compile + enqueue)
-        with stage("device_enqueue"):
+        with stage("device_enqueue", family=self.family):
             out = self.fn(*args)
+        ns = time.perf_counter_ns() - t0
+        if not first:
+            # whatever the switches: what one call costs the host
+            metrics.DEVICE_ENQUEUE_CALL_HIST.observe_ns(ns)
         if not enabled():
             return out
         if first:
-            ns = time.perf_counter_ns() - t0
             self.compile_ns = ns
             PROGRAMS.record_compile_time(self.family, ns)
             tr = current_trace()
@@ -417,7 +483,8 @@ class ProgramLedger:
         import jax
         prog = CompiledProgram(
             jax.jit(_named(family, builder()),
-                    donate_argnums=donate_argnums), family)
+                    donate_argnums=donate_argnums), family,
+            donate_argnums)
         with self._lock:
             cur = self._progs.get(full)
             if cur is not None:

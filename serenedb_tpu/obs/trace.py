@@ -237,7 +237,8 @@ TRACE_RING_CAP = 8192
 #: `Stage*` histogram each (utils/metrics.py), one observation per
 #: request = the stage's summed time in that request
 STAGES = ("fd_parse", "fd_queue", "fd_encode", "cache_probe", "plan",
-          "device_prepare", "device_enqueue", "device_wait",
+          "device_prepare", "device_upload", "device_enqueue",
+          "device_wait",
           "device_finalize", "host_scan", "host_concat", "host_group",
           "host_sort", "batch_wait", "search_plan", "search_phrase",
           "search_host_score")

@@ -36,6 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..obs import device as obs_device
+from ..obs.trace import stage
 
 BLOCK = 128
 HEAVY_DF = 32     # terms with at least this many postings get block tiles
@@ -828,15 +829,18 @@ def score_topk_planes(store: BlockStore, qb: QueryBatch, rung: Rung,
     qb.n_queries are padding."""
     with_hits = bool(qb.require.any())
     planes: tuple = ()
-    for ints, floats in query_chunks(qb, rung, store.n_packed, store.n_raw):
+    with stage("search_plan"):
+        steps = query_chunks(qb, rung, store.n_packed, store.n_raw)
+    for ints, floats in steps:
         prog = _accumulate_program(store, rung, with_hits, not planes,
                                    scorer)
         planes = prog(*store.tiles, ints, floats, k1, b, avgdl, *planes)
-    if masks:
-        planes += (doc_masks(masks, rung.nq, store.ndocs_pad),)
+    with stage("search_plan"):
+        if masks:
+            planes += (doc_masks(masks, rung.nq, store.ndocs_pad),)
+        require = _pad_to(qb.require, rung.nq, 0)
     return _topk_program(store.ndocs_pad, rung.nq, with_hits, k,
-                         bool(masks))(
-        _pad_to(qb.require, rung.nq, 0), *planes)
+                         bool(masks))(require, *planes)
 
 
 def prebuild_plane_programs(store: BlockStore, rungs: tuple[Rung, ...],
@@ -1036,20 +1040,24 @@ def score_topk_mesh(store, qb: "QueryBatch", ndocs_pad: int, k: int,
 
     fn = _mesh_score_fn(mesh_n, ndocs_pad, k, qb.n_queries, scorer,
                         float(k1), float(b))
-    return fn(*store.tiles, jnp.float32(avgdl),
-              jnp.asarray(pad_sec(qb.row_idx, store.n_packed)),
-              jnp.asarray(pad_sec(qb.row_w, np.float32(0.0))),
-              jnp.asarray(pad_sec(qb.row_qid, 0)),
-              jnp.asarray(pad_sec(qb.raw_idx, store.n_raw)),
-              jnp.asarray(pad_sec(qb.raw_w, np.float32(0.0))),
-              jnp.asarray(pad_sec(qb.raw_qid, 0)),
-              jnp.asarray(pad_sec(qb.tail_docs, -1, BLOCK)),
-              jnp.asarray(pad_sec(qb.tail_tfs, 0, BLOCK)),
-              jnp.asarray(pad_sec(qb.tail_dls, 0, BLOCK)),
-              jnp.asarray(pad_sec(qb.tail_w, np.float32(0.0), BLOCK)),
-              jnp.asarray(pad_sec(qb.tail_qid, 0, BLOCK)))
-
-
+    with stage("search_plan"):
+        sections = [pad_sec(qb.row_idx, store.n_packed),
+                    pad_sec(qb.row_w, np.float32(0.0)),
+                    pad_sec(qb.row_qid, 0),
+                    pad_sec(qb.raw_idx, store.n_raw),
+                    pad_sec(qb.raw_w, np.float32(0.0)),
+                    pad_sec(qb.raw_qid, 0),
+                    pad_sec(qb.tail_docs, -1, BLOCK),
+                    pad_sec(qb.tail_tfs, 0, BLOCK),
+                    pad_sec(qb.tail_dls, 0, BLOCK),
+                    pad_sec(qb.tail_w, np.float32(0.0), BLOCK),
+                    pad_sec(qb.tail_qid, 0, BLOCK)]
+    # the program's own shardings place them: not `CompiledProgram`'s
+    # single-device commit
+    with stage("device_upload", family="bm25_mesh"):
+        operands = [jnp.float32(avgdl)] + [jnp.asarray(a)
+                                           for a in sections]
+    return fn(*store.tiles, *operands)
 
 
 # ------------------------------------------------------------ dense path
@@ -1237,19 +1245,21 @@ def dense_score_topk(ds: DenseStore, slots: list[tuple[np.ndarray,
     are padding."""
     n_steps = max(1, -(-max((len(t) for t, _ in slots), default=0)
                        // DENSE_SLOTS))
-    require = _pad_to(require, nq, 0)
+    with stage("search_plan"):
+        require = _pad_to(require, nq, 0)
     out: tuple = ()
     for c in range(n_steps):
-        tids = np.zeros((nq, DENSE_SLOTS), dtype=np.int32)
-        w = np.zeros((nq, DENSE_SLOTS), dtype=np.float32)
-        for qi, (t, wq) in enumerate(slots):
-            part = slice(c * DENSE_SLOTS, (c + 1) * DENSE_SLOTS)
-            tids[qi, :len(t[part])] = t[part]
-            w[qi, :len(t[part])] = wq[part]
         last = c == n_steps - 1
-        if last and masks:
-            out = (doc_masks(masks, nq, ds.ndocs_pad),) + out
-            last = MASKED
+        with stage("search_plan"):
+            tids = np.zeros((nq, DENSE_SLOTS), dtype=np.int32)
+            w = np.zeros((nq, DENSE_SLOTS), dtype=np.float32)
+            for qi, (t, wq) in enumerate(slots):
+                part = slice(c * DENSE_SLOTS, (c + 1) * DENSE_SLOTS)
+                tids[qi, :len(t[part])] = t[part]
+                w[qi, :len(t[part])] = wq[part]
+            if last and masks:
+                out = (doc_masks(masks, nq, ds.ndocs_pad),) + out
+                last = MASKED
         out = _dense_program(ds, nq, c == 0, last, k)(
             ds.St, tids, w, require, *out)
     return out

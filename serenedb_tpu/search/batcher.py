@@ -209,7 +209,6 @@ class SearchBatcher:
                 outs = None   # members retry serially; the bad re-raises
         REQUEST_MATCHES.reset(memo)
         t1 = time.perf_counter_ns()
-        wait_ns = 0
         seq = next(_DISPATCH_SEQ) if outs is not None else 0
         with self._lock:
             g.dispatching = False
@@ -220,7 +219,6 @@ class SearchBatcher:
                     x.window_ns = max(t0 - x.t_submit_ns, 0)
                     x.scoring_ns = t1 - t0
                     x.t_scored_ns = t1
-                    wait_ns += x.window_ns
                     if x.trace is not None:
                         # per-member timeline: how long THIS query
                         # waited queued (the `batch_wait` stage), then
@@ -245,7 +243,6 @@ class SearchBatcher:
         if outs is not None:
             metrics.SEARCH_BATCH_DISPATCHES.add()
             metrics.SEARCH_BATCH_QUERIES.add(len(batch))
-            metrics.SEARCH_BATCH_WINDOW_WAIT_NS.add(wait_ns)
             if len(batch) > 1:
                 metrics.SEARCH_BATCH_COALESCED.add(len(batch))
             for x in batch:

@@ -876,26 +876,34 @@ class SegmentSearcher:
                 n_postings = qb.n_postings
         if live:
             t_d = time.perf_counter_ns()
-            if use_mesh:
-                # mesh-sharded scoring: posting-row sections shard across
-                # the devices, score planes psum over ICI (SURVEY §5.7 —
-                # "scale one query across all compute"). require-free
-                # shapes only; _finish_batch applies exact-match masks as
-                # usual.
-                out = bm25_ops.score_topk_mesh(
-                    store, qb, nd_pad, kk, mesh_n,
-                    bm25_ops.scorer_param(scorer, K1), B, avgdl, scorer)
-            elif use_dense:
-                # small-corpus dense path: row gathers, no host WAND
-                # planning needed (the dense kernel is not scatter-bound)
-                out = bm25_ops.dense_score_topk(
-                    self._dense_store(scorer, avgdl), slots, require,
-                    bm25_ops.rung_for(rungs, len(queries)).nq, kk, masks)
-            else:
-                out = bm25_ops.score_topk_planes(
-                    store, qb, bm25_ops.rung_for(rungs, len(queries)), kk,
-                    bm25_ops.scorer_param(scorer, K1), B, avgdl, scorer,
-                    masks)
+            # `device_prepare` wraps the dispatch's calls, as it wraps a
+            # SQL offload: the steps' buffers, uploads and calls carve
+            # their own stages out of it, and what is left — program
+            # lookups, the ledger's notes between the calls — is its own
+            with stage("device_prepare"):
+                if use_mesh:
+                    # mesh-sharded scoring: posting-row sections shard
+                    # across the devices, score planes psum over ICI
+                    # (SURVEY §5.7 — "scale one query across all
+                    # compute"). require-free shapes only; _finish_batch
+                    # applies exact-match masks as usual.
+                    out = bm25_ops.score_topk_mesh(
+                        store, qb, nd_pad, kk, mesh_n,
+                        bm25_ops.scorer_param(scorer, K1), B, avgdl,
+                        scorer)
+                elif use_dense:
+                    # small-corpus dense path: row gathers, no host WAND
+                    # planning needed (the dense kernel is not
+                    # scatter-bound)
+                    out = bm25_ops.dense_score_topk(
+                        self._dense_store(scorer, avgdl), slots, require,
+                        bm25_ops.rung_for(rungs, len(queries)).nq, kk,
+                        masks)
+                else:
+                    out = bm25_ops.score_topk_planes(
+                        store, qb, bm25_ops.rung_for(rungs, len(queries)),
+                        kk, bm25_ops.scorer_param(scorer, K1), B, avgdl,
+                        scorer, masks)
             vals, docs = obs_device.fetch_all(out)
             metrics.DEVICE_DISPATCH_HIST.observe_ns(
                 time.perf_counter_ns() - t_d)

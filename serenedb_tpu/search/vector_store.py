@@ -706,7 +706,10 @@ class VectorPool:
                 rung = vops.flat_rung(len(chunk))
                 q = np.zeros((rung, int(idx.dim)), np.float32)
                 q[:len(chunk)] = chunk
-                qd = commit_host_array(q)
+                # the scan's one host operand, committed once for every
+                # segment's call: the request's `device_upload`
+                with stage("device_upload", family="knn_flat_scan"):
+                    qd = commit_host_array(q)
             t0 = time.perf_counter_ns()
             outs = []
             for seg, arrays in ents:

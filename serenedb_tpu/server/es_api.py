@@ -31,6 +31,7 @@ from .. import errors
 from ..columnar import dtypes as dt
 from ..columnar.column import Batch, Column
 from ..engine import Connection, Database, MemTable, StoredTable
+from ..obs.trace import stage_of
 
 
 class EsError(Exception):
@@ -82,13 +83,16 @@ class EsApi:
     def _read(self, sql: str, trace=None):
         """One read statement on this thread's connection — on the
         request's trace when there is one, so that the statements of one
-        `_search` land on one timeline."""
+        `_search` land on one timeline, the text's parse as its
+        `fd_parse`."""
         conn = self._rconn()
         if trace is None:
             return conn.execute(sql)
         from ..sql import parser
+        with stage_of(trace, "fd_parse"):
+            stmts = parser.parse(sql)
         res = None
-        for st in parser.parse(sql):
+        for st in stmts:
             res = conn.execute_statement(st, [], sql_text=sql, trace=trace)
         return res
 
@@ -438,25 +442,30 @@ class EsApi:
                 f"[{index}] is a SQL table, not an ES document index — "
                 "query it over the PG wire, or ingest documents through "
                 "the ES API (_doc/_bulk) to search here")
-        where, score_col = self._translate_query(body.get("query"))
-        want_hits = size > 0
-        want_total = body.get("track_total_hits", True) is not False
-        _count_request(body.get("query"), want_hits, want_total)
-        multi_claims = score_col if isinstance(score_col, list) else None
-        cols = '"_id", "_source"'
-        order = ""
-        if score_col and multi_claims is None:
-            cols += f", {score_col} AS _score"
-            order = " ORDER BY _score DESC"
-        sort = body.get("sort")
-        if sort:
-            order = " ORDER BY " + ", ".join(_sort_clause(s) for s in sort)
-            multi_claims = None     # explicit sort: no score ordering
-        sql = f'SELECT {cols} FROM "{index}"'
-        total_sql = f'SELECT count(*) FROM "{index}"'
-        if where:
-            sql += f" WHERE {where}"
-            total_sql += f" WHERE {where}"
+        # the DSL's translation and the two SQL texts are the request's
+        # `fd_parse`, as the body's JSON and the texts' own parse are
+        with stage_of(trace, "fd_parse"):
+            where, score_col = self._translate_query(body.get("query"))
+            want_hits = size > 0
+            want_total = body.get("track_total_hits", True) is not False
+            _count_request(body.get("query"), want_hits, want_total)
+            multi_claims = score_col if isinstance(score_col, list) \
+                else None
+            cols = '"_id", "_source"'
+            order = ""
+            if score_col and multi_claims is None:
+                cols += f", {score_col} AS _score"
+                order = " ORDER BY _score DESC"
+            sort = body.get("sort")
+            if sort:
+                order = " ORDER BY " + ", ".join(_sort_clause(s)
+                                                 for s in sort)
+                multi_claims = None     # explicit sort: no score ordering
+            sql = f'SELECT {cols} FROM "{index}"'
+            total_sql = f'SELECT count(*) FROM "{index}"'
+            if where:
+                sql += f" WHERE {where}"
+                total_sql += f" WHERE {where}"
         rows, total = [], None
         if multi_claims is not None:
             # multi-field scoring, rank-first (Lucene BooleanQuery: doc
@@ -490,26 +499,35 @@ class EsApi:
             with request_matches():
                 if want_hits:
                     sql += order + f" LIMIT {size} OFFSET {from_}"
-                    rows = list(self._read(sql, trace).rows())
+                    res = self._read(sql, trace)
+                    with stage_of(trace, "fd_encode"):
+                        rows = list(res.rows())
                 if want_total:
                     total = int(self._read(total_sql, trace).scalar())
-        hits = []
-        max_score = 0.0
-        for row in rows:
-            score = float(row[2]) if score_col and len(row) > 2 and \
-                row[2] is not None else 1.0
-            max_score = max(max_score, score)
-            hits.append({"_index": index, "_id": row[0], "_score": score,
-                         "_source": json.loads(row[1]) if row[1] else {}})
-        found = {"max_score": max_score if hits else None, "hits": hits}
-        if total is not None:
-            found = {"total": {"value": total, "relation": "eq"}, **found}
-        return {
-            "took": 1, "timed_out": False,
-            "_shards": {"total": 1, "successful": 1, "skipped": 0,
-                        "failed": 0},
-            "hits": found,
-        }
+        # the response's assembly (json.loads of every `_source`) is the
+        # request's `fd_encode`, as `_search_knn`'s is
+        with stage_of(trace, "fd_encode"):
+            hits = []
+            max_score = 0.0
+            for row in rows:
+                score = float(row[2]) if score_col and len(row) > 2 and \
+                    row[2] is not None else 1.0
+                max_score = max(max_score, score)
+                hits.append({"_index": index, "_id": row[0],
+                             "_score": score,
+                             "_source": json.loads(row[1]) if row[1]
+                             else {}})
+            found = {"max_score": max_score if hits else None,
+                     "hits": hits}
+            if total is not None:
+                found = {"total": {"value": total, "relation": "eq"},
+                         **found}
+            return {
+                "took": 1, "timed_out": False,
+                "_shards": {"total": 1, "successful": 1, "skipped": 0,
+                            "failed": 0},
+                "hits": found,
+            }
 
     def _multi_claim_page(self, index: str, where: str,
                           scores: dict[str, float],
@@ -565,8 +583,6 @@ class EsApi:
         metric is the INDEX's (`similarity` of the mapping), `_score` is
         ES's for it, and the query vector reaches the scan as an array
         parameter, never as SQL text."""
-        import numpy as np
-        from ..obs.trace import stage_of
         from ..search.ivf import declared_ivf_index
         knn = body["knn"]
         field = knn.get("field")

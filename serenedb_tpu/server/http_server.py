@@ -310,6 +310,11 @@ class Router:
             # the body's JSON (9 KB of it under a knn query vector) is
             # the request's `fd_parse`, as the SQL text's parse is
             tr = es.begin_request(f"{method} /{index}/_search", clock)
+            if tr is not None:
+                # the route's own parse (URL, query string, the body's
+                # decode) ran before there was a trace to stamp it on
+                tr.add_stage("fd_parse", clock.start_ns,
+                             time.perf_counter_ns())
             with stage_of(tr, "fd_parse"):
                 b = _json_body(body)
             return 200, es.search(index, b, tr), JSON_CTYPE

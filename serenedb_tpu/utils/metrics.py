@@ -305,8 +305,6 @@ ZONEMAP_STALE_REBUILDS = REGISTRY.gauge(
     "mutation invalidated the cached version")
 QUERIES_EXECUTED = REGISTRY.gauge(
     "QueriesExecuted", "statements completed (success) since start")
-QUERY_TIME_NS = REGISTRY.gauge(
-    "QueryTimeNs", "cumulative ns spent executing completed statements")
 SLOW_QUERIES = REGISTRY.gauge(
     "SlowQueries",
     "statements that exceeded serene_log_min_duration_ms and were "
@@ -340,10 +338,6 @@ SEARCH_BATCH_QUERIES = REGISTRY.gauge(
     "SearchBatchQueries",
     "top-k queries scored through batcher dispatches (QUERIES / "
     "DISPATCHES = mean batch size)")
-SEARCH_BATCH_WINDOW_WAIT_NS = REGISTRY.gauge(
-    "SearchBatchWindowWaitNs",
-    "cumulative ns queries spent queued in the batcher before their "
-    "dispatch started (coalescing latency cost)")
 SEARCH_BATCH_COALESCED = REGISTRY.gauge(
     "SearchBatchCoalesced",
     "queries that shared their scoring dispatch with at least one other "
@@ -604,12 +598,15 @@ REQUEST_LATENCY_HIST = REGISTRY.histogram(
 #: only when the stage occurred; per request they add up to
 #: RequestLatency exactly
 STAGE_HISTS = {name: REGISTRY.histogram(hist, desc) for name, hist, desc in (
-    ("fd_parse", "StageParse", "parser.parse at the front door"),
+    ("fd_parse", "StageParse",
+     "parser.parse at the front door; of a `_search`, the body's JSON, "
+     "the DSL's translation and its SQL texts' parse"),
     ("fd_queue", "StageFdQueue",
      "front-door thread handoffs: run_in_executor submit -> callable "
      "starts, callable done -> the session coroutine resumes"),
     ("fd_encode", "StageFdEncode",
-     "wire encoding and flush of the response"),
+     "wire encoding and flush of the response; of a `_search`, the "
+     "hits' assembly from the result rows"),
     ("cache_probe", "StageCacheProbe",
      "result-cache digest, lookups and store"),
     ("plan", "StagePlan", "bind, plan and search rewrite"),
@@ -617,8 +614,12 @@ STAGE_HISTS = {name: REGISTRY.histogram(hist, desc) for name, hist, desc in (
      "host work before a device program runs: admission, pin, "
      "factorize, key planning, residency lookup / upload, program "
      "lookup"),
+    ("device_upload", "StageDeviceUpload",
+     "a program call's host operands (numpy arrays, scalars) committed "
+     "to the device before the call, and the mesh scorer's sections"),
     ("device_enqueue", "StageDeviceEnqueue",
-     "the jitted call returning (first call: trace + compile)"),
+     "the jitted call returning, on device-resident operands only "
+     "(first call: trace + compile)"),
     ("device_wait", "StageDeviceWait",
      "the blocking readback: device execution + device->host copy"),
     ("device_finalize", "StageDeviceFinalize",
@@ -638,7 +639,9 @@ STAGE_HISTS = {name: REGISTRY.histogram(hist, desc) for name, hist, desc in (
      "dispatched, the end of that dispatch until its own thread resumes"),
     ("search_plan", "StageSearchPlan",
      "host planning of a scoring dispatch: query shapes, block-max WAND "
-     "plans, MaxScore candidates, batch assembly and packing"),
+     "plans, MaxScore candidates, batch assembly and packing, the "
+     "accumulate steps' buffers (query_chunks), doc masks and the "
+     "dense steps' slot fill"),
     ("search_phrase", "StageSearchPhrase",
      "the positional join of a phrase: the (document, position) keys of "
      "its slots intersected over the documents that hold all its terms "
@@ -701,6 +704,13 @@ DEVICE_DISPATCH_HIST = REGISTRY.histogram(
     "start of the program call (enqueue) to the end of the blocking "
     "readback of its outputs; a chained stage that leaves its outputs "
     "in HBM is observed by the stage that reads them back")
+DEVICE_ENQUEUE_CALL_HIST = REGISTRY.histogram(
+    "DeviceEnqueueCall",
+    "what one call of a jitted program costs the host: its host "
+    "operands' commit (`device_upload`) plus the call returning "
+    "(`device_enqueue`); one observation per call whatever the tracing "
+    "switches and however many requests a coalesced dispatch carries; "
+    "a first call is DeviceCompile's")
 DEVICE_COMPILE_HIST = REGISTRY.histogram(
     "DeviceCompile",
     "first-dispatch latency of each jitted device program (XLA "

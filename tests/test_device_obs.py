@@ -502,3 +502,155 @@ def test_programs_are_named_after_their_family():
     assert "jit_device_agg" in lowered.as_text()
     assert "device_agg" in lowered.as_text(debug_info=True)
     assert int(prog(x)) == 56
+
+
+# -- a call's host operands cross BEFORE the call (ISSUE 35) -------------------
+
+
+def _affine_program(donate=()):
+    """A program of its own key (nothing else in the process shares its
+    first call): out = a * k + b * n, plus an untouched pass-through."""
+    import uuid
+    return obs_device.compiled(
+        "upload_unit", (uuid.uuid4().hex,),
+        lambda: (lambda a, k, b, n, keep: (a * k + b * n, keep)),
+        donate_argnums=donate)
+
+
+def _upload_args():
+    import jax.numpy as jnp
+    a = np.arange(1024, dtype=np.float32) / 7.0
+    b = np.arange(1024, dtype=np.int32)
+    return a, 1.25, b, np.int32(3), jnp.ones(4, dtype=jnp.float32)
+
+
+def _stage_names(entry):
+    return [s["name"] for s in entry["spans"] if s["cat"] == "stage"]
+
+
+@pytest.mark.parametrize("telemetry", [True, False])
+def test_a_call_uploads_its_host_operands_then_enqueues(telemetry):
+    """numpy arrays, a numpy scalar and a Python float among the
+    operands: `device_upload` then `device_enqueue`, the jitted function
+    sees `jax.Array`s only, the transfer gauge and the ledger's bytes
+    move by the operands' bytes (dark with telemetry off), and the
+    outputs are the bits of the plain call."""
+    import jax
+
+    from serenedb_tpu.obs.trace import QueryTrace
+    prog = _affine_program()
+    args = _upload_args()
+    want = jax.jit(lambda a, k, b, n, keep: (a * k + b * n, keep))(*args)
+    prog(*args)                                    # the compiling call
+    seen = []
+    inner = prog.fn
+    prog.fn = lambda *xs: (seen.append(xs), inner(*xs))[1]
+    obs_device._SCALARS.clear()                    # 1.25 crosses again
+    prior = SETTINGS.get_global("serene_device_telemetry")
+    SETTINGS.set_global("serene_device_telemetry", telemetry)
+    try:
+        up0 = metrics.DEVICE_TRANSFERS_UP.value
+        led0 = sum(d["bytes_up"] for d in obs_device.LEDGER.snapshot()
+                   .values())
+        tr = QueryTrace("unit")
+        with tr.pinned():
+            out = prog(*args)
+        entry = tr.finish()
+        ups = metrics.DEVICE_TRANSFERS_UP.value - up0
+        led = sum(d["bytes_up"] for d in obs_device.LEDGER.snapshot()
+                  .values()) - led0
+    finally:
+        SETTINGS.set_global("serene_device_telemetry", prior)
+        prog.fn = inner
+    assert _stage_names(entry) == ["device_upload", "device_enqueue"]
+    spans = {s["name"]: s for s in entry["spans"]}
+    assert spans["device_upload"]["end_ns"] <= \
+        spans["device_enqueue"]["begin_ns"]
+    assert spans["device_upload"]["args"] == {"family": "upload_unit"} \
+        == spans["device_enqueue"]["args"]
+    (xs,) = seen
+    assert all(isinstance(x, jax.Array) for x in xs)
+    assert xs[1].weak_type and not xs[3].weak_type    # as the values were
+    assert xs[4] is args[4]                           # left alone
+    # 4 KiB + 4 KiB of arrays, 4 B + 4 B of scalars, four transfers
+    assert (ups, led) == ((4, 2 * 4096 + 8) if telemetry else (0, 0))
+    for got, ref in zip(out, want):
+        assert got.dtype == ref.dtype
+        assert np.asarray(got).tobytes() == np.asarray(ref).tobytes()
+
+
+def test_resident_operands_open_no_upload_and_a_scalar_crosses_once():
+    import jax
+
+    from serenedb_tpu.obs.trace import QueryTrace
+    prog = _affine_program()
+    a, k, b, n, keep = _upload_args()
+    prog(a, k, b, n, keep)                         # compile; 1.25 is kept
+    res = jax.device_put((a, b, n))
+    assert (float, 1.25) in obs_device._SCALARS
+    up0 = metrics.DEVICE_TRANSFERS_UP.value
+    tr = QueryTrace("unit")
+    with tr.pinned():
+        prog(res[0], k, res[1], res[2], keep)      # only the kept scalar
+        prog(res[0], res[0][0], res[1], res[2], keep)     # none at all
+    entry = tr.finish()
+    assert _stage_names(entry) == ["device_enqueue", "device_enqueue"]
+    assert metrics.DEVICE_TRANSFERS_UP.value == up0
+    # a value not seen before crosses (once), under the stage
+    tr = QueryTrace("unit")
+    with tr.pinned():
+        prog(res[0], 7.5, res[1], res[2], keep)
+        prog(res[0], 7.5, res[1], res[2], keep)
+    assert _stage_names(tr.finish()) == ["device_upload", "device_enqueue",
+                                         "device_enqueue"]
+    assert metrics.DEVICE_TRANSFERS_UP.value - up0 == 1
+
+
+def test_a_kept_scalar_is_never_handed_to_a_donated_position():
+    """Donation invalidates the buffer it is given: a Python scalar at a
+    donated position is put afresh every call, and the kept one of the
+    same value stays valid for the next program."""
+    prog = obs_device.compiled(
+        "upload_unit", ("donated",),
+        lambda: (lambda plane, k: (plane + k,)), donate_argnums=(0,))
+    keep = _affine_program()
+    a, _k, b, n, tail = _upload_args()
+    keep(a, 2.0, b, n, tail)                       # 2.0 is kept now
+    for _ in range(3):
+        (out,) = prog(2.0, 2.0)
+        assert float(out) == 4.0
+    (out, _t) = keep(a, 2.0, b, n, tail)
+    assert np.asarray(out)[1] == np.float32(a[1] * 2.0 + b[1] * 3)
+
+
+@pytest.mark.parametrize("trace,telemetry", [(True, True), (False, False)])
+def test_enqueue_call_histogram_counts_calls_not_compiles(trace, telemetry):
+    """`DeviceEnqueueCall`: one observation per program call whatever
+    `serene_trace` and `serene_device_telemetry` say; the compiling call
+    is `DeviceCompile`'s."""
+    from serenedb_tpu.obs.trace import QueryTrace
+    prior = SETTINGS.get_global("serene_device_telemetry")
+    SETTINGS.set_global("serene_device_telemetry", telemetry)
+    try:
+        n0 = metrics.DEVICE_ENQUEUE_CALL_HIST.count
+        c0 = metrics.DEVICE_COMPILE_HIST.count
+        prog = _affine_program()
+        args = _upload_args()
+        tr = QueryTrace("unit") if trace else None
+        if tr is not None:
+            tr._cv_token = tr.pinned()
+            tr._cv_token.__enter__()
+        try:
+            prog(*args)
+            assert metrics.DEVICE_ENQUEUE_CALL_HIST.count == n0
+            for _ in range(5):
+                prog(*args)
+        finally:
+            if tr is not None:
+                tr._cv_token.__exit__(None, None, None)
+        assert metrics.DEVICE_ENQUEUE_CALL_HIST.count - n0 == 5
+        assert metrics.DEVICE_COMPILE_HIST.count - c0 == int(telemetry)
+        _counts, total = metrics.DEVICE_ENQUEUE_CALL_HIST.snapshot()
+        assert total > 0
+    finally:
+        SETTINGS.set_global("serene_device_telemetry", prior)

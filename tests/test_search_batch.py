@@ -477,23 +477,31 @@ def test_msearch_error_isolation(db):
 
 
 def test_gauges_and_exports(db):
-    """SearchBatch{Dispatches,Queries,WindowWaitNs,Coalesced} exist, move
-    under load, and surface through /metrics and the /_stats metric
+    """SearchBatch{Dispatches,Queries,Coalesced} and the SearchBatchWindow
+    histogram (its sum is the queued time: no gauge repeats it) exist,
+    move under load, and surface through /metrics and the /_stats metric
     map."""
     base = {g: metrics.REGISTRY.snapshot()[g]
             for g in ("SearchBatchDispatches", "SearchBatchQueries",
-                      "SearchBatchWindowWaitNs", "SearchBatchCoalesced")}
+                      "SearchBatchCoalesced")}
+    counts0, wait0 = metrics.SEARCH_BATCH_WINDOW_HIST.snapshot()
     _run_queries(db, [QUERIES[0], QUERIES[4]], "on", 4, "off", threads=6)
     snap = metrics.REGISTRY.snapshot()
     assert snap["SearchBatchDispatches"] > base["SearchBatchDispatches"]
     assert snap["SearchBatchQueries"] > base["SearchBatchQueries"]
+    counts1, wait1 = metrics.SEARCH_BATCH_WINDOW_HIST.snapshot()
+    assert sum(counts1) - sum(counts0) == \
+        snap["SearchBatchQueries"] - base["SearchBatchQueries"]
+    assert wait1 >= wait0
     from serenedb_tpu.obs.export import prometheus_text, stats_json
     text = prometheus_text()
     for prom in ("serenedb_search_batch_dispatches",
                  "serenedb_search_batch_queries",
-                 "serenedb_search_batch_window_wait_ns",
+                 "serenedb_search_batch_window_seconds_sum",
                  "serenedb_search_batch_coalesced"):
         assert prom in text
+    assert "serenedb_search_batch_window_wait_ns" not in text
+    assert "serenedb_query_time_ns" not in text
     assert "SearchBatchDispatches" in stats_json()["metrics"]
 
 

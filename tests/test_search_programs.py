@@ -673,3 +673,135 @@ def test_es_search_is_one_request_on_one_timeline(monkeypatch):
     fams = {p["family"] for p in
             obs_device.stats_section()["programs"]}
     assert "dense_topk" in fams or "bm25_accumulate" in fams
+
+
+# -- a `_search`'s front and back doors, and the step buffers, have a stage
+# (ISSUE 35): structure, not timing ---------------------------------------------
+
+
+def _owner_of(entry, t_ns):
+    """The stage that owns the instant `t_ns` (an offset from the
+    request's start) on the request's timeline; "other" under none."""
+    for name, b, e in entry["timeline"]:
+        if b <= t_ns < e:
+            return name
+    return "other"
+
+
+def test_a_search_runs_its_parse_its_buffers_and_its_response_under_stages(
+        monkeypatch):
+    """The DSL's translation and both SQL texts' parse under `fd_parse`,
+    the accumulate steps' buffers and the doc masks under `search_plan`,
+    the response's assembly under `fd_encode`: each function is wrapped
+    to note WHEN it ran, and the request's timeline says whose instant
+    that was."""
+    from serenedb_tpu.search.searcher import SegmentSearcher
+    from serenedb_tpu.server import es_api as es_mod
+    from serenedb_tpu.server.http_server import RequestClock, Router
+    from serenedb_tpu.sql import parser as sql_parser
+    monkeypatch.setattr(bm25_ops, "DENSE_HBM_BUDGET", 0)     # plane steps
+    # every phrase match set is "large": a doc mask, not host candidates
+    monkeypatch.setattr(SegmentSearcher, "MAXSCORE_CAND_CAP", 0)
+    db = _search_db(800)
+    router = Router(es_mod.EsApi(db))
+    prior = SETTINGS.get_global("serene_result_cache")
+    SETTINGS.set_global("serene_result_cache", False)
+    ran: list = []
+
+    def noting(label, fn):
+        def wrapped(*a, **kw):
+            ran.append((label, trace_mod.time.perf_counter_ns()))
+            return fn(*a, **kw)
+        return wrapped
+
+    class Json:                       # es_api's own `json`, loads noted
+        dumps = staticmethod(json.dumps)
+        loads = staticmethod(noting("response", json.loads))
+
+    try:
+        docs = _corpus(13)[:800]
+        w = docs[3].split()
+        phrase = f"{w[0]} {w[1]}"
+        bodies = [{"query": {"match": {"body": "w1 w2 w30"}}, "size": 10},
+                  {"query": {"match_phrase": {"body": phrase}}, "size": 10}]
+        for body in bodies:                                  # warm
+            assert router.handle("POST", "/passages/_search",
+                                 json.dumps(body).encode())[0] == 200
+        monkeypatch.setattr(sql_parser, "parse",
+                            noting("parse", sql_parser.parse))
+        monkeypatch.setattr(es_mod.EsApi, "_translate_query", noting(
+            "translate", es_mod.EsApi._translate_query))
+        monkeypatch.setattr(bm25_ops, "query_chunks",
+                            noting("chunks", bm25_ops.query_chunks))
+        monkeypatch.setattr(bm25_ops, "doc_masks",
+                            noting("masks", bm25_ops.doc_masks))
+        monkeypatch.setattr(es_mod, "json", Json)
+        owners = {}
+        for body in bodies:
+            del ran[:]
+            clock = RequestClock()
+            status, data, _ = router.handle(
+                "POST", "/passages/_search", json.dumps(body).encode(),
+                clock)
+            clock.end()
+            assert status == 200 and json.loads(data)["hits"]["hits"]
+            entry = clock.trace.entry
+            _stages_add_up(entry)
+            for label, t in ran:
+                owners.setdefault(label, set()).add(
+                    _owner_of(entry, t - clock.trace.t0_ns))
+            # both texts of the request were parsed on its timeline
+            assert sum(label == "parse" for label, _ in ran) == 2
+        assert owners == {"parse": {"fd_parse"}, "translate": {"fd_parse"},
+                          "chunks": {"search_plan"},
+                          "masks": {"search_plan"},
+                          "response": {"fd_encode"}}, owners
+    finally:
+        SETTINGS.set_global("serene_result_cache", prior)
+
+
+@pytest.mark.parametrize("n_queries", [1, 5, 20])          # rungs 1, 8, 32
+@pytest.mark.parametrize("form", ["or", "and", "masked"])
+def test_every_rung_and_form_calls_prebuilt_programs_on_arrays_only(
+        plane, n_queries, form, monkeypatch):
+    """After `prebuild`, a batch of every rung in every form (union,
+    conjunction, a phrase under a doc mask) builds nothing, though its
+    host operands are committed before the call now: the prebuild's
+    calls went through the same commit, so a scalar reaches the program
+    with the type it was traced with. Every jitted function is handed
+    `jax.Array`s only."""
+    import jax
+
+    from serenedb_tpu.search.query import QPhrase
+    ms, seg, _ = plane
+    # no candidate list is short enough for the host rung: every
+    # question goes to the chip, a phrase's match set as a doc mask
+    monkeypatch.setattr(SegmentSearcher, "MAXSCORE_CAND_CAP", 0)
+    if form == "masked":
+        docs = _corpus()
+        nodes = []
+        for d in docs[100:100 + n_queries]:
+            w = d.split()
+            nodes.append(QPhrase([w[0], w[1]]))
+    else:
+        rng = np.random.default_rng(n_queries)
+        nodes = [(QAnd if form == "and" else QOr)(
+            [QTerm(f"w{t}") for t in rng.choice(40, 3, replace=False)])
+            for _ in range(n_queries)]
+    handed: list = []
+    with obs_device.PROGRAMS._lock:
+        progs = [p for (fam, _k), p in obs_device.PROGRAMS._progs.items()
+                 if fam in ("bm25_accumulate", "bm25_topk")]
+    inner = [p.fn for p in progs]
+    for p, fn in zip(progs, inner):
+        p.fn = (lambda *xs, _fn=fn: (handed.extend(xs), _fn(*xs))[1])
+    try:
+        with _Builds() as b:
+            got = ms.topk_batch(nodes, 10)
+    finally:
+        for p, fn in zip(progs, inner):
+            p.fn = fn
+    assert (b.jax, b.ledger) == (0, 0)
+    assert handed and all(isinstance(x, jax.Array) for x in handed)
+    serial = [ms.topk_batch([q], 10)[0] for q in nodes]
+    assert _bits(got) == _bits(serial)

@@ -194,11 +194,14 @@ def test_trace_coverage_at_workers_shards():
 # -- coalesced-batch span fan-out -------------------------------------------
 
 
-def test_coalesced_batch_span_fanout():
+@pytest.mark.parametrize("carried", ["batch_dispatch", "device_upload"])
+def test_coalesced_batch_span_fanout(carried):
     """A coalesced search dispatch stamps its spans under EVERY member
     query's trace: concurrent identical top-k searches must yield at
     least one trace whose batch_dispatch span carries queries >= 2,
-    and every member of that dispatch must carry the span too."""
+    and every member of that dispatch must carry the span too — and
+    (`device_upload`) the dispatch's stages, the commit of its host
+    operands among them, though one thread ran them."""
     db = Database()
     c = db.connect()
     c.execute("CREATE TABLE docs (id INT, body TEXT)")
@@ -252,6 +255,20 @@ def test_coalesced_batch_span_fanout():
             sizes.update(s["args"]["queries"] for s in e["spans"]
                          if s["name"] == "batch_dispatch")
         assert len(sizes) >= 1 and max(sizes) >= len(members)
+        if carried == "device_upload":
+            for tid in members:
+                e = FLIGHT.get(tid)
+                assert e["stages"].get("device_upload", 0) > 0, e["stages"]
+                assert e["stages"].get("device_enqueue", 0) > 0
+                assert sum(e["stages"].values()) == e["duration_ns"]
+                ups = [s for s in e["spans"] if s["cat"] == "stage"
+                       and s["name"] == "device_upload"]
+                # inside the dispatch it rode
+                (disp,) = [s for s in e["spans"]
+                           if s["name"] == "batch_dispatch"]
+                assert ups and all(
+                    disp["begin_ns"] <= s["begin_ns"] and
+                    s["end_ns"] <= disp["end_ns"] for s in ups)
     finally:
         SETTINGS.set_global("serene_result_cache", prior)
 
@@ -853,11 +870,22 @@ def test_profiler_host_plane_holds_stages_not_envelopes(tmp_path):
     from jax.profiler import ProfileData
     db, c = _device_conn()
     c.execute(DEVICE_AGG_Q)                  # compile outside the trace
+    # a search's steps take host operands (a device aggregate's are
+    # resident columns: its calls open no `device_upload`)
+    c.execute("CREATE TABLE docs (id INT, body TEXT)")
+    c.execute("INSERT INTO docs VALUES " + ", ".join(
+        f"({i}, 'quick brown fox number{i % 7} jumps')"
+        for i in range(256)))
+    c.execute("CREATE INDEX ON docs USING inverted (body)")
+    search = ("SELECT id, bm25(body) s FROM docs WHERE body @@ "
+              "'fox number3' ORDER BY s DESC, id LIMIT 5")
+    c.execute(search)
     jax.profiler.start_trace(str(tmp_path))
     try:
         c.execute(DEVICE_AGG_Q)
         tid = c._active_trace.trace_id
         c.execute(HOST_Q)
+        assert len(c.execute(search).rows()) == 5
     finally:
         jax.profiler.stop_trace()
     paths = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
@@ -872,7 +900,8 @@ def test_profiler_host_plane_holds_stages_not_envelopes(tmp_path):
                     names.add(ev.name)
                     stats.setdefault(ev.name, []).append(dict(ev.stats))
     assert {"sdb.plan", "sdb.device_wait", "sdb.device_enqueue",
-            "sdb.host_group"} <= names
+            "sdb.host_group", "sdb.device_upload",
+            "sdb.search_plan"} <= names
     assert names <= {"sdb." + s for s in trace_mod.STAGES}
     assert "sdb.request" not in names and "sdb.execute" not in names \
         and "sdb.query" not in names
