@@ -9,7 +9,12 @@ Here the logical SQL type system is small and explicit, and every type has a
 - VARCHAR: dictionary-encoded int32 codes on device; the dictionary
   (per-column, per-segment) stays host-side. String predicates are resolved
   against the dictionary on CPU and become integer-code predicates on device.
-- DECIMAL is not implemented yet (DOUBLE covers the analytics benchmarks).
+- DECIMAL(p, s), p <= 18: the value times 10^s as int64 (DuckDB's own
+  physical form for such widths), so zone maps and the device tiers see
+  an integer. `+` and `-` align scales, `*` adds them, an int64 overflow
+  raises 22003; SUM is DECIMAL(18, s), MIN/MAX keep the type. `/` and
+  AVG return DOUBLE: a departure from PG's `numeric`, whose quotient is
+  another exact numeric.
 """
 
 from __future__ import annotations
@@ -49,6 +54,7 @@ class TypeId(enum.Enum):
                              # JSON {"o":[oid,...],"v":[...]} text in a
                              # dictionary column; wire layer renders PG
                              # (…) text / the binary record format (2249)
+    DECIMAL = "DECIMAL"      # DECIMAL(p, s), p <= 18: value * 10^s, int64
     VECTOR = "VECTOR"        # pgvector's VECTOR(n): n float32 a row,
                              # physically ONE contiguous (rows, n) float32
                              # array (the only 2-D column); text form
@@ -71,6 +77,7 @@ _NUMPY_OF = {
     TypeId.ARRAY: np.dtype(np.int32),     # dictionary codes (JSON text)
     TypeId.RECORD: np.dtype(np.int32),    # dictionary codes (JSON text)
     TypeId.VECTOR: np.dtype(np.float32),  # (rows, dim)
+    TypeId.DECIMAL: np.dtype(np.int64),   # scaled by 10^scale
     TypeId.OID: np.dtype(np.int64),
     TypeId.REGCLASS: np.dtype(np.int64),
     TypeId.REGTYPE: np.dtype(np.int64),
@@ -95,6 +102,9 @@ class SqlType:
     elem: "TypeId | None" = None
     #: VECTOR dimension count (0 elsewhere)
     dim: int = 0
+    #: DECIMAL precision and scale (0 elsewhere)
+    prec: int = 0
+    scale: int = 0
 
     @property
     def is_vector(self) -> bool:
@@ -113,8 +123,13 @@ class SqlType:
         return self.id in _FLOATS
 
     @property
+    def is_decimal(self) -> bool:
+        return self.id is TypeId.DECIMAL
+
+    @property
     def is_numeric(self) -> bool:
-        return self.is_integer or self.is_float or self.id is TypeId.BOOL
+        return self.is_integer or self.is_float or \
+            self.id in (TypeId.BOOL, TypeId.DECIMAL)
 
     @property
     def is_string(self) -> bool:
@@ -128,6 +143,8 @@ class SqlType:
             return "record"
         if self.id is TypeId.VECTOR:
             return f"VECTOR({self.dim})"
+        if self.id is TypeId.DECIMAL:
+            return f"DECIMAL({self.prec},{self.scale})"
         return self.id.value
 
 
@@ -167,6 +184,36 @@ def vector_of(dim: int) -> SqlType:
     return SqlType(TypeId.VECTOR, None, dim)
 
 
+#: widest DECIMAL an int64 holds: 18 digits
+MAX_DECIMAL_PRECISION = 18
+
+
+def decimal_of(prec: int, scale: int) -> SqlType:
+    """DECIMAL(prec, scale) as a scaled int64 (prec <= 18)."""
+    prec, scale = int(prec), int(scale)
+    if not 1 <= prec <= MAX_DECIMAL_PRECISION:
+        raise ValueError(f"DECIMAL precision {prec} must be between 1 and "
+                         f"{MAX_DECIMAL_PRECISION}")
+    if not 0 <= scale <= prec:
+        raise ValueError(f"DECIMAL scale {scale} must be between 0 and "
+                         f"the precision {prec}")
+    return SqlType(TypeId.DECIMAL, None, 0, prec, scale)
+
+
+def decimal_text(v: int, scale: int) -> str:
+    """Text of a scaled integer with exactly `scale` fraction digits."""
+    v = int(v)
+    if not scale:
+        return str(v)
+    sign = "-" if v < 0 else ""
+    whole, frac = divmod(abs(v), 10 ** scale)
+    return f"{sign}{whole}.{frac:0{scale}d}"
+
+
+_DECIMAL_NAME = re.compile(
+    r"^(?:DECIMAL|NUMERIC|DEC)\s*(?:\(\s*(\d+)\s*(?:,\s*(\d+)\s*)?\))?$")
+
+
 #: VECTOR(n), pgvector's spelling — and FLOAT4[n] / REAL[n] / FLOAT[n],
 #: what a Postgres user without the extension writes for the same thing
 _VECTOR_NAME = re.compile(
@@ -196,7 +243,7 @@ _RANK = {
     TypeId.BOOL: 0, TypeId.TINYINT: 1, TypeId.SMALLINT: 2, TypeId.INT: 3,
     TypeId.DATE: 3, TypeId.BIGINT: 4, TypeId.TIMESTAMP: 4,
     TypeId.OID: 4, TypeId.REGCLASS: 4, TypeId.REGTYPE: 4, TypeId.REGPROC: 4,
-    TypeId.REGNAMESPACE: 4,
+    TypeId.REGNAMESPACE: 4, TypeId.DECIMAL: 4.5,
     TypeId.FLOAT: 5, TypeId.DOUBLE: 6,
 }
 
@@ -206,6 +253,12 @@ def type_from_name(name: str) -> SqlType:
     m = _VECTOR_NAME.match(key)
     if m:
         return vector_of(int(m.group(1) or m.group(2)))
+    m = _DECIMAL_NAME.match(key)
+    if m:
+        # DECIMAL alone is DuckDB's DECIMAL(18,3); DECIMAL(p) has scale 0
+        if m.group(1) is None:
+            return decimal_of(18, 3)
+        return decimal_of(int(m.group(1)), int(m.group(2) or 0))
     key = re.sub(r"\[\d+\]$", "[]", key)   # PG ignores a declared array size
     if key.endswith("[]"):
         return array_of(type_from_name(key[:-2]))
@@ -248,12 +301,50 @@ def common_numeric(a: SqlType, b: SqlType) -> SqlType:
         raise TypeError(f"non-numeric type {a}")
     if not (b.is_numeric or b.id in (TypeId.TIMESTAMP, TypeId.DATE)):
         raise TypeError(f"non-numeric type {b}")
+    if a.is_decimal or b.is_decimal:
+        if a.is_float or b.is_float:
+            return DOUBLE
+        return decimal_of(MAX_DECIMAL_PRECISION, max(a.scale, b.scale))
     return a if _RANK[a.id] >= _RANK[b.id] else b
+
+
+class ExactFloat(float):
+    """A numeric literal written with a decimal point: the float the
+    engine has always used, plus its exact decimal text, so that beside a
+    DECIMAL it is typed exactly (`0.05` is 5 at scale 2, never a float
+    rounded back). A constant folded from two such literals keeps the
+    binary float's value and the exact text (`0.06 - 0.01`)."""
+
+    def __new__(cls, text: str, value: "float | None" = None):
+        obj = super().__new__(cls, text if value is None else value)
+        obj.text = text
+        return obj
+
+    def __reduce__(self):
+        return (ExactFloat, (self.text, float(self)))
+
+
+def exact_decimal(v) -> "tuple[int, int] | None":
+    """(scaled integer, scale) of an int or an ExactFloat literal written
+    without an exponent; None for any other value."""
+    if isinstance(v, bool):
+        return None
+    if isinstance(v, int):
+        return v, 0
+    if isinstance(v, ExactFloat):
+        m = re.fullmatch(r"([+-]?)(\d*)\.(\d*)", v.text.strip())
+        if m is None:
+            return None
+        frac = m.group(3)
+        n = int((m.group(2) or "0") + frac)
+        return (-n if m.group(1) == "-" else n), len(frac)
+    return None
 
 
 def type_of_numpy(dt: np.dtype) -> SqlType:
     for tid, nd in _NUMPY_OF.items():
-        if tid in (TypeId.VARCHAR, TypeId.NULL, TypeId.DATE, TypeId.VECTOR):
+        if tid in (TypeId.VARCHAR, TypeId.NULL, TypeId.DATE, TypeId.VECTOR,
+                   TypeId.DECIMAL):
             continue
         if nd == dt:
             return SqlType(tid)

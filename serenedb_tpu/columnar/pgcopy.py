@@ -51,6 +51,8 @@ def encode_value(v, typ: dt.SqlType) -> Optional[bytes]:
         return struct.pack("!i", int(v) - _PG_EPOCH_DAYS)
     if tid is dt.TypeId.INTERVAL:
         return struct.pack("!qii", int(v), 0, 0)
+    if tid is dt.TypeId.DECIMAL:
+        return _encode_numeric_binary(int(v), typ.scale)
     if tid in _OID_IDS:
         return struct.pack("!I", int(v) & 0xFFFFFFFF)
     if tid is dt.TypeId.ARRAY:
@@ -58,6 +60,36 @@ def encode_value(v, typ: dt.SqlType) -> Optional[bytes]:
     if tid is dt.TypeId.RECORD:
         return _encode_record_binary(str(v))
     return str(v).encode()
+
+
+def _encode_numeric_binary(v: int, scale: int) -> bytes:
+    """PG `numeric` binary send of a scaled integer: base-10000 digits
+    around the decimal point, then weight, sign and display scale."""
+    sign = 0x4000 if v < 0 else 0
+    whole, frac = divmod(abs(v), 10 ** scale)
+    pad = (-scale) % 4                 # fraction digits to a 4-digit group
+    frac_groups = []
+    f = frac * 10 ** pad
+    for _ in range((scale + pad) // 4):
+        f, d = divmod(f, 10000)
+        frac_groups.append(d)
+    frac_groups.reverse()
+    whole_groups = []
+    while whole:
+        whole, d = divmod(whole, 10000)
+        whole_groups.append(d)
+    whole_groups.reverse()
+    digits = whole_groups + frac_groups
+    weight = len(whole_groups) - 1
+    while digits and digits[-1] == 0:      # trailing zero groups
+        digits.pop()
+    while digits and digits[0] == 0:       # leading zero groups
+        digits.pop(0)
+        weight -= 1
+    if not digits:
+        weight = 0
+    return struct.pack(f"!hhHH{len(digits)}H", len(digits), weight, sign,
+                       scale, *digits)
 
 
 #: element TypeId → array OID (PG catalog values; record fields carry
@@ -88,6 +120,7 @@ FIELD_OID = {
     dt.TypeId.DOUBLE: 701, dt.TypeId.VARCHAR: 25, dt.TypeId.NULL: 25,
     dt.TypeId.DATE: 1082, dt.TypeId.TIMESTAMP: 1114,
     dt.TypeId.INTERVAL: 1186, dt.TypeId.RECORD: 2249,
+    dt.TypeId.DECIMAL: 1700,
 }
 
 
@@ -121,6 +154,8 @@ def _scalar_field_text(t: dt.SqlType, v) -> str:
     if t.id is dt.TypeId.DATE:
         import numpy as _np
         return str(_np.datetime64(int(v), "D"))
+    if t.id is dt.TypeId.DECIMAL:
+        return dt.decimal_text(v, t.scale)
     if t.id is dt.TypeId.INTERVAL:
         from ..sql.binder import format_interval
         return format_interval(int(v))

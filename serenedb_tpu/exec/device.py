@@ -21,11 +21,34 @@ import numpy as np
 
 from ..columnar import dtypes as dt
 from ..columnar.device import DeviceColumn
-from ..sql.expr import (BoundColumn, BoundExpr, BoundFunc, BoundLiteral)
+from ..sql.expr import (BoundCase, BoundColumn, BoundExpr, BoundFunc,
+                        BoundLiteral)
 
 _NUMERIC_IDS = {dt.TypeId.BOOL, dt.TypeId.TINYINT, dt.TypeId.SMALLINT,
                 dt.TypeId.INT, dt.TypeId.BIGINT, dt.TypeId.FLOAT,
-                dt.TypeId.DOUBLE, dt.TypeId.TIMESTAMP, dt.TypeId.DATE}
+                dt.TypeId.DOUBLE, dt.TypeId.TIMESTAMP, dt.TypeId.DATE,
+                dt.TypeId.DECIMAL}
+
+#: functions that pass their argument's physical value through: a
+#: DECIMAL's scaled integer read as the BIGINT it is, and back
+_IDENTITY_FUNCS = {"decimal_raw", "decimal_of"}
+
+
+def _scale(t: dt.SqlType) -> int:
+    return t.scale if t.is_decimal else 0
+
+
+def _rescale(fn, k: int):
+    """A compiled operand times 10^k (a DECIMAL brought to a larger
+    scale; k >= 0)."""
+    if k == 0:
+        return fn
+    f = 10 ** k
+
+    def scaled(env, _fn=fn, _f=f):
+        v, ok = _fn(env)
+        return v * jnp.int32(_f), ok
+    return scaled
 
 _CMP = {"op=", "op<>", "op!=", "op<", "op<=", "op>", "op>="}
 _ARITH = {"op+", "op-", "op*", "op/", "op%"}
@@ -97,7 +120,31 @@ def compile_expr(expr: BoundExpr, col_types: list[dt.SqlType],
             return lambda env, _s=s: env[_s]
         if isinstance(e, BoundFunc):
             return rec_func(e)
+        if isinstance(e, BoundCase):
+            return compile_case(e)
         raise NotCompilable(type(e).__name__)
+
+    def compile_case(e: BoundCase):
+        """First TRUE branch wins (SQL drops FALSE and NULL alike); no
+        branch and no ELSE is NULL."""
+        if e.type.is_string or e.type.is_float:
+            raise NotCompilable(f"CASE of {e.type}")
+        arms = [(rec(c), rec(v)) for c, v in e.branches]
+        other = rec(e.else_) if e.else_ is not None else None
+
+        def fn(env, _arms=arms, _other=other):
+            if _other is None:
+                val, ok = jnp.int32(0), jnp.bool_(False)
+            else:
+                val, ok = _other(env)
+            for cf, vf in reversed(_arms):
+                cv, cok = cf(env)
+                hit = jnp.logical_and(_as_bool(cv), _m(cok))
+                v, vok = vf(env)
+                val = jnp.where(hit, v, val)
+                ok = jnp.where(hit, _m(vok), _m(ok))
+            return val, ok
+        return fn
 
     def rec_func(e: BoundFunc):
         name = e.name
@@ -141,8 +188,30 @@ def compile_expr(expr: BoundExpr, col_types: list[dt.SqlType],
                 m = _m(ok)
                 return (m if _neg else ~m), True
             return fn
+        if name in _IDENTITY_FUNCS:
+            return rec(e.args[0])
+        if name in ("int32_hi16", "int32_lo16"):
+            # the halves of an int32: x = (x >> 16) * 2^16 + (x & 0xFFFF)
+            sub = rec(e.args[0])
+            hi = name == "int32_hi16"
+
+            def fn(env, _sub=sub, _hi=hi):
+                v, ok = _sub(env)
+                v = v.astype(jnp.int32)
+                return (jnp.right_shift(v, 16) if _hi
+                        else jnp.bitwise_and(v, 0xFFFF)), ok
+            return fn
         if name == "cast":
             sub = rec(e.args[0])
+            src = e.args[0].type
+            if e.type.is_decimal:
+                k = e.type.scale - _scale(src)
+                if k < 0 or not (src.is_decimal or src.is_integer or
+                                 src.id is dt.TypeId.BOOL):
+                    raise NotCompilable("cast to DECIMAL rounds")
+                return _rescale(sub, k)
+            if src.is_decimal:
+                raise NotCompilable("cast from DECIMAL")
             if e.type.is_float:
                 def fn(env, _sub=sub):
                     v, ok = _sub(env)
@@ -175,6 +244,12 @@ def compile_expr(expr: BoundExpr, col_types: list[dt.SqlType],
                 (isinstance(b, BoundColumn) and b.type.is_string):
             raise NotCompilable("string-string comparison on device")
         fa, fb = rec(a), rec(b)
+        if a.type.is_decimal or b.type.is_decimal:
+            if a.type.is_float or b.type.is_float:
+                raise NotCompilable("DECIMAL against a float")
+            s = max(_scale(a.type), _scale(b.type))
+            fa, fb = _rescale(fa, s - _scale(a.type)), \
+                _rescale(fb, s - _scale(b.type))
         op = name[2:]
 
         def fn(env, _fa=fa, _fb=fb, _op=op):
@@ -229,6 +304,10 @@ def compile_expr(expr: BoundExpr, col_types: list[dt.SqlType],
     def compile_arith(e: BoundFunc):
         fa, fb = rec(e.args[0]), rec(e.args[1])
         op = e.name[2:]
+        if e.type.is_decimal and op in ("+", "-"):
+            s = e.type.scale
+            fa = _rescale(fa, s - _scale(e.args[0].type))
+            fb = _rescale(fb, s - _scale(e.args[1].type))
         int_result = e.type.is_integer
 
         def fn(env, _fa=fa, _fb=fb, _op=op, _int=int_result):
@@ -250,6 +329,69 @@ def compile_expr(expr: BoundExpr, col_types: list[dt.SqlType],
 
 def _m(ok):
     return ok if not isinstance(ok, bool) else jnp.bool_(ok)
+
+
+INT32 = (-(1 << 31), (1 << 31) - 1)
+
+
+def expr_bounds(e: BoundExpr, col_bounds) -> tuple[int, int]:
+    """Interval of an integer expression's physical values (a DECIMAL's
+    scaled integer) from `col_bounds(column index) -> (lo, hi)`, checking
+    that every node stays inside int32: the device runs with x64 off, so
+    a product that would leave int32 wraps silently there. Raises
+    NotCompilable('int_range') where a node can leave int32."""
+    def rec(x):
+        if isinstance(x, BoundLiteral):
+            if x.value is None or isinstance(x.value, bool):
+                return 0, 1
+            if isinstance(x.value, int):
+                return x.value, x.value
+            raise NotCompilable("non-integer literal", "int_range")
+        if isinstance(x, BoundColumn):
+            if x.type.is_float or x.type.is_string:
+                return 0, 0          # floats do not wrap; codes never add
+            lo, hi = col_bounds(x.index)
+        elif isinstance(x, BoundCase):
+            parts = [rec(v) for _, v in x.branches]
+            parts.append(rec(x.else_) if x.else_ is not None else (0, 0))
+            for c, _ in x.branches:
+                rec(c)
+            lo, hi = min(p[0] for p in parts), max(p[1] for p in parts)
+        elif isinstance(x, BoundFunc):
+            if x.type.id is dt.TypeId.BOOL:
+                for a in x.args:
+                    if not a.type.is_string:
+                        rec(a)
+                return 0, 1
+            if x.name in _IDENTITY_FUNCS:
+                return rec(x.args[0])
+            if x.name == "cast" and x.type.is_decimal:
+                lo, hi = rec(x.args[0])
+                f = 10 ** (x.type.scale - _scale(x.args[0].type))
+                lo, hi = lo * f, hi * f
+            elif x.name == "cast" and x.type.is_integer:
+                lo, hi = rec(x.args[0])
+            elif x.name in ("op+", "op-", "op*"):
+                (al, ah), (bl, bh) = rec(x.args[0]), rec(x.args[1])
+                if x.type.is_decimal and x.name != "op*":
+                    fa = 10 ** (x.type.scale - _scale(x.args[0].type))
+                    fb = 10 ** (x.type.scale - _scale(x.args[1].type))
+                    al, ah, bl, bh = al * fa, ah * fa, bl * fb, bh * fb
+                if x.name == "op+":
+                    lo, hi = al + bl, ah + bh
+                elif x.name == "op-":
+                    lo, hi = al - bh, ah - bl
+                else:
+                    ps = (al * bl, al * bh, ah * bl, ah * bh)
+                    lo, hi = min(ps), max(ps)
+            else:
+                raise NotCompilable(f"bounds of {x.name}", "int_range")
+        else:
+            raise NotCompilable(type(x).__name__, "int_range")
+        if lo < INT32[0] or hi > INT32[1]:
+            raise NotCompilable("int32 overflow on device", "int_range")
+        return lo, hi
+    return rec(e)
 
 
 def _as_bool(v):

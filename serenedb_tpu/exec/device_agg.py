@@ -8,6 +8,12 @@ single jitted XLA program per (table, query) over HBM-cached columns:
     counts = MXU histogram / scatter over codes  (ops/agg.py)
     sums   = exact int64 via limb scatter        (ops/agg.py)
 
+A DECIMAL is its scaled int64, so a DECIMAL argument is an integer one;
+with x64 off every node of a statement that reads one is bounded inside
+int32 first (`device.expr_bounds`), and a SUM / AVG whose product leaves
+int32 (TPC-H Q1's `sum(l_extendedprice * (1 - l_discount) * (1 + l_tax))`
+at scale 6) is summed as two int32 products (`wide_parts`).
+
 Falls back to the CPU oracle (plan.AggregateNode._cpu_aggregate) whenever
 anything in the query shape isn't device-compilable — result parity between
 the two paths is asserted in tests.
@@ -20,15 +26,16 @@ from typing import Optional
 import jax
 import numpy as np
 
+from .. import errors
 from ..columnar import dtypes as dt
 from ..columnar.column import Batch, Column
 from ..columnar.device import DeviceNarrowingError, pad_len
 from ..obs.trace import stage
 from ..ops import agg as ops_agg
 from ..sql.binder import _expr_key
-from ..sql.expr import AggSpec, BoundColumn, BoundExpr
+from ..sql.expr import AggSpec, BoundColumn, BoundExpr, BoundFunc
 from ..utils import log, metrics
-from .device import DeviceExpr, NotCompilable, compile_expr
+from .device import DeviceExpr, NotCompilable, compile_expr, expr_bounds
 from .tables import TableProvider
 
 MAX_GROUP_PRODUCT = 1 << 21   # combined-key code-space cap
@@ -52,6 +59,73 @@ def _factorize_lock(provider) -> "_threading.Lock":
     return lk
 
 _AGG_FUNCS = {"count_star", "count", "sum", "min", "max", "avg"}
+
+
+def wide_parts(a: BoundExpr, col_bounds) -> list:
+    """A SUM argument x * y whose product leaves int32, as the two int32
+    products of x's halves, (x >> 16) * y and (x & 0xFFFF) * y: the sum
+    is 2^16 S1 + S2. [(part, weight, lo, hi)]; NotCompilable where that
+    does not bound it either."""
+    inner = a
+    while isinstance(inner, BoundFunc) and inner.name in (
+            "decimal_raw", "decimal_of"):
+        inner = inner.args[0]
+    if not (isinstance(inner, BoundFunc) and inner.name == "op*"):
+        raise NotCompilable("sum argument leaves int32", "int_range")
+    x, y = inner.args
+    xlo, xhi = expr_bounds(x, col_bounds)
+    ylo, yhi = expr_bounds(y, col_bounds)
+    if max(abs(ylo), abs(yhi)) * 65535 >= (1 << 31):
+        raise NotCompilable("sum argument leaves int32", "int_range")
+    t = dt.INT
+
+    def half(kind):
+        def impl(cols, batch, _k=kind):
+            v = cols[0].data.astype(np.int64)
+            return Column(t, (v >> 16 if _k == "hi" else v & 0xFFFF)
+                          .astype(np.int32), cols[0].validity)
+        return BoundFunc(f"int32_{kind}16", [x], t, impl)
+
+    def times(lo, hi):
+        ps = (lo * ylo, lo * yhi, hi * ylo, hi * yhi)
+        return min(ps), max(ps)
+    # `y` as the integer its physical values are: the product's scale is
+    # the whole argument's, restored above the aggregate
+    plain = BoundFunc("decimal_raw", [y], t, None) if y.type.is_decimal \
+        else y
+    return [(BoundFunc("op*", [half("hi"), plain], t, None), 65536,
+             *times(xlo >> 16, xhi >> 16)),
+            (BoundFunc("op*", [half("lo"), plain], t, None), 1,
+             *times(0, 0xFFFF))]
+
+
+class _Wide:
+    """A SUM / AVG argument summed as `wide_parts`: each part is its own
+    limb sum on the device, and the host adds them at their weights."""
+
+    def __init__(self, parts: list):
+        self.parts = parts               # [(DeviceExpr, weight)]
+        self.inputs = sorted({i for ce, _ in parts for i in ce.inputs})
+
+    @property
+    def weights(self) -> list:
+        return [w for _, w in self.parts]
+
+
+def _weights(ce) -> Optional[list]:
+    return ce.weights if isinstance(ce, _Wide) else None
+
+
+def _add_weighted(total, part: np.ndarray, w: int) -> np.ndarray:
+    """total + part * w in int64, or 22003 where a sum leaves it."""
+    part = part.astype(np.int64)
+    wide = np.abs(part.astype(np.float64)) * w
+    if total is not None:
+        wide = wide + np.abs(total.astype(np.float64))
+    if np.any(wide >= 2.0 ** 63):
+        raise errors.SqlError("22003", "numeric field overflow")
+    part = part * w
+    return part if total is None else total + part
 
 
 def try_device_aggregate(node, ctx) -> Optional[Batch]:
@@ -129,6 +203,27 @@ def _run(node, scan, provider: TableProvider, preds: list[BoundExpr], ctx) -> Ba
                 dictionaries[i] = col.dictionary
 
     compiled_preds = [compile_expr(p, scan.types, dictionaries) for p in preds]
+    wide: dict[int, list] = {}
+    if any(scan.types[i].is_decimal for i in referenced):
+        # a DECIMAL's scaled products leave int32 where its range says
+        # so (x64 off: they would wrap): bound every node, sum a product
+        # past int32 as its int32 parts, or decline
+        from .device_pipeline import _pub, col_stats
+        pub = _pub(provider, pin)
+
+        def col_bounds(i):
+            return col_stats(pub, col_names[i], host_col)[2:]
+        for e in preds:
+            expr_bounds(e, col_bounds)
+        for si, spec in enumerate(node.aggs):
+            if spec.arg is None:
+                continue
+            try:
+                expr_bounds(spec.arg, col_bounds)
+            except NotCompilable:
+                if spec.func not in ("sum", "avg") or spec.distinct:
+                    raise
+                wide[si] = wide_parts(spec.arg, col_bounds)
 
     # group keys: direct coding (dict codes / small-range ints) when it
     # fits, else composite host factorization (arbitrary keys/cardinality)
@@ -144,9 +239,13 @@ def _run(node, scan, provider: TableProvider, preds: list[BoundExpr], ctx) -> Ba
         key_plans, group_space = [], max(fact["g"], 1)
 
     agg_plans = []
-    for spec in node.aggs:
+    for si, spec in enumerate(node.aggs):
         if spec.func == "count_star":
             agg_plans.append((spec, None))
+        elif si in wide:
+            agg_plans.append((spec, _Wide([
+                (compile_expr(p, scan.types, dictionaries), w)
+                for p, w, _lo, _hi in wide[si]])))
         else:
             if spec.arg.type.is_string and spec.func != "count":
                 raise NotCompilable(f"{spec.func} over strings")
@@ -504,10 +603,11 @@ def _out_combines(node, agg_plans, group_mode) -> list:
             continue
         is_float = spec.arg is not None and spec.arg.type.is_float
         if spec.func in ("sum", "avg"):
+            n = len(ce.parts) if isinstance(ce, _Wide) else 1
             if group_mode or is_float:
-                out.extend(["sum", "sum"])      # (limbs|float sum) + count
+                out.extend(["sum"] * n + ["sum"])   # (limbs|float sum) + count
             else:
-                out.extend(["rows", "sum"])     # per-row int partials
+                out.extend(["rows"] * n + ["sum"])  # per-row int partials
         elif spec.func in ("min", "max"):
             out.extend([spec.func, "sum"])
         else:
@@ -706,6 +806,14 @@ def _scalar_agg_device(spec: AggSpec, ce, arrays, mask, env_for):
     import jax.numpy as jnp
     if spec.func == "count_star":
         return []  # uses the shared row count output
+    if isinstance(ce, _Wide):
+        outs, m = [], mask
+        for part, _w in ce.parts:
+            v, ok = part.fn(env_for(part, arrays))
+            outs.append(ops_agg.masked_sum_int_partials(
+                v, jnp.logical_and(mask, ok)))
+            m = jnp.logical_and(m, ok)
+        return outs + [jnp.sum(m, dtype=jnp.int32)]
     v, ok = ce.fn(env_for(ce, arrays))
     m = jnp.logical_and(mask, ok)
     if spec.func == "count":
@@ -741,6 +849,14 @@ def _group_agg_device(spec: AggSpec, ce, arrays, codes, mask, env_for, g):
     import jax.numpy as jnp
     if spec.func == "count_star":
         return []  # shared group counts output
+    if isinstance(ce, _Wide):
+        outs, m = [], mask
+        for part, _w in ce.parts:
+            v, ok = part.fn(env_for(part, arrays))
+            outs.append(_group_int_sum(codes, jnp.logical_and(mask, ok), v,
+                                       g))
+            m = jnp.logical_and(m, ok)
+        return outs + [ops_agg.group_count_cells(codes, m, g)]
     v, ok = ce.fn(env_for(ce, arrays))
     m = jnp.logical_and(mask, ok)
     if spec.func == "count":
@@ -750,9 +866,7 @@ def _group_agg_device(spec: AggSpec, ce, arrays, codes, mask, env_for, g):
         cnt = ops_agg.group_count_cells(codes, m, g)
         if is_float:
             return [ops_agg.group_sum_float(codes, m, v, g), cnt]
-        if codes.shape[0] > ops_agg.SCATTER_CHUNK_TILES:
-            return [ops_agg.group_sum_int_limbs_chunked(codes, m, v, g), cnt]
-        return [ops_agg.group_sum_int_limbs(codes, m, v, g), cnt]
+        return [_group_int_sum(codes, m, v, g), cnt]
     if spec.func in ("min", "max"):
         if is_float and spec.func == "min":
             # PG: NaN is the greatest float — MIN skips NaN unless a
@@ -771,6 +885,16 @@ def _group_agg_device(spec: AggSpec, ce, arrays, codes, mask, env_for, g):
     raise NotCompilable(spec.func)
 
 
+def _group_int_sum(codes, m, v, g):
+    """Exact per-group int sums as limbs: masked reductions for a few
+    groups, else a scatter, chunked past the limbs' row bound."""
+    if g <= ops_agg.SMALL_SPACE and codes.size <= ops_agg.LIMB_ROWS_EXACT:
+        return ops_agg.group_sum_int_limbs_masked(codes, m, v, g)
+    if codes.shape[0] > ops_agg.SCATTER_CHUNK_TILES:
+        return ops_agg.group_sum_int_limbs_chunked(codes, m, v, g)
+    return ops_agg.group_sum_int_limbs(codes, m, v, g)
+
+
 def _build_scalar_batch(node, agg_plans, results,
                         distinct_plans=None) -> Batch:
     ri = iter(results)
@@ -783,7 +907,7 @@ def _build_scalar_batch(node, agg_plans, results,
             cols.append(_distinct_result_col(spec, dplan, pres,
                                              np.asarray([0]))[0])
         else:
-            cols.append(_scalar_result_col(spec, ri, total))
+            cols.append(_scalar_result_col(spec, ri, total, _weights(ce)))
     return Batch(list(node.names), cols)
 
 
@@ -811,20 +935,29 @@ def _distinct_result_col(spec: AggSpec, dplan, pres: np.ndarray,
                    ~empty if empty.any() else None)]
 
 
-def _scalar_result_col(spec: AggSpec, ri, total: int) -> Column:
+def _partials_sum(first: np.ndarray) -> int:
+    """masked_sum_int_partials' (rows, 2) [hi, lo] halves → the sum."""
+    parts = first.astype(np.int64)
+    return int((parts[:, 0].sum() << 16) + parts[:, 1].sum())
+
+
+def _scalar_result_col(spec: AggSpec, ri, total: int,
+                       weights: Optional[list] = None) -> Column:
     t = spec.type
     if spec.func == "count_star":
         return Column.from_pylist([total], t)
     if spec.func == "count":
         return Column.from_pylist([int(np.asarray(next(ri)))], t)
     if spec.func in ("sum", "avg"):
-        first = np.asarray(next(ri))
-        cnt = int(np.asarray(next(ri)))
-        if first.ndim == 0:
-            s = float(first)
+        if weights:
+            s = sum(_partials_sum(np.asarray(next(ri))) * w
+                    for w in weights)
+            if not -2 ** 63 <= s < 2 ** 63:
+                raise errors.SqlError("22003", "numeric field overflow")
         else:
-            parts = first.astype(np.int64)
-            s = int((parts[:, 0].sum() << 16) + parts[:, 1].sum())
+            first = np.asarray(next(ri))
+            s = float(first) if first.ndim == 0 else _partials_sum(first)
+        cnt = int(np.asarray(next(ri)))
         if cnt == 0:
             return Column.from_pylist([None], t)
         if spec.func == "avg":
@@ -864,7 +997,8 @@ def _build_group_batch(node, key_plans, agg_plans, results, provider,
                 cols.extend(_distinct_result_col(spec, dplan, pres,
                                                  present))
             else:
-                cols.append(_group_result_col(spec, ri, counts, present))
+                cols.append(_group_result_col(spec, ri, counts, present,
+                                              _weights(ce)))
         return Batch(list(node.names), cols)
     # decode combined codes back to per-key codes
     sizes = [kp[3] for kp in key_plans]
@@ -893,11 +1027,13 @@ def _build_group_batch(node, key_plans, agg_plans, results, provider,
             pres = np.asarray(next(ri))
             cols.extend(_distinct_result_col(spec, dplan, pres, present))
         else:
-            cols.append(_group_result_col(spec, ri, counts, present))
+            cols.append(_group_result_col(spec, ri, counts, present,
+                                          _weights(ce)))
     return Batch(list(node.names), cols)
 
 
-def _group_result_col(spec: AggSpec, ri, star_counts, present) -> Column:
+def _group_result_col(spec: AggSpec, ri, star_counts, present,
+                      weights: Optional[list] = None) -> Column:
     t = spec.type
     if spec.func == "count_star":
         return Column(dt.BIGINT, star_counts[present])
@@ -905,12 +1041,18 @@ def _group_result_col(spec: AggSpec, ri, star_counts, present) -> Column:
         c = np.asarray(next(ri)).astype(np.int64)
         return Column(dt.BIGINT, c[present])
     if spec.func in ("sum", "avg"):
-        first = np.asarray(next(ri))
-        cnt = np.asarray(next(ri)).astype(np.int64)[present]
-        if first.ndim >= 2:  # int limbs (G,5) or chunked (C,G,5)
-            sums = ops_agg.combine_sum_int_limbs(first)[present]
+        if weights:
+            sums = None
+            for w in weights:
+                sums = _add_weighted(sums, ops_agg.combine_sum_int_limbs(
+                    np.asarray(next(ri)))[present], w)
         else:
-            sums = first.astype(np.float64)[present]
+            first = np.asarray(next(ri))
+            if first.ndim >= 2:  # int limbs (G,5) or chunked (C,G,5)
+                sums = ops_agg.combine_sum_int_limbs(first)[present]
+            else:
+                sums = first.astype(np.float64)[present]
+        cnt = np.asarray(next(ri)).astype(np.int64)[present]
         empty = cnt == 0
         if spec.func == "avg":
             with np.errstate(invalid="ignore", divide="ignore"):

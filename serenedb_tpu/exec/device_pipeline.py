@@ -5,7 +5,8 @@ operator". The single-table half of that already exists (exec/device_agg.py
 fuses Scan→Filter→Aggregate); this module extends the discipline across the
 relational tier: a Scan→Filter→Join→Aggregate chain compiles into ONE jitted
 JAX program over device-resident columns of BOTH tables, and a filtered
-top-N (Sort+Limit over Filter→Scan) into one masked `top_k` dispatch.
+top-N (Sort+Limit over Filter→Scan) into one masked `top_k` dispatch. A
+chain of primary-key joins of any length is exec/device_chain.py's.
 
 Join representation (the PR-3 trick, moved on device): both sides' equi-keys
 factorize host-side into ONE dense int64 code space
@@ -19,8 +20,11 @@ ever materializes, on host or device. The fused-kernel shape mirrors
 FLASH-MAXSIM's IO-aware late-interaction kernels and Ragged Paged
 Attention's one-program-over-resident-data design (PAPERS.md).
 
-Exactness policy (PG parity, x64 off): only integer/bool/date aggregate
-arguments compile — int sums ride the 8-bit limb decomposition of
+Exactness policy (PG parity, x64 off): only integer/bool/date/DECIMAL
+aggregate arguments compile (a DECIMAL's SUM/AVG/MIN/MAX aggregate its
+scaled int64 as the BIGINT it is, `decimal_raw`, and every node of a
+DECIMAL expression is bounded inside int32 first, `device.expr_bounds`)
+— int sums ride the 8-bit limb decomposition of
 ops/agg.py, weighted by the per-row match count (or ONE direct int32
 scatter column when the argument is a plain column whose value bound
 times the worst-case pair count provably fits int32), and the whole
@@ -662,8 +666,14 @@ def try_device_pipeline(node, ctx) -> Optional[Batch]:
     # `device_finalize` at the host decode)
     with stage("device_prepare", op="fused"):
         try:
-            return _run_fused(node, join, probe_side, build_side,
-                              post_preds, ctx)
+            out = _run_fused(node, join, probe_side, build_side,
+                             post_preds, ctx)
+            if out is not None:
+                from .device_chain import pair_bytes
+                metrics.DEVICE_JOINS_FUSED.add()
+                metrics.DEVICE_JOIN_BYTES.add(pair_bytes(
+                    node, join, probe_side, build_side, post_preds, ctx))
+            return out
         except (NotCompilable, DeviceNarrowingError) as e:
             log.debug("device", f"fused pipeline fell back to CPU: {e}")
             return decline(getattr(e, "reason", "not_compilable"))
@@ -2407,18 +2417,23 @@ def _agg_result_col(spec: AggSpec, ri, pair_counts, present,
 
 
 def _col_stats(side: _Side, name: str) -> tuple:
-    """(all_valid, finite_all, lo, hi) of one column — a pure function
-    of the publication, so cached repeats skip the O(n) host scans.
+    return col_stats(side.pub, name, side.host_col)
+
+
+def col_stats(pub: tuple, name: str, host_col) -> tuple:
+    """(all_valid, finite_all, lo, hi) of one column of the publication
+    `pub` (`_pub`), read through `host_col(name)` — a pure function of
+    the publication, so cached repeats skip the O(n) host scans.
     lo/hi are None for float columns (only finiteness gates those) and
     span EVERY slot including NULL ones (garbage under an invalid slot
     widens the range, which can only make callers more conservative)."""
-    ck = (side.pub, name)
+    ck = (pub, name)
     with _col_stats_lock:
         hit = _COL_STATS_CACHE.get(ck)
         if hit is not None:
             _COL_STATS_CACHE.move_to_end(ck)
             return hit
-    host = side.host_col(name)
+    host = host_col(name)
     all_valid = bool(host.valid_mask().all())
     if host.data.dtype.kind == "f":
         stats = (all_valid, bool(np.isfinite(host.data).all()), None, None)
@@ -2677,6 +2692,8 @@ def try_device_chained_topn(limit_node, ctx) -> Optional[Batch]:
         return None
     if not agg.group_exprs:
         return None               # scalar aggregate: one row, host-trivial
+    if agg.chain_claimed():
+        return None               # the chain program's; the host sorts
 
     def decline(reason: str) -> None:
         _note_decline(reason, ctx, limit_node)

@@ -453,6 +453,8 @@ def _partial_state(spec: AggSpec, b: Batch, codes: np.ndarray,
         # result batch would contradict the RowDescription type
         int_sum = spec.func == "sum" and spec.type.is_integer
         if int_sum:
+            from .plan import check_int_sums
+            check_int_sums(spec, vc, vals, g)
             acc = np.zeros(g, dtype=np.int64)
             np.add.at(acc, vc, vals.astype(np.int64))
             return [_i64(acc), _i64(cnt)]
@@ -757,6 +759,8 @@ def _combine(spec: AggSpec, states: list[Column], codes: np.ndarray,
     if spec.func == "sum":
         v = states[0]
         if v.data.dtype.kind == "i":
+            from .plan import check_int_sums
+            check_int_sums(spec, lc, v.data[live], g)
             acc = np.zeros(g, dtype=np.int64)
             np.add.at(acc, lc, v.data[live])
             return Column(dt.BIGINT, acc, validity)

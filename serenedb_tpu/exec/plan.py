@@ -19,7 +19,7 @@ from .. import errors
 from ..columnar import dtypes as dt
 from ..columnar.column import Batch, Column, concat_batches, merge_dictionaries
 from ..obs.trace import stage
-from ..sql.expr import AggSpec, BoundColumn, BoundExpr
+from ..sql.expr import AggSpec, BoundColumn, BoundExpr, BoundFunc
 from ..utils import metrics
 from ..utils.config import SessionSettings
 from .tables import TableProvider
@@ -524,6 +524,46 @@ class DropColumnsNode(PlanNode):
         return f"Project(keep {self.keep})"
 
 
+def _decimal_sum(spec: AggSpec) -> bool:
+    """A DECIMAL's SUM / AVG: its argument is the scaled int64 read as a
+    BIGINT (`decimal_raw`), and its sum must stay inside int64 (22003);
+    a BIGINT sum keeps the engine's int64 arithmetic as it was."""
+    return isinstance(spec.arg, BoundFunc) and spec.arg.name == "decimal_raw"
+
+
+def exact_int_sum(spec: AggSpec, vals: np.ndarray) -> int:
+    """Sum of one batch's integers: a Python int for a DECIMAL's sum,
+    so that one past int64 is 22003 when the result is built."""
+    if not len(vals):
+        return 0
+    if not _decimal_sum(spec) or float(np.abs(vals.astype(
+            np.float64)).max()) * len(vals) < 2.0 ** 62:
+        return int(vals.astype(np.int64).sum())
+    return sum(int(v) for v in vals.tolist())
+
+
+def check_int_sums(spec: AggSpec, codes: np.ndarray, vals: np.ndarray,
+                   g: int) -> None:
+    """22003 where a group's DECIMAL sum would leave int64 (np.add.at
+    wraps silently there)."""
+    if not _decimal_sum(spec) or not len(vals) or \
+            float(np.abs(vals.astype(np.float64)).max()) * len(vals) < \
+            2.0 ** 62:
+        return
+    f = np.bincount(codes, weights=np.abs(vals.astype(np.float64)),
+                    minlength=g)
+    if (f >= 2.0 ** 63).any():
+        exact: dict = {}
+        for c, v in zip(codes.tolist(), vals.tolist()):
+            exact[c] = exact.get(c, 0) + int(v)
+        if any(not -2 ** 63 <= x < 2 ** 63 for x in exact.values()):
+            raise errors.SqlError("22003", "numeric field overflow")
+
+
+def _key_text(e: BoundExpr) -> str:
+    return e.name if isinstance(e, BoundColumn) else type(e).__name__
+
+
 class JoinNode(PlanNode):
     """Hash join (inner/left/right/full/cross). Equi-keys are extracted
     by the planner; residual predicates run over candidate pairs.
@@ -619,39 +659,45 @@ class JoinNode(PlanNode):
         # this operator's dominant buffers; the candidate pair index
         # arrays join them below. Charged here, released when the
         # output batch has been consumed (generator close).
-        hold(batch_nbytes(rb))
-        hold(batch_nbytes(lb))
-        li, ri = self._match_inner(lb, rb, ctx, rkey_cols)
-        hold(int(li.nbytes) + int(ri.nbytes))
-        # ON-clause residual applies to *candidate pairs* (outer-join
-        # semantics: a pair failing the residual is unmatched, the left row
-        # survives null-extended — PG LEFT JOIN ... ON a AND b)
-        if self.residual is not None and len(li):
-            pair = Batch(list(self.names),
-                         lb.take(li).columns + rb.take(ri).columns)
-            c = self.residual.eval(pair)
-            keep = c.data.astype(bool) & c.valid_mask()
-            li, ri = li[keep], ri[keep]
-        if self.kind in ("left", "full"):
-            matched = np.zeros(lb.num_rows, dtype=bool)
-            matched[li] = True
-            extra = np.flatnonzero(~matched)
-            li = np.concatenate([li, extra])
-            ri = np.concatenate([ri, np.full(len(extra), -1, dtype=np.int64)])
-        if self.kind in ("right", "full"):
-            matched = np.zeros(rb.num_rows, dtype=bool)
-            matched[ri[ri >= 0]] = True
-            extra = np.flatnonzero(~matched)
-            ri = np.concatenate([ri, extra])
-            li = np.concatenate([li, np.full(len(extra), -1, dtype=np.int64)])
-        lcols = _take_null_extended(lb, li)
-        rcols = _take_null_extended(rb, ri)
-        if self.merge_pairs:
-            right_only = li < 0
-            if right_only.any():
-                for lk, rk in self.merge_pairs:
-                    lcols[lk] = _merge_using_columns(
-                        lcols[lk], rcols[rk], right_only)
+        metrics.HOST_JOINS.add()
+        # the request's `host_join` stage: the match of keys, the pairs'
+        # residual and null extension, and the gather of both sides
+        with stage("host_join"):
+            hold(batch_nbytes(rb))
+            hold(batch_nbytes(lb))
+            li, ri = self._match_inner(lb, rb, ctx, rkey_cols)
+            hold(int(li.nbytes) + int(ri.nbytes))
+            # ON-clause residual applies to *candidate pairs* (outer-join
+            # semantics: a pair failing the residual is unmatched, the left
+            # row survives null-extended — PG LEFT JOIN ... ON a AND b)
+            if self.residual is not None and len(li):
+                pair = Batch(list(self.names),
+                             lb.take(li).columns + rb.take(ri).columns)
+                c = self.residual.eval(pair)
+                keep = c.data.astype(bool) & c.valid_mask()
+                li, ri = li[keep], ri[keep]
+            if self.kind in ("left", "full"):
+                matched = np.zeros(lb.num_rows, dtype=bool)
+                matched[li] = True
+                extra = np.flatnonzero(~matched)
+                li = np.concatenate([li, extra])
+                ri = np.concatenate([ri, np.full(len(extra), -1,
+                                                 dtype=np.int64)])
+            if self.kind in ("right", "full"):
+                matched = np.zeros(rb.num_rows, dtype=bool)
+                matched[ri[ri >= 0]] = True
+                extra = np.flatnonzero(~matched)
+                ri = np.concatenate([ri, extra])
+                li = np.concatenate([li, np.full(len(extra), -1,
+                                                 dtype=np.int64)])
+            lcols = _take_null_extended(lb, li)
+            rcols = _take_null_extended(rb, ri)
+            if self.merge_pairs:
+                right_only = li < 0
+                if right_only.any():
+                    for lk, rk in self.merge_pairs:
+                        lcols[lk] = _merge_using_columns(
+                            lcols[lk], rcols[rk], right_only)
         try:
             yield Batch(list(self.names), lcols + rcols)
         finally:
@@ -728,7 +774,11 @@ class JoinNode(PlanNode):
                 np.asarray(ri, dtype=np.int64))
 
     def label(self):
-        return f"HashJoin {self.kind}"
+        if not self.left_keys:
+            return f"HashJoin {self.kind}"
+        keys = ", ".join(f"{_key_text(a)} = {_key_text(b)}" for a, b in
+                         zip(self.left_keys, self.right_keys))
+        return f"HashJoin {self.kind} on ({keys})"
 
 
 class SetOpNode(PlanNode):
@@ -1184,22 +1234,35 @@ class AggregateNode(PlanNode):
         return (f"Aggregate groups={len(self.group_exprs)} "
                 f"aggs=[{', '.join(a.func for a in self.aggs)}]")
 
+    def chain_claimed(self) -> bool:
+        """Is this aggregate the join-chain program's alone (a key-join
+        chain of three relations or more, exec/device_chain.py)? The
+        top-N tiers above it then leave it to `batches`."""
+        from .device_chain import claims
+        return claims(self)
+
     def batches(self, ctx):
         fast = self._try_count_fast_path(ctx)
         if fast is not None:
             yield fast
             return
-        # fused relational pipeline first: Aggregate over an inner
-        # equi-join of two (filtered) scans runs as ONE device dispatch
-        # (exec/device_pipeline.py); single-table chains stay with
-        # try_device_aggregate below
+        # fused relational programs first: a chain of key joins runs as
+        # ONE device dispatch (exec/device_chain.py); an inner equi-join
+        # of two (filtered) scans it does not admit as the pair-count
+        # program (exec/device_pipeline.py); one table, whatever its
+        # column types, stays with try_device_aggregate below
+        from .device_chain import try_device_chain
+        result, declined = try_device_chain(self, ctx)
+        if result is not None:
+            yield result
+            return
         from .device_pipeline import try_device_pipeline
-        result = try_device_pipeline(self, ctx)
+        result = None if declined else try_device_pipeline(self, ctx)
         if result is not None:
             yield result
             return
         from .device_agg import try_device_aggregate
-        result = try_device_aggregate(self, ctx)
+        result = None if declined else try_device_aggregate(self, ctx)
         if result is not None:
             yield result
             return
@@ -1286,9 +1349,7 @@ class AggregateNode(PlanNode):
         empty = counts == 0
         if spec.func == "sum":
             if arg.type.is_integer or arg.type.id is dt.TypeId.BOOL:
-                data = np.bincount(vc, weights=vals.astype(np.float64),
-                                   minlength=g)
-                # exact: redo in int64 via add.at
+                check_int_sums(spec, vc, vals, g)
                 acc = np.zeros(g, dtype=np.int64)
                 np.add.at(acc, vc, vals.astype(np.int64))
                 return Column(dt.BIGINT, acc, ~empty if empty.any() else None)
@@ -1433,6 +1494,7 @@ class AggregateNode(PlanNode):
                     with np.errstate(invalid="ignore", divide="ignore"):
                         acc = np.where(empty, 0.0, acc / np.maximum(cnt, 1))
                 return Column(dt.DOUBLE, acc, validity)
+            check_int_sums(spec, uc, uv, g)
             acc = np.zeros(g, dtype=np.int64)
             np.add.at(acc, uc, uv.astype(np.int64))
             return Column(dt.BIGINT, acc, validity)
@@ -1506,7 +1568,7 @@ class _ScalarAcc:
                          "variance", "stddev_pop", "var_pop"):
             vals = col.data[valid]
             if col.type.is_integer or col.type.id is dt.TypeId.BOOL:
-                self.sum_i += int(vals.astype(np.int64).sum())
+                self.sum_i += exact_int_sum(spec, vals)
             self.sum_f += float(vals.astype(np.float64).sum())
             self.sum_sq += float((vals.astype(np.float64) ** 2).sum())
         elif spec.func in ("min", "max"):

@@ -317,6 +317,17 @@ def _arrow_to_column(arr) -> Column:
     if pa.types.is_boolean(t):
         data = np.asarray(arr.fill_null(False))
         return Column(dt.BOOL, data.astype(np.bool_), null_mask)
+    if pa.types.is_decimal128(t):
+        if t.precision > dt.MAX_DECIMAL_PRECISION:
+            raise ValueError(f"decimal128({t.precision},{t.scale}) is wider "
+                             f"than DECIMAL({dt.MAX_DECIMAL_PRECISION})")
+        # 16-byte two's complement words; precision <= 18 lives in the
+        # low eight bytes, which are the scaled int64 as it stands
+        words = np.frombuffer(arr.buffers()[1], dtype=np.int64)
+        data = words[2 * arr.offset:2 * (arr.offset + len(arr)):2].copy()
+        if null_mask is not None:
+            data[~null_mask] = 0
+        return Column(dt.decimal_of(t.precision, t.scale), data, null_mask)
     if arr.null_count:
         arr = arr.fill_null(0)
     data = np.asarray(arr)

@@ -96,6 +96,9 @@ def _make_compare(op: str):
             if a.type.is_string or b.type.is_string:
                 av, bv = string_values(a), string_values(b)
                 data = _CMP_NP[op](av, bv)
+            elif a.type.is_decimal or b.type.is_decimal:
+                av, bv = _decimal_operands(a, b)
+                data = _CMP_NP[op](av, bv)
             else:
                 data = _CMP_NP[op](a.data, b.data)
                 # PG float total order: NaN = NaN, NaN > everything
@@ -305,6 +308,8 @@ def _make_arith(op: str):
                 return _make_datetime_arith(op, ts, out_t)
         if len(ts) != 2 or not _all_numeric(ts):
             return None
+        if any(t.is_decimal for t in ts):
+            return _decimal_arith(op, ts)
         t = _arith_type(op, ts[0], ts[1])
         if op == "/" and t.is_integer:
             pass  # PG: int/int truncates toward zero
@@ -386,6 +391,82 @@ def _make_arith(op: str):
 
 for _op in ("+", "-", "*", "/", "%"):
     _REGISTRY[f"op{_op}"] = _make_arith(_op)
+
+
+# -- DECIMAL: scaled int64 -------------------------------------------------
+
+def _scale_of(t: dt.SqlType) -> int:
+    return t.scale if t.is_decimal else 0
+
+
+def decimal_scaled(col: Column, scale: int) -> np.ndarray:
+    """The column's values times 10^scale as int64 (a DECIMAL column's
+    own scale subtracted first); 22003 where that leaves int64."""
+    x = col.data.astype(np.int64)
+    k = scale - _scale_of(col.type)
+    if k <= 0:
+        assert k == 0, "decimal_scaled never rounds"
+        return x
+    f = 10 ** k
+    bad = np.abs(x) > (2 ** 63 - 1) // f
+    if col.validity is not None:
+        bad &= col.validity
+    if bad.any():
+        raise errors.SqlError("22003", "numeric field overflow")
+    return x * f
+
+
+def decimal_float(col: Column) -> np.ndarray:
+    """A numeric column's values as float64 (a DECIMAL divided by its
+    scale's power of ten)."""
+    x = col.data.astype(np.float64)
+    return x / 10.0 ** col.type.scale if col.type.is_decimal else x
+
+
+def _decimal_operands(a: Column, b: Column):
+    """Comparable arrays of two numeric columns, one of them DECIMAL:
+    exact scaled integers at the larger scale, or floats beside a float."""
+    if a.type.is_float or b.type.is_float:
+        return decimal_float(a), decimal_float(b)
+    s = max(_scale_of(a.type), _scale_of(b.type))
+    return decimal_scaled(a, s), decimal_scaled(b, s)
+
+
+def _decimal_arith(op: str, ts: list):
+    """`+`/`-` align scales, `*` adds them (both overflow-checked in
+    int64, 22003); `/` and `%` and any float operand give DOUBLE."""
+    if op in ("/", "%") or any(t.is_float for t in ts):
+        def fimpl(cols, n):
+            fl = [Column(dt.DOUBLE, decimal_float(c), c.validity)
+                  for c in cols]
+            return _make_arith(op)([dt.DOUBLE, dt.DOUBLE]).impl(fl, n)
+        return FunctionResolution(dt.DOUBLE, fimpl)
+    if op == "*":
+        scale = _scale_of(ts[0]) + _scale_of(ts[1])
+        if scale > dt.MAX_DECIMAL_PRECISION:
+            raise errors.SqlError(
+                "22003", f"DECIMAL product scale {scale} exceeds "
+                f"{dt.MAX_DECIMAL_PRECISION}")
+    else:
+        scale = max(_scale_of(ts[0]), _scale_of(ts[1]))
+    out_t = dt.decimal_of(dt.MAX_DECIMAL_PRECISION, scale)
+    int_op = _make_arith(op)([dt.BIGINT, dt.BIGINT])
+
+    def impl(cols, n):
+        if op == "*":
+            ints = [Column(dt.BIGINT, c.data.astype(np.int64), c.validity)
+                    for c in cols]
+        else:
+            ints = [Column(dt.BIGINT, decimal_scaled(c, scale), c.validity)
+                    for c in cols]
+        try:
+            r = int_op.impl(ints, n)
+        except errors.SqlError as e:
+            if e.sqlstate == "22003":
+                raise errors.SqlError("22003", "numeric field overflow")
+            raise
+        return Column(out_t, r.data, r.validity)
+    return FunctionResolution(out_t, impl)
 
 
 # '+' and comparison registrations collide on name; re-dispatch by type:

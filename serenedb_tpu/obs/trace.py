@@ -240,7 +240,7 @@ STAGES = ("fd_parse", "fd_queue", "fd_encode", "cache_probe", "plan",
           "device_prepare", "device_upload", "device_enqueue",
           "device_wait",
           "device_finalize", "host_scan", "host_concat", "host_group",
-          "host_sort", "batch_wait", "search_plan", "search_phrase",
+          "host_sort", "host_join", "batch_wait", "search_plan", "search_phrase",
           "search_host_score")
 
 _TRACE_IDS = itertools.count(1)

@@ -14,7 +14,8 @@ reference gets these from its DuckDB fork; SURVEY.md §1 L3). TPU re-design:
   are provably exact for their shapes, and the host combines them in numpy
   int64. Integer SUM scatters four 8-bit limbs into int32 group accumulators
   (exact while each group sees < 2^31/255 ≈ 8.4M rows per call; the executor
-  chunks input below that). Grouped COUNT (and the count half of SUM / AVG,
+  chunks input below that), or, for at most SMALL_SPACE groups, sums them
+  as masked reductions (`group_reduce_masked`). Grouped COUNT (and the count half of SUM / AVG,
   and the DISTINCT presence table) over at most HIST_MAX_CELLS cells rides
   the MXU as a two-digit one-hot product (`group_count_hist`): 0/1 int8
   operands, int32 accumulation, so the counts are the scatter's integers.
@@ -196,11 +197,111 @@ def group_sum_float(codes: jax.Array, mask: jax.Array, vals: jax.Array,
     return jnp.zeros((num_groups,), dtype=jnp.float32).at[safe].add(v)
 
 
+INT32_RANGE = (-(1 << 31), (1 << 31) - 1)
+
+
+def limb_count(lo: int, hi: int, w: int) -> tuple[int, bool]:
+    """(limbs, whether a negative count rides along) of int32 values in
+    [lo, hi] cut into w-bit limbs: a range that can be negative as its
+    32-bit two's complement."""
+    if lo < 0:
+        return -(-32 // w), True
+    return max(1, -(-int(hi).bit_length() // w)), False
+
+
+def int_limbs(v: jax.Array, m: jax.Array, lo: int, hi: int,
+              w: int) -> list:
+    """The masked w-bit limbs of int32 values in [lo, hi], then the
+    masked negative count where the range can be negative: their sums
+    over a group are exact in int32 while (2^w - 1) x the group's rows
+    stays under 2^31, and `combine_limbs` makes the int64 sum of them."""
+    n, neg = limb_count(lo, hi, w)
+    vu = jax.lax.bitcast_convert_type(v, jnp.uint32)
+    out = [(jnp.right_shift(vu, w * limb) & jnp.uint32((1 << w) - 1))
+           .astype(jnp.int32) * m for limb in range(n)]
+    if neg:
+        out.append((v < 0).astype(jnp.int32) * m)
+    return out
+
+
+def combine_limbs(sums: list, lo: int, hi: int, w: int) -> np.ndarray:
+    """Exact int64 sums from the per-group sums of `int_limbs`'s columns."""
+    n, neg = limb_count(lo, hi, w)
+    total = np.zeros(len(sums[0]), dtype=np.int64)
+    for limb in range(n):
+        total += np.asarray(sums[limb]).astype(np.int64) << (w * limb)
+    if neg:
+        total -= np.asarray(sums[n]).astype(np.int64) << 32
+    return total
+
+
+#: group spaces reduced as masked reductions instead of a scatter,
+#: PASS_GROUPS groups a pass over the rows
+SMALL_SPACE = 256
+PASS_GROUPS = 16
+#: most rows whose 8-bit limbs one int32 group sum holds exactly
+LIMB_ROWS_EXACT = 1 << 23
+
+
+def group_reduce_masked(code: jax.Array, cols: list, extremes: list,
+                        space: int):
+    """Per-group sums of the int32 columns `cols` and the min / max of
+    `extremes` [(func, identity, values)] over the rows whose code is in
+    [0, space), as masked reductions, PASS_GROUPS groups a pass over the
+    rows: XLA fuses a pass's reductions into one read of them. For a few
+    groups this is many times the speed of a scatter, whose updates to
+    one slot serialize (8.4M rows x 5 columns into 26 groups on one TPU
+    v5e: 3.1 ms against 65). All arrays flat, one entry a row.
+    Returns ((space, len(cols)) int32 sums, tuple of (space,) extremes)."""
+    stacked = jnp.stack(cols, axis=1)
+    width = min(space, PASS_GROUPS)
+    passes = -(-space // width)
+
+    def one_pass(base):
+        """Groups base .. base + width - 1, one read of the rows."""
+        sels = [code == base + g for g in range(width)]
+        acc = jnp.stack([jnp.sum(jnp.where(s[:, None], stacked, 0), axis=0)
+                         for s in sels])
+        ex = tuple(jnp.stack([(jnp.min if f == "min" else jnp.max)(
+            jnp.where(s, v, jnp.int32(ident))) for s in sels])
+            for f, ident, v in extremes)
+        return acc, ex
+    if passes == 1:
+        return one_pass(0)
+
+    def body(p, carry):
+        acc, ex = carry
+        a, e = one_pass(p * width)
+        at = p * width
+        acc = jax.lax.dynamic_update_slice(acc, a, (at, 0))
+        ex = tuple(jax.lax.dynamic_update_slice(x, y, (at,))
+                   for x, y in zip(ex, e))
+        return acc, ex
+    n = passes * width
+    init = (jnp.zeros((n, len(cols)), jnp.int32),
+            tuple(jnp.full(n, ident, jnp.int32)
+                  for _f, ident, _v in extremes))
+    acc, ex = jax.lax.fori_loop(0, passes, body, init)
+    return acc[:space], tuple(x[:space] for x in ex)
+
+
+def group_sum_int_limbs_masked(codes: jax.Array, mask: jax.Array,
+                               vals: jax.Array, num_groups: int) -> jax.Array:
+    """`group_sum_int_limbs`' (G, 5) as masked reductions
+    (`group_reduce_masked`), for at most SMALL_SPACE groups. Exact while
+    the rows are at most LIMB_ROWS_EXACT."""
+    v = vals.reshape(-1).astype(jnp.int32)
+    m32 = mask.reshape(-1).astype(jnp.int32)
+    cols = int_limbs(v, m32, *INT32_RANGE, 8)
+    return group_reduce_masked(codes.reshape(-1), cols, [], num_groups)[0]
+
+
 @functools.partial(jax.jit, static_argnames=("num_groups",))
 def group_sum_int_limbs(codes: jax.Array, mask: jax.Array, vals: jax.Array,
                         num_groups: int) -> jax.Array:
     """Exact int sum via 8-bit limb scatter-adds of the two's-complement
-    representation: sum(v) = Σ_i (limb_sum_i << 8i) − (neg_count << 32).
+    representation (`int_limbs`): sum(v) = Σ_i (limb_sum_i << 8i) −
+    (neg_count << 32).
 
     Returns (G, 5) int32: four byte-limb sums + count of negative values.
     Exact while each group sees < 2^31/255 ≈ 8.4M rows per call (the
@@ -209,14 +310,11 @@ def group_sum_int_limbs(codes: jax.Array, mask: jax.Array, vals: jax.Array,
     flat_codes = codes.reshape(-1)
     flat_mask = mask.reshape(-1)
     v = vals.reshape(-1).astype(jnp.int32)
-    vu = jax.lax.bitcast_convert_type(v, jnp.uint32)
     safe = jnp.where(flat_mask, flat_codes, 0)
     m32 = flat_mask.astype(jnp.int32)
     out = jnp.zeros((num_groups, 5), dtype=jnp.int32)
-    for limb in range(4):
-        byte = (jnp.right_shift(vu, 8 * limb) & jnp.uint32(0xFF)).astype(jnp.int32)
-        out = out.at[safe, limb].add(byte * m32)
-    out = out.at[safe, 4].add((v < 0).astype(jnp.int32) * m32)
+    for limb, col in enumerate(int_limbs(v, m32, *INT32_RANGE, 8)):
+        out = out.at[safe, limb].add(col)
     return out
 
 
@@ -225,10 +323,7 @@ def combine_sum_int_limbs(limbs: np.ndarray) -> np.ndarray:
     chunked (C,G,5) array too (summed in int64 first)."""
     if limbs.ndim == 3:
         limbs = limbs.astype(np.int64).sum(axis=0)
-    acc = np.zeros(limbs.shape[0], dtype=np.int64)
-    for limb in range(4):
-        acc += limbs[:, limb].astype(np.int64) << (8 * limb)
-    return acc - (limbs[:, 4].astype(np.int64) << 32)
+    return combine_limbs([limbs[:, k] for k in range(5)], *INT32_RANGE, 8)
 
 
 SCATTER_CHUNK_TILES = SCATTER_SUM_MAX_ROWS // 128

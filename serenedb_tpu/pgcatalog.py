@@ -68,6 +68,7 @@ _ATT_OID = {
     dt.TypeId.INTERVAL: 1186, dt.TypeId.NULL: 25, dt.TypeId.OID: 26,
     dt.TypeId.REGCLASS: 2205, dt.TypeId.REGTYPE: 2206,
     dt.TypeId.REGPROC: 24, dt.TypeId.REGNAMESPACE: 4089,
+    dt.TypeId.DECIMAL: 1700,
 }
 
 # type oid → SQL rendering for format_type()

@@ -47,10 +47,11 @@ _OID = {
     dt.TypeId.OID: 26, dt.TypeId.REGCLASS: 2205,
     dt.TypeId.REGTYPE: 2206, dt.TypeId.REGPROC: 24,
     dt.TypeId.REGNAMESPACE: 4089, dt.TypeId.RECORD: 2249,
+    dt.TypeId.DECIMAL: 1700,
 }
 _TYPLEN = {16: 1, 21: 2, 23: 4, 20: 8, 700: 4, 701: 8, 25: -1, 1114: 8,
            1082: 4, 1186: 16, 26: 4, 2205: 4, 2206: 4, 24: 4, 4089: 4,
-           2249: -1}
+           2249: -1, 1700: -1}
 
 #: element TypeId → array OID (PG catalog values)
 _ARRAY_OID = {
@@ -107,6 +108,8 @@ def pg_text(value, typ: dt.SqlType, db=None) -> Optional[bytes]:
     if tid is dt.TypeId.DATE:
         import numpy as np
         return str(np.datetime64(int(value), "D")).encode()
+    if tid is dt.TypeId.DECIMAL:
+        return dt.decimal_text(value, typ.scale).encode()
     if tid is dt.TypeId.INTERVAL:
         from ..sql.binder import format_interval
         return format_interval(int(value)).encode()

@@ -158,7 +158,53 @@ def literal_type(v) -> dt.SqlType:
         # a vector handed over as a parameter (the ES knn route): never
         # printed into SQL text and parsed back
         return dt.vector_of(len(v))
+    if isinstance(v, float):
+        return dt.DOUBLE
     return _LIT_TYPE.get(type(v), dt.VARCHAR)
+
+
+#: operators whose operands meet a DECIMAL exactly: a literal written
+#: with a decimal point becomes a DECIMAL literal there
+_DECIMAL_OPS = {"op=", "op<>", "op!=", "op<", "op<=", "op>", "op>=",
+                "op+", "op-", "op*", "op/", "op%"}
+
+
+def _decimal_literal(e: BoundExpr) -> BoundExpr:
+    """An ExactFloat literal as the DECIMAL it was written as."""
+    if isinstance(e, BoundLiteral) and isinstance(e.value, dt.ExactFloat):
+        ex = dt.exact_decimal(e.value)
+        if ex is not None and ex[1] <= dt.MAX_DECIMAL_PRECISION and \
+                abs(ex[0]) < 10 ** dt.MAX_DECIMAL_PRECISION:
+            return BoundLiteral(ex[0], dt.decimal_of(
+                dt.MAX_DECIMAL_PRECISION, ex[1]))
+    return e
+
+
+def _fold_exact(name: str, a: BoundExpr, b: BoundExpr):
+    """`0.06 - 0.01` folded twice: the binary float the engine has always
+    computed, and the exact decimal text a DECIMAL beside it reads
+    (ExactFloat). None where either side is not such a literal."""
+    if name not in ("op+", "op-", "op*") or not (
+            isinstance(a, BoundLiteral) and isinstance(b, BoundLiteral)):
+        return None
+    if not (isinstance(a.value, dt.ExactFloat) or
+            isinstance(b.value, dt.ExactFloat)):
+        return None
+    xa, xb = dt.exact_decimal(a.value), dt.exact_decimal(b.value)
+    if xa is None or xb is None:
+        return None
+    if name == "op*":
+        v, scale = xa[0] * xb[0], xa[1] + xb[1]
+    else:
+        scale = max(xa[1], xb[1])
+        va = xa[0] * 10 ** (scale - xa[1])
+        vb = xb[0] * 10 ** (scale - xb[1])
+        v = va + vb if name == "op+" else va - vb
+    fa, fb = float(a.value), float(b.value)
+    value = fa * fb if name == "op*" else (fa + fb if name == "op+"
+                                          else fa - fb)
+    text = dt.decimal_text(v, scale) if scale else f"{v}.0"
+    return BoundLiteral(dt.ExactFloat(text, value), dt.DOUBLE)
 
 
 class ExprBinder:
@@ -358,6 +404,8 @@ class ExprBinder:
             if len(e.args) != 1:
                 raise errors.unsupported(f"{name} with {len(e.args)} args")
             arg = self.bind(e.args[0])
+            if arg.type.is_decimal and name in _DECIMAL_AGGS:
+                return self._bind_decimal_agg(e, name, arg)
             out_t = _agg_result_type(name, arg.type)
             spec = AggSpec(name, arg, e.distinct, out_t)
         if getattr(e, "filter", None) is not None:
@@ -373,6 +421,55 @@ class ExprBinder:
                     spec.sep, _expr_key(spec.filter),
                     tuple((_expr_key(k), d, nf)
                           for k, d, nf in (spec.order_by or []))))
+        if key in self._agg_keys:
+            idx = self._agg_keys[key]
+            return BoundAggRef(idx, self.aggs[idx].type)
+        self.aggs.append(spec)
+        idx = len(self.aggs) - 1
+        self._agg_keys[key] = idx
+        return BoundAggRef(idx, spec.type)
+
+    def _bind_decimal_agg(self, e: ast.FuncCall, name: str,
+                          arg: BoundExpr) -> BoundExpr:
+        """SUM/AVG/MIN/MAX of a DECIMAL aggregate its scaled int64 as the
+        BIGINT it is (`decimal_raw`), so every aggregation tier, host or
+        device, sees an integer argument; the result is read back as
+        DECIMAL(18, s) for SUM, the argument's type for MIN/MAX and a
+        DOUBLE for AVG."""
+        t = arg.type
+
+        def raw(cols, batch):
+            return Column(dt.BIGINT, cols[0].data, cols[0].validity)
+        inner = ast.FuncCall(name, [ast.Literal(None)], distinct=e.distinct)
+        for attr in ("filter", "agg_order"):
+            setattr(inner, attr, getattr(e, attr, None))
+        bound_raw = BoundFunc("decimal_raw", [arg], dt.BIGINT, raw)
+        ref = self._bind_agg_arg(inner, name, bound_raw)
+        if name == "avg":
+            f = 10.0 ** t.scale
+
+            def avg(cols, batch, _f=f):
+                c = cols[0]
+                return Column(dt.DOUBLE, c.data.astype(np.float64) / _f,
+                              c.validity)
+            return BoundFunc("decimal_avg", [ref], dt.DOUBLE, avg)
+        out_t = t if name in ("min", "max") else \
+            dt.decimal_of(dt.MAX_DECIMAL_PRECISION, t.scale)
+
+        def typed(cols, batch, _t=out_t):
+            return Column(_t, cols[0].data.astype(np.int64),
+                          cols[0].validity)
+        return BoundFunc("decimal_of", [ref], out_t, typed)
+
+    def _bind_agg_arg(self, e: ast.FuncCall, name: str,
+                      arg: BoundExpr) -> BoundExpr:
+        """`_bind_agg` for one already-bound argument."""
+        spec = AggSpec(name, arg, e.distinct, _agg_result_type(name,
+                                                               arg.type))
+        if getattr(e, "filter", None) is not None:
+            spec.filter = self.bind(e.filter)
+        key = repr((spec.func, _expr_key(spec.arg), spec.distinct,
+                    spec.sep, _expr_key(spec.filter), ()))
         if key in self._agg_keys:
             idx = self._agg_keys[key]
             return BoundAggRef(idx, self.aggs[idx].type)
@@ -416,7 +513,9 @@ class ExprBinder:
 
         def impl(cols, batch, _t=target):
             return cast_column(cols[0], _t)
-        return BoundFunc("cast", [arg], target, impl)
+        # a constant cast (`DATE '1995-03-15'`) folds to a typed literal,
+        # which the device compiler and zone maps read as a constant
+        return _fold_if_const(BoundFunc("cast", [arg], target, impl))
 
     def _bind_case(self, e: ast.Case) -> BoundExpr:
         if e.operand is not None:
@@ -432,6 +531,16 @@ class ExprBinder:
         arms = [v for _, v in bound] + ([else_b] if else_b is not None
                                         else [])
         t = dt.unify_all(v.type for v in arms)
+        if t.is_decimal:
+            # every arm at the one scale: a DECIMAL value is its scaled int
+            def to_t(v):
+                if v.type == t:
+                    return v
+                return _fold_if_const(BoundFunc(
+                    "cast", [_decimal_literal(v)], t,
+                    lambda cols, batch, _t=t: cast_column(cols[0], _t)))
+            bound = [(c, to_t(v)) for c, v in bound]
+            else_b = to_t(else_b) if else_b is not None else None
         return BoundCase(bound, else_b, t)
 
     # -- subqueries --------------------------------------------------------
@@ -705,6 +814,12 @@ class ExprBinder:
                       dt.TypeId.INTERVAL)
 
     def _call(self, name: str, args: list[BoundExpr]) -> BoundExpr:
+        if name in _DECIMAL_OPS and len(args) == 2:
+            folded = _fold_exact(name, *args)
+            if folded is not None:
+                return folded
+            if any(a.type.is_decimal for a in args):
+                args = [_decimal_literal(a) for a in args]
         if name == "opnot":
             def impl(cols, batch):
                 c = cols[0]
@@ -732,6 +847,9 @@ class ExprBinder:
         f = BoundFunc(name, args, res.result_type, impl2)
         return _fold_if_const(f)
 
+
+#: aggregates that run over a DECIMAL's scaled int64 (`_bind_decimal_agg`)
+_DECIMAL_AGGS = {"sum", "avg", "min", "max"}
 
 from ..functions.volatility import (IMMUTABLE, VOLATILE,  # noqa: E402
                                     VOLATILE_FUNCS, volatility)
@@ -1074,6 +1192,8 @@ def cast_column(col: Column, target: dt.SqlType) -> Column:
     if src.is_vector and not target.is_string:
         raise errors.SqlError(
             "42846", f"cannot cast type {src} to {target}")
+    if target.is_decimal or src.is_decimal:
+        return _cast_decimal(col, target)
     _REG = (dt.TypeId.REGCLASS, dt.TypeId.REGTYPE, dt.TypeId.REGPROC,
             dt.TypeId.REGNAMESPACE)
     if target.id in _REG and src.is_string:
@@ -1219,6 +1339,96 @@ def cast_column(col: Column, target: dt.SqlType) -> Column:
     raise errors.unsupported(f"cast {src} -> {target}")
 
 
+def _round_div(x: np.ndarray, f: int) -> np.ndarray:
+    """x / f rounded half away from zero, in integers (PG numeric)."""
+    q, r = np.divmod(np.abs(x), f)
+    q = q + (2 * r >= f)
+    return np.where(x < 0, -q, q)
+
+
+def _decimal_fits(data: np.ndarray, t: dt.SqlType, validity) -> None:
+    bad = np.abs(data) >= 10 ** t.prec
+    if validity is not None:
+        bad &= validity
+    if bad.any():
+        raise errors.SqlError(
+            "22003", f"numeric field overflow: a field with precision "
+            f"{t.prec}, scale {t.scale} must round to an absolute value "
+            f"less than 10^{t.prec - t.scale}")
+
+
+def _cast_decimal(col: Column, target: dt.SqlType) -> Column:
+    """Casts to and from DECIMAL: exact in integers, rounding half away
+    from zero where scale drops (PG numeric)."""
+    from decimal import ROUND_HALF_UP, Decimal, InvalidOperation
+    src = col.type
+    validity = col.validity
+    if target.is_decimal:
+        s = target.scale
+        if src.is_decimal or src.is_integer or src.id in (
+                dt.TypeId.BOOL, dt.TypeId.NULL):
+            x = col.data.astype(np.int64)
+            k = s - (src.scale if src.is_decimal else 0)
+            if k >= 0:
+                lim = (2 ** 63 - 1) // 10 ** k
+                bad = np.abs(x) > lim
+                if validity is not None:
+                    bad &= validity
+                if bad.any():
+                    raise errors.SqlError("22003", "numeric field overflow")
+                data = x * 10 ** k
+            else:
+                data = _round_div(x, 10 ** -k)
+        elif src.is_float:
+            x = col.data.astype(np.float64) * 10.0 ** s
+            r = np.sign(x) * np.floor(np.abs(x) + 0.5)
+            ok = col.valid_mask()
+            bad = ok & ~(np.abs(r) < 9.2e18)
+            if bad.any():
+                raise errors.SqlError("22003", "numeric field overflow")
+            data = np.where(ok, r, 0.0).astype(np.int64)
+        elif src.is_string:
+            vals = col.to_pylist()
+            data = np.zeros(len(vals), np.int64)
+            q = Decimal(1).scaleb(-s)
+            for i, v in enumerate(vals):
+                if v is None:
+                    continue
+                try:
+                    d = Decimal(str(v).strip())
+                    if not d.is_finite():
+                        raise InvalidOperation
+                    data[i] = int(d.quantize(q, rounding=ROUND_HALF_UP)
+                                  .scaleb(s))
+                except (InvalidOperation, ValueError):
+                    raise errors.SqlError(
+                        errors.INVALID_TEXT_REPRESENTATION,
+                        f'invalid input syntax for type numeric: "{v}"')
+                except OverflowError:
+                    raise errors.SqlError("22003", "numeric field overflow")
+        else:
+            raise errors.SqlError(
+                "42846", f"cannot cast type {src} to {target}")
+        _decimal_fits(data, target, validity)
+        return Column(target, data, validity)
+    # DECIMAL -> other types
+    f = 10 ** src.scale
+    if target.is_string:
+        from .expr import make_string_column
+        out = [dt.decimal_text(v, src.scale) for v in col.data.tolist()]
+        return make_string_column(np.asarray(out, dtype=object).astype(str),
+                                  validity)
+    if target.is_float:
+        return Column(target, (col.data.astype(np.float64) / f)
+                      .astype(target.np_dtype), validity)
+    if target.id is dt.TypeId.BOOL:
+        return Column(target, col.data != 0, validity)
+    if target.is_integer:
+        data = _round_div(col.data.astype(np.int64), f)
+        return cast_column(Column(dt.BIGINT, data, validity), target)
+    raise errors.SqlError("42846", f"cannot cast type {src} to {target}")
+
+
 def _cast_to_text(v, src: dt.SqlType) -> str:
     if src.id is dt.TypeId.INTERVAL:
         return format_interval(int(v))
@@ -1264,6 +1474,9 @@ def _cast_text_to(v: str, target: dt.SqlType):
             return int(d64.astype(np.int64))
         if target.id is dt.TypeId.INTERVAL:
             return parse_interval(s)
+        if target.is_decimal:
+            return int(_cast_decimal(Column.from_pylist([s], dt.VARCHAR),
+                                     target).data[0])
         if target.is_vector:
             return s              # Column.from_pylist parses the text
     except ValueError:

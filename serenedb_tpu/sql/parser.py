@@ -13,6 +13,7 @@ from __future__ import annotations
 from typing import Optional
 
 from .. import errors
+from ..columnar import dtypes as dt
 from ..errors import SqlError
 from . import ast
 from .lexer import T, Token, tokenize
@@ -837,6 +838,8 @@ class Parser:
             self.expect_op(")")
             if name.upper() == "VECTOR":    # VECTOR(n): n is the type
                 name = f"VECTOR({''.join(params)})"
+            elif name.upper() in ("DECIMAL", "NUMERIC", "DEC"):
+                name = f"DECIMAL({''.join(params)})"
         if self.at_op("["):      # INT[] array type; FLOAT4[n] = VECTOR(n)
             self.next()
             size = ""
@@ -851,8 +854,11 @@ class Parser:
         if t.kind is T.NUMBER:
             self.next()
             text = t.value
-            if "." in text or "e" in text or "E" in text:
+            if "e" in text or "E" in text:
                 return ast.Literal(float(text))
+            if "." in text:
+                # the text rides along: beside a DECIMAL it types exactly
+                return ast.Literal(dt.ExactFloat(text))
             v = int(text)
             return ast.Literal(v)
         if t.kind is T.STRING:

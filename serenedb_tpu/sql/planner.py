@@ -11,6 +11,7 @@ ORDER BY / GROUP BY resolve select aliases and positions per PG scoping.
 
 from __future__ import annotations
 
+import dataclasses
 from dataclasses import dataclass
 from typing import Callable, Optional
 
@@ -156,7 +157,13 @@ class Planner:
                     Batch(["__dummy"], [Column.from_pylist([0])]))
                 scope = Scope([])
             else:
-                plan, scope = self._plan_from(sel.from_)
+                leaves = _from_list(sel.from_)
+                if len(leaves) > 1 and sel.where is not None:
+                    plan, scope, rest = self._plan_join_graph(leaves,
+                                                              sel.where)
+                    sel = dataclasses.replace(sel, where=rest)
+                else:
+                    plan, scope = self._plan_from(sel.from_)
             return self._plan_body(sel, plan, scope)
         finally:
             self.ctes = saved
@@ -460,6 +467,140 @@ class Planner:
         node = JoinNode(kind, left, right, left_keys, right_keys,
                         residual, names, types, merge_pairs=merge_pairs)
         return node, combined
+
+    def _plan_join_graph(self, leaves: list, where: ast.Expr):
+        """A FROM list under a WHERE as a join graph, not a cross product.
+
+        A conjunct that reads one relation goes into that relation's
+        scan; one that equates expressions of two relations is an edge.
+        Joins go left-deep from the largest relation, each next relation
+        being the largest one an edge connects to what is joined. Its
+        keys are every edge to ONE joined relation, the earliest joined;
+        edges to others close a cycle and stay above the joins with the
+        rest of the WHERE (Q5's `c_nationkey = s_nationkey`). A relation
+        no edge reaches is a cross join, as before. Returns (plan, scope
+        in FROM order, the conjuncts left over or None)."""
+        planned = [self._plan_from(ref) for ref in leaves]
+        n = len(planned)
+        offsets, at = [], 0
+        for _, sc in planned:
+            offsets.append(at)
+            at += len(sc.columns)
+        flat = Scope([ScopeColumn(c.table, c.name, c.type, c.index + off,
+                                  c.hidden)
+                      for (_, sc), off in zip(planned, offsets)
+                      for c in sc.columns])
+
+        def leaf_of(col_index: int) -> int:
+            k = 0
+            while k + 1 < n and offsets[k + 1] <= col_index:
+                k += 1
+            return k
+
+        def leaves_read(e: ast.Expr):
+            """Relations an expression reads, or None when it cannot be
+            bound on its own (subqueries, outer references, errors)."""
+            try:
+                b = self._binder(flat).bind(e)
+            except errors.SqlError:
+                return None
+            if any(not isinstance(x, (BoundColumn, BoundLiteral, BoundFunc,
+                                      BoundCase)) for x in b.walk()):
+                return None
+            return {leaf_of(x.index) for x in b.walk()
+                    if isinstance(x, BoundColumn)}
+
+        local: list[list] = [[] for _ in range(n)]
+        edges: list[tuple] = []          # (i, j, expr of i, expr of j)
+        rest: list = []
+        for c in _split_conjuncts(where):
+            rs = leaves_read(c)
+            if rs is not None and len(rs) == 1:
+                local[rs.pop()].append(c)
+                continue
+            if rs is not None and len(rs) == 2 and \
+                    isinstance(c, ast.BinaryOp) and c.op == "=":
+                ls, rs_ = leaves_read(c.left), leaves_read(c.right)
+                if ls is not None and rs_ is not None and \
+                        len(ls) == 1 and len(rs_) == 1 and ls != rs_:
+                    edges.append((ls.pop(), rs_.pop(), c.left, c.right))
+                    continue
+            rest.append(c)
+        plans = []
+        for (plan, sc), preds in zip(planned, local):
+            if preds:
+                both = preds[0] if len(preds) == 1 else \
+                    ast.Logical("AND", preds)
+                plan = self._push_filter(plan, self._binder(sc).bind(both))
+            plans.append(plan)
+
+        def size(k: int) -> int:
+            node = plans[k]
+            while isinstance(node, FilterNode):
+                node = node.child
+            try:
+                return node.provider.row_count() \
+                    if isinstance(node, ScanNode) else 0
+            except (NotImplementedError, AttributeError):
+                return 0
+
+        def linked(k: int, joined: list) -> list:
+            return [e for e in edges
+                    if (e[0] == k and e[1] in joined) or
+                    (e[1] == k and e[0] in joined)]
+
+        order = [max(range(n), key=lambda k: (size(k), -k))]
+        plan = plans[order[0]]
+        lcols = [ScopeColumn(c.table, c.name, c.type, c.index, c.hidden)
+                 for c in planned[order[0]][1].columns]
+        used: set = set()
+        while len(order) < n:
+            todo = [k for k in range(n) if k not in order]
+            reach = [k for k in todo if linked(k, order)]
+            k = max(reach or todo, key=lambda r: (size(r), -r))
+            lscope = Scope(lcols)
+            rscope = planned[k][1]
+            left_keys: list = []
+            right_keys: list = []
+            es = linked(k, order)
+            if es:
+                first = min((e[0] if e[1] == k else e[1] for e in es),
+                            key=order.index)
+                for e in es:
+                    other = e[0] if e[1] == k else e[1]
+                    if other != first:
+                        continue
+                    a, b = (e[2], e[3]) if e[0] == other else (e[3], e[2])
+                    left_keys.append(self._binder(lscope).bind(a))
+                    right_keys.append(self._binder(rscope).bind(b))
+                    used.add(id(e))
+            nl = len(lcols)
+            rcols = [ScopeColumn(c.table, c.name, c.type, c.index + nl,
+                                 c.hidden) for c in rscope.columns]
+            cols = lcols + rcols
+            plan = JoinNode("inner" if left_keys else "cross", plan,
+                            plans[k], left_keys, right_keys, None,
+                            _dedup_names([c.name for c in cols]),
+                            [c.type for c in cols])
+            lcols = cols
+            order.append(k)
+        rest += [ast.BinaryOp("=", e[2], e[3]) for e in edges
+                 if id(e) not in used]
+        # the scope keeps FROM order (SELECT *, name resolution); each
+        # column points at its place in the join order's output
+        place, at = {}, 0
+        for k in order:
+            place[k] = at
+            at += len(planned[k][1].columns)
+        scope = Scope([ScopeColumn(c.table, c.name, c.type,
+                                   c.index + place[k], c.hidden)
+                       for k, (_, sc) in enumerate(planned)
+                       for c in sc.columns])
+        where_rest = None
+        for c in rest:
+            where_rest = c if where_rest is None else \
+                ast.Logical("AND", [where_rest, c])
+        return plan, scope, where_rest
 
     def _try_equi_key(self, e: ast.Expr, lscope: Scope, rscope: Scope):
         if not (isinstance(e, ast.BinaryOp) and e.op == "="):
@@ -872,6 +1013,15 @@ def _dedup_names(names: list[str]) -> list[str]:
             seen[n] = 0
             out.append(n)
     return out
+
+
+def _from_list(ref: ast.TableRef) -> list:
+    """The relations of a comma-separated FROM list (plain cross joins),
+    in FROM order; explicit JOINs stay one relation each."""
+    if isinstance(ref, ast.JoinRef) and ref.kind == "cross" and \
+            ref.condition is None and not ref.using:
+        return _from_list(ref.left) + _from_list(ref.right)
+    return [ref]
 
 
 def _split_conjuncts(e: ast.Expr) -> list[ast.Expr]:

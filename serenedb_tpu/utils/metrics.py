@@ -203,6 +203,25 @@ STATEMENTS_ANSWERED_HOST = REGISTRY.gauge(
     "StatementsAnsweredHost",
     "traced non-utility statements answered without any device program")
 DEVICE_BYTES = REGISTRY.gauge("DeviceBytesMoved", "bytes copied host->device")
+DEVICE_JOINS_FUSED = REGISTRY.gauge(
+    "DeviceJoinsFused",
+    "joins executed inside a fused device program: one per join edge "
+    "per dispatch (the chain program, exec/device_chain.py, and the "
+    "two-table pair-count program)")
+HOST_JOINS = REGISTRY.gauge("HostJoins", "host JoinNode executions")
+DEVICE_JOIN_BYTES = REGISTRY.gauge(
+    "DeviceJoinBytes",
+    "per fused join dispatch, the bytes the statement has to read "
+    "whatever implements it: for every table it references, rows x the "
+    "narrowest 1/2/4/8-byte integer width of each column it references "
+    "(a string column as its code)")
+DEVICE_JOIN_INDEX_BUILDS = REGISTRY.gauge(
+    "DeviceJoinIndexBuilds",
+    "join row indexes built (a chain program's per-edge key->row "
+    "lookups, once per pair of table publications)")
+DEVICE_JOIN_INDEX_BYTES = REGISTRY.gauge(
+    "DeviceJoinIndexBytes",
+    "bytes of join row indexes resident (set, not summed)")
 DEVICE_CACHE_HITS = REGISTRY.gauge(
     "DeviceCacheHits",
     "a device-resident column was asked for and found in HBM (no "
@@ -633,6 +652,9 @@ STAGE_HISTS = {name: REGISTRY.histogram(hist, desc) for name, hist, desc in (
     ("host_group", "StageHostGroup",
      "host hash-aggregate, factorize and distinct finalize"),
     ("host_sort", "StageHostSort", "the materializing host sort"),
+    ("host_join", "StageHostJoin",
+     "a host JoinNode's own work: key match, residual, null extension "
+     "and the gather of both sides (its inputs' scans are their own)"),
     ("batch_wait", "StageBatchWait",
      "a top-k query waiting in the search batcher: submission until the "
      "dispatch that carries it starts, and, for a member another thread "

@@ -60,6 +60,48 @@ def test_group_sum_int_exact_with_negatives():
     np.testing.assert_array_equal(sums, expected)
 
 
+@pytest.mark.parametrize("lo,hi,w,rows", [
+    (0, 10_494_950, 28, 7),            # a TPC-H order's lines, 28-bit limbs
+    (-500_000_000, 1_049_495_000, 8, 4000),
+    (0, 255, 8, 4000),                 # one limb
+    (-(1 << 31), (1 << 31) - 1, 8, 4000),
+])
+def test_int_limbs_sum_exactly(lo, hi, w, rows):
+    """`int_limbs` cut int32 values into w-bit limbs (and a negative count)
+    whose int32 sums `combine_limbs` turns into the exact int64 sum."""
+    import jax.numpy as jnp
+    rng = np.random.default_rng(rows + w)
+    vals = rng.integers(lo, hi, size=rows, endpoint=True, dtype=np.int64)
+    vals[:2] = [lo, hi]
+    keep = rng.random(rows) < 0.8
+    cols = agg.int_limbs(jnp.asarray(vals.astype(np.int32)),
+                         jnp.asarray(keep.astype(np.int32)), lo, hi, w)
+    n, neg = agg.limb_count(lo, hi, w)
+    assert len(cols) == n + neg
+    sums = [np.asarray(c).sum(dtype=np.int32)[None] for c in cols]
+    assert int(agg.combine_limbs(sums, lo, hi, w)[0]) == int(vals[keep].sum())
+
+
+@pytest.mark.parametrize("groups", [1, 12, 16, 208])
+def test_masked_group_sums_match_the_scatter(groups):
+    """`group_sum_int_limbs_masked` (a pass of masked reductions per 16
+    groups) gives `group_sum_int_limbs`' limb sums, NULL rows left out."""
+    import jax.numpy as jnp
+    rng = np.random.default_rng(groups)
+    n = 128 * 40
+    codes = jnp.asarray(rng.integers(0, groups, n).astype(np.int32))
+    vals = jnp.asarray(rng.integers(-2**31, 2**31 - 1, n).astype(np.int32))
+    mask = jnp.asarray(rng.random(n) < 0.7)
+    want = np.asarray(agg.group_sum_int_limbs(codes, mask, vals, groups))
+    got = np.asarray(agg.group_sum_int_limbs_masked(codes, mask, vals,
+                                                    groups))
+    assert (got == want).all()
+    sums = agg.combine_sum_int_limbs(got)
+    v, c, m = np.asarray(vals).astype(np.int64), np.asarray(codes), \
+        np.asarray(mask)
+    assert list(sums) == [int(v[m & (c == g)].sum()) for g in range(groups)]
+
+
 def test_group_min_max_and_float_sum():
     codes_np = np.array([0, 1, 0, 1, 2], dtype=np.int64)
     vals_np = np.array([5.0, -1.0, 3.0, 7.0, 0.5])
