@@ -40,7 +40,9 @@ ops/agg.py's limb columns (`int_limbs`) summed in int32, each limb as
 wide as the most rows a group can hold allows (8 bits for 2^23 rows; 28
 where a group is an order's lines). A product that leaves int32 is summed
 as device_agg's `wide_parts`. Few groups reduce as masked sums, sixteen a
-pass over the rows; many as one 1-D scatter a column.
+pass over the rows; many as one 1-D scatter a column of the rows that
+survive, gathered first into the smallest rung of a closed ladder of
+sizes that holds them (every row past its last rung).
 
 One table is device_agg's, whatever its column types. What the program
 cannot admit declines with a named reason. A two-table join whose build
@@ -756,6 +758,7 @@ def _run(node, rels, post, group, aggs, ctx) -> Batch:
     small_rel = {k: packs[k][2] for k in pack_rels}
     limb_w = layout.limb_width(_group_rows(rels, key_plans, edge_of,
                                            probe))
+    rungs = _rungs(p_pad, space)
 
     def program(*flat):
         it = iter(flat)
@@ -851,7 +854,7 @@ def _run(node, rels, post, group, aggs, ctx) -> Batch:
                 jnp.int32(ident)).ravel()))
         cols = [c.ravel() for c in cols]
         pos = jnp.arange(code.size, dtype=jnp.int32)
-        return _reduce(code, cols, pos, extremes, space)
+        return _reduce(code, cols, pos, extremes, space, rungs)
 
     consts = tuple(ce.consts for ce in compiled_preds + compiled_masks +
                    [c for c, _, _ in compiled_parts + compiled_mm])
@@ -860,7 +863,7 @@ def _run(node, rels, post, group, aggs, ctx) -> Batch:
                  tuple(sorted(decode.items())), p_pad,
                  tuple((k, tuple(slots[k]), small_rel[k],
                         packs[k][0].shape) for k in pack_rels),
-                 tuple(key_plans), space, limb_w,
+                 tuple(key_plans), space, rungs, limb_w,
                  tuple((si, lk_src[si], len(lookups[si][2]))
                        for si in lk_needed),
                  tuple(_expr_key(p) for p in dev_preds),
@@ -889,7 +892,7 @@ def _run(node, rels, post, group, aggs, ctx) -> Batch:
                                   node_key=id(node))
     with stage("device_finalize"):
         return _finalize(node, rels, group, aggs, agg_plans, layout,
-                         results, group_mode, probe, limb_w)
+                         results, group_mode, probe, limb_w, rungs)
 
 
 def _pack_tag(d: tuple, col_name, edge_of, lookups, rels) -> tuple:
@@ -960,26 +963,130 @@ def _group_rows(rels, key_plans, edge_of, probe) -> int:
     return max(bound, 1)
 
 
-def _reduce(code, cols, pos, extremes, space: int):
+#: the many-group reduction's ladder: a rung holds p_pad // d surviving
+#: rows (2^23 probe rows: 65,536, 262,144 and 1,048,576), compacted
+#: before their scatter; past the last rung every row is scattered
+COMPACT_RUNGS = (128, 32, 8)
+
+
+def _rungs(p_pad: int, space: int) -> tuple:
+    """The ladder's sizes for a program of `p_pad` probe rows, ascending;
+    none where the groups are few enough for masked reductions."""
+    if space + 1 <= ops_agg.SMALL_SPACE:
+        return ()
+    return tuple(sorted({max(p_pad // d, 1) for d in COMPACT_RUNGS}))
+
+
+def _reduce(code, cols, pos, extremes, space: int, rungs: tuple):
     """Per-group sums of the int32 columns, the largest probe position
     and the min/max columns. A few groups (the sentinel slot included):
-    ops/agg.py's masked reductions; many: a 1-D scatter a column (58 ms
+    ops/agg.py's masked reductions. Many: a 1-D scatter a column (58 ms
     for 8.4M rows into 1.5M slots, against 724 for five columns as one
-    (rows, 5) scatter, on one TPU v5e)."""
+    (rows, 5) scatter, on one TPU v5e) of the rows that survive, gathered
+    first into the smallest rung of `rungs` that holds them (a scatter
+    costs its updates, not its slots); past the last rung, of every
+    row."""
+    import jax
     import jax.numpy as jnp
     if space + 1 <= ops_agg.SMALL_SPACE:
         acc, mm = ops_agg.group_reduce_masked(
             code, cols, [("max", -1, pos)] + list(extremes), space)
         return (acc, mm[0]) + tuple(mm[1:])
-    acc = jnp.stack([jnp.zeros(space + 1, jnp.int32).at[code].add(c)
-                     [:space] for c in cols], axis=1)
-    rep = jnp.full(space + 1, -1, jnp.int32).at[code].max(pos)[:space]
-    mm = []
-    for f, ident, v in extremes:
-        t = jnp.full(space + 1, ident, jnp.int32)
-        mm.append((t.at[code].min(v) if f == "min"
-                   else t.at[code].max(v))[:space])
-    return (acc, rep) + tuple(mm)
+    funcs = [(f, ident) for f, ident, _v in extremes]
+
+    def scatter(code, cols, pos, vals):
+        acc = jnp.stack([jnp.zeros(space + 1, jnp.int32).at[code].add(c)
+                         [:space] for c in cols], axis=1)
+        rep = jnp.full(space + 1, -1, jnp.int32).at[code].max(pos)[:space]
+        mm = []
+        for (f, ident), v in zip(funcs, vals):
+            t = jnp.full(space + 1, ident, jnp.int32)
+            mm.append((t.at[code].min(v) if f == "min"
+                       else t.at[code].max(v))[:space])
+        return (acc, rep) + tuple(mm)
+    blocks = _survivor_blocks(code != space)
+
+    def compacted(b: int):
+        def run(code, cols, pos, vals):
+            at, live = _compact_positions(blocks, b)
+            # one row gather of the stacked columns: a 1-D gather a column
+            # costs 6x as much at 262,144 rows (one TPU v5e)
+            got = jnp.take(jnp.stack([code] + cols + vals, axis=1), at,
+                           axis=0)
+            fills = [space] + [0] * len(cols) + [i for _f, i in funcs]
+            got = [jnp.where(live, got[:, j], jnp.int32(f))
+                   for j, f in enumerate(fills)]
+            n = 1 + len(cols)
+            return scatter(got[0], got[1:n], jnp.where(live, at, -1),
+                           got[n:])
+        return run
+    total = jnp.sum(blocks[1])
+    rung = sum((total > b).astype(jnp.int32) for b in rungs)
+    return jax.lax.switch(rung, [compacted(b) for b in rungs] + [scatter],
+                          code, cols, pos, [v for _f, _i, v in extremes])
+
+
+def _survivor_blocks(valid):
+    """The survivors of flat `valid` (a multiple of 128 rows) in blocks
+    of 128 rows: each block's flags as four 32-bit words (int32), its
+    survivors, and the survivors before it."""
+    import jax
+    import jax.numpy as jnp
+    bits = valid.reshape(-1, 4, 32).astype(jnp.uint32)
+    words = jnp.sum(bits << jnp.arange(32, dtype=jnp.uint32), axis=2,
+                    dtype=jnp.uint32)
+    counts = jax.lax.population_count(words).astype(jnp.int32).sum(axis=1)
+    return (jax.lax.bitcast_convert_type(words, jnp.int32), counts,
+            jnp.cumsum(counts) - counts)
+
+
+def _running_max(x):
+    """Running max of a flat array of non-negative values, by doubling
+    shifts: XLA's cummax of 2^20 values takes 40 s to compile for a TPU
+    v5e."""
+    import jax.numpy as jnp
+    step = 1
+    while step < x.shape[0]:
+        x = jnp.maximum(x, jnp.pad(x[:-step], (step, 0)))
+        step *= 2
+    return x
+
+
+def _compact_positions(blocks, b: int):
+    """(row of each of the first b survivors, in row order; which of the
+    b slots hold one). A block that holds survivors marks the slot its
+    first one lands in (a scatter of one update a block), a running max
+    hands every slot its block, and the slot's rank inside the block
+    picks the word, then the bit."""
+    import jax
+    import jax.numpy as jnp
+    words, counts, before = blocks
+    mark = jnp.zeros(b, jnp.int32).at[jnp.where(counts > 0, before, b)] \
+        .max(jnp.arange(counts.shape[0], dtype=jnp.int32), mode="drop")
+    blk = _running_max(mark)
+    meta = jnp.take(jnp.concatenate([before[:, None], words], axis=1), blk,
+                    axis=0)
+    slot = jnp.arange(b, dtype=jnp.int32)
+    k = slot - meta[:, 0]
+    ws = [jax.lax.bitcast_convert_type(meta[:, 1 + q], jnp.uint32)
+          for q in range(4)]
+    word, lane = ws[0], jnp.zeros(b, jnp.int32)
+    for q in range(1, 4):
+        c = jax.lax.population_count(word).astype(jnp.int32)
+        go = k >= c
+        k = jnp.where(go, k - c, k)
+        word = jnp.where(go, ws[q], word)
+        lane = jnp.where(go, 32 * q, lane)
+    bit = jnp.zeros(b, jnp.int32)
+    for w in (16, 8, 4, 2, 1):
+        c = jax.lax.population_count(
+            (word >> bit.astype(jnp.uint32)) & jnp.uint32((1 << w) - 1)
+        ).astype(jnp.int32)
+        go = k >= c
+        k = jnp.where(go, k - c, k)
+        bit = jnp.where(go, bit + w, bit)
+    live = slot < jnp.sum(counts)
+    return jnp.where(live, blk * 128 + lane + bit, 0), live
 
 
 class _Layout:
@@ -1064,11 +1171,16 @@ class _Layout:
 
 
 def _finalize(node, rels, group, aggs, agg_plans, layout, results,
-              group_mode, probe, limb_w: int) -> Batch:
+              group_mode, probe, limb_w: int, rungs: tuple) -> Batch:
     acc = np.asarray(results[0])
     rep = np.asarray(results[1])
     mm = [np.asarray(x) for x in results[2:]]
     counts = acc[:, 0].astype(np.int64)
+    if rungs:
+        if int(counts.sum()) <= rungs[-1]:
+            metrics.DEVICE_CHAIN_COMPACTED.add()
+        else:
+            metrics.DEVICE_CHAIN_SCATTERED_FULL.add()
     present = np.flatnonzero(counts > 0) if group_mode else np.asarray([0])
     cols: list[Column] = []
     if group_mode:

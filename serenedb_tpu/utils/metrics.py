@@ -222,6 +222,14 @@ DEVICE_JOIN_INDEX_BUILDS = REGISTRY.gauge(
 DEVICE_JOIN_INDEX_BYTES = REGISTRY.gauge(
     "DeviceJoinIndexBytes",
     "bytes of join row indexes resident (set, not summed)")
+DEVICE_CHAIN_COMPACTED = REGISTRY.gauge(
+    "DeviceChainCompacted",
+    "join chain dispatches into many groups whose surviving rows fit a "
+    "rung of the compaction ladder: only they were scattered")
+DEVICE_CHAIN_SCATTERED_FULL = REGISTRY.gauge(
+    "DeviceChainScatteredFull",
+    "join chain dispatches into many groups whose surviving rows passed "
+    "the ladder's last rung: every probe row was scattered")
 DEVICE_CACHE_HITS = REGISTRY.gauge(
     "DeviceCacheHits",
     "a device-resident column was asked for and found in HBM (no "

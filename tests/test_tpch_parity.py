@@ -5,7 +5,9 @@ each, through exec/device_agg.py as every single-table aggregate) against
 the host oracle (`serene_device_fused = off`) and against the plain
 reference (benchmark/references/tpch_numpy.py). Each statement takes ONE
 device dispatch and no decline; a join statement counts one fused join
-per edge."""
+per edge. Q3 and Q10 group by a row id, past the masked reductions' few
+groups: their surviving rows are compacted into a rung of the chain's
+ladder before they are scattered, or all rows are scattered past it."""
 
 import json
 import os
@@ -14,6 +16,7 @@ import numpy as np
 import pytest
 
 from serenedb_tpu.engine import Database
+from serenedb_tpu.exec import device_chain
 from serenedb_tpu.obs import device as obs_device
 from serenedb_tpu.server.pgwire import pg_text
 from serenedb_tpu.utils import metrics
@@ -131,3 +134,104 @@ def test_wide_products_stay_exact(grouped):
     assert obs_device.PROGRAMS.family("device_agg")["hits"] == hits + 1
     c.execute("SET serene_device = 'cpu'")
     assert dev == c.execute(sql).rows()
+
+
+#: ladders (divisors of the padded probe rows: 65,536 at SF 0.01) that put
+#: Q3 (about 300 surviving rows) and Q10 (about 1,150) on each branch of
+#: the many-group reduction: rung 0, 1 or 2, or past the last (every row)
+LADDERS = {0: (16, 8, 4), 1: (65536, 16, 8), 2: (65536, 32768, 16),
+           3: (65536, 32768, 16384)}
+#: the statements with a date range no order falls in: no row survives
+NO_SURVIVOR = {"q3": ("o_orderdate < DATE '1995-03-15'",
+                      "o_orderdate < DATE '1990-01-01'"),
+               "q10": ("o_orderdate >= DATE '1993-10-01'",
+                       "o_orderdate >= DATE '1999-10-01'")}
+
+
+def _spy_rungs(monkeypatch) -> list:
+    """(ladder, surviving rows) of every chain dispatch, as its finalize
+    reads them."""
+    seen = []
+    real = device_chain._finalize
+
+    def finalize(*args):
+        rungs, results = args[-1], args[6]
+        seen.append((rungs, int(np.asarray(results[0])[:, 0].sum())))
+        return real(*args)
+    monkeypatch.setattr(device_chain, "_finalize", finalize)
+    return seen
+
+
+def _rung(rungs: tuple, survivors: int) -> int:
+    """The branch the program takes: a rung's index, len(rungs) past them."""
+    return sum(survivors > b for b in rungs)
+
+
+def _fused_tick(c, sql):
+    """(rows, compacted ticks, full-scatter ticks) of one fused run."""
+    compacted = metrics.DEVICE_CHAIN_COMPACTED.value
+    full = metrics.DEVICE_CHAIN_SCATTERED_FULL.value
+    rows = _text_rows(c.execute(sql))
+    return (rows, metrics.DEVICE_CHAIN_COMPACTED.value - compacted,
+            metrics.DEVICE_CHAIN_SCATTERED_FULL.value - full)
+
+
+@pytest.mark.parametrize("branch", [0, 1, 2, 3, "none"])
+@pytest.mark.parametrize("qid", ["q3", "q10"])
+def test_many_group_ladder_branches(tpch, monkeypatch, qid, branch):
+    """Every branch of the many-group reduction answers as the host plan
+    and the reference do, and ticks one of its two counters once."""
+    from benchmark.references import tpch_numpy as ref
+    c, ds = tpch
+    sql = _statements()[qid]
+    if branch == "none":
+        sql = sql.replace(*NO_SURVIVOR[qid])
+    else:
+        monkeypatch.setattr(device_chain, "COMPACT_RUNGS", LADDERS[branch])
+    c.execute("SET serene_device = 'tpu'")
+    c.execute("SET serene_device_fused = off")
+    host = _text_rows(c.execute(sql))
+    c.execute("SET serene_device_fused = on")
+    seen = _spy_rungs(monkeypatch)
+    dev, compacted, full = _fused_tick(c, sql)
+    assert len(seen) == 1
+    rungs, survivors = seen[0]
+    assert dev == host
+    if branch == "none":
+        assert survivors == 0 and dev == []
+        assert _rung(rungs, survivors) == 0
+    else:
+        assert survivors > 4
+        assert _rung(rungs, survivors) == branch
+        want = ref.evaluate(ref.Data(ds["tables"], ds["dictionaries"]), qid)
+        ok, err = ref.compare(dev, want)
+        assert ok and err <= 1e-9, (dev[:3], want["rows"][:3])
+    assert (compacted, full) == ((0, 1) if branch == 3 else (1, 0))
+
+
+@pytest.mark.parametrize("qid", ["q5", "q9", "q12", "q14"])
+def test_few_group_statements_tick_neither_ladder_counter(tpch, qid):
+    c, _ = tpch
+    c.execute("SET serene_device = 'tpu'")
+    c.execute("SET serene_device_fused = on")
+    _rows, compacted, full = _fused_tick(c, _statements()[qid])
+    assert (compacted, full) == (0, 0)
+
+
+def test_rungs_share_one_program(tpch, monkeypatch):
+    """Q3 and Q10 land on different rungs of the default ladder, and
+    neither builds a program after its first run: every branch is built
+    with the program."""
+    c, _ = tpch
+    c.execute("SET serene_device = 'tpu'")
+    c.execute("SET serene_device_fused = on")
+    sts = _statements()
+    for qid in ("q3", "q10"):
+        c.execute(sts[qid])
+    compiles = obs_device.PROGRAMS.family("join_chain")["compiles"]
+    seen = _spy_rungs(monkeypatch)
+    for qid in ("q3", "q10", "q3"):
+        c.execute(sts[qid])
+    assert obs_device.PROGRAMS.family("join_chain")["compiles"] == compiles
+    branches = [_rung(r, n) for r, n in seen]
+    assert branches[0] != branches[1] and branches[0] == branches[2]
