@@ -348,6 +348,13 @@ class ProjectNode(PlanNode):
         return [self.child]
 
     def batches(self, ctx):
+        # rows of a key-join chain with a flattened subquery: ONE device
+        # program compacts them (exec/device_chain.py)
+        from .device_chain import try_device_chain_rows
+        out = try_device_chain_rows(self, ctx)
+        if out is not None:
+            yield out
+            return
         for b in self.child.batches(ctx):
             with stage("host_scan"):
                 cols = [e.eval(b) for e in self.exprs]
@@ -564,6 +571,9 @@ def _key_text(e: BoundExpr) -> str:
     return e.name if isinstance(e, BoundColumn) else type(e).__name__
 
 
+_SEMI_LABELS = {"semi": "SemiJoin", "anti": "AntiJoin", "mark": "MarkJoin"}
+
+
 class JoinNode(PlanNode):
     """Hash join (inner/left/right/full/cross). Equi-keys are extracted
     by the planner; residual predicates run over candidate pairs.
@@ -583,8 +593,19 @@ class JoinNode(PlanNode):
                  left_keys: list[BoundExpr], right_keys: list[BoundExpr],
                  residual: Optional[BoundExpr], names: list[str],
                  types: list[dt.SqlType],
-                 merge_pairs: Optional[list] = None):
+                 merge_pairs: Optional[list] = None,
+                 mark: Optional[tuple] = None, flattened: bool = False):
+        #: `semi` / `anti` emit each left row that has / has no partner
+        #: once, however many it has; `mark` emits every left row with
+        #: one BOOL column more, SQL's `x IN (right)` for `mark` =
+        #: (x over the left, the value over the right): NULL where no
+        #: partner equals x but x is NULL beside a non-empty set, or the
+        #: set holds a NULL. The keys of all three are the correlation's;
+        #: a semi / anti join has at least one.
         self.kind = kind
+        self.mark = mark
+        #: a subquery sql/decorrelate.py flattened (HostFlattenedJoins)
+        self.flattened = flattened
         self.left = left
         self.right = right
         self.left_keys = left_keys
@@ -660,6 +681,13 @@ class JoinNode(PlanNode):
         # arrays join them below. Charged here, released when the
         # output batch has been consumed (generator close).
         metrics.HOST_JOINS.add()
+        if self.flattened:
+            metrics.HOST_FLATTENED_JOINS.add()
+        if self.kind in ("semi", "anti", "mark"):
+            with stage("host_join"):
+                out = self._semi_batch(lb, rb, ctx)
+            yield out
+            return
         # the request's `host_join` stage: the match of keys, the pairs'
         # residual and null extension, and the gather of both sides
         with stage("host_join"):
@@ -732,6 +760,49 @@ class JoinNode(PlanNode):
                     return None
         return scan
 
+    def _semi_batch(self, lb: Batch, rb: Batch, ctx) -> Batch:
+        lkeys = [k.eval(lb) for k in self.left_keys]
+        rkeys = [k.eval(rb) for k in self.right_keys]
+        if self.kind != "mark":
+            li, ri = self._pairs(lkeys, rkeys, lb.num_rows, rb.num_rows, ctx)
+            if self.residual is not None and len(li):
+                cols = lb.take(li).columns + rb.take(ri).columns
+                pair = Batch([f"c{i}" for i in range(len(cols))], cols)
+                c = self.residual.eval(pair)
+                li = li[c.data.astype(bool) & c.valid_mask()]
+            hit = np.zeros(lb.num_rows, dtype=bool)
+            hit[li] = True
+            return lb.filter(hit if self.kind == "semi" else ~hit)
+        x, v = self.mark[0].eval(lb), self.mark[1].eval(rb)
+        n = lb.num_rows
+
+        def matched(lk, rk, rows=None) -> np.ndarray:
+            if rows is not None:
+                rk = [c.take(rows) for c in rk]
+            nr = rb.num_rows if rows is None else len(rows)
+            if not lk:
+                return np.full(n, nr > 0)
+            li, _ = self._pairs(lk, rk, n, nr, ctx)
+            hit = np.zeros(n, dtype=bool)
+            hit[li] = True
+            return hit
+        true = matched(lkeys + [x], rkeys + [v])
+        unknown = ~x.valid_mask() & matched(lkeys, rkeys)
+        vnull = np.flatnonzero(~v.valid_mask())
+        if len(vnull):
+            unknown |= matched(lkeys, rkeys, vnull)
+        valid = true | ~unknown
+        mark = Column(dt.BOOL, true, None if valid.all() else valid)
+        return Batch(list(self.names), lb.columns + [mark])
+
+    def _pairs(self, lkeys, rkeys, nl: int, nr: int, ctx):
+        from .morsel import join_pairs, vectorized_enabled
+        if vectorized_enabled(ctx.settings):
+            out = join_pairs(lkeys, rkeys, ctx.settings, nl, nr)
+            if out is not None:
+                return out
+        return self._match_legacy(lkeys, rkeys, nl, nr)
+
     def _match_inner(self, lb: Batch, rb: Batch, ctx,
                      rkey_cols=None) -> tuple[np.ndarray, np.ndarray]:
         """Candidate (inner) pairs; left-join null extension happens later."""
@@ -774,6 +845,11 @@ class JoinNode(PlanNode):
                 np.asarray(ri, dtype=np.int64))
 
     def label(self):
+        if self.kind in _SEMI_LABELS:
+            keys = ", ".join(f"{_key_text(a)} = {_key_text(b)}" for a, b in
+                             zip(self.left_keys, self.right_keys))
+            res = " residual=yes" if self.residual is not None else ""
+            return f"{_SEMI_LABELS[self.kind]} on ({keys}){res}"
         if not self.left_keys:
             return f"HashJoin {self.kind}"
         keys = ", ".join(f"{_key_text(a)} = {_key_text(b)}" for a, b in

@@ -21,6 +21,7 @@ from .. import errors
 from ..columnar import dtypes as dt
 from ..columnar.column import Column
 from ..functions import scalar as fnlib
+from ..utils import metrics
 from . import ast
 from .expr import (AggSpec, BoundAggRef, BoundCase, BoundColumn, BoundExpr,
                    BoundFunc, BoundLiteral, kleene_and, kleene_or)
@@ -625,19 +626,25 @@ class ExprBinder:
             raise errors.SqlError("42601",
                                   "subquery must return only one column")
         t = plan.types[0]
-        cache: list = []
+        # computed once, now: the outer plan holds a literal, which the
+        # device tiers read. A subquery that fails, or returns two rows,
+        # raises where a row evaluates it, as the lazy form did
+        from ..exec.plan import ExecContext
+        try:
+            rows = plan.execute(ExecContext()).rows()
+        except errors.SqlError as e:
+            if e.sqlstate == errors.QUERY_CANCELED:
+                raise
+            failure = e
+        else:
+            if len(rows) <= 1:
+                return BoundLiteral(rows[0][0] if rows else None, t)
+            failure = errors.SqlError(
+                "21000", "more than one row returned by a subquery used "
+                "as an expression")
 
-        def impl(cols, batch, _plan=plan, _t=t, _cache=cache):
-            if not _cache:
-                from ..exec.plan import ExecContext
-                rows = _plan.execute(ExecContext()).rows()
-                if len(rows) > 1:
-                    raise errors.SqlError(
-                        "21000",
-                        "more than one row returned by a subquery used as "
-                        "an expression")
-                _cache.append(rows[0][0] if rows else None)
-            return Column.const(_cache[0], batch.num_rows, _t)
+        def impl(cols, batch, _e=failure):
+            raise _e
         return BoundFunc("scalar_subquery", [], t, impl)
 
     def _bind_array_subquery(self, query) -> BoundExpr:
@@ -653,7 +660,7 @@ class ExprBinder:
             if len(trial.types) != 1:
                 raise errors.SqlError(
                     "42601", "subquery must return only one column")
-
+            metrics.SUBQUERIES_PER_ROW.add()
             plan_cache: dict = {}
 
             def impl_corr(cols, batch, _q=query, _refs=outer_refs,
@@ -679,6 +686,7 @@ class ExprBinder:
         return BoundFunc("array_subquery", [], dt.VARCHAR, impl)
 
     def _bind_correlated_scalar(self, query) -> BoundExpr:
+        metrics.SUBQUERIES_PER_ROW.add()
         outer_refs, trial = self._discover_correlation(query)
         if len(trial.types) != 1:
             raise errors.SqlError("42601",
@@ -743,6 +751,7 @@ class ExprBinder:
         return BoundFunc("in_subquery", [operand], dt.BOOL, impl)
 
     def _bind_correlated_in(self, e) -> BoundExpr:
+        metrics.SUBQUERIES_PER_ROW.add()
         outer_refs, trial = self._discover_correlation(e.query)
         if len(trial.types) != 1:
             raise errors.SqlError("42601",
@@ -772,6 +781,7 @@ class ExprBinder:
         return BoundFunc("in_subquery", [operand], dt.BOOL, impl)
 
     def _bind_correlated_exists(self, e) -> BoundExpr:
+        metrics.SUBQUERIES_PER_ROW.add()
         outer_refs, _ = self._discover_correlation(e.query)
 
         _pc: dict = {}

@@ -24,6 +24,7 @@ from ..exec.plan import (AggregateNode, DropColumnsNode, FilterNode, JoinNode,
 from ..exec.tables import TableProvider
 from . import ast
 from .binder import AGG_FUNCS, ExprBinder, Scope, ScopeColumn
+from .decorrelate import bound_and, conjoin, flatten, has_subquery
 from .expr import (BoundAggRef, BoundCase, BoundColumn, BoundExpr, BoundFunc,
                    BoundLiteral, kleene_and)
 
@@ -157,13 +158,29 @@ class Planner:
                     Batch(["__dummy"], [Column.from_pylist([0])]))
                 scope = Scope([])
             else:
+                # conjuncts that hold a subquery are planned last, as
+                # joins over the FROM list's plan where they flatten
+                conj = _split_conjuncts(sel.where) \
+                    if sel.where is not None else []
+                nested = [c for c in conj if has_subquery(c)]
+                where = conjoin([c for c in conj if not has_subquery(c)]) \
+                    if nested else sel.where
                 leaves = _from_list(sel.from_)
-                if len(leaves) > 1 and sel.where is not None:
-                    plan, scope, rest = self._plan_join_graph(leaves,
-                                                              sel.where)
-                    sel = dataclasses.replace(sel, where=rest)
+                if len(leaves) > 1 and where is not None:
+                    plan, scope, where = self._plan_join_graph(leaves,
+                                                               where)
                 else:
                     plan, scope = self._plan_from(sel.from_)
+                if nested:
+                    if where is not None:
+                        plan = self._push_filter(
+                            plan, self._binder(scope).bind(where))
+                    plan, scope, preds, left = flatten(self, plan, scope,
+                                                       nested)
+                    if preds:
+                        plan = self._push_filter(plan, bound_and(preds))
+                    where = conjoin(left)
+                sel = dataclasses.replace(sel, where=where)
             return self._plan_body(sel, plan, scope)
         finally:
             self.ctes = saved

@@ -209,6 +209,25 @@ DEVICE_JOINS_FUSED = REGISTRY.gauge(
     "per dispatch (the chain program, exec/device_chain.py, and the "
     "two-table pair-count program)")
 HOST_JOINS = REGISTRY.gauge("HostJoins", "host JoinNode executions")
+HOST_FLATTENED_JOINS = REGISTRY.gauge(
+    "HostFlattenedJoins",
+    "host JoinNode executions of a flattened subquery: the semi, anti "
+    "or mark join of an EXISTS, IN or NOT IN, or the left join of a "
+    "correlated aggregate (sql/decorrelate.py), run on the host")
+SUBQUERIES_FLATTENED = REGISTRY.gauge(
+    "SubqueriesFlattened",
+    "subquery expressions planned as a join (semi, anti, mark, or an "
+    "aggregate grouped by the correlation keys and joined back) or, "
+    "uncorrelated under a comparison, computed once into a constant")
+SUBQUERIES_PER_ROW = REGISTRY.gauge(
+    "SubqueriesPerRow",
+    "correlated subquery expressions bound to run once per distinct "
+    "outer key (the literal-substitution path)")
+DEVICE_REDUCTIONS_FUSED = REGISTRY.gauge(
+    "DeviceReductionsFused",
+    "flattened subqueries executed inside a join chain program: one per "
+    "reduction edge (a sub-chain reduced into one relation's rows) or "
+    "semi join over a key its build side is unique on, per dispatch")
 DEVICE_JOIN_BYTES = REGISTRY.gauge(
     "DeviceJoinBytes",
     "per fused join dispatch, the bytes the statement has to read "
