@@ -636,12 +636,16 @@ def _pad_to(a: np.ndarray, n: int, fill) -> np.ndarray:
 # several dispatches (searcher.topk_batch).
 #
 # Parity: a (query, document) cell receives at most one contribution per
-# term, and the steps run in sequence, each adding packed rows, then raw
-# rows, then tails. `query_chunks` never puts a query's raw rows in an
-# earlier step than its packed rows, nor its tails before its raw rows, so
-# a cell's f32 additions happen in ONE order — packed, raw, tail, each in
-# query-term order — however the batch was composed or cut: the order of
-# the single-program kernel this replaced.
+# term, and the steps run in sequence. The resident steps come first:
+# the store's resident terms (`resident_terms`) add their whole rows, in
+# slot order — the query's own term order —, and a pad slot adds
+# nothing. Then each accumulate step adds packed rows, then raw rows, then
+# tails. `query_chunks` never puts a query's raw rows in an earlier step
+# than its packed rows, nor its tails before its raw rows, so a cell's
+# f32 additions happen in ONE order — resident rows, packed rows, raw
+# rows, tails, each in query-term order — however the batch was composed
+# or cut. Which terms are resident is the store's alone, never the
+# batch's.
 
 #: the largest plane a rung may ask for, in queries
 NQ_TOP = 32
@@ -821,16 +825,27 @@ def doc_masks(masks: dict, nq: int, ndocs_pad: int) -> np.ndarray:
 
 def score_topk_planes(store: BlockStore, qb: QueryBatch, rung: Rung,
                       k: int, k1: float, b: float, avgdl: float,
-                      scorer: str, masks: Optional[dict] = None):
-    """One dispatch of a batch that fits `rung`: its accumulate steps in
+                      scorer: str, masks: Optional[dict] = None,
+                      resident: Optional[tuple] = None):
+    """One dispatch of a batch that fits `rung`: the resident steps
+    where `resident` = (the store's resident rows, a DenseStore; [(rows,
+    weights) per query]) holds any slot, then its accumulate steps in
     sequence on the donated planes, then the top-k step — under the
     queries' doc masks where `masks` ({query row: doc ids}) holds any.
-    Returns the device (vals, docs), each (rung.nq, k): rows past
-    qb.n_queries are padding."""
+    `qb` holds what is not resident. Returns the device (vals, docs),
+    each (rung.nq, k): rows past qb.n_queries are padding."""
     with_hits = bool(qb.require.any())
     planes: tuple = ()
+    if resident is not None:
+        rows, slots = resident
+        for c in range(_slot_steps(slots, 0)):
+            with stage("search_plan"):
+                tids, w = _slot_block(slots, c, rung.nq)
+            planes = _resident_program(rows, rung.nq, not planes,
+                                       with_hits)(rows.St, tids, w, *planes)
     with stage("search_plan"):
-        steps = query_chunks(qb, rung, store.n_packed, store.n_raw)
+        steps = query_chunks(qb, rung, store.n_packed, store.n_raw,
+                             min_steps=0 if planes else 1)
     for ints, floats in steps:
         prog = _accumulate_program(store, rung, with_hits, not planes,
                                    scorer)
@@ -1074,6 +1089,14 @@ def score_topk_mesh(store, qb: "QueryBatch", ndocs_pad: int, k: int,
 # its default precision is not f32). St is built ON DEVICE from the
 # already-resident block tiles (+ a one-time light-term tail upload), so
 # no dense matrix ever crosses the host↔device link.
+#
+# A store whose whole matrix does not fit keeps the rows of its DENSE
+# terms resident (`resident_terms`: the terms whose doc bitset is no
+# larger than their posting list, most frequent first, within the same
+# budget), built by the same code: the plane kernel adds those rows to
+# its planes in resident steps (`score_topk_planes`) and scatters only
+# the other terms' postings. The whole matrix is the case where every
+# term is resident.
 
 DENSE_HBM_BUDGET = int(float(os.environ.get("SDB_DENSE_HBM_MB", "1024"))
                        * (1 << 20))
@@ -1082,45 +1105,40 @@ DENSE_HBM_BUDGET = int(float(os.environ.get("SDB_DENSE_HBM_MB", "1024"))
 @dataclass
 class DenseStore:
     """Device-resident dense saturation matrix for one (segment, scorer,
-    avgdl): St[t, d] = sat(tf(t, d), dl(d)) with the idf weight factored
-    OUT (it lives in the per-query weights). St[t, d] = 0 exactly where
-    the term is absent, so a document's score is the ordered sum of its
-    query terms' rows and St[t] > 0 marks exactly the term's matches."""
+    avgdl): St[row_of[t], d] = sat(tf(t, d), dl(d)) with the idf weight
+    factored OUT (it lives in the per-query weights). A row is 0 exactly
+    where the term is absent, so a document's score is the ordered sum
+    of its query terms' rows and St[row] > 0 marks exactly the term's
+    matches. `row_of` maps a term id to its row, -1 for a term that has
+    none; None where every term has one, at its own id."""
 
     St: jax.Array       # (V_pad, ndocs_pad) f32, term-major
     ndocs_pad: int
     v_pad: int
+    row_of: Optional[np.ndarray] = None   # (T,) int32
 
 
-def _build_dense(block_base, block_gaps, block_tfs8, pk_tid,
-                 raw_docs, raw_tfs, raw_tid, light_docs, light_tfs,
-                 light_tid, norms, k1, b, avgdl, *, ndocs_pad: int,
-                 v_pad: int, scorer: str) -> jax.Array:
-    """One-time scatter of every posting (decoded from the packed planes)
-    into a dense term-major TF plane, then the scorer's saturation applied
+def _build_dense(block_base, block_gaps, block_tfs8, pk_rows, pk_tid,
+                 raw_docs, raw_tfs, raw_rows, raw_tid, light_docs,
+                 light_tfs, light_tid, norms, k1, b, avgdl, *,
+                 ndocs_pad: int, v_pad: int, scorer: str) -> jax.Array:
+    """One-time scatter of the postings of the packed rows `pk_rows`
+    (decoded from the packed planes), of the raw rows `raw_rows` and of
+    the light postings into a dense term-major TF plane, each into the
+    matrix row its `*_tid` names; then the scorer's saturation applied
     elementwise. Runs once per (segment, scorer, avgdl); per-query
     dispatches touch only the result."""
-    tf = jnp.zeros((v_pad, ndocs_pad), dtype=jnp.float32)
-    all_rows = jnp.arange(block_base.shape[0], dtype=jnp.int32)
-    pdocs, ptfs = _decode_rows(block_base, block_gaps, block_tfs8, all_rows)
-    pd = pdocs.reshape(-1)
-    pt = ptfs.reshape(-1)
-    ptid = jnp.broadcast_to(pk_tid[:, None], pdocs.shape).reshape(-1)
-    pvalid = pd >= 0
-    tf = tf.at[jnp.where(pvalid, ptid, 0),
-               jnp.where(pvalid, pd, 0)].add(
-        jnp.where(pvalid, pt.astype(jnp.float32), 0.0))
-    rd = raw_docs.reshape(-1)
-    rt = raw_tfs.reshape(-1)
-    rtid = jnp.broadcast_to(raw_tid[:, None], raw_docs.shape).reshape(-1)
-    rvalid = rd >= 0
-    tf = tf.at[jnp.where(rvalid, rtid, 0),
-               jnp.where(rvalid, rd, 0)].add(
-        jnp.where(rvalid, rt.astype(jnp.float32), 0.0))
-    lvalid = light_docs >= 0
-    tf = tf.at[jnp.where(lvalid, light_tid, 0),
-               jnp.where(lvalid, light_docs, 0)].add(
-        jnp.where(lvalid, light_tfs.astype(jnp.float32), 0.0))
+    tf = jnp.zeros((v_pad * ndocs_pad,), dtype=jnp.float32)
+    pdocs, ptfs = _decode_rows(block_base, block_gaps, block_tfs8, pk_rows)
+    for docs, tfs, tid in (
+            (pdocs, ptfs, pk_tid[:, None]),
+            (raw_docs[raw_rows], raw_tfs[raw_rows], raw_tid[:, None]),
+            (light_docs, light_tfs, light_tid)):
+        valid = docs >= 0
+        at = jnp.where(valid, tid * ndocs_pad + docs, 0)
+        tf = tf.at[at.reshape(-1)].add(
+            jnp.where(valid, tfs.astype(jnp.float32), 0.0).reshape(-1))
+    tf = tf.reshape(v_pad, ndocs_pad)
     if scorer == "tfidf":
         return jnp.sqrt(tf)
     alpha = k1 * (1.0 - b + b * norms[:ndocs_pad].astype(jnp.float32) /
@@ -1135,46 +1153,73 @@ def dense_fits(ndocs_pad: int, vocab: int) -> bool:
     return ndocs_pad * _bucket(vocab, 128, 128) * 4 <= DENSE_HBM_BUDGET
 
 
+def resident_terms(doc_freq: np.ndarray, dense: np.ndarray,
+                   ndocs_pad: int) -> np.ndarray:
+    """The term ids whose rows a store keeps resident: its dense terms
+    (`dense`, a bool per term), most frequent first, as many as a
+    (rows, ndocs_pad) f32 matrix holds within DENSE_HBM_BUDGET."""
+    tids = np.flatnonzero(dense)
+    tids = tids[np.argsort(-doc_freq[tids].astype(np.int64), kind="stable")]
+    n = min(len(tids), DENSE_HBM_BUDGET // (4 * ndocs_pad))
+    while n and _bucket(n, 8, 8) * ndocs_pad * 4 > DENSE_HBM_BUDGET:
+        n -= 1
+    return tids[:n]
+
+
 def build_dense_store(store: BlockStore, doc_freq: np.ndarray,
-                      avgdl: float, k1: float, b: float,
-                      scorer: str) -> DenseStore:
+                      avgdl: float, k1: float, b: float, scorer: str,
+                      terms: Optional[np.ndarray] = None) -> DenseStore:
+    """The saturation rows of `terms` (every term where None), in that
+    order, built on the device from the resident tiles."""
     T = len(doc_freq)
-    v_pad = _bucket(T, 128, 128)
     nd_pad = store.ndocs_pad
-    # heavy terms: already device-resident as block tiles; ship only the
-    # per-row term id. Light terms: one-time flat upload (df < HEAVY_DF
-    # each, so the tail is small).
+    if terms is None:
+        v_pad, row_of = _bucket(T, 128, 128), None
+        term_row = np.arange(T, dtype=np.int32)
+    else:
+        v_pad = _bucket(len(terms), 8, 8)
+        term_row = np.full(T, -1, dtype=np.int32)
+        term_row[terms] = np.arange(len(terms), dtype=np.int32)
+        row_of = term_row
+    # heavy terms: already device-resident as block tiles; ship the
+    # plane slots of the held terms' rows and each one's matrix row.
+    # Light terms: one-time flat upload (df < HEAVY_DF each, so the
+    # tail is small).
+    nb_total = store.pad_row
     rows_per_term = np.diff(store.block_offsets).astype(np.int64)
-    row_tid = np.zeros(len(store.row_plane), dtype=np.int32)
-    row_tid[:int(rows_per_term.sum())] = np.repeat(
-        np.arange(T, dtype=np.int32), rows_per_term)
-    # split the global row→term map by plane (the planes' extra pad rows
-    # keep tid 0 — their postings decode as invalid and never scatter)
-    pk_tid = np.zeros(store.block_base.shape[0], dtype=np.int32)
-    raw_tid = np.zeros(store.raw_docs.shape[0], dtype=np.int32)
-    packed_rows = store.row_plane == 0
-    pk_tid[store.row_slot[packed_rows]] = row_tid[packed_rows]
-    raw_tid[store.row_slot[~packed_rows]] = row_tid[~packed_rows]
+    row_tid = term_row[np.repeat(np.arange(T), rows_per_term)]
+    held = row_tid >= 0
+    packed = store.row_plane[:nb_total] == 0
+    slot = store.row_slot[:nb_total]
+    pk, rw = held & packed, held & ~packed
+    pk_rows, pk_tid = slot[pk], row_tid[pk]
+    raw_rows, raw_tid = slot[rw], row_tid[rw]
+    # the planes' pad slots decode as invalid and never scatter
+    n_pk = _bucket(len(pk_rows) + 1, 1)
+    n_rw = _bucket(len(raw_rows) + 1, 1)
     # light terms: one boolean mask over the flat postings (vectorized —
     # vocab can reach ~260k at the budget boundary)
     df = np.diff(store.offsets).astype(np.int64)
     post_tid = np.repeat(np.arange(T, dtype=np.int32), df)
-    light_mask = ~store.heavy[post_tid]
+    light_mask = ~store.heavy[post_tid] & (term_row[post_tid] >= 0)
     light_docs = store.flat_docs[light_mask].astype(np.int32)
     light_tfs = store.flat_tfs[light_mask].astype(np.int32)
-    light_tid = post_tid[light_mask]
+    light_tid = term_row[post_tid[light_mask]]
     n_pad = _pow2(len(light_docs), BLOCK)
     build = obs_device.compiled(
         "dense_build",
-        (len(pk_tid), len(raw_tid), n_pad, nd_pad, v_pad, scorer),
+        (store.block_base.shape[0], store.raw_docs.shape[0], n_pk, n_rw,
+         n_pad, nd_pad, v_pad, scorer),
         lambda: functools.partial(_build_dense, ndocs_pad=nd_pad,
                                   v_pad=v_pad, scorer=scorer))
     St = build(
         store.block_base, store.block_gaps, store.block_tfs8,
-        pk_tid, store.raw_docs, store.raw_tfs, raw_tid,
+        _pad_to(pk_rows, n_pk, store.n_packed), _pad_to(pk_tid, n_pk, 0),
+        store.raw_docs, store.raw_tfs,
+        _pad_to(raw_rows, n_rw, store.n_raw), _pad_to(raw_tid, n_rw, 0),
         _pad_to(light_docs, n_pad, -1), _pad_to(light_tfs, n_pad, 0),
         _pad_to(light_tid, n_pad, 0), store.norms, k1, b, avgdl)
-    return DenseStore(St=St, ndocs_pad=nd_pad, v_pad=v_pad)
+    return DenseStore(St=St, ndocs_pad=nd_pad, v_pad=v_pad, row_of=row_of)
 
 
 def dense_body(nq: int, first: bool, last: bool, k: int,
@@ -1234,6 +1279,24 @@ def dense_program_keys(rungs: tuple[Rung, ...]) -> list[tuple]:
             for first in (True, False) for last in (False, True, MASKED)]
 
 
+def _slot_steps(slots: list, floor: int) -> int:
+    """Steps of DENSE_SLOTS that hold the longest query's slots."""
+    return max(floor, -(-max((len(t) for t, _ in slots), default=0)
+                        // DENSE_SLOTS))
+
+
+def _slot_block(slots: list, c: int, nq: int) -> tuple:
+    """Step c's (nq, DENSE_SLOTS) term rows and weights; pad slots carry
+    row 0 and weight 0."""
+    tids = np.zeros((nq, DENSE_SLOTS), dtype=np.int32)
+    w = np.zeros((nq, DENSE_SLOTS), dtype=np.float32)
+    part = slice(c * DENSE_SLOTS, (c + 1) * DENSE_SLOTS)
+    for qi, (t, wq) in enumerate(slots):
+        tids[qi, :len(t[part])] = t[part]
+        w[qi, :len(t[part])] = wq[part]
+    return tids, w
+
+
 def dense_score_topk(ds: DenseStore, slots: list[tuple[np.ndarray,
                                                        np.ndarray]],
                      require: np.ndarray, nq: int, k: int,
@@ -1243,20 +1306,14 @@ def dense_score_topk(ds: DenseStore, slots: list[tuple[np.ndarray,
     queries' doc masks where `masks` ({query row: doc ids}) holds any.
     Returns the device (vals, docs), each (nq, k): rows past len(slots)
     are padding."""
-    n_steps = max(1, -(-max((len(t) for t, _ in slots), default=0)
-                       // DENSE_SLOTS))
+    n_steps = _slot_steps(slots, 1)
     with stage("search_plan"):
         require = _pad_to(require, nq, 0)
     out: tuple = ()
     for c in range(n_steps):
         last = c == n_steps - 1
         with stage("search_plan"):
-            tids = np.zeros((nq, DENSE_SLOTS), dtype=np.int32)
-            w = np.zeros((nq, DENSE_SLOTS), dtype=np.float32)
-            for qi, (t, wq) in enumerate(slots):
-                part = slice(c * DENSE_SLOTS, (c + 1) * DENSE_SLOTS)
-                tids[qi, :len(t[part])] = t[part]
-                w[qi, :len(t[part])] = wq[part]
+            tids, w = _slot_block(slots, c, nq)
             if last and masks:
                 out = (doc_masks(masks, nq, ds.ndocs_pad),) + out
                 last = MASKED
@@ -1306,6 +1363,100 @@ def dense_slots(queries: list[tuple[np.ndarray, int]], n_docs: int,
             idf = idf_for(scorer, n_docs, doc_freq[tid_arr])
         slots.append((tid_arr.astype(np.int32), idf))
     return slots, np.asarray([req for _, req in queries], dtype=np.int32)
+
+
+# --------------------------------------------------- resident rows, planes
+
+
+def resident_body(nq: int, first: bool, with_hits: bool):
+    """The traced body of one resident step of the plane kernel: for
+    each query row q, scores[q, d] += w[q, j] · St[tids[q, j], d] over
+    its slots j in slot order — the query's own term order —, counting
+    the slots that hit (`dense_body`'s sum and count, cell for cell),
+    on the flat (nq * ndocs_pad,) planes the accumulate steps add to:
+    made here when `first`, else the donated ones handed in (the hits
+    plane only `with_hits`, as theirs). A query's slots stop at its
+    last non-zero weight: the pad slots after it, which `dense_body`
+    adds as exact zeros, are not read at all. Each slot is one row read
+    and one row of the plane updated in place; a vector of rows
+    gathered at once (`dense_body`'s `St[tids[:, j]]`) compiles for a
+    v5e, past one query, into chunked copies of the whole matrix."""
+    def step(St, tids, w, *planes):
+        nd = St.shape[1]
+        if first:
+            planes = (jnp.zeros((nq * nd,), dtype=jnp.float32),) + \
+                ((jnp.zeros((nq * nd,), dtype=jnp.int32),)
+                 if with_hits else ())
+        used = jnp.max(jnp.where(w != 0, jnp.arange(1, w.shape[1] + 1), 0),
+                       axis=1)
+
+        def add_query(q, planes):
+            def add_slot(j, planes):
+                row = jax.lax.dynamic_index_in_dim(St, tids[q, j], 0, False)
+                wj = w[q, j]
+                at = q * nd
+                scores = planes[0]
+                cur = jax.lax.dynamic_slice_in_dim(scores, at, nd)
+                out = (jax.lax.dynamic_update_slice_in_dim(
+                    scores, cur + row * wj, at, 0),)
+                if with_hits:
+                    hits = planes[1]
+                    hit = jnp.logical_and(row > 0, wj > 0).astype(jnp.int32)
+                    out += (jax.lax.dynamic_update_slice_in_dim(
+                        hits, jax.lax.dynamic_slice_in_dim(hits, at, nd) + hit,
+                        at, 0),)
+                return out
+
+            return jax.lax.fori_loop(0, used[q], add_slot, planes)
+
+        return jax.lax.fori_loop(0, nq, add_query, tuple(planes))
+
+    return step
+
+
+def _resident_program(rs: DenseStore, nq: int, first: bool,
+                      with_hits: bool):
+    return obs_device.compiled(
+        "bm25_resident", (rs.v_pad, rs.ndocs_pad, nq, first, with_hits),
+        lambda: resident_body(nq, first, with_hits),
+        donate_argnums=() if first else tuple(range(3, 5 if with_hits
+                                                    else 4)))
+
+
+def resident_program_keys(rungs: tuple[Rung, ...]) -> list[tuple]:
+    """Every resident step a batch that fits one of `rungs` can
+    dispatch: (queries, with_hits, first)."""
+    return [(rung.nq, with_hits, first)
+            for rung in rungs
+            for with_hits in (False, True) for first in (True, False)]
+
+
+def prebuild_resident_programs(rs: DenseStore,
+                               rungs: tuple[Rung, ...]) -> int:
+    """Build every program of `resident_program_keys` by running it once
+    on an all-padding step — first step, then the next on the planes it
+    gave. The (rung, conjunction) pairs compile side by side. Returns
+    how many programs this call built."""
+    from concurrent.futures import ThreadPoolExecutor
+    pairs: dict = {}
+    for nq, with_hits, first in resident_program_keys(rungs):
+        pairs.setdefault((nq, with_hits), {})[first] = _resident_program(
+            rs, nq, first, with_hits)
+    todo = [(nq, pair) for (nq, _h), pair in pairs.items()
+            if not all(p.called for p in pair.values())]
+
+    def run(item):
+        nq, pair = item
+        tids = np.zeros((nq, DENSE_SLOTS), dtype=np.int32)
+        w = np.zeros((nq, DENSE_SLOTS), dtype=np.float32)
+        jax.block_until_ready(
+            pair[False](rs.St, tids, w, *pair[True](rs.St, tids, w)))
+
+    built = sum(not p.called for _, pair in todo for p in pair.values())
+    if todo:
+        with ThreadPoolExecutor(max_workers=len(todo)) as pool:
+            list(pool.map(run, todo))
+    return built
 
 
 def pad_k(k: int) -> int:

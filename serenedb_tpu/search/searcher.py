@@ -179,25 +179,51 @@ class SegmentSearcher:
         return self._dev
 
     def _dense_store(self, scorer: str,
-                     avgdl: float) -> bm25_ops.DenseStore:
-        """Dense saturation matrix for the small-corpus dense path,
-        cached per (scorer shape, avgdl) — segments are immutable, and
-        avgdl only drifts when collection stats change."""
+                     avgdl: float) -> Optional[bm25_ops.DenseStore]:
+        """Dense saturation matrix, cached per (scorer shape, avgdl) —
+        segments are immutable, and avgdl only drifts when collection
+        stats change: every term's rows for the small-corpus dense path
+        (`_use_dense`), else the resident rows of the dense terms
+        (`bm25_ops.resident_terms`) that the plane kernel adds in
+        place of their postings — None where no dense term fits."""
         cache = getattr(self, "_dense_cache", None)
         if cache is None:
             cache = self._dense_cache = {}
+        store = self._device_store()
+        whole = self._use_dense(store, scorer, avgdl)
         # tfidf's St (sqrt tf) is avgdl-independent — don't rebuild it when
         # collection stats drift
-        key = ("tfidf",) if scorer == "tfidf" \
-            else ("bm25", round(avgdl, 6))
-        hit = cache.get(key)
-        if hit is None:
+        key = (whole, "tfidf") if scorer == "tfidf" \
+            else (whole, "bm25", round(avgdl, 6))
+        if key not in cache:
             if len(cache) >= 2:   # St is the dominant HBM tenant — keep ≤2
                 cache.clear()
-            hit = cache[key] = bm25_ops.build_dense_store(
-                self._device_store(), self.index.doc_freq, avgdl, K1, B,
-                scorer)
-        return hit
+            terms = None
+            if not whole:
+                every = np.arange(len(self.index.doc_freq))
+                terms = bm25_ops.resident_terms(
+                    self.index.doc_freq, self._dense_terms(every),
+                    store.ndocs_pad)
+            hit = None
+            if terms is None or len(terms):
+                hit = bm25_ops.build_dense_store(
+                    store, self.index.doc_freq, avgdl, K1, B, scorer, terms)
+                metrics.SEARCH_RESIDENT_ROW_BYTES.add(hit.St.nbytes)
+                weakref.finalize(hit, metrics.SEARCH_RESIDENT_ROW_BYTES.sub,
+                                 hit.St.nbytes)
+            cache[key] = hit
+        return cache[key]
+
+    def _resident_rows(self, scorer: str,
+                       avgdl: float) -> Optional[bm25_ops.DenseStore]:
+        """The resident rows the plane kernel adds for this store, or
+        None where it adds none: a scorer that is not `w · sat` (the LM
+        family), no avgdl to saturate with, or no dense term within the
+        budget."""
+        if scorer not in ("bm25", "tfidf") or \
+                (scorer == "bm25" and avgdl <= 0.0):
+            return None
+        return self._dense_store(scorer, avgdl)
 
     # -- filter evaluation (CPU doc-set algebra) --------------------------
 
@@ -733,6 +759,10 @@ class SegmentSearcher:
             else:
                 built = bm25_ops.prebuild_plane_programs(
                     store, self._rungs(store), kk, scorer)
+                rows = self._resident_rows(scorer, avgdl)
+                if rows is not None:
+                    built += bm25_ops.prebuild_resident_programs(
+                        rows, self._rungs(store))
         metrics.SEARCH_PROGRAMS_PREBUILT.add(built)
         return built
 
@@ -762,10 +792,11 @@ class SegmentSearcher:
            essential terms (MaxScore) leave at most MAXSCORE_CAND_CAP
            candidates, or a phrase whose match set is no larger, is
            scored by `_cpu_score` and takes no slot of the dispatch;
-        4. plane: everything else accumulates WAND-kept posting rows
-           into the score plane and takes its top-k
-           (`score_topk_planes`), both programs of the set `prebuild`
-           built.
+        4. plane: everything else adds the store's resident rows of its
+           dense terms (bm25 and tfidf: `_resident_rows`) and
+           accumulates the other terms' WAND-kept posting rows into the
+           score plane, then takes its top-k (`score_topk_planes`), all
+           programs of the set `prebuild` built.
         A conjunction of terms rides rungs 2 and 4 in their `require`
         form (the hits plane). A phrase is a conjunction with a narrower
         match set, joined once per request (`_phrase_docs`), and the set
@@ -858,22 +889,39 @@ class SegmentSearcher:
             live = any(len(q[0]) > 0 for q in queries)
             for qi, q in enumerate(queries):
                 tiers[qi] = "device" if len(q[0]) > 0 else "host"
+            resident = None
             if use_dense:
                 slots, require = bm25_ops.dense_slots(
                     queries, self.num_docs, self.index.doc_freq, scorer,
                     idf_of)
-                n_postings = sum(int(self.index.doc_freq[t].sum())
-                                 for t, _ in slots)
+                n_postings = n_resident = sum(
+                    int(self.index.doc_freq[t].sum()) for t, _ in slots)
             elif live:
                 if use_mesh:
                     # the mesh programs are jitted per n_queries: pad the
                     # query axis to a power of two with no-op empties
                     queries += [(np.empty(0, dtype=np.int64), 0)] * (
                         bm25_ops._pow2(len(queries), 1) - len(queries))
+                rows = None if use_mesh else \
+                    self._resident_rows(scorer, avgdl)
+                n_resident = 0
+                if rows is not None:
+                    # the resident terms' rows are added whole; only the
+                    # other terms' postings go to the accumulate steps
+                    held = [rows.row_of[t] >= 0 for t, _ in queries]
+                    slots, _ = bm25_ops.dense_slots(
+                        [(t[h], r) for (t, r), h in zip(queries, held)],
+                        self.num_docs, self.index.doc_freq, scorer, idf_of)
+                    resident = (rows, [(rows.row_of[t], w)
+                                       for t, w in slots])
+                    n_resident = self._resident_postings(
+                        store, slots, queries, plans)
+                    queries = [(t[~h], r)
+                               for (t, r), h in zip(queries, held)]
                 qb = bm25_ops.assemble_query_batch(
                     store, self.num_docs, queries, self.index.doc_freq,
                     scorer, idf_of=idf_of, plans=plans)
-                n_postings = qb.n_postings
+                n_postings = qb.n_postings + n_resident
         if live:
             t_d = time.perf_counter_ns()
             # `device_prepare` wraps the dispatch's calls, as it wraps a
@@ -903,11 +951,12 @@ class SegmentSearcher:
                     out = bm25_ops.score_topk_planes(
                         store, qb, bm25_ops.rung_for(rungs, len(queries)),
                         kk, bm25_ops.scorer_param(scorer, K1), B, avgdl,
-                        scorer, masks)
+                        scorer, masks, resident)
             vals, docs = obs_device.fetch_all(out)
             metrics.DEVICE_DISPATCH_HIST.observe_ns(
                 time.perf_counter_ns() - t_d)
             metrics.SEARCH_POSTINGS_DISPATCHED.add(n_postings)
+            metrics.SEARCH_POSTINGS_RESIDENT.add(n_resident)
         else:  # every query resolved host-side — skip the dispatch entirely
             vals = np.zeros((len(queries), kk), dtype=np.float32)
             docs = np.zeros((len(queries), kk), dtype=np.int32)
@@ -915,6 +964,19 @@ class SegmentSearcher:
             return self._finish_batch(nodes, shapes, vals, docs,
                                       host_results, k, scorer, idf_of,
                                       avgdl_override, nd_pad, tiers)
+
+    def _resident_postings(self, store, slots, queries, plans) -> int:
+        """The postings the resident terms' rows stand for, counted as
+        `assemble_query_batch` counts the rows it hands over: a pruned
+        disjunction's kept rows, else the whole list."""
+        n = 0
+        for qi, (t, _w) in enumerate(slots):
+            plan = plans[qi] if queries[qi][1] == 0 else None
+            for tid in t.tolist():
+                n += int(store.row_count[plan.kept[tid]].sum()) \
+                    if plan is not None and store.heavy[tid] \
+                    else int(self.index.doc_freq[tid])
+        return n
 
     def _finish_batch(self, nodes, shapes, vals, docs, host_results, k,
                       scorer, idf_of, avgdl_override, nd_pad, tiers,

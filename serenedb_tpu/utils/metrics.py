@@ -437,6 +437,12 @@ SEARCH_POSTINGS_DISPATCHED = REGISTRY.gauge(
     "valid (non-padding) postings in the block rows and light-term "
     "tails handed to device scoring programs after pruning; the dense "
     "path counts the document frequencies of the rows it gathers")
+SEARCH_POSTINGS_RESIDENT = REGISTRY.gauge(
+    "SearchPostingsResident",
+    "the postings, within SearchPostingsDispatched, whose term's whole "
+    "saturation row was added to the score plane in place of a "
+    "scatter: a resident row of the plane kernel, or a row of the "
+    "dense path's matrix")
 SEARCH_PROGRAMS_PREBUILT = REGISTRY.gauge(
     "SearchProgramsPrebuilt",
     "scoring programs built by an index build or refresh before the "
@@ -472,6 +478,12 @@ SEARCH_POSTING_LENGTH_BYTES = REGISTRY.gauge(
     "posting stores (BlockStore.block_dls + raw_dls: what the BM25 "
     "accumulate step reads by row in place of a gather from the norms "
     "table); falls when a segment's store is released")
+SEARCH_RESIDENT_ROW_BYTES = REGISTRY.gauge(
+    "SearchResidentRowBytes",
+    "HBM bytes of the saturation rows held resident (ops/bm25.py "
+    "DenseStore: a store's dense terms for the plane kernel, or every "
+    "term for the dense path; at most DENSE_HBM_BUDGET a matrix); falls "
+    "when a matrix is released")
 VECTOR_SEARCH_QUERIES = REGISTRY.gauge(
     "VectorSearchQueries",
     "knn / MaxSim queries scored by the vector subsystem "

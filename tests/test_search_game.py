@@ -82,12 +82,20 @@ def _served(ds, budget=None):
     return EsApi(db), mp
 
 
-@pytest.fixture(scope="module", params=["dense", "plane"])
+#: a dense budget of 64 rows of the corpus's 2,048 padded articles: the
+#: whole matrix does not fit, the 64 most frequent dense terms' rows do
+RESIDENT_BUDGET = 64 * 2048 * 4
+
+
+@pytest.fixture(scope="module", params=["dense", "plane", "resident"])
 def served(request, corpus):
     """(EsApi over the loaded articles, the regime): the small corpus's
-    dense steps, or (dense budget 0 BEFORE the index is built) the plane
-    kernel the full-size cell runs."""
-    es, mp = _served(corpus[1], 0 if request.param == "plane" else None)
+    dense steps; (dense budget 0 BEFORE the index is built) the plane
+    kernel alone; or, as the full-size cell runs, the plane kernel with
+    the resident rows of the store's most frequent terms."""
+    es, mp = _served(corpus[1], {"dense": None, "plane": 0,
+                                 "resident": RESIDENT_BUDGET}[
+                                     request.param])
     yield es, request.param
     mp.undo()
 
@@ -189,6 +197,30 @@ def test_every_key_answers_as_the_reference(corpus, served, shape, cmd):
                                       "bf16")[2] > 1e-5 or \
                 not answer["hits"]
     assert worst <= 1e-5
+
+
+def test_a_union_adds_the_rows_its_regime_holds(corpus, served,
+                                                monkeypatch):
+    """The plane kernel of the `resident` regime adds its store's dense
+    terms' rows whole (`SearchPostingsResident` moves, and stays within
+    `SearchPostingsDispatched`); the plane kernel alone adds none; the
+    dense steps add every term's row."""
+    _cfg, ds, _game = corpus
+    es, regime = served
+    # no candidate list is short enough for the host rung
+    monkeypatch.setattr(SegmentSearcher, "MAXSCORE_CAND_CAP", 0)
+    at = int(ds["bounds"][7])
+    words = " ".join(ds["words"][t] for t in ds["toks"][at:at + 6])
+    r0 = metrics.SEARCH_POSTINGS_RESIDENT.value
+    d0 = metrics.SEARCH_POSTINGS_DISPATCHED.value
+    resp = es.search("wiki", {"query": {"match": {"body": words}},
+                              "size": 10, "track_total_hits": False})
+    assert resp["hits"]["hits"]
+    held = metrics.SEARCH_POSTINGS_RESIDENT.value - r0
+    sent = metrics.SEARCH_POSTINGS_DISPATCHED.value - d0
+    assert sent > 0
+    assert held == {"dense": sent, "plane": 0}.get(regime, held)
+    assert regime != "resident" or 0 < held < sent
 
 
 def test_the_adjacency_control_fails_the_totals(corpus):

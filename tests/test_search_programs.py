@@ -104,8 +104,8 @@ def _ledger(*fields, since=None):
 
 
 #: the families a search can dispatch
-SEARCH_FAMILIES = ("bm25_accumulate", "bm25_topk", "dense_topk",
-                   "dense_build")
+SEARCH_FAMILIES = ("bm25_accumulate", "bm25_topk", "bm25_resident",
+                   "dense_topk", "dense_build")
 
 
 class _Builds:
@@ -805,3 +805,273 @@ def test_every_rung_and_form_calls_prebuilt_programs_on_arrays_only(
     assert handed and all(isinstance(x, jax.Array) for x in handed)
     serial = [ms.topk_batch([q], 10)[0] for q in nodes]
     assert _bits(got) == _bits(serial)
+
+
+# -- a store's dense terms as resident rows of the plane kernel ------------------
+
+#: a dense budget of 24 rows of the corpus's 3,072 padded documents: the
+#: whole matrix (512 rows) does not fit, 24 of its dense terms' rows do
+RESIDENT_ROWS = 24
+ND_PAD = 3072
+
+
+@pytest.fixture(scope="module")
+def resident():
+    """A single-segment searcher on the plane kernel whose 24 most
+    frequent dense terms are resident rows (the budget set BEFORE the
+    prebuild), with the fragment cache out of the way."""
+    mp = pytest.MonkeyPatch()
+    mp.setattr(bm25_ops, "DENSE_HBM_BUDGET", RESIDENT_ROWS * ND_PAD * 4)
+    prior = SETTINGS.get_global("serene_result_cache")
+    SETTINGS.set_global("serene_result_cache", False)
+    an = get_analyzer("simple")
+    docs = _corpus()
+    seg = SegmentSearcher(build_field_index(docs, an), an, len(docs))
+    ms = MultiSearcher(an)
+    ms.add_segment(seg, 0)
+    before = _ledger("compiles", "storms")
+    built = ms.prebuild()
+    yield ms, seg, (built, _ledger("compiles", "storms", since=before))
+    SETTINGS.set_global("serene_result_cache", prior)
+    mp.undo()
+
+
+def _rows_of(seg):
+    store = seg._device_store()
+    return store, seg._resident_rows("bm25", seg.index.avgdl)
+
+
+def _many_resident(seg, n):
+    """A disjunction of the `n` most frequent terms and three rare ones:
+    more resident terms than one resident step's DENSE_SLOTS."""
+    order = np.argsort(-seg.index.doc_freq, kind="stable")
+    terms = [str(seg.index.terms_str[t]) for t in order[:n]]
+    return QOr([QTerm(t) for t in terms + ["w390", "w377", "w301"]])
+
+
+def _same_topk(got, want):
+    """Scores within f32 of the host's float64; an id that differs only
+    where the scores tie."""
+    (scores, docs), (ws, wd) = got, want
+    assert len(scores) == len(ws)
+    np.testing.assert_allclose(scores, ws, rtol=2e-5)
+    wd = wd.tolist()
+    for a, c in zip(docs.tolist(), wd):
+        assert a == c or (a in wd and np.isclose(
+            ws[wd.index(a)], scores[docs.tolist().index(a)], rtol=2e-5))
+
+
+def test_resident_rows_are_the_most_frequent_dense_terms(resident):
+    """The rows held are dense terms (`_dense_terms`: the count bitsets'
+    rule), the most frequent first, as many as the budget holds; each
+    row is the term's saturation at its documents and 0 elsewhere."""
+    _ms, seg, _ = resident
+    store, rows = _rows_of(seg)
+    every = np.arange(len(seg.index.doc_freq))
+    dense = seg._dense_terms(every)
+    held = every[rows.row_of >= 0]
+    assert store.ndocs_pad == ND_PAD and not seg._use_dense(
+        store, "bm25", seg.index.avgdl)
+    assert len(held) == RESIDENT_ROWS < dense.sum()
+    assert dense[held].all()
+    df = seg.index.doc_freq
+    assert df[held].min() >= df[dense & (rows.row_of < 0)].max()
+    assert rows.St.shape == (RESIDENT_ROWS, ND_PAD)
+    St = np.asarray(rows.St)
+    for t in held[::5]:
+        docs, tfs = seg.index.postings(int(t))
+        want = bm25_ops._sat_exact(tfs, seg.index.norms[docs], 1.2, 0.75,
+                                   seg.index.avgdl, "bm25")
+        row = St[rows.row_of[t]]
+        np.testing.assert_allclose(row[docs], want, rtol=2e-6)
+        assert np.count_nonzero(row) == len(docs)
+
+
+@pytest.mark.parametrize("budget_rows", [0, 7.9, 8, 30.5, 24, 200])
+def test_resident_rows_stay_in_the_budget_and_are_counted(budget_rows,
+                                                          monkeypatch):
+    """Whatever the budget, the matrix holds no more bytes than it (its
+    rows in a sixteenth of their octave, a multiple of 8), the gauge
+    rises by its bytes while it is held and falls when it goes; under
+    8 rows no term is resident and nothing is built."""
+    import gc
+    budget = int(budget_rows * ND_PAD * 4)
+    monkeypatch.setattr(bm25_ops, "DENSE_HBM_BUDGET", budget)
+    an = get_analyzer("simple")
+    docs = _corpus()
+    seg = SegmentSearcher(build_field_index(docs, an), an, len(docs))
+    g0 = metrics.SEARCH_RESIDENT_ROW_BYTES.value
+    store, rows = _rows_of(seg)
+    if budget_rows < 8:
+        assert rows is None
+        assert metrics.SEARCH_RESIDENT_ROW_BYTES.value == g0
+        return
+    n = int((rows.row_of >= 0).sum())
+    assert rows.v_pad % 8 == 0 and n <= rows.v_pad
+    assert rows.St.nbytes == rows.v_pad * ND_PAD * 4 <= budget
+    assert metrics.SEARCH_RESIDENT_ROW_BYTES.value - g0 == rows.St.nbytes
+    assert metrics.REGISTRY.snapshot()["SearchResidentRowBytes"] == \
+        g0 + rows.St.nbytes                         # what /metrics renders
+    del rows, seg
+    gc.collect()
+    assert metrics.SEARCH_RESIDENT_ROW_BYTES.value == g0
+
+
+@pytest.mark.parametrize("form", ["or", "and", "masked", "steps", "many"])
+@pytest.mark.parametrize("n_queries", [1, 5, 20, 70])   # rungs 1, 8, 32; split
+def test_resident_plane_answers_as_the_host(resident, form, n_queries,
+                                            monkeypatch):
+    """On a store with dense and sparse terms the plane rung's top-10 is
+    the host's: `cpu_topk_wand` for unions and conjunctions, `_cpu_score`
+    over a phrase's match set for a phrase under a doc mask — in batches
+    of every rung and past the largest, cut into many accumulate steps
+    (`steps`), and over more resident terms than one resident step
+    holds (`many`)."""
+    from serenedb_tpu.search.query import QPhrase
+    ms, seg, _ = resident
+    # no candidate list is short enough for the host rung
+    monkeypatch.setattr(SegmentSearcher, "MAXSCORE_CAND_CAP", 0)
+    rng = np.random.default_rng(n_queries)
+    if form == "masked":
+        nodes = [QPhrase(d.split()[:2])
+                 for d in _corpus()[200:200 + n_queries]]
+    elif form == "and":
+        nodes = [QAnd([QTerm(f"w{t}")
+                       for t in rng.choice(30, 3, replace=False)])
+                 for _ in range(n_queries)]
+    elif form == "many":
+        nodes = [_many_resident(seg, int(n)) for n in
+                 rng.integers(RESIDENT_ROWS - 6, RESIDENT_ROWS + 1,
+                              n_queries)]
+    else:
+        nodes = _questions(60 + n_queries, n_queries)
+    # the first question holds a resident term in every form
+    nodes[0] = {"or": QOr([QTerm("w1"), QTerm("w350")]),
+                "steps": QOr([QTerm("w1"), QTerm("w350")]),
+                "and": QAnd([QTerm("w1"), QTerm("w7")])}.get(form, nodes[0])
+    if form == "steps":
+        monkeypatch.setattr(
+            bm25_ops, "score_rungs",
+            lambda nd, cap, acc: (bm25_ops.Rung(32, 3, 1, 5),))
+    r0 = metrics.SEARCH_POSTINGS_RESIDENT.value
+    got = seg.topk_batch(nodes, 10)
+    assert metrics.SEARCH_POSTINGS_RESIDENT.value > r0
+    checked = 0
+    for q, res in zip(nodes, got):
+        tids, req, _mask, empty = seg._query_shape(q)
+        if empty or not tids:
+            assert len(res[0]) == 0
+            continue
+        if form == "masked":
+            want = seg._cpu_score(seg._phrase_docs(q), tids, 10)
+            want = (want[0][want[0] > 0], want[1][want[0] > 0])
+        else:
+            want = seg.cpu_topk_wand(tids, 10, require_all=req)
+        _same_topk(res, want)
+        checked += len(want[0]) > 0
+    assert checked >= n_queries // 2
+
+
+def test_resident_path_is_taken_and_a_store_without_dense_terms_takes_none(
+        resident, monkeypatch):
+    """`SearchPostingsResident` moves on a store with resident rows, by
+    no more than `SearchPostingsDispatched`; a store none of whose terms
+    is dense (each in under 1/32 of its documents) adds none and answers
+    as the host."""
+    ms, seg, _ = resident
+    monkeypatch.setattr(SegmentSearcher, "MAXSCORE_CAND_CAP", 0)
+    r0 = metrics.SEARCH_POSTINGS_RESIDENT.value
+    d0 = metrics.SEARCH_POSTINGS_DISPATCHED.value
+    seg.topk_batch(_questions(5, 8), 10)
+    held = metrics.SEARCH_POSTINGS_RESIDENT.value - r0
+    assert 0 < held < metrics.SEARCH_POSTINGS_DISPATCHED.value - d0
+    rng = np.random.default_rng(2)
+    docs = [" ".join(f"u{t}" for t in rng.choice(20000, 12))
+            for _ in range(3000)]
+    an = get_analyzer("simple")
+    sparse = SegmentSearcher(build_field_index(docs, an), an, len(docs))
+    assert sparse.prebuild() >= 0
+    assert not sparse._dense_terms(
+        np.arange(len(sparse.index.doc_freq))).any()
+    assert sparse._resident_rows("bm25", sparse.index.avgdl) is None
+    nodes = [QOr([QTerm(w) for w in d.split()[:4]]) for d in docs[:9]]
+    r0 = metrics.SEARCH_POSTINGS_RESIDENT.value
+    d0 = metrics.SEARCH_POSTINGS_DISPATCHED.value
+    got = sparse.topk_batch(nodes, 10)
+    assert metrics.SEARCH_POSTINGS_RESIDENT.value == r0
+    assert metrics.SEARCH_POSTINGS_DISPATCHED.value > d0
+    for q, res in zip(nodes, got):
+        _same_topk(res, sparse.cpu_topk_wand(sparse._query_shape(q)[0], 10))
+
+
+def test_resident_rows_add_in_one_order_whatever_the_batch(resident,
+                                                           monkeypatch):
+    """Bit for bit, a question alone is the same question in a batch
+    split past the largest rung, in a batch cut into many accumulate
+    steps, and beside questions that take a second resident step."""
+    ms, seg, _ = resident
+    monkeypatch.setattr(SegmentSearcher, "MAXSCORE_CAND_CAP", 0)
+    nodes = _questions(29, 60) + [_many_resident(seg, RESIDENT_ROWS),
+                                  QAnd([QTerm("w0"), QTerm("w5")])] + \
+        _questions(30, 8)
+    with _Builds() as b:
+        whole = seg.topk_batch(nodes, 10)
+        alone = [seg.topk_batch([q], 10)[0] for q in nodes]
+    assert (b.jax, b.ledger) == (0, 0)
+    assert _bits(whole) == _bits(alone)
+    monkeypatch.setattr(
+        bm25_ops, "score_rungs",
+        lambda nd, cap, acc: (bm25_ops.Rung(32, 3, 1, 5),))
+    assert _bits(seg.topk_batch(nodes, 10)) == _bits(alone)
+
+
+def test_resident_prebuild_builds_every_program_and_500_questions_none(
+        resident):
+    """`prebuild` builds the plane programs and the resident steps — 24
+    + 12 in a fresh process — and every program of both sets is built
+    after it; 500 mixed questions in batches of every size, and 40
+    through the batcher, build nothing and look up only those families."""
+    ms, seg, (built, compiled) = resident
+    store, rows = _rows_of(seg)
+    rungs = seg._rungs(store)
+    keys = bm25_ops.resident_program_keys(rungs)
+    assert len(keys) == len(set(keys)) == 12
+    assert set(compiled) <= {"bm25_accumulate", "bm25_topk",
+                             "bm25_resident", "dense_build"}
+    assert compiled.get("bm25_resident", 0) <= 12
+    assert built == sum(n for f, n in compiled.items()
+                        if f != "dense_build") <= 36
+    for nq, with_hits, first in keys:
+        assert bm25_ops._resident_program(rows, nq, first,
+                                          with_hits).called
+    kk = min(bm25_ops.pad_k(1), store.ndocs_pad)
+    for rung, with_hits, first in bm25_ops.plane_program_keys(rungs):
+        assert (bm25_ops._topk_program(
+            store.ndocs_pad, rung.nq, with_hits, kk,
+            first == bm25_ops.MASKED)
+            if first in (None, bm25_ops.MASKED) else
+            bm25_ops._accumulate_program(store, rung, with_hits, first,
+                                         "bm25")).called
+    nodes = _questions(77, 500)
+    rng = np.random.default_rng(9)
+    before = _ledger("hits", "misses")
+    with _Builds() as b:
+        at = 0
+        while at < len(nodes):
+            n = int(rng.choice([1, 2, 5, 8, 9, 31, 32, 33]))
+            seg.topk_batch(nodes[at:at + n], int(rng.integers(1, 11)))
+            at += n
+        outs = [None] * 40
+        bar = threading.Barrier(40)
+
+        def submit(i):
+            bar.wait(timeout=30)
+            outs[i] = batched_topk(ms, nodes[i], 10)[0]
+        ts = [threading.Thread(target=submit, args=(i,)) for i in range(40)]
+        [t.start() for t in ts]
+        [t.join(timeout=120) for t in ts]
+    assert (b.jax, b.ledger) == (0, 0)
+    moved = {f for f in _ledger("hits", "misses", since=before)
+             if f.startswith(("bm25_", "dense_"))}
+    assert moved == {"bm25_accumulate", "bm25_topk", "bm25_resident"}
+    assert ms.prebuild() == 0
