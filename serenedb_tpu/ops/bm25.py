@@ -28,7 +28,7 @@ from __future__ import annotations
 
 import functools
 import os
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from typing import NamedTuple, Optional
 
 import jax
@@ -395,18 +395,68 @@ def _bucket_tables(store: BlockStore, tid: int, avg: float, k1: float,
     return tab
 
 
+def _row_bounds(store: BlockStore, tid: int, w: float, avg: float,
+                k1: float, b: float, scorer: str):
+    """Heavy term `tid`'s global block rows, and each row's upper bound
+    w·sat(block_max_tf, block_min_dl)."""
+    r = np.arange(int(store.block_offsets[tid]),
+                  int(store.block_offsets[tid + 1]), dtype=np.int64)
+    return r, w * _sat_exact(store.block_bmax_tf[r], store.block_bmin_dl[r],
+                             k1, b, avg, scorer)
+
+
 @dataclass
 class WandPlan:
     """Threshold + bounds for one pure-disjunction query (WAND family).
 
     theta: lower bound on the k-th final score (from exact champion
-    scoring); maxscore: {tid: w·max sat} for every query term; kept:
-    {tid: surviving global block-row indices} for heavy terms after
-    block-max row pruning against theta."""
+    scoring); maxscore: {tid: w·max sat} for every query term; terms:
+    (tid, w) of the heavy terms, then of the light ones; scoring: the
+    (avg, k1, b, scorer) the bounds are taken with. `kept` gives a heavy
+    term's surviving block rows, computed on the first request for that
+    term: a question the host scores reads only theta and maxscore, and
+    a resident term's row is added whole, so neither asks."""
 
     theta: float
     maxscore: dict
-    kept: dict
+    terms: list
+    scoring: tuple
+    _kept: dict = field(default_factory=dict)
+
+    def kept(self, store: BlockStore, tid: int) -> np.ndarray:
+        """Global block-row indices of heavy term `tid` after block-max
+        row pruning against theta: a row is dropped when its own
+        w·sat(block_max_tf, block_min_dl) plus, for every OTHER term of
+        the question, the max of that term's per-bucket upper bounds
+        over the row's doc range (sparse-table range-max, cached per
+        segment) cannot reach theta."""
+        hit = self._kept.get(tid)
+        if hit is not None:
+            return hit
+        avg, k1, b, scorer = self.scoring
+        w = next(w for t, w in self.terms if t == tid)
+        r, ub = _row_bounds(store, tid, w, avg, k1, b, scorer)
+        if len(r):
+            # doc-space bucket size: ≥1024 docs, ≤16384 buckets
+            shift = 10
+            while (store.ndocs_pad >> shift) + 1 > 16384:
+                shift += 1
+            s, e = int(store.offsets[tid]), int(store.offsets[tid + 1])
+            loc = r - r[0]
+            first = store.flat_docs[s + loc * BLOCK]
+            last = store.flat_docs[np.minimum(s + (loc + 1) * BLOCK, e) - 1]
+            lo_b, hi_b = first >> shift, last >> shift
+            other = np.zeros(len(r))
+            for tid2, w2 in self.terms:
+                if tid2 == tid:
+                    continue
+                tab = _bucket_tables(store, tid2, avg, k1, b, scorer, shift)
+                other += w2 * np.maximum(_range_max(tab, lo_b, hi_b), 0.0)
+            r = r[ub + other >= self.theta]
+        # a plan is shared through the store's memo: two threads that
+        # race here compute the same rows, and either may store them
+        self._kept[tid] = r
+        return r
 
 
 def wand_plan(store: BlockStore, term_ids, idf: np.ndarray, k: int,
@@ -425,11 +475,11 @@ def wand_plan(store: BlockStore, term_ids, idf: np.ndarray, k: int,
        cannot alone lift a doc into the top-k, so candidate docs are the
        remaining ("essential") terms' postings only — selective queries
        collapse to a small sparse scoring problem.
-    2. Block-row pruning for the dense path: a heavy block row is dropped
-       when its own w·sat(block_max_tf, block_min_dl) plus, for every
-       OTHER query term, the max of that term's per-bucket upper bounds
-       over the row's doc range (sparse-table range-max, cached per
-       segment) cannot reach θ.
+    2. Block-row pruning for the dense path (`WandPlan.kept`, per heavy
+       term on request): a heavy block row is dropped when its own
+       w·sat(block_max_tf, block_min_dl) plus, for every OTHER query
+       term, the max of that term's per-bucket upper bounds over the
+       row's doc range cannot reach θ.
 
     Both preserve exact top-k: any doc losing a contribution is provably
     below the true k-th score. Returns None when not applicable (θ=0 or
@@ -446,10 +496,7 @@ def wand_plan(store: BlockStore, term_ids, idf: np.ndarray, k: int,
     rows_per, ub_per = [], []
     maxscore = {}
     for tid, w in heavy_ts:
-        b0, b1 = int(store.block_offsets[tid]), int(store.block_offsets[tid + 1])
-        r = np.arange(b0, b1, dtype=np.int64)
-        ub = w * _sat_exact(store.block_bmax_tf[r], store.block_bmin_dl[r],
-                            k1, b, avg, scorer)
+        r, ub = _row_bounds(store, tid, w, avg, k1, b, scorer)
         rows_per.append(r)
         ub_per.append(ub)
         maxscore[tid] = float(ub.max()) if len(ub) else 0.0
@@ -502,29 +549,8 @@ def wand_plan(store: BlockStore, term_ids, idf: np.ndarray, k: int,
     if theta <= 0.0:
         return None
 
-    # doc-space bucket size: ≥1024 docs, ≤16384 buckets
-    shift = 10
-    while (store.ndocs_pad >> shift) + 1 > 16384:
-        shift += 1
-    kept = {}
-    for (tid, _w), r, ub in zip(heavy_ts, rows_per, ub_per):
-        if len(r) == 0:
-            kept[tid] = r
-            continue
-        b0 = int(store.block_offsets[tid])
-        s, e = int(store.offsets[tid]), int(store.offsets[tid + 1])
-        loc = r - b0
-        first = store.flat_docs[s + loc * BLOCK]
-        last = store.flat_docs[np.minimum(s + (loc + 1) * BLOCK, e) - 1]
-        lo_b, hi_b = first >> shift, last >> shift
-        other = np.zeros(len(r))
-        for tid2, w2 in heavy_ts + light_ts:
-            if tid2 == tid:
-                continue
-            tab = _bucket_tables(store, tid2, avg, k1, b, scorer, shift)
-            other += w2 * np.maximum(_range_max(tab, lo_b, hi_b), 0.0)
-        kept[tid] = r[ub + other >= theta]
-    return WandPlan(theta=theta, maxscore=maxscore, kept=kept)
+    return WandPlan(theta=theta, maxscore=maxscore,
+                    terms=heavy_ts + light_ts, scoring=(avg, k1, b, scorer))
 
 
 def assemble_query_batch(store: BlockStore, n_docs: int,
@@ -538,8 +564,9 @@ def assemble_query_batch(store: BlockStore, n_docs: int,
     searches.
 
     plans: optional per-query WandPlan list (see wand_plan) — a plan's
-    kept-rows replace the term's full block-row span, dropping rows
-    provably unable to reach the top-k before the device gather.
+    kept rows of each heavy term asked here replace the term's full
+    block-row span, dropping rows provably unable to reach the top-k
+    before the device gather.
     """
     rows, row_w, row_q = [], [], []
     rrows, rrow_w, rrow_q = [], [], []
@@ -555,15 +582,15 @@ def assemble_query_batch(store: BlockStore, n_docs: int,
             idf = np.asarray(idf_of(tid_arr), dtype=np.float32)
         else:
             idf = idf_for(scorer, n_docs, doc_freq[tid_arr])
-        kept = None
+        plan = None
         if plans is not None and plans[qi] is not None and req == 0:
-            kept = plans[qi].kept
+            plan = plans[qi]
         for k, tid in enumerate(term_ids):
             tid = int(tid)
             w = float(idf[k])
             if store.heavy[tid]:
-                if kept is not None:
-                    r = kept[tid].astype(np.int64)
+                if plan is not None:
+                    r = plan.kept(store, tid)
                 else:
                     b0 = int(store.block_offsets[tid])
                     b1 = int(store.block_offsets[tid + 1])
@@ -1030,7 +1057,9 @@ def _mesh_score_fn(mesh_n: int, ndocs_pad: int, k: int, n_queries: int,
                 raw_qid, tail_docs, tail_tfs, tail_dls, tail_w, tail_qid,
                 ndocs_pad, n_queries, False, k1, b, avgdl, scorer)
             scores = jax.lax.psum(scores, AXIS)
-            return jax.lax.top_k(scores.reshape(n_queries, ndocs_pad), k)
+            # a tuple, as out_specs is: top_k returns a list
+            return tuple(jax.lax.top_k(scores.reshape(n_queries, ndocs_pad),
+                                       k))
 
         return step
 

@@ -906,22 +906,25 @@ class SegmentSearcher:
                     self._resident_rows(scorer, avgdl)
                 n_resident = 0
                 if rows is not None:
-                    # the resident terms' rows are added whole; only the
-                    # other terms' postings go to the accumulate steps
+                    # the resident terms' rows are added whole (their
+                    # whole lists count); only the other terms'
+                    # postings go to the accumulate steps, and only
+                    # they ask their plan for kept rows
                     held = [rows.row_of[t] >= 0 for t, _ in queries]
                     slots, _ = bm25_ops.dense_slots(
                         [(t[h], r) for (t, r), h in zip(queries, held)],
                         self.num_docs, self.index.doc_freq, scorer, idf_of)
                     resident = (rows, [(rows.row_of[t], w)
                                        for t, w in slots])
-                    n_resident = self._resident_postings(
-                        store, slots, queries, plans)
+                    n_resident = sum(int(self.index.doc_freq[t].sum())
+                                     for t, _ in slots)
                     queries = [(t[~h], r)
                                for (t, r), h in zip(queries, held)]
                 qb = bm25_ops.assemble_query_batch(
                     store, self.num_docs, queries, self.index.doc_freq,
                     scorer, idf_of=idf_of, plans=plans)
                 n_postings = qb.n_postings + n_resident
+            self._count_bounds(store, plans, shapes, queries)
         if live:
             t_d = time.perf_counter_ns()
             # `device_prepare` wraps the dispatch's calls, as it wraps a
@@ -965,18 +968,19 @@ class SegmentSearcher:
                                       host_results, k, scorer, idf_of,
                                       avgdl_override, nd_pad, tiers)
 
-    def _resident_postings(self, store, slots, queries, plans) -> int:
-        """The postings the resident terms' rows stand for, counted as
-        `assemble_query_batch` counts the rows it hands over: a pruned
-        disjunction's kept rows, else the whole list."""
-        n = 0
-        for qi, (t, _w) in enumerate(slots):
-            plan = plans[qi] if queries[qi][1] == 0 else None
-            for tid in t.tolist():
-                n += int(store.row_count[plan.kept[tid]].sum()) \
-                    if plan is not None and store.heavy[tid] \
-                    else int(self.index.doc_freq[tid])
-        return n
+    @staticmethod
+    def _count_bounds(store, plans, shapes, queries) -> None:
+        """A planned question's heavy terms: the block-row bounds
+        `assemble_query_batch` asks of its plan (the terms left in
+        `queries`) and those never asked (resident terms, which are
+        added whole, and every term of a question the host scored)."""
+        planned = [qi for qi, plan in enumerate(plans) if plan is not None]
+        heavy = sum(int(store.heavy[np.asarray(shapes[qi][0],
+                                               dtype=np.int64)].sum())
+                    for qi in planned)
+        asked = sum(int(store.heavy[queries[qi][0]].sum()) for qi in planned)
+        metrics.SEARCH_PLAN_BOUNDS_COMPUTED.add(asked)
+        metrics.SEARCH_PLAN_BOUNDS_SKIPPED.add(heavy - asked)
 
     def _finish_batch(self, nodes, shapes, vals, docs, host_results, k,
                       scorer, idf_of, avgdl_override, nd_pad, tiers,
@@ -1073,13 +1077,13 @@ class SegmentSearcher:
             ess = [int(t) for t in tids]
         parts = []
         for tid in ess:
-            if store.heavy[tid] and tid in plan.kept:
-                # block-max pruning: plan.kept already dropped rows that
-                # can't reach θ together with the other terms' bounds
+            if store.heavy[tid]:
+                # block-max pruning: plan.kept drops rows that can't
+                # reach θ together with the other terms' bounds
                 s = int(store.offsets[tid])
                 b0 = int(store.block_offsets[tid])
                 e = int(store.offsets[tid + 1])
-                loc = plan.kept[tid] - b0
+                loc = plan.kept(store, tid) - b0
                 if len(loc) == 0:
                     continue
                 spans = [store.flat_docs[s + i * bm25_ops.BLOCK:

@@ -441,8 +441,18 @@ SEARCH_POSTINGS_RESIDENT = REGISTRY.gauge(
     "SearchPostingsResident",
     "the postings, within SearchPostingsDispatched, whose term's whole "
     "saturation row was added to the score plane in place of a "
-    "scatter: a resident row of the plane kernel, or a row of the "
-    "dense path's matrix")
+    "scatter, each term's whole list: a resident row of the plane "
+    "kernel, or a row of the dense path's matrix")
+SEARCH_PLAN_BOUNDS_COMPUTED = REGISTRY.gauge(
+    "SearchPlanBoundsComputed",
+    "heavy terms of a WAND-planned question whose block-row bounds "
+    "(WandPlan.kept) the plane rung asked for, to scatter the rows "
+    "they keep; one per heavy term per planned question")
+SEARCH_PLAN_BOUNDS_SKIPPED = REGISTRY.gauge(
+    "SearchPlanBoundsSkipped",
+    "heavy terms of a WAND-planned question whose block-row bounds "
+    "were never computed: a resident term (its row is added whole) "
+    "or any term of a question the host rung scored")
 SEARCH_PROGRAMS_PREBUILT = REGISTRY.gauge(
     "SearchProgramsPrebuilt",
     "scoring programs built by an index build or refresh before the "

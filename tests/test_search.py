@@ -320,7 +320,6 @@ def test_wand_prune_never_drops_topk_docs():
                               "bm25")
     if plan is None:
         return  # nothing prunable on this corpus — parity covered above
-    kept = plan.kept
     ref_s, ref_d = searcher._cpu_score(
         np.arange(searcher.num_docs, dtype=np.int32), tids, k)
     for d in ref_d:
@@ -334,7 +333,7 @@ def test_wand_prune_never_drops_topk_docs():
             if i >= len(pd) or pd[i] != d:
                 continue  # term doesn't hit this doc
             row = int(store.block_offsets[tid]) + i // 128
-            assert row in set(kept[tid].tolist()), (d, tid)
+            assert row in set(plan.kept(store, tid).tolist()), (d, tid)
 
 
 def test_query_batch_chunking_parity():

@@ -1075,3 +1075,236 @@ def test_resident_prebuild_builds_every_program_and_500_questions_none(
              if f.startswith(("bm25_", "dense_"))}
     assert moved == {"bm25_accumulate", "bm25_topk", "bm25_resident"}
     assert ms.prebuild() == 0
+
+
+# -- a question's WAND plan computes block-row bounds only where they are read --
+
+def _plan_mix(seg):
+    """Questions of which the default MAXSCORE_CAND_CAP sends some to
+    the host rung and some to the plane rung: generated ones, unions of
+    resident terms with a few heavy terms the budget did not hold, and
+    unions of more frequent terms than are resident."""
+    rng = np.random.default_rng(3)
+    order = np.argsort(-seg.index.doc_freq, kind="stable")
+
+    def union(ranks):
+        return QOr([QTerm(str(seg.index.terms_str[order[i]]))
+                    for i in ranks])
+    mixed = [union(np.concatenate([
+        rng.choice(RESIDENT_ROWS, int(rng.integers(2, 12)), replace=False),
+        rng.choice(np.arange(RESIDENT_ROWS, 120), int(rng.integers(1, 5)),
+                   replace=False)])) for _ in range(14)]
+    return _questions(11, 12) + mixed + \
+        [_many_resident(seg, n) for n in (20, 28, 30)]
+
+
+def _planned(seg, monkeypatch, eager=False):
+    """Every WAND plan the searcher makes from here on, by its question's
+    term ids, from an empty plan memo; `eager` asks each plan for the
+    kept rows of every heavy term as soon as it is made."""
+    seen = {}
+    plan_of = SegmentSearcher._wand_plan_cached
+
+    def spy(self, store, tids, *args):
+        plan = plan_of(self, store, tids, *args)
+        if plan is not None and eager:
+            for t in tids:
+                if store.heavy[int(t)]:
+                    plan.kept(store, int(t))
+        seen[tuple(int(t) for t in tids)] = plan
+        return plan
+    monkeypatch.setattr(SegmentSearcher, "_wand_plan_cached", spy)
+    vars(seg._device_store()).pop("_plan_cache", None)
+    return seen
+
+
+def _reference_kept(seg, tids, theta):
+    """Every heavy term's block rows that may reach `theta`, all at once
+    as the bound is defined: a row's own upper bound plus, for every
+    other term of the question (heavy ones first, then light ones, each
+    in question order), the range max of that term's per-bucket bounds
+    over the row's documents."""
+    store, fi = seg._device_store(), seg.index
+    avg = fi.avgdl
+    idf = bm25_ops.idf_for("bm25", seg.num_docs,
+                           fi.doc_freq[np.asarray(tids, dtype=np.int64)])
+    terms = [(int(t), float(w)) for t, w in zip(tids, idf)]
+    terms = [x for x in terms if store.heavy[x[0]]] + \
+        [x for x in terms if not store.heavy[x[0]]]
+    shift = 10
+    while (store.ndocs_pad >> shift) + 1 > 16384:
+        shift += 1
+    out = {}
+    for tid, w in terms:
+        if not store.heavy[tid]:
+            continue
+        b0 = int(store.block_offsets[tid])
+        r = np.arange(b0, int(store.block_offsets[tid + 1]), dtype=np.int64)
+        ub = w * bm25_ops._sat_exact(store.block_bmax_tf[r],
+                                     store.block_bmin_dl[r], 1.2, 0.75, avg,
+                                     "bm25")
+        s, e = int(store.offsets[tid]), int(store.offsets[tid + 1])
+        first = store.flat_docs[s + (r - b0) * 128] >> shift
+        last = store.flat_docs[np.minimum(s + (r - b0 + 1) * 128, e) - 1] \
+            >> shift
+        other = np.zeros(len(r))
+        for tid2, w2 in terms:
+            if tid2 != tid:
+                tab = bm25_ops._bucket_tables(store, tid2, avg, 1.2, 0.75,
+                                              "bm25", shift)
+                other += w2 * np.maximum(
+                    bm25_ops._range_max(tab, first, last), 0.0)
+        out[tid] = r[ub + other >= theta]
+    return out
+
+
+def _bounds_wanted(seg, nodes, seen, tiers):
+    """Per planned question (term ids, plan, the heavy terms the plane
+    rung scatters: the non-resident ones of a question it scored, none
+    of a question the host scored)."""
+    store, rows = _rows_of(seg)
+    out = []
+    for q, tier in zip(nodes, tiers):
+        tids = tuple(int(t) for t in seg._query_shape(q)[0])
+        plan = seen.get(tids)
+        if plan is None:
+            continue
+        out.append((tids, plan, set() if tier == "host" else
+                    {t for t in tids
+                     if store.heavy[t] and rows.row_of[t] < 0}))
+    return out
+
+
+def test_a_lazy_plan_answers_bit_for_bit_as_an_eager_one(resident,
+                                                        monkeypatch):
+    """On a store with resident rows, a mix of questions at the default
+    candidate cap answers bit for bit whether each plan computes the
+    kept rows of the terms asked or of every heavy term; the rows a
+    device-scored question's non-resident heavy terms keep are the
+    definition's, and those of the eager plan's every heavy term too."""
+    ms, seg, _ = resident
+    nodes = _plan_mix(seg)
+    tiers = [None] * len(nodes)
+    seen = _planned(seg, monkeypatch)
+    lazy = seg.topk_batch(nodes, 10, tiers=tiers)
+    wanted = _bounds_wanted(seg, nodes, seen, tiers)
+    eager_seen = _planned(seg, monkeypatch, eager=True)
+    eager_tiers = [None] * len(nodes)
+    assert _bits(seg.topk_batch(nodes, 10, tiers=eager_tiers)) == _bits(lazy)
+    assert eager_tiers == tiers
+    store = seg._device_store()
+    scattered = 0
+    for tids, plan, want in wanted:
+        ref = _reference_kept(seg, tids, plan.theta)
+        assert set(plan._kept) == want
+        for t in want:
+            assert np.array_equal(plan._kept[t], ref[t]), t
+        scattered += len(want)
+        eager = eager_seen[tids]
+        assert eager.theta == plan.theta
+        assert set(eager._kept) == set(ref)
+        for t, r in ref.items():
+            assert eager.kept(store, t).dtype == np.int64
+            assert np.array_equal(eager.kept(store, t), r), t
+    hosted = sum(tier == "host" for tier in tiers)
+    assert scattered and hosted and len(wanted) > hosted
+
+
+def test_no_bound_is_computed_for_a_resident_term_or_a_hosted_question(
+        resident, monkeypatch):
+    """The plane rung computes a block-row bound only for the heavy terms
+    it scatters: `_range_max` runs once per (such term, other term of
+    its question), and `SearchPlanBoundsComputed` /
+    `SearchPlanBoundsSkipped` count every heavy term of a planned
+    question once, skipped where it is resident or its question went
+    to the host."""
+    ms, seg, _ = resident
+    calls = []
+    range_max = bm25_ops._range_max
+
+    def counted(tab, lo, hi):
+        calls.append(len(lo))
+        return range_max(tab, lo, hi)
+    monkeypatch.setattr(bm25_ops, "_range_max", counted)
+    nodes = _plan_mix(seg)
+    tiers = [None] * len(nodes)
+    seen = _planned(seg, monkeypatch)
+    c0 = metrics.SEARCH_PLAN_BOUNDS_COMPUTED.value
+    s0 = metrics.SEARCH_PLAN_BOUNDS_SKIPPED.value
+    seg.topk_batch(nodes, 10, tiers=tiers)
+    store = seg._device_store()
+    wanted = _bounds_wanted(seg, nodes, seen, tiers)
+    heavy = sum(sum(bool(store.heavy[t]) for t in tids)
+                for tids, _, _ in wanted)
+    asked = sum(len(want) for _, _, want in wanted)
+    assert metrics.SEARCH_PLAN_BOUNDS_COMPUTED.value - c0 == asked
+    assert metrics.SEARCH_PLAN_BOUNDS_SKIPPED.value - s0 == heavy - asked
+    assert 0 < asked < heavy - asked
+    assert len(calls) == sum(len(tids) - tids.count(t)
+                             for tids, _, want in wanted for t in want)
+    assert metrics.REGISTRY.snapshot()["SearchPlanBoundsSkipped"] == \
+        metrics.SEARCH_PLAN_BOUNDS_SKIPPED.value    # what /metrics renders
+
+
+def test_the_host_reference_and_a_batch_without_resident_rows_get_every_kept_row(
+        resident, monkeypatch):
+    """`cpu_topk_wand` reads the definition's kept rows of every heavy
+    term it scores, and answers as exhaustive scoring; a batch assembled
+    with no resident rows (the mesh scorer's) carries, for every heavy
+    term of every question, the rows the definition keeps. The mesh
+    rung plans nothing: its answers are the host's, and neither counter
+    moves."""
+    from serenedb_tpu.search.searcher import _maxscore_split
+    ms, seg, _ = resident
+    store, fi = seg._device_store(), seg.index
+    nodes = _plan_mix(seg)
+    seen = _planned(seg, monkeypatch)
+    for q in nodes:
+        tids = [int(t) for t in seg._query_shape(q)[0]]
+        got = seg.cpu_topk_wand(tids, 10)
+        plan = seen[tuple(tids)]
+        if plan is None:
+            continue
+        ref = _reference_kept(seg, tids, plan.theta)
+        ess = set(tids) - _maxscore_split(plan) or set(tids)
+        assert set(plan._kept) == {t for t in ess if store.heavy[t]}
+        for t in plan._kept:
+            assert np.array_equal(plan._kept[t], ref[t]), t
+        want = seg._cpu_score(np.arange(seg.num_docs, dtype=np.int32),
+                              tids, 10)
+        _same_topk(got, (want[0][want[0] > 0], want[1][want[0] > 0]))
+
+    queries = [(np.asarray(seg._query_shape(q)[0], dtype=np.int64), 0)
+               for q in nodes]
+    plans = [seen[tuple(t.tolist())] for t, _ in queries]
+    assert sum(p is not None for p in plans) > len(plans) // 2
+
+    class Reference:
+        def __init__(self, tids, plan):
+            self.rows = _reference_kept(seg, tids.tolist(), plan.theta)
+
+        def kept(self, store, tid):
+            return self.rows[tid]
+    vars(store).pop("_plan_cache", None)
+    fresh = [seg._wand_plan_cached(store, t.tolist(), 10, fi.avgdl, "bm25",
+                                   None) for t, _ in queries]
+    got = bm25_ops.assemble_query_batch(store, seg.num_docs, queries,
+                                        fi.doc_freq, plans=fresh)
+    want = bm25_ops.assemble_query_batch(
+        store, seg.num_docs, queries, fi.doc_freq,
+        plans=[p and Reference(t, p) for (t, _), p in zip(queries, plans)])
+    for name in ("row_idx", "row_qid", "raw_idx", "raw_qid", "tail_docs"):
+        assert np.array_equal(getattr(got, name), getattr(want, name)), name
+    assert got.n_postings == want.n_postings
+    for (t, _), p in zip(queries, fresh):
+        if p is not None:
+            assert set(p._kept) == {int(x) for x in t if store.heavy[x]}
+
+    c0 = metrics.SEARCH_PLAN_BOUNDS_COMPUTED.value
+    s0 = metrics.SEARCH_PLAN_BOUNDS_SKIPPED.value
+    unions = [q for q in nodes if isinstance(q, QOr)][:8]
+    mesh = seg.topk_batch(unions, 10, mesh_n=2)
+    assert (metrics.SEARCH_PLAN_BOUNDS_COMPUTED.value,
+            metrics.SEARCH_PLAN_BOUNDS_SKIPPED.value) == (c0, s0)
+    for q, res in zip(unions, mesh):
+        _same_topk(res, seg.cpu_topk_wand(seg._query_shape(q)[0], 10))
